@@ -113,7 +113,6 @@ def test_scatter_to_buckets_roundtrip():
 def test_exchange_group_agg_all_to_all():
     """Each device owns one hash partition after all_to_all; per-key counts
     across the mesh match a host group-by."""
-    from tidb_tpu.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P_
 
     mesh = region_mesh()
@@ -139,7 +138,7 @@ def test_exchange_group_agg_all_to_all():
         total = jax.lax.psum(counts, "region")
         return total[None], overflow[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn,
         mesh=mesh,
         in_specs=(P_("region"), P_("region")),

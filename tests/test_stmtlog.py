@@ -96,8 +96,10 @@ def test_top_sql_cpu_attribution():
         "select exec_count, cpu_ns, cost_class from information_schema.tidb_top_sql "
         f"where digest = '{digest}'"
     ).values()
-    assert rows and rows[0][0] == 5 and rows[0][1] > 0
-    assert rows[0][2] in ("point", "small", "scan", "heavy")
+    # one row per window: the first, compiling execution can land in an
+    # earlier window than the other four
+    assert sum(r[0] for r in rows) == 5 and all(r[1] > 0 for r in rows)
+    assert all(r[2] in ("point", "small", "scan", "heavy") for r in rows)
     # rows come out ranked by cumulative cpu+device within each window:
     # the repeated aggregation outranks `select 1`
     top = s.execute(

@@ -281,3 +281,34 @@ def test_failpoint_catalog_generation(tmp_path):
     assert "| `pd/operator-timeout` |" in text
     for name in sites:
         assert f"| `{name}` |" in text
+
+
+# ------------------------------------------------------- chip smoke (CPU)
+
+
+def test_chip_smoke_cpu_rehearsal(tmp_path, monkeypatch, capsys):
+    """chip_smoke.py's phases 2-4 at 4,096 rows on the CPU: a PR that
+    breaks an entry point the smoke uses is caught without chip time.
+    The device assertion (phase 1) is not called and the Pallas-kernel
+    assertion is switched off here — on the CPU no kernel is traced."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    import chip_smoke as cs
+
+    from tidb_tpu.util import failpoint
+
+    monkeypatch.setattr(cs, "OUT_DIR", str(tmp_path))
+    monkeypatch.setattr(cs, "require_pallas", lambda stmt, traced: None)
+    ctx = cs.Ctx(rows=4096, seed=0)
+    try:
+        with failpoint.enabled("cop-debug-raise"):
+            cs.phase_load(ctx)
+            cs.phase_row_store(ctx)
+            cs.phase_columnar(ctx)
+    finally:
+        ctx.close()
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert [x["phase"] for x in lines if "phase" in x] == ["load", "row_store", "columnar"]
+    assert {x["stmt"] for x in lines if "stmt" in x} >= {"q6", "q1", "q3", "columnar_q1"}
+    assert not os.listdir(tmp_path)  # the data files are gone again

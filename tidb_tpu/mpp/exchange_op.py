@@ -310,14 +310,13 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
 
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.compat import shard_map
     from ..parallel.mesh import group_mesh_out_spec
 
     def wrap(stacked_probe, *stacked_builds):
         spec_p = jax.tree.map(lambda _: P(REGION_AXIS), stacked_probe)
         spec_bs = tuple(jax.tree.map(lambda _: P(REGION_AXIS), sb) for sb in stacked_builds)
-        fn = shard_map(device_fn, mesh=mesh, in_specs=(spec_p, *spec_bs),
-                       out_specs=group_mesh_out_spec(agg), check_vma=False)
+        fn = jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_p, *spec_bs),
+                           out_specs=group_mesh_out_spec(agg), check_vma=False)
         return fn(stacked_probe, *stacked_builds)
 
     return wrap

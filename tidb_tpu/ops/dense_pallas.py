@@ -43,11 +43,10 @@ therefore gated on N < MAX_ROWS (2^26); larger batches ride the XLA
 dense/sort kernels, whose int64 accumulation has no such bound
 (ADVICE r5 medium — the old docstring claimed safety for any N < 2^31).
 
-The whole pallas_call is traced under jax.enable_x64(False): this
-platform's remote Mosaic compiler rejects 64-bit grid/index arithmetic,
-and with x64 enabled globally every Python int in the blocked lowering
-becomes an i64 (measured: any gridded kernel fails to compile). The
-kernel body is pure int32 either way.
+The whole pallas_call is traced under jax.enable_x64(False): with x64
+enabled globally every Python int in the blocked lowering becomes an i64,
+and Mosaic has no 64-bit grid/index arithmetic. The kernel body is pure
+int32 either way.
 """
 
 from __future__ import annotations
@@ -59,7 +58,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..util.jaxcompat import enable_x64 as _enable_x64
 from .keys import sort_key_arrays
 
 LANES = 128
@@ -407,7 +405,7 @@ def group_aggregate_dense_pallas(group_bys, aggs, row_valid, g_cap: int, mode: s
             for g in range(G):
                 o_ref[acc_rows + 2 + g, :] = jnp.full((LANES,), repm[g], jnp.int32)
 
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         in_specs = [
             pl.BlockSpec((tr, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM)
             for _ in lanes

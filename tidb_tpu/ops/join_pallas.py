@@ -113,17 +113,20 @@ def probe_tables_pallas(b_key_tbl, b_slot_ok, p_key_tbl, p_slot_ok,
     phi, plo = _split32(p_key_tbl.reshape(-1))
 
     def btab(a):
-        return a.reshape(P, part_cap)
+        # [P, 1, part_cap]: the (1, part_cap) block then spans the array's
+        # last two dimensions whole, which is what the Mosaic lowering
+        # asks of a block that is not an (8, 128) multiple
+        return a.reshape(P, 1, part_cap)
 
     def plane(a):
         return a.reshape(P * trp, LANES)
 
     ins = [
-        btab(bhi), btab(blo), b_slot_ok.astype(jnp.int32),
+        btab(bhi), btab(blo), btab(b_slot_ok.astype(jnp.int32)),
         plane(phi), plane(plo),
         p_slot_ok.astype(jnp.int32).reshape(P * trp, LANES),
     ]
-    sspec = pl.BlockSpec((1, part_cap), lambda i: (i, 0), memory_space=pltpu.SMEM)
+    sspec = pl.BlockSpec((None, 1, part_cap), lambda i: (i, 0, 0), memory_space=pltpu.SMEM)
     vspec = pl.BlockSpec((trp, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM)
     mspec = pl.BlockSpec((8, LANES), lambda i: (0, 0), memory_space=pltpu.VMEM)
     with _x64_ctx(interpret):

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from .compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..chunk.device import DeviceBatch
@@ -248,8 +247,8 @@ def run_sharded_grouped_agg(
 
     fn = cached_exchange_program(
         dag, mesh,
-        lambda: shard_map(device_fn, mesh=mesh, in_specs=(spec_batch,),
-                          out_specs=group_mesh_out_spec(agg), check_vma=False),
+        lambda: jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_batch,),
+                              out_specs=group_mesh_out_spec(agg), check_vma=False),
         group_capacity, bcap)
     outs = fn(stacked)
     # decode: [agg results..., group keys...] with Complete-mode fts —

@@ -25,7 +25,7 @@ from ..exec.dag import Aggregation, ColumnInfo, DAGRequest, IndexScan, Join, Lim
 from ..expr.agg import AGG_FUNCS, AggDesc
 from ..expr.ir import Expr, col, const, func, lit
 from ..parser import ast as A
-from ..types import Datum, DatumKind, FieldType, Flag, MyDecimal, MyTime, TypeCode, new_datetime, new_decimal, new_double, new_longlong, new_varchar
+from ..types import Datum, DatumKind, FieldType, Flag, MyDecimal, MyTime, TypeCode, new_date, new_datetime, new_decimal, new_double, new_longlong, new_varchar
 from .catalog import Catalog, CatalogError, TableMeta, field_type_from_spec
 
 BOOL = new_longlong()
@@ -458,6 +458,11 @@ class _Lowerer:
 
     def _func_call(self, n: A.FuncCall, rec):
         name = _FUNC_RENAME.get(n.name, n.name)
+        if name in ("cast_literal_date", "cast_literal_timestamp"):
+            # DATE '1994-01-01' (the TPC-H text): a time constant, so that
+            # INTERVAL arithmetic on it needs no string cast on the device
+            ft = new_date() if name == "cast_literal_date" else new_datetime()
+            return lit(n.args[0].value, ft)
         if name in self._JSON_FUNCS:
             from ..types import new_json
 
@@ -496,6 +501,12 @@ class _Lowerer:
             if unit not in ("second", "minute", "hour", "day", "week", "month", "quarter", "year"):
                 raise PlanError(f"interval unit {unit!r} not supported")
             nexpr = rec(iv.value)
+            if isinstance(iv.value, A.Literal) and iv.value.kind == "str":
+                # INTERVAL '90' DAY (the TPC-H text): the count is a number
+                try:
+                    nexpr = lit(int(iv.value.value), new_longlong())
+                except ValueError:
+                    raise PlanError(f"interval count {iv.value.value!r} not supported") from None
             if not d.ft.is_time():
                 d = func("cast", new_datetime(), d)
             return func(name, d.ft.clone(), d, nexpr, lit(unit, new_varchar(8)))
@@ -1346,6 +1357,36 @@ def _plan_select(stmt: A.SelectStmt, catalog: Catalog, mat: dict | None = None, 
                 others = [i for i in range(len(flat)) if aliases_flat[i] != hb]
                 probe_i = max(others, key=lambda i: est[i])
         flat = [flat[probe_i]] + flat[:probe_i] + flat[probe_i + 1 :]
+        # build sides keep their textual order, except that a table an
+        # equi condition ties to the tables already placed goes before
+        # one that would join as a cartesian product (TPC-H Q3 lists
+        # customer before orders; lineitem x customer has no key)
+        conj = _split_conjuncts(stmt.where)
+        for _, _, _, on in flat:
+            if on is not None:
+                conj.extend(_split_conjuncts(on))
+        links = []
+        for c in conj:
+            sides = None if isinstance(c, A.SemiJoinCond) else _equi_sides(c)
+            if sides is None:
+                continue
+            try:
+                lt, rt = tmp_scope.tables_of(sides[0]), tmp_scope.tables_of(sides[1])
+            except PlanError:
+                continue
+            if len(lt) == 1 and len(rt) == 1 and lt != rt:
+                links.append(lt | rt)
+        ordered, rest = [flat[0]], flat[1:]
+        placed_ = {flat[0][1]}
+        while rest:
+            nxt = next(
+                (f for f in rest if any(f[1] in t and t - {f[1]} <= placed_ for t in links)),
+                rest[0],
+            )
+            rest.remove(nxt)
+            ordered.append(nxt)
+            placed_.add(nxt[1])
+        flat = ordered
 
     # ---- scope over the combined schema in placement order
     trefs = []
@@ -1798,8 +1839,11 @@ def _ndv_group_hint(dag: DAGRequest, trefs: list, catalog: Catalog, cap: int = 5
     column NDVs bounds the group count."""
     from ..expr.ir import ColumnRef
 
-    agg = dag.executors[-1] if dag.executors else None
-    if not isinstance(agg, Aggregation) or not agg.group_by:
+    # the first Aggregation, not the last executor: ORDER BY / HAVING /
+    # a projection after it (every TPC-H text has one) leave its keys,
+    # which index the aggregation's input, where they were
+    agg = next((e for e in dag.executors if isinstance(e, Aggregation)), None)
+    if agg is None or not agg.group_by:
         return None
     product = 1
     for g in agg.group_by:
