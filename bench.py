@@ -40,14 +40,6 @@ import numpy as np
 # persistent cache makes every bench run after the first start in seconds
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(os.path.dirname(__file__), ".xla_cache"))
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1.0")
-# XLA TPU mis-sizes scoped vmem for fused int64 (u32-pair) cumsum
-# reduce-windows ("It should not be possible to run out of scoped vmem —
-# please file a bug against XLA"); raising the documented knob unblocks the
-# group-by kernels. Harmless on CPU (ignored).
-if "--xla_tpu_scoped_vmem_limit_kib" not in os.environ.get("LIBTPU_INIT_ARGS", ""):
-    os.environ["LIBTPU_INIT_ARGS"] = (
-        os.environ.get("LIBTPU_INIT_ARGS", "") + " --xla_tpu_scoped_vmem_limit_kib=49152"
-    ).strip()
 
 
 def log(*a):
@@ -69,9 +61,27 @@ CPU_ROWS = 1 << 19
 PARITY_ROWS = 1 << 12
 ORACLE_ROWS = 1 << 13
 ITERS = 8
-# generous upper bound on single-chip HBM bandwidth (v5e ~0.82 TB/s,
-# v5p ~2.77 TB/s); any claimed number above this is a measurement bug
-HBM_ROOFLINE_GBS = 3000.0
+# published peak HBM bandwidth of one chip in GB/s, keyed by
+# jax.devices()[0].device_kind; any claimed number above it is a
+# measurement bug. Source: Google Cloud documentation, "TPU v5e"
+# (16 GB HBM2e, 819 GB/s). A device that is not here is an error.
+HBM_PEAK_GBS = {"TPU v5 lite": 819.0}
+
+
+def chip_peak_gbs(dev) -> float:
+    """The published HBM peak of the chip the default mode measures on.
+    No TPU, or a device kind that is not in the table, is an error."""
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"bench.py's default mode needs a TPU; JAX reports {dev.platform} ({dev.device_kind})"
+        )
+    try:
+        return HBM_PEAK_GBS[dev.device_kind]
+    except KeyError:
+        raise SystemExit(
+            f"no published HBM bandwidth for device kind {dev.device_kind!r}: "
+            "add it to HBM_PEAK_GBS with its source"
+        ) from None
 
 
 # --------------------------------------------------------------------------
@@ -363,9 +373,10 @@ def _make_loop(prog_fn, batches, K):
     return jax.jit(loop_fn)
 
 
-def bench_config(cfg, device, n, iters, loop_k=None):
+def bench_config(cfg, device, n, iters, loop_k=None, peak_gbs=None):
     """(rows/s median, GB/s, spread%, checksum): K-deep on-device loop per
-    timed call, block_until_ready around each call.
+    timed call, block_until_ready around each call. peak_gbs: the device's
+    published HBM peak (chip_peak_gbs); None (the CPU baseline) = no check.
 
     Capacities resolve through the SAME overflow-retry contract production
     uses (exec/executor.py:83 drive_program): grow the knob that overflowed
@@ -389,7 +400,8 @@ def bench_config(cfg, device, n, iters, loop_k=None):
                 topn_full=tf, small_groups=smg, unique_joins=uj, radix_joins=rj,
                 # summaries stay ON: removing the per-executor row-count
                 # reduces measured no speedup (they fuse), and the
-                # reduce-free q3 program SIGSEGVs this platform's compiler
+                # reduce-free q3 program SIGSEGV'd the TPU compiler
+                # (2026-07-31, not repeated since)
             )
             out = jax.block_until_ready(prog.fn(*batches))
             packed, valid, _, (g_ovf, j_ovf, t_ovf, g_need, j_need, _esc), _ = out
@@ -419,16 +431,14 @@ def bench_config(cfg, device, n, iters, loop_k=None):
         chunk = decode_outputs(packed, valid, prog.out_fts)
         K = loop_k or LOOP_K.get(cfg.name, 128)
         loop = _make_loop(prog.fn, batches, K)
-        # timing fetches the int64 carry VALUE: a host fetch of the
-        # data-dependent scalar ends only when the K passes have run
         t0 = time.perf_counter()
-        int(loop(*batches))
+        jax.block_until_ready(loop(*batches))
         compile_s = time.perf_counter() - t0  # trace+compile dominate call 1
         log(f"  [{cfg.name}/{device.platform}] compile+first: {compile_s:.2f}s")
         times = []
         for _ in range(iters):
             t0 = time.perf_counter()
-            int(loop(*batches))
+            jax.block_until_ready(loop(*batches))
             times.append(time.perf_counter() - t0)
         med = statistics.median(times)
         # spread trims the single worst sample WHEN there are enough
@@ -446,9 +456,11 @@ def bench_config(cfg, device, n, iters, loop_k=None):
         rows = sum(int(b.n_rows) for b in batches)
         rps = rows * K / med
         gbs = nbytes * K / med / 1e9
-        assert gbs <= HBM_ROOFLINE_GBS, (
-            f"{cfg.name}: claimed {gbs:.0f} GB/s exceeds any plausible HBM roofline — measurement bug"
-        )
+        if peak_gbs is not None and gbs > peak_gbs:
+            raise RuntimeError(
+                f"{cfg.name}: claimed {gbs:.0f} GB/s exceeds the {device.device_kind}'s "
+                f"published {peak_gbs:.0f} GB/s HBM peak — measurement bug"
+            )
         return rps, gbs, spread, _checksum(chunk), compile_s
 
 
@@ -517,24 +529,23 @@ def bench_oracle(cfg, n=ORACLE_ROWS):
     return sum(c.num_rows() for c in chunks) / dt
 
 
-def _cpu_baseline_subprocess() -> dict:
-    """All five configs on the XLA-CPU backend in a process of their own.
-    Returns {config: rows/s}."""
-    import os
+def _cpu_child() -> dict:
+    """The parity gates and the XLA-CPU baseline of all five configs, in a
+    child of their own with JAX_PLATFORMS=cpu. Such a process never loads
+    the TPU library (shown here by running one beside a process that held
+    libtpu's lock), so it can run beside the parent that holds the chip.
+    Returns {"parity": {config: "ok" | error}, "rows_per_sec": {config: n}}."""
     import subprocess
 
     env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_CPU_ONLY="1")
-    try:
-        out = subprocess.run(
-            [sys.executable, __file__], env=env, capture_output=True, text=True, timeout=1200
-        )
-        sys.stderr.write(out.stderr[-2000:])
-        for line in out.stdout.strip().splitlines():
-            if line.startswith("{"):
-                return json.loads(line)
-    except Exception as exc:  # noqa: BLE001
-        log(f"  cpu baseline subprocess failed: {exc}")
-    return {}
+    out = subprocess.run(
+        [sys.executable, __file__], env=env, capture_output=True, text=True, timeout=1800
+    )
+    sys.stderr.write(out.stderr[-4000:])
+    for line in out.stdout.strip().splitlines():
+        if line.startswith("{"):
+            return json.loads(line)
+    raise RuntimeError(f"cpu child printed no result (rc={out.returncode})")
 
 
 def _cpu_config_rows(name: str) -> int:
@@ -548,12 +559,19 @@ def _cpu_only_main():
 
     jax.config.update("jax_platforms", "cpu")
     cpu = jax.devices("cpu")[0]
-    out = {}
+    out = {"parity": {}, "rows_per_sec": {}}
     for cfg in _configs():
+        try:
+            parity_gate(cfg)
+            out["parity"][cfg.name] = "ok"
+            log(f"  [{cfg.name}] parity gate vs oracle: OK")
+        except Exception as exc:  # noqa: BLE001 — the parent fails the config
+            out["parity"][cfg.name] = f"{type(exc).__name__}: {exc}"
+            log(f"  [{cfg.name}] parity gate FAILED: {exc}")
         try:
             rps, gbs, spread, _, _c = bench_config(cfg, cpu, _cpu_config_rows(cfg.name), 3, loop_k=CPU_LOOP_K)
             log(f"  [{cfg.name}/cpu-subprocess] {rps/1e6:.2f} Mrows/s, {gbs:.1f} GB/s, spread {spread:.0f}%")
-            out[cfg.name] = rps
+            out["rows_per_sec"][cfg.name] = rps
         except Exception as exc:  # noqa: BLE001
             log(f"  [{cfg.name}/cpu-subprocess] failed: {exc}")
     print(json.dumps(out))
@@ -692,69 +710,6 @@ def _batch_cop_main():
         "wall_ms_batched": round(t_batch * 1e3, 2),
         "speedup": round(t_plain / max(t_batch, 1e-9), 2),
     }))
-
-
-def _config_rows(name: str) -> int:
-    # every config now runs the full 4M-row resident batch: q3's packed
-    # join+groupsum kernel (r5) compiles in ~75s warm-cache at 4M — the
-    # old fused mega-program needed ROWS//16 to compile at all
-    return ROWS
-
-
-def _parity_only_main(name: str):
-    """Grandchild process: the small-N parity diff on hermetic CPU."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-    cfg = next(c for c in _configs() if c.name == name)
-    parity_gate(cfg)
-    print("PARITY_OK")
-
-
-def _one_config_main(name: str):
-    """Child process: parity (isolated CPU subprocess — running it on the
-    in-process TPU backend left the device in a state where the subsequent
-    4M-row loop failed with INVALID_ARGUMENT) + accel measurement."""
-    import subprocess
-
-    import jax
-
-    cfg = next(c for c in _configs() if c.name == name)
-    env = dict(os.environ, BENCH_PARITY=name, JAX_PLATFORMS="cpu")
-    env.pop("BENCH_ONE", None)
-    out = subprocess.run([sys.executable, __file__], env=env, capture_output=True, text=True, timeout=900)
-    if "PARITY_OK" not in out.stdout:
-        sys.stderr.write(out.stderr[-3000:])
-        raise RuntimeError(f"{name}: parity gate failed")
-    log(f"  [{name}] parity gate vs oracle: OK")
-    rps, gbs, spread, csum, compile_s = bench_config(cfg, jax.devices()[0], _config_rows(name), ITERS)
-    print(json.dumps({
-        "mrows_per_sec": round(rps / 1e6, 2),
-        "gb_per_sec": round(gbs, 1),
-        "spread_pct": round(spread, 1),
-        "compile_s": round(compile_s, 2),
-        "checksum": csum,
-    }))
-
-
-def _run_config_subprocess(name: str, budget: int):
-    import os
-    import subprocess
-
-    env = dict(os.environ, BENCH_ONE=name)
-    try:
-        out = subprocess.run(
-            [sys.executable, __file__], env=env, capture_output=True, text=True, timeout=budget
-        )
-        sys.stderr.write(out.stderr)
-        for line in out.stdout.strip().splitlines():
-            if line.startswith("{"):
-                return json.loads(line)
-        return {"skipped": f"no result (rc={out.returncode})"}
-    except subprocess.TimeoutExpired:
-        return {"skipped": f"compile/run budget ({budget}s) exceeded — rerun with a warm .xla_cache"}
-    except Exception as exc:  # noqa: BLE001
-        return {"skipped": str(exc)}
 
 
 def _chaos_main():
@@ -2003,39 +1958,54 @@ def main():
     if os.environ.get("BENCH_CHAOS"):
         _chaos_main()
         return
-    if os.environ.get("BENCH_PARITY"):
-        _parity_only_main(os.environ["BENCH_PARITY"])
-        return
-    if os.environ.get("BENCH_ONE"):
-        _one_config_main(os.environ["BENCH_ONE"])
-        return
+    _default_main()
+
+
+def _default_main():
+    """The five BASELINE configs in the ONE process that holds the chip.
+    A configuration that fails (parity, overflow, compile, a rate above
+    the HBM peak) is recorded and makes the run exit non-zero; no TPU, or
+    a device kind without a published peak, fails before anything runs."""
+    import traceback
 
     import jax
 
     devs = jax.devices()
     log(f"jax {jax.__version__}, devices: {devs}")
     accel = devs[0]
-    budget = int(os.environ.get("BENCH_CONFIG_BUDGET", "420"))
+    peak = chip_peak_gbs(accel)
+    cpu = _cpu_child()
 
-    results = {}
+    results, failed = {}, []
     for cfg in _configs():
-        # each config in its own process: a pathological compile (cold
-        # cache) skips that config instead of losing the whole bench run
-        results[cfg.name] = _run_config_subprocess(cfg.name, budget)
+        try:
+            if cpu["parity"].get(cfg.name) != "ok":
+                raise RuntimeError(f"parity gate failed: {cpu['parity'].get(cfg.name)}")
+            rps, gbs, spread, csum, compile_s = bench_config(cfg, accel, ROWS, ITERS, peak_gbs=peak)
+            results[cfg.name] = {
+                "mrows_per_sec": round(rps / 1e6, 2),
+                "gb_per_sec": round(gbs, 1),
+                "spread_pct": round(spread, 1),
+                "compile_s": round(compile_s, 2),
+                "checksum": csum,
+            }
+        except Exception as exc:  # noqa: BLE001 — the other configs still run
+            log(traceback.format_exc())
+            results[cfg.name] = {"failed": f"{type(exc).__name__}: {exc}"}
+            failed.append(cfg.name)
         log(f"  [{cfg.name}] {json.dumps(results[cfg.name])}")
 
-    cpu_rps = {} if accel.platform == "cpu" else _cpu_baseline_subprocess()
-    for cfg in _configs():
-        r = results.get(cfg.name, {})
-        if "mrows_per_sec" in r and cpu_rps.get(cfg.name):
-            r["cpu_mrows_per_sec"] = round(cpu_rps[cfg.name] / 1e6, 2)
-            r["vs_xla_cpu"] = round(r["mrows_per_sec"] * 1e6 / cpu_rps[cfg.name], 2)
-    if "mrows_per_sec" in results.get("q6", {}):
+    cpu_rps = cpu["rows_per_sec"]
+    for name, r in results.items():
+        if "mrows_per_sec" in r and cpu_rps.get(name):
+            r["cpu_mrows_per_sec"] = round(cpu_rps[name] / 1e6, 2)
+            r["vs_xla_cpu"] = round(r["mrows_per_sec"] * 1e6 / cpu_rps[name], 2)
+    if "mrows_per_sec" in results["q6"]:
         oracle_rps = bench_oracle(next(c for c in _configs() if c.name == "q6"))
         log(f"  [q6] oracle {oracle_rps/1e3:.1f} Krows/s")
         results["q6"]["vs_oracle_rowwise"] = round(results["q6"]["mrows_per_sec"] * 1e6 / oracle_rps, 0)
 
-    q6 = results.get("q6", {})
+    q6 = results["q6"]
     print(json.dumps({
         "metric": "q6_fused_filter_agg_throughput",
         "value": q6.get("mrows_per_sec", 0.0),
@@ -2043,8 +2013,12 @@ def main():
         "vs_baseline": q6.get("vs_xla_cpu", 0.0),
         "gb_per_sec": q6.get("gb_per_sec", 0.0),
         "vs_oracle_rowwise": q6.get("vs_oracle_rowwise", 0.0),
+        "device": {"platform": accel.platform, "kind": accel.device_kind, "count": len(devs)},
         "configs": results,
+        "failed": failed,
     }))
+    if failed:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

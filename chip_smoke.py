@@ -33,7 +33,10 @@ os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(HERE, ".xla_cach
 
 import numpy as np  # noqa: E402
 
-DEFAULT_ROWS = 1 << 20  # lineitem; orders = rows/4, customer = rows/32
+# lineitem rows; orders = rows/4, customer = rows/32 (TPC-H's ratios, SF~0.044).
+# The host bounds it, not the chip: LOAD DATA, ANALYZE and the replica's
+# backfill are per-row Python, and a cold run must fit the smoke's time limit
+DEFAULT_ROWS = 1 << 18
 OUT_DIR = os.path.join(HERE, "chiprun_out")
 WIRE_TIMEOUT = 1100.0  # a first execution compiles; the wire must wait
 
@@ -106,6 +109,8 @@ PALLAS_KERNELS = {
     "join_pallas": ("probe_tables_pallas",),
 }
 TRACED: collections.Counter = collections.Counter()
+COLLECTIVES = ("all_reduce", "all_gather", "all_to_all", "reduce_scatter", "collective_permute")
+MESH_PROGRAMS: list = []  # one record per mesh program, made at its first launch
 
 
 def emit(**line) -> None:
@@ -372,6 +377,39 @@ def watch_pallas_kernels() -> None:
             setattr(mod, name, functools.wraps(fn)(counted))
 
 
+def watch_mesh_programs() -> None:
+    """A program built for the mesh records, at its first launch, the
+    collectives in its lowered text and how many devices its outputs
+    live on.  build_program is looked up in its module at call time."""
+    import jax
+
+    from tidb_tpu.exec import builder
+
+    build = builder.build_program
+
+    def watched(*a, **k):
+        prog = build(*a, **k)
+        if k.get("mesh_lanes") is None:
+            return prog
+        fn = prog.fn
+
+        def launch(*args):
+            out = fn(*args)
+            if not any(r["fn"] is fn for r in MESH_PROGRAMS):
+                text = fn.lower(*args).as_text()
+                MESH_PROGRAMS.append({
+                    "fn": fn, "kind": k.get("mesh_kind"), "lanes": k["mesh_lanes"],
+                    "collectives": [c for c in COLLECTIVES if c in text],
+                    "devices": max(len(x.sharding.device_set) for x in jax.tree.leaves(out)),
+                })
+            return out
+
+        prog.fn = launch
+        return prog
+
+    builder.build_program = watched
+
+
 def require_pallas(stmt: str, traced: dict) -> None:
     """Q1 and Q3 must have traced a Pallas kernel, not the XLA branch
     beside it."""
@@ -596,6 +634,54 @@ def phase_columnar(ctx: Ctx) -> None:
          columnar_scans=scans, columnar_fallbacks=fallbacks, **p.done())
 
 
+def phase_mesh(ctx: Ctx, n_devices: int) -> None:
+    """Q6, Q1 and Q3 with the defaults — mesh and MPP tiers on — and again
+    with both off, which is what they are compared with.  Both cross-chip
+    tiers degrade in silence, so a right answer proves nothing alone: the
+    counters, the devices and the collectives are asserted too."""
+    from tidb_tpu.util import metrics as m
+
+    c, data, store = ctx.client, ctx.data, ctx.srv.store
+    # the mesh tier shards REGIONS over the devices: let PD's split checker
+    # cut the loaded tables (64Ki keys / 4 MiB a region) until it is done
+    ticks, n_regions = 0, len(store.cluster.regions())
+    while True:
+        store.pd.tick()
+        ticks += 1
+        now = len(store.cluster.regions())
+        if now == n_regions:
+            break
+        n_regions = now
+        assert ticks < 16, f"regions still splitting after {ticks} ticks: {now}"
+    assert n_regions >= n_devices, f"{n_regions} region(s) cannot span {n_devices} devices"
+    emit(phase="split", ticks=ticks, regions=n_regions)
+
+    counters = ("MPP_SELECTS", "MESH_COP_BATCHES", "MPP_FALLBACKS", "MESH_COP_FALLBACKS")
+    checks = (("q6", Q6, check_q6), ("q1", Q1, check_q1), ("q3", Q3, check_q3))
+    for mode in ("mesh", "single_device"):
+        if mode == "single_device":
+            c.query("set tidb_enable_tpu_mesh = OFF")
+            c.query("set tidb_allow_mpp = OFF")
+        for name, sql, check in checks:
+            p = Probe()
+            before = {k: getattr(m, k).value for k in counters}
+            n_progs = len(MESH_PROGRAMS)
+            result = check(c.query(sql)[1], data)
+            moved = {k: getattr(m, k).value - before[k] for k in counters}
+            progs = [{k: v for k, v in r.items() if k != "fn"} for r in MESH_PROGRAMS[n_progs:]]
+            line = p.done()
+            assert line["oracle_fallbacks"] == 0, (name, line)
+            if mode == "mesh":
+                assert moved["MPP_SELECTS"] + moved["MESH_COP_BATCHES"] > 0, (name, moved)
+                assert moved["MPP_FALLBACKS"] == 0 and moved["MESH_COP_FALLBACKS"] == 0, (name, moved)
+                for r in progs:
+                    assert r["collectives"] and r["devices"] == n_devices, (name, r)
+                assert progs or moved["MPP_SELECTS"], f"{name}: no mesh program was launched"
+            else:
+                assert not any(moved.values()) and not progs, (name, moved, progs)
+            emit(stmt=f"{mode}_{name}", tiers=moved, mesh_programs=progs, **line, **result)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
@@ -608,8 +694,12 @@ def main(argv=None) -> int:
     ctx = Ctx(args.rows, args.seed)
     try:
         phase_load(ctx)
-        phase_row_store(ctx)
-        phase_columnar(ctx)
+        if args.chips == 1:
+            phase_row_store(ctx)
+            phase_columnar(ctx)
+        else:  # the cross-chip tiers and what they are compared with, nothing else
+            watch_mesh_programs()
+            phase_mesh(ctx, args.chips)
     finally:
         ctx.close()
     print(json.dumps({"ok": True, "device": dev}), flush=True)
