@@ -55,7 +55,6 @@ def _mesh8():
 def test_broadcast_exchange():
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from tidb_tpu.parallel.exchange import broadcast_exchange
@@ -70,14 +69,14 @@ def test_broadcast_exchange():
         # every device must hold every row
         return jnp.sum(jnp.where(gv, out, 0))[None]
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=P("x"))
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=P("x"))
     got = f(vals, valid)
     assert np.all(np.asarray(got) == int(vals.sum()))
 
 
 def test_passthrough_exchange():
+    import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from tidb_tpu.parallel.exchange import passthrough_exchange
@@ -91,7 +90,7 @@ def test_passthrough_exchange():
         (out,), gv = passthrough_exchange("x", [v], m, target=0)
         return jnp.sum(jnp.where(gv, out, 0))[None]
 
-    got = np.asarray(shard_map(body, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=P("x"))(vals, valid))
+    got = np.asarray(jax.shard_map(body, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=P("x"))(vals, valid))
     # only device 0 owns rows; everyone else sums to zero
     assert got[0] == int(vals.sum()) and np.all(got[1:] == 0)
 

@@ -330,3 +330,78 @@ def test_planner_marks_pk_build_unique():
         "select o_id, sum(qty) from lineitem join orders on l_oid = o_id group by o_id order by o_id"
     )
     assert [(int(x[0].val), int(str(x[1].val))) for x in r.rows] == [(1, 12), (2, 9)]
+
+
+def _tpch_lite():
+    """customer/orders/lineitem with TPC-H's key columns, lineitem largest."""
+    from tidb_tpu.sql import Session
+
+    s = Session()
+    s.execute("create table customer (c_custkey bigint primary key, c_mktsegment char(10))")
+    s.execute("create table orders (o_orderkey bigint primary key, o_custkey bigint, o_orderdate date)")
+    s.execute("create table lineitem (l_orderkey bigint, l_linenumber bigint, l_flag char(1), "
+              "l_price decimal(15,2), l_shipdate date, primary key (l_orderkey, l_linenumber))")
+    s.execute("insert into customer values (1, 'BUILDING'), (2, 'MACHINERY')")
+    s.execute("insert into orders values (10, 1, '1995-03-01'), (11, 2, '1995-03-02'), (12, 1, '1995-04-01')")
+    s.execute("insert into lineitem values (10, 1, 'A', 5.00, '1995-03-20'), (10, 2, 'N', 7.00, '1995-03-21'), "
+              "(11, 1, 'A', 9.00, '1995-03-22'), (12, 1, 'N', 1.00, '1995-05-01'), (12, 2, 'N', 2.00, '1994-05-01')")
+    return s
+
+
+def _plan(s, sql):
+    from tidb_tpu.parser import parse_one
+    from tidb_tpu.sql.planner import plan_select
+
+    return plan_select(parse_one(sql), s.catalog)
+
+
+Q3_LITE = (
+    "select l_orderkey, sum(l_price) as revenue, o_orderdate from customer, orders, lineitem "
+    "where c_mktsegment = 'BUILDING' and c_custkey = o_custkey and l_orderkey = o_orderkey "
+    "and o_orderdate < date '1995-03-15' and l_shipdate > date '1995-03-15' "
+    "group by l_orderkey, o_orderdate order by revenue desc, o_orderdate limit 10"
+)
+
+
+def test_build_sides_follow_equi_connectivity():
+    """TPC-H Q3 lists customer before orders, and lineitem (the probe) has
+    no key in common with customer: orders must be joined first, or the
+    first join is a cartesian product on constant keys."""
+    from tidb_tpu.exec.dag import Join
+    from tidb_tpu.expr.ir import Const
+
+    s = _tpch_lite()
+    plan = _plan(s, Q3_LITE)
+    assert [m.name for m in plan.build_tables] == ["orders", "customer"]
+    for j in (e for e in plan.dag.executors if isinstance(e, Join)):
+        assert not any(isinstance(k, Const) for k in j.probe_keys)
+    r = s.execute(Q3_LITE).values()
+    assert [(int(x[0]), str(x[1]), str(x[2])[:10]) for x in r] == [(10, "12.00", "1995-03-01")]
+
+
+@pytest.mark.parametrize("where,want", [
+    ("l_shipdate >= date '1995-03-21'", 3),
+    ("l_shipdate < date '1994-05-01' + interval '1' year", 4),
+    ("l_shipdate <= date '1995-06-01' - interval '70' day", 4),
+])
+def test_date_literal_and_quoted_interval_stay_on_device(where, want):
+    """The TPC-H text's `date '...' +/- interval '1' year`: a time constant
+    and a numeric count, so no string cast reaches the device program and
+    the statement does not ride the oracle fallback."""
+    from tidb_tpu.util import metrics
+
+    s = _tpch_lite()
+    before = metrics.COP_FALLBACKS.value
+    r = s.execute(f"select count(*) from lineitem where {where}").values()
+    assert int(r[0][0]) == want
+    assert metrics.COP_FALLBACKS.value == before
+
+
+def test_ndv_hint_survives_order_by():
+    """ANALYZE's NDV product reaches the aggregation although ORDER BY (or
+    a projection) follows it in the DAG — every TPC-H text has one."""
+    s = _tpch_lite()
+    s.execute("analyze table lineitem")
+    bare = _plan(s, "select l_flag, count(*) from lineitem group by l_flag")
+    ordered = _plan(s, "select l_flag, count(*) + 1 from lineitem group by l_flag order by l_flag")
+    assert bare.small_groups == 16 and ordered.small_groups == 16
