@@ -10,10 +10,15 @@ that is given this file loads the TPU library, and every worker collects
 the same tests.
 """
 
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))  # __graft_entry__
 
 ROWS = 1 << 20
 

@@ -77,9 +77,9 @@ class _TraceState:
 
     summaries=False drops the per-executor produced-row counts: each one
     is a full-array reduce with a ~1.5-3ms dispatch floor on the v5e
-    (2026-07-31 measurement, not repeated since), which for a 9-executor join plan is more than the sorts cost —
-    the bench path runs without them, production keeps them (EXPLAIN
-    ANALYZE needs the numbers)."""
+    (2026-07-31 measurement, not repeated since), which for a 9-executor
+    join plan is more than the sorts cost — the bench path runs without
+    them, production keeps them (EXPLAIN ANALYZE needs the numbers)."""
 
     def __init__(self, summaries: bool = True):
         self.group_overflow = jnp.bool_(False)

@@ -1,10 +1,10 @@
 """Pallas post-sort pass for the packed join+group kernel (TPC-H Q3).
 
 After packed_join_groupsum's ONE int32 sort, the XLA path pays ~10ms of
-scan floors at 4.65M rows on the v5e (2026-07-31 measurement, not
-repeated since: an int64 cumsum + int64
-reverse cummin per agg combo, an int32 reverse cummin for run extents,
-plus a batched overflow reduce — each op carries a 2-4ms dispatch floor).
+scan floors at 4.65M rows on the v5e (an int64 cumsum + int64 reverse
+cummin per agg combo, an int32 reverse cummin for run extents, plus a
+batched overflow reduce — each op carries a 2-4ms dispatch floor; a
+2026-07-31 measurement, not repeated since).
 This kernel replaces ALL of it with one sequential-grid sweep over the
 sorted arrays: a flagged Hillis-Steele segmented scan (lane phase by
 pltpu.roll along lanes, sublane phase by roll + last-lane broadcast,
@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
 
 
 def _x64_ctx(interpret: bool):

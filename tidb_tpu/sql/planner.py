@@ -944,6 +944,38 @@ def _equi_sides(e: A.ExprNode):
     return None
 
 
+def _connected_first(flat: list, where, scope: "_Scope") -> list:
+    """Build sides (flat[1:]) keep their textual order, except that a table
+    an equi condition ties to the tables already placed goes before one
+    that would join as a cartesian product: TPC-H Q3 lists customer before
+    orders, and lineitem (the probe) shares no key with customer."""
+    conj = _split_conjuncts(where)
+    for _, _, _, on in flat:
+        if on is not None:
+            conj.extend(_split_conjuncts(on))
+    links = []
+    for c in conj:
+        sides = None if isinstance(c, A.SemiJoinCond) else _equi_sides(c)
+        if sides is None:
+            continue
+        try:
+            lt, rt = scope.tables_of(sides[0]), scope.tables_of(sides[1])
+        except PlanError:
+            continue
+        if len(lt) == 1 and len(rt) == 1 and lt != rt:
+            links.append(lt | rt)
+    ordered, rest, placed = [flat[0]], flat[1:], {flat[0][1]}
+    while rest:
+        nxt = next(
+            (f for f in rest if any(f[1] in t and t - {f[1]} <= placed for t in links)),
+            rest[0],
+        )
+        rest.remove(nxt)
+        ordered.append(nxt)
+        placed.add(nxt[1])
+    return ordered
+
+
 def _has_agg(n) -> bool:
     if isinstance(n, A.AggFunc):
         return True
@@ -1357,36 +1389,7 @@ def _plan_select(stmt: A.SelectStmt, catalog: Catalog, mat: dict | None = None, 
                 others = [i for i in range(len(flat)) if aliases_flat[i] != hb]
                 probe_i = max(others, key=lambda i: est[i])
         flat = [flat[probe_i]] + flat[:probe_i] + flat[probe_i + 1 :]
-        # build sides keep their textual order, except that a table an
-        # equi condition ties to the tables already placed goes before
-        # one that would join as a cartesian product (TPC-H Q3 lists
-        # customer before orders; lineitem x customer has no key)
-        conj = _split_conjuncts(stmt.where)
-        for _, _, _, on in flat:
-            if on is not None:
-                conj.extend(_split_conjuncts(on))
-        links = []
-        for c in conj:
-            sides = None if isinstance(c, A.SemiJoinCond) else _equi_sides(c)
-            if sides is None:
-                continue
-            try:
-                lt, rt = tmp_scope.tables_of(sides[0]), tmp_scope.tables_of(sides[1])
-            except PlanError:
-                continue
-            if len(lt) == 1 and len(rt) == 1 and lt != rt:
-                links.append(lt | rt)
-        ordered, rest = [flat[0]], flat[1:]
-        placed_ = {flat[0][1]}
-        while rest:
-            nxt = next(
-                (f for f in rest if any(f[1] in t and t - {f[1]} <= placed_ for t in links)),
-                rest[0],
-            )
-            rest.remove(nxt)
-            ordered.append(nxt)
-            placed_.add(nxt[1])
-        flat = ordered
+        flat = _connected_first(flat, stmt.where, tmp_scope)
 
     # ---- scope over the combined schema in placement order
     trefs = []
