@@ -1,0 +1,93 @@
+"""The one traffic generator.  A mix is a data file (`traffic/<mix>.json`):
+how many clients, the list of steps that make one operation, and a rule
+for every parameter of every step.  The same seed gives the same
+statements; every seed gives the same shapes, with other values.
+
+A step is `{"sql": text}` (sent as it is, not compared: BEGIN, COMMIT) or
+`{"statement": name, "repeat": n, "once": {param: rule}, "params": {param:
+rule}}`, the name being a key of the configuration's `statements.json`;
+`once` is drawn one time for the step, `params` anew for each repetition.
+A rule is a JSON
+scalar (a constant), `{"uniform_int": [low, high]}` with both ends
+included, `{"choice": [...]}`, or `{"plus": [other_param, n]}`.  An end of
+`uniform_int` may be a number or a size of the configuration's file, with
+an offset: `"table_size-99"`.
+"""
+
+from __future__ import annotations
+
+import re
+from dataclasses import dataclass, field
+
+import numpy as np
+
+_SIZE = re.compile(r"^([A-Za-z_]\w*)([+-]\d+)?$")
+
+
+@dataclass
+class Step:
+    sql: str
+    name: str | None = None          # None: a raw step, not compared
+    params: dict = field(default_factory=dict)
+
+
+class Mix:
+    def __init__(self, spec: dict, statements: dict, sizes: dict):
+        self.spec, self.statements, self.sizes = spec, statements, sizes
+        self.clients = int(spec["clients"])
+        if spec.get("loop") != "closed":
+            raise ValueError(f"loop {spec.get('loop')!r}: this generator drives closed loops")
+        for step in spec["operation"]:
+            if "statement" in step and step["statement"] not in statements:
+                raise KeyError(f"traffic names statement {step['statement']!r}; "
+                               f"the configuration has {sorted(statements)}")
+
+    def statement_names(self) -> list:
+        seen = []
+        for step in self.spec["operation"]:
+            if "statement" in step and step["statement"] not in seen:
+                seen.append(step["statement"])
+        return seen
+
+    def _bound(self, v) -> int:
+        if isinstance(v, int):
+            return v
+        m = _SIZE.match(v)
+        if not m or m.group(1) not in self.sizes:
+            raise ValueError(f"bound {v!r} is neither a number nor a size of the configuration")
+        return int(self.sizes[m.group(1)]) + int(m.group(2) or 0)
+
+    def _draw(self, rules: dict, rng) -> dict:
+        out = {}
+        for key, rule in rules.items():
+            if not isinstance(rule, dict):
+                out[key] = rule
+            elif "uniform_int" in rule:
+                lo, hi = map(self._bound, rule["uniform_int"])
+                out[key] = int(rng.integers(lo, hi + 1))
+            elif "choice" in rule:
+                out[key] = rule["choice"][int(rng.integers(0, len(rule["choice"])))]
+            elif "plus" in rule:
+                out[key] = out[rule["plus"][0]] + int(rule["plus"][1])
+            else:
+                raise ValueError(f"parameter {key!r}: unknown rule {rule!r}")
+        return out
+
+    def operation(self, rng) -> list:
+        """The steps of one operation, parameters drawn from `rng`."""
+        steps = []
+        for step in self.spec["operation"]:
+            if "sql" in step:
+                steps.append(Step(step["sql"]))
+                continue
+            once = self._draw(step.get("once", {}), rng)
+            for _ in range(int(step.get("repeat", 1))):
+                params = {**once, **self._draw(step.get("params", {}), rng)}
+                steps.append(Step(self.statements[step["statement"]].format(**params),
+                                  step["statement"], params))
+        return steps
+
+
+def client_rng(seed: int, client: int, phase: int):
+    """One stream per client and phase (0 warm-up, 1 window)."""
+    return np.random.default_rng([int(seed), client, phase])
