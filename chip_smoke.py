@@ -111,7 +111,6 @@ PALLAS_KERNELS = {
 TRACED: collections.Counter = collections.Counter()
 COLLECTIVES = ("all_reduce", "all_gather", "all_to_all", "reduce_scatter", "collective_permute")
 MESH_PROGRAMS: list = []  # one record per mesh program, made at its first launch
-XLA_COMPILE = {"seconds": 0.0}  # backend compile time, as JAX's monitoring reports it
 
 
 def emit(**line) -> None:
@@ -418,8 +417,9 @@ def require_pallas(stmt: str, traced: dict) -> None:
 
 
 class Probe:
-    """Metric deltas around a block: compile seconds, launches, and the
-    counters that must not move."""
+    """Metric deltas around a block: compile seconds (program calls that
+    traced or compiled, whole; XLA's backend compiles alone), launches, and
+    the counters that must not move."""
 
     def __init__(self):
         from tidb_tpu.util import metrics
@@ -430,30 +430,18 @@ class Probe:
         self.launch0 = metrics.PROGRAM_LAUNCHES.value
         self.oracle0 = metrics.COP_FALLBACKS.value
         self.traced0 = collections.Counter(TRACED)
-        self.xla0 = XLA_COMPILE["seconds"]
+        self.xla0 = metrics.XLA_BACKEND_COMPILE_NS.value
 
     def done(self) -> dict:
         m = self.m
         return {
             "wall_s": round(time.perf_counter() - self.t0, 3),
             "compile_s": round(m.PROGRAM_COMPILE_DURATION.sum - self.compile0, 3),
-            "xla_compile_s": round(XLA_COMPILE["seconds"] - self.xla0, 3),
+            "xla_compile_s": round((m.XLA_BACKEND_COMPILE_NS.value - self.xla0) / 1e9, 3),
             "launches": m.PROGRAM_LAUNCHES.value - self.launch0,
             "oracle_fallbacks": m.COP_FALLBACKS.value - self.oracle0,
             "kernels": dict(TRACED - self.traced0),
         }
-
-
-def watch_xla_compiles() -> None:
-    """PROGRAM_COMPILE_DURATION times build_program (the trace); the XLA
-    compile itself runs at the first call.  JAX reports it as an event."""
-    import jax
-
-    def on_event(event: str, seconds: float, **_kw) -> None:
-        if event.endswith("backend_compile_duration"):
-            XLA_COMPILE["seconds"] += seconds
-
-    jax.monitoring.register_event_duration_secs_listener(on_event)
 
 
 def prepare_engine() -> None:
@@ -467,7 +455,6 @@ def prepare_engine() -> None:
     # a device error raises instead of turning into CopResponse.other_error
     failpoint.enable("cop-debug-raise")
     watch_pallas_kernels()
-    watch_xla_compiles()
 
 
 def run_twice(name: str, run, check, data, want_pallas: bool = False) -> None:

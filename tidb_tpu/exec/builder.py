@@ -212,128 +212,129 @@ def _run_pipeline(executors, batches, cursor, group_capacity, join_capacity, sta
     while ei < len(executors):
         ex = executors[ei]
         comp = ExprCompiler(fts)
-        if isinstance(ex, Selection):
-            conds = comp.run(list(ex.conditions), cols)
-            valid = apply_selection(valid, conds)
-        elif isinstance(ex, Projection):
-            cols = comp.run(list(ex.exprs), cols)
-            fts = [e.ft for e in ex.exprs]
-        elif isinstance(ex, Limit):
-            keep = jnp.cumsum(valid.astype(jnp.int32)) <= ex.limit
-            valid = valid & keep
-        elif isinstance(ex, TopN):
-            order_vals = comp.run([e for e, _ in ex.order_by], cols)
-            by = list(zip(order_vals, [d for _, d in ex.order_by]))
-            idx, out_valid, t_ovf = topn(by, valid, ex.limit, full_sort=topn_full)
-            state.topn_overflow = state.topn_overflow | t_ovf
-            cols = _gather(cols, idx)
-            valid = out_valid
-        elif isinstance(ex, Sort):
-            from ..ops.topn import sort_all
+        with jax.named_scope(stage_name(ex)):
+            if isinstance(ex, Selection):
+                conds = comp.run(list(ex.conditions), cols)
+                valid = apply_selection(valid, conds)
+            elif isinstance(ex, Projection):
+                cols = comp.run(list(ex.exprs), cols)
+                fts = [e.ft for e in ex.exprs]
+            elif isinstance(ex, Limit):
+                keep = jnp.cumsum(valid.astype(jnp.int32)) <= ex.limit
+                valid = valid & keep
+            elif isinstance(ex, TopN):
+                order_vals = comp.run([e for e, _ in ex.order_by], cols)
+                by = list(zip(order_vals, [d for _, d in ex.order_by]))
+                idx, out_valid, t_ovf = topn(by, valid, ex.limit, full_sort=topn_full)
+                state.topn_overflow = state.topn_overflow | t_ovf
+                cols = _gather(cols, idx)
+                valid = out_valid
+            elif isinstance(ex, Sort):
+                from ..ops.topn import sort_all
 
-            order_vals = comp.run([e for e, _ in ex.order_by], cols)
-            by = list(zip(order_vals, [d for _, d in ex.order_by]))
-            idx, out_valid = sort_all(by, valid)
-            cols = _gather(cols, idx)
-            valid = out_valid
-        elif isinstance(ex, Join):
-            nxt = executors[ei + 1] if ei + 1 < len(executors) else None
-            fused_ok = isinstance(nxt, Aggregation) and _joinagg_pattern(ex, nxt, len(fts), unique_joins)
-            if fused_ok:
-                fused = _trace_packed_chain(
-                    ex, nxt, comp, cols, valid, batches, cursor,
-                    group_capacity, join_capacity, state, topn_full,
-                    small_groups, unique_joins,
-                )
-                if fused is not None:
-                    cols, valid, fts = fused
-                    state.rows(valid)
-                    ei += 2
-                    continue
-            bcols, bvalid, bfts = _run_pipeline(ex.build, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins)
-            bcomp = ExprCompiler(bfts)
-            bkeys = bcomp.run(list(ex.build_keys), bcols)
-            pkeys = comp.run(list(ex.probe_keys), cols)
-            _check_join_key_types(pkeys, bkeys)
-            if fused_ok and _single_word(pkeys[0]) and _single_word(bkeys[0]):
-                fused = _trace_joinagg(
-                    nxt, comp, cols, bkeys, pkeys, bvalid, valid,
-                    group_capacity, state,
-                )
-                if fused is not None:
-                    cols, valid, fts = fused
-                    state.rows(valid)
-                    ei += 2
-                    continue
-            res = _trace_radix_join(ex, bkeys, pkeys, bvalid, valid,
-                                    join_capacity, state, unique_joins)
-            if res is None:
-                res = hash_join(bkeys, pkeys, bvalid, valid, join_capacity, ex.join_type,
-                                build_unique=ex.build_unique and unique_joins)
-            state.join_overflow = state.join_overflow | res.overflow
-            state.note_join(res.need)
-            if ex.join_type in ("semi", "anti"):
-                # probe schema preserved, rows filtered by match-existence
-                valid = res.out_valid
-            else:
-                nb = bvalid.shape[0]
-                used = _used_cols_after(executors[ei + 1:], len(fts) + len(bfts), out_offsets)
-                if res.probe_identity:
-                    p_g = cols  # unique-build layout: slot j == probe row j
+                order_vals = comp.run([e for e, _ in ex.order_by], cols)
+                by = list(zip(order_vals, [d for _, d in ex.order_by]))
+                idx, out_valid = sort_all(by, valid)
+                cols = _gather(cols, idx)
+                valid = out_valid
+            elif isinstance(ex, Join):
+                nxt = executors[ei + 1] if ei + 1 < len(executors) else None
+                fused_ok = isinstance(nxt, Aggregation) and _joinagg_pattern(ex, nxt, len(fts), unique_joins)
+                if fused_ok:
+                    fused = _trace_packed_chain(
+                        ex, nxt, comp, cols, valid, batches, cursor,
+                        group_capacity, join_capacity, state, topn_full,
+                        small_groups, unique_joins,
+                    )
+                    if fused is not None:
+                        cols, valid, fts = fused
+                        state.rows(valid)
+                        ei += 2
+                        continue
+                bcols, bvalid, bfts = _run_pipeline(ex.build, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins)
+                bcomp = ExprCompiler(bfts)
+                bkeys = bcomp.run(list(ex.build_keys), bcols)
+                pkeys = comp.run(list(ex.probe_keys), cols)
+                _check_join_key_types(pkeys, bkeys)
+                if fused_ok and _single_word(pkeys[0]) and _single_word(bkeys[0]):
+                    fused = _trace_joinagg(
+                        nxt, comp, cols, bkeys, pkeys, bvalid, valid,
+                        group_capacity, state,
+                    )
+                    if fused is not None:
+                        cols, valid, fts = fused
+                        state.rows(valid)
+                        ei += 2
+                        continue
+                res = _trace_radix_join(ex, bkeys, pkeys, bvalid, valid,
+                                        join_capacity, state, unique_joins)
+                if res is None:
+                    res = hash_join(bkeys, pkeys, bvalid, valid, join_capacity, ex.join_type,
+                                    build_unique=ex.build_unique and unique_joins)
+                state.join_overflow = state.join_overflow | res.overflow
+                state.note_join(res.need)
+                if ex.join_type in ("semi", "anti"):
+                    # probe schema preserved, rows filtered by match-existence
+                    valid = res.out_valid
                 else:
-                    p_g = _gather_pruned(cols, res.probe_idx, used, 0)
-                b_g = _gather_pruned(bcols, jnp.clip(res.build_idx, 0, nb - 1), used, len(fts))
-                b_g = [CompVal(c.value, c.null | res.build_null, c.ft, raw=c.raw) for c in b_g]
-                cols = p_g + b_g
-                valid = res.out_valid
-                if ex.join_type == "left_outer":
-                    bfts = [f.clone_nullable() for f in bfts]
-                fts = fts + bfts
-        elif isinstance(ex, Window):
-            from ..ops.window import window_cols
+                    nb = bvalid.shape[0]
+                    used = _used_cols_after(executors[ei + 1:], len(fts) + len(bfts), out_offsets)
+                    if res.probe_identity:
+                        p_g = cols  # unique-build layout: slot j == probe row j
+                    else:
+                        p_g = _gather_pruned(cols, res.probe_idx, used, 0)
+                    b_g = _gather_pruned(bcols, jnp.clip(res.build_idx, 0, nb - 1), used, len(fts))
+                    b_g = [CompVal(c.value, c.null | res.build_null, c.ft, raw=c.raw) for c in b_g]
+                    cols = p_g + b_g
+                    valid = res.out_valid
+                    if ex.join_type == "left_outer":
+                        bfts = [f.clone_nullable() for f in bfts]
+                    fts = fts + bfts
+            elif isinstance(ex, Window):
+                from ..ops.window import window_cols
 
-            part_vals = comp.run(list(ex.partition_by), cols) if ex.partition_by else []
-            order_vals = comp.run([e for e, _ in ex.order_by], cols) if ex.order_by else []
-            order_pairs = list(zip(order_vals, [d for _, d in ex.order_by]))
-            funcs = []
-            for w in ex.funcs:
-                argv = comp.run(list(w.args), cols) if w.args else []
-                if w.default is not None:
-                    argv = argv + comp.run([w.default], cols)
-                funcs.append((w, argv))
-            cols = cols + window_cols(part_vals, order_pairs, funcs, valid)
-            fts = fts + [w.ft for w in ex.funcs]
-        elif isinstance(ex, Aggregation):
-            garg_exprs = []
-            for a in ex.aggs:
-                garg_exprs.extend(a.args)
-            gvals = comp.run(list(ex.group_by), cols) if ex.group_by else []
-            avals = comp.run(list(garg_exprs), cols) if garg_exprs else []
-            aggs = []
-            k = 0
-            for a in ex.aggs:
-                aggs.append((a, avals[k : k + len(a.args)]))
-                k += len(a.args)
-            new_cols: list[CompVal] = []
-            if ex.group_by:
-                res = group_aggregate(gvals, aggs, valid, group_capacity, merge=ex.merge, small_groups=small_groups, stream=ex.stream)
-                state.group_overflow = state.group_overflow | res.overflow
-                state.note_group(res.need)
-                for (a, av), st in zip(aggs, res.states):
-                    new_cols.extend(_agg_result_cols(a, av, st, res.group_valid, ex.partial))
-                new_cols.extend(_gather(gvals, res.group_rep))
-                valid = res.group_valid
+                part_vals = comp.run(list(ex.partition_by), cols) if ex.partition_by else []
+                order_vals = comp.run([e for e, _ in ex.order_by], cols) if ex.order_by else []
+                order_pairs = list(zip(order_vals, [d for _, d in ex.order_by]))
+                funcs = []
+                for w in ex.funcs:
+                    argv = comp.run(list(w.args), cols) if w.args else []
+                    if w.default is not None:
+                        argv = argv + comp.run([w.default], cols)
+                    funcs.append((w, argv))
+                cols = cols + window_cols(part_vals, order_pairs, funcs, valid)
+                fts = fts + [w.ft for w in ex.funcs]
+            elif isinstance(ex, Aggregation):
+                garg_exprs = []
+                for a in ex.aggs:
+                    garg_exprs.extend(a.args)
+                gvals = comp.run(list(ex.group_by), cols) if ex.group_by else []
+                avals = comp.run(list(garg_exprs), cols) if garg_exprs else []
+                aggs = []
+                k = 0
+                for a in ex.aggs:
+                    aggs.append((a, avals[k : k + len(a.args)]))
+                    k += len(a.args)
+                new_cols: list[CompVal] = []
+                if ex.group_by:
+                    res = group_aggregate(gvals, aggs, valid, group_capacity, merge=ex.merge, small_groups=small_groups, stream=ex.stream)
+                    state.group_overflow = state.group_overflow | res.overflow
+                    state.note_group(res.need)
+                    for (a, av), st in zip(aggs, res.states):
+                        new_cols.extend(_agg_result_cols(a, av, st, res.group_valid, ex.partial))
+                    new_cols.extend(_gather(gvals, res.group_rep))
+                    valid = res.group_valid
+                else:
+                    states, s_ovf = scalar_aggregate(aggs, valid, merge=ex.merge, salt=group_capacity)
+                    state.group_overflow = state.group_overflow | s_ovf
+                    ones = jnp.ones(1, bool)
+                    for (a, av), st in zip(aggs, states):
+                        new_cols.extend(_agg_result_cols(a, av, st, ones, ex.partial))
+                    valid = ones
+                cols = new_cols
+                fts = ex.output_fts()
             else:
-                states, s_ovf = scalar_aggregate(aggs, valid, merge=ex.merge, salt=group_capacity)
-                state.group_overflow = state.group_overflow | s_ovf
-                ones = jnp.ones(1, bool)
-                for (a, av), st in zip(aggs, states):
-                    new_cols.extend(_agg_result_cols(a, av, st, ones, ex.partial))
-                valid = ones
-            cols = new_cols
-            fts = ex.output_fts()
-        else:
-            raise TypeError(f"unsupported executor {ex}")
+                raise TypeError(f"unsupported executor {ex}")
         state.rows(valid)
         ei += 1
 
@@ -594,6 +595,36 @@ def _pack_cols(cols: list[CompVal]) -> list[tuple]:
     return packed
 
 
+_STAGE_NAMES = {TableScan: "scan", IndexScan: "iscan", Selection: "sel", Projection: "proj",
+                Limit: "limit", TopN: "topn", Sort: "sort", Window: "win"}
+_JOIN_NAMES = {"inner": "join", "left_outer": "ljoin", "semi": "semijoin", "anti": "antijoin"}
+
+
+def stage_name(ex) -> str:
+    """The name of one executor inside a program: the `jax.named_scope`
+    of its operations, and its part of the program's name."""
+    if isinstance(ex, Aggregation):
+        if not ex.group_by:
+            return "agg"
+        return "groupagg" if ex.aggs else "distinct"
+    if isinstance(ex, Join):
+        return _JOIN_NAMES[ex.join_type]
+    return _STAGE_NAMES[type(ex)]
+
+
+def program_name(dag: DAGRequest, vmap_batch: int | None = None, mesh_lanes: int | None = None,
+                 mesh_devices: int | None = None) -> str:
+    """What the jitted function is called, and with it the HLO module and
+    the profiler's `PjitFunction(...)` event: the DAG's shape (executor
+    chain of the probe pipeline) and the tier, e.g. `cop_scan_sel_agg`,
+    `cop_scan_sel_sort_b8`.  Never a literal or an address: statements
+    that differ in their constants share a name."""
+    name = "cop_" + "_".join(stage_name(ex) for ex in dag.executors)
+    if mesh_lanes is not None:
+        return f"{name}_m{mesh_lanes}x{mesh_devices or 1}"
+    return name if vmap_batch is None else f"{name}_b{vmap_batch}"
+
+
 def build_program(
     dag: DAGRequest,
     capacities,
@@ -665,13 +696,15 @@ def build_program(
         return packed, valid, n_out, ovfs, ex
 
     if mesh_lanes is not None:
-        jit_fn = _build_mesh_fn(dag, program, n_scans, mesh_lanes,
-                                mesh_devices or 1, mesh_kind, group_capacity)
+        fn = _build_mesh_fn(dag, program, n_scans, mesh_lanes,
+                            mesh_devices or 1, mesh_kind, group_capacity)
     elif vmap_batch is not None:
         # region axis on the probe batch only; aux/build batches broadcast
-        jit_fn = jax.jit(jax.vmap(program, in_axes=(0,) + (None,) * (n_scans - 1)))
+        fn = jax.vmap(program, in_axes=(0,) + (None,) * (n_scans - 1))
     else:
-        jit_fn = jax.jit(program)
+        fn = program
+    fn.__name__ = fn.__qualname__ = program_name(dag, vmap_batch, mesh_lanes, mesh_devices)
+    jit_fn = jax.jit(fn)
     return CompiledDAG(jit_fn, dag.output_fts(), capacities, group_capacity, join_capacity,
                        radix_info=radix_info)
 
@@ -729,7 +762,7 @@ def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, lanes: int,
         out_specs=(P(), P(), P(REGION_AXIS), P(), P()),
         check_vma=False,
     )
-    return jax.jit(fn)
+    return fn
 
 
 def _gather_mesh_outputs(packed, valid, out_fts):
@@ -924,8 +957,7 @@ class ProgramCache:
                 t0 = _t.perf_counter_ns()
                 prog = build_program(dag, capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch=vmap_batch,
                                      mesh_lanes=mesh_lanes, mesh_devices=mesh_devices, mesh_kind=mesh_kind, radix_joins=radix_joins)
-                compile_ns = _t.perf_counter_ns() - t0
-                metrics.PROGRAM_COMPILE_DURATION.observe(compile_ns / 1e9)
+                compile_ns = _t.perf_counter_ns() - t0  # the Python closure only: JAX traces and compiles at the first call (exec/launch.py)
                 if sp is not None:
                     sp.set("compile_ns", compile_ns)
                     if vmap_batch is not None:

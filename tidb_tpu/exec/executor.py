@@ -28,16 +28,18 @@ def _pow2(n: int) -> int:
     return c
 
 
-def decode_outputs(packed, valid, out_fts) -> Chunk:
-    valid = np.asarray(valid)
+def decode_outputs(packed, valid, out_fts, to_host=np.asarray) -> Chunk:
+    """Program outputs -> host Chunk.  `to_host` converts each leaf; the
+    drivers pass the counting one of `launch.read_back`."""
+    valid = to_host(valid)
     idx = np.nonzero(valid)[0]
     cols = []
     for ft, out in zip(out_fts, packed):
         if len(out) == 4:  # string: words, null, raw bytes, lengths
             _, null, data, length = out
-            null = np.asarray(null)[idx]
-            data = np.asarray(data)[idx]
-            length = np.asarray(length)[idx]
+            null = to_host(null)[idx]
+            data = to_host(data)[idx]
+            length = to_host(length)[idx]
             offs = np.zeros(len(idx) + 1, np.int64)
             np.cumsum(np.where(null, 0, length), out=offs[1:])
             blob = np.zeros(int(offs[-1]), np.uint8)
@@ -45,11 +47,11 @@ def decode_outputs(packed, valid, out_fts) -> Chunk:
                 if not null[j]:
                     blob[offs[j] : offs[j + 1]] = data[j, : length[j]]
             cols.append(Column(ft, None, null, offs, blob))
-        elif ft.is_string() and np.asarray(out[0]).ndim == 2:
+        elif ft.is_string() and out[0].ndim == 2:
             # string column without raw bytes (e.g. CASE/IF over string
             # operands): reconstruct from the packed compare words — covers
             # the first STRING_WORDS*8 bytes, the packed-key contract
-            words, null = np.asarray(out[0]), np.asarray(out[1])
+            words, null = to_host(out[0]), to_host(out[1])
             words, null = words[idx], null[idx]
             w = words.shape[1] - 1
             payload = (words[:, :w].astype(np.uint64) ^ np.uint64(1 << 63))
@@ -67,8 +69,8 @@ def decode_outputs(packed, valid, out_fts) -> Chunk:
             cols.append(Column(ft, None, null.copy(), offs, blob))
         else:
             v, null = out
-            v = np.asarray(v)[idx]
-            null = np.asarray(null)[idx]
+            v = to_host(v)[idx]
+            null = to_host(null)[idx]
             if ft.is_unsigned() or ft.is_time():
                 v = v.view(np.uint64) if v.dtype == np.int64 else v.astype(np.uint64)
             cols.append(Column(ft, v.copy(), null.copy()))
@@ -91,17 +93,17 @@ def drive_program(cache: ProgramCache, dag: DAGRequest, batches, group_capacity:
     return chunk, counts
 
 
-def _radix_attribution(prog, jc: int, radix_esc, info: dict):
+def _radix_attribution(prog, jc: int, radix_esc, info: dict, to_host):
     """`join_radix` attribution (ISSUE 13 satellite): a TRACE span under
-    the ambient cop.execute/session span plus an info entry the store
-    folds into the exec summaries for EXPLAIN ANALYZE.  The escape count
-    arrived in the same device fetch as the overflow flags."""
+    the ambient span plus an info entry the store folds into the exec
+    summaries for EXPLAIN ANALYZE.  The escape count is fetched only
+    where a Join rode the radix kernel."""
     ri = prog.radix_info or {}
     if not ri:
         return
     from ..util import tracing
 
-    esc = int(radix_esc)
+    esc = int(to_host(radix_esc))
     with tracing.span("exec.join_radix", partitions=ri.get("partitions"),
                       rung=jc, escapes=esc, strategy=ri.get("strategy")):
         pass
@@ -122,9 +124,7 @@ def drive_program_info(cache: ProgramCache, dag: DAGRequest, batches, group_capa
     fan-out that rode the same device fetch as the flags — to re-dispatch
     the exact rung: a warm ladder makes every retry a ProgramCache hit
     (zero recompiles, pinned in tests/test_radix_join.py)."""
-    import time as _time
-
-    from ..util import metrics
+    from . import launch
     from .ladder import overflow_step, rung_for
 
     if not isinstance(batches, (list, tuple)):
@@ -139,25 +139,26 @@ def drive_program_info(cache: ProgramCache, dag: DAGRequest, batches, group_capa
     info = {"cache_hit": True, "compile_ns": 0}
     for _ in range(max_retries + 1):
         prog, hit, build_ns = cache.get_info(dag, caps, gc, jc, tf, smg, uj, radix_joins=rj)
-        t0 = _time.perf_counter_ns()
-        metrics.PROGRAM_LAUNCHES.inc()
-        packed, valid, n, (g_ovf, j_ovf, t_ovf, g_need, j_need, radix_esc), ex_rows = prog.fn(*batches)
-        g_ovf, j_ovf, t_ovf = bool(g_ovf), bool(j_ovf), bool(t_ovf)
+        out, (g_ovf, j_ovf, t_ovf), first_ns = launch.run_program(
+            prog.fn, batches, first_call=not hit, flags=lambda o: tuple(bool(f) for f in o[3][:3]))
+        packed, valid, _n, (_g, _j, _t, g_need, j_need, radix_esc), ex_rows = out
         if not hit:
             info["cache_hit"] = False
-            # bool() above blocked on the result: first-call = trace+compile
-            info["compile_ns"] += build_ns + (_time.perf_counter_ns() - t0)
-        if not g_ovf and not j_ovf and not t_ovf:
-            counts = [int(x) for x in np.asarray(ex_rows)]
-            _radix_attribution(prog, jc, radix_esc, info)
-            return decode_outputs(packed, valid, prog.out_fts), counts, info
+            # the flag fetch blocked on the result: first-call = trace+compile
+            info["compile_ns"] += build_ns + first_ns
+        with launch.read_back() as to_host:
+            if not g_ovf and not j_ovf and not t_ovf:
+                counts = [int(x) for x in to_host(ex_rows)]
+                _radix_attribution(prog, jc, radix_esc, info, to_host)
+                return decode_outputs(packed, valid, prog.out_fts, to_host), counts, info
+            g_need, j_need = int(to_host(g_need)), int(to_host(j_need))
         if g_ovf:
             # also drop a wrong stats hint in the same retry: the driver
             # cannot tell whether the dense kernel ran (the agg mix may
             # have been ineligible), so doing both never wastes a retry
             # on a byte-identical program
             smg = None
-        gc, jc, drop = overflow_step(gc, jc, g_ovf, j_ovf, int(g_need), int(j_need))
+        gc, jc, drop = overflow_step(gc, jc, g_ovf, j_ovf, g_need, j_need)
         if drop:
             uj = False
             rj = False
@@ -169,13 +170,6 @@ def drive_program_info(cache: ProgramCache, dag: DAGRequest, batches, group_capa
 class OverflowRetryError(RuntimeError):
     """Capacity growth retries exhausted; caller may fall back to the
     row-at-a-time oracle (the host fallback SURVEY §7 promises)."""
-
-
-def _slice_region(packed, b: int) -> list:
-    """Region lane `b` of a vmapped program's packed outputs — each leaf
-    loses its leading region axis, recovering the single-region layout
-    decode_outputs consumes."""
-    return [tuple(np.asarray(a)[b] for a in out) for out in packed]
 
 
 def drive_batched_program_info(
@@ -200,10 +194,7 @@ def drive_batched_program_info(
     through the single-region capacity ladder (drive_program_info) while
     every other region's result stands. info is the shared
     {"cache_hit", "compile_ns"} attribution of the one batched program."""
-    import time as _time
-
-    from ..util import metrics
-
+    from . import launch
     from .ladder import rung_for
 
     B = int(stacked.row_valid.shape[0])
@@ -213,31 +204,36 @@ def drive_batched_program_info(
     prog, hit, build_ns = cache.get_info(
         dag, caps, rung_for(group_capacity), jc, False, small_groups, True, vmap_batch=B
     )
-    t0 = _time.perf_counter_ns()
-    metrics.PROGRAM_LAUNCHES.inc()
-    packed, valid, n, (g_ovf, j_ovf, t_ovf, _g_need, _j_need, radix_esc), ex_rows = prog.fn(stacked, *aux_batches)
-    g_ovf, j_ovf, t_ovf = np.asarray(g_ovf), np.asarray(j_ovf), np.asarray(t_ovf)
-    info = {"cache_hit": hit, "compile_ns": 0}
-    if not hit:
-        # the flag fetch above blocked on the result: first-call time is
-        # trace+compile, same attribution as drive_program_info
-        info["compile_ns"] = build_ns + (_time.perf_counter_ns() - t0)
-    valid_np = np.asarray(valid)
-    ex_np = np.asarray(ex_rows)
-    per_region: list = []
-    esc_np = np.asarray(radix_esc)
-    served_esc = 0
-    esc_by_lane: list = []
-    for b in range(B):
-        if bool(g_ovf[b]) or bool(j_ovf[b]) or bool(t_ovf[b]):
-            per_region.append(None)
-            esc_by_lane.append(0)
-            continue
-        served_esc += int(esc_np[b])
-        esc_by_lane.append(int(esc_np[b]))
-        chunk = decode_outputs(_slice_region(packed, b), valid_np[b], prog.out_fts)
-        per_region.append((chunk, [int(x) for x in ex_np[b]]))
-    _radix_attribution(prog, jc, served_esc, info)
+    out, (g_ovf, j_ovf, t_ovf), first_ns = launch.run_program(
+        prog.fn, (stacked, *aux_batches), first_call=not hit,
+        flags=lambda o: tuple(np.asarray(f) for f in o[3][:3]))
+    packed, valid, _n, (_g, _j, _t, _g_need, _j_need, radix_esc), ex_rows = out
+    # the flag fetch blocked on the result: first-call time is
+    # trace+compile, same attribution as drive_program_info
+    info = {"cache_hit": hit, "compile_ns": 0 if hit else build_ns + first_ns}
+    with launch.read_back() as to_host:
+        valid_np = to_host(valid)
+        ex_np = to_host(ex_rows)
+        per_region: list = []
+        esc_np = to_host(radix_esc)
+        served_esc = 0
+        esc_by_lane: list = []
+        packed_np = None  # the stacked outputs on the host, once a lane is served
+        for b in range(B):
+            if bool(g_ovf[b]) or bool(j_ovf[b]) or bool(t_ovf[b]):
+                per_region.append(None)
+                esc_by_lane.append(0)
+                continue
+            served_esc += int(esc_np[b])
+            esc_by_lane.append(int(esc_np[b]))
+            if packed_np is None:
+                packed_np = [tuple(to_host(a) for a in out_col) for out_col in packed]
+            # region lane b: each leaf loses its leading region axis,
+            # recovering the single-region layout decode_outputs consumes
+            lane = [tuple(a[b] for a in out_col) for out_col in packed_np]
+            chunk = decode_outputs(lane, valid_np[b], prog.out_fts)
+            per_region.append((chunk, [int(x) for x in ex_np[b]]))
+        _radix_attribution(prog, jc, served_esc, info, to_host)
     if "radix" in info:
         # per-lane escape counts, aligned with per_region: the batched
         # store attributes each lane's OWN escapes to its summaries
@@ -272,10 +268,7 @@ def drive_mesh_program_info(
     lane_counts[b] is lane b's per-executor produced-row counts (the same
     honest per-region numbers the vmap tier reports); info is the shared
     {"cache_hit", "compile_ns"} attribution."""
-    import time as _time
-
-    from ..util import metrics
-
+    from . import launch
     from .ladder import rung_for
 
     R = int(stacked.row_valid.shape[0])
@@ -286,21 +279,18 @@ def drive_mesh_program_info(
         dag, caps, rung_for(group_capacity), jc, False, small_groups, True,
         mesh_lanes=R, mesh_devices=mesh_devices, mesh_kind=kind,
     )
-    t0 = _time.perf_counter_ns()
-    metrics.PROGRAM_LAUNCHES.inc()
-    merged, mvalid, ex_rows, ovf, radix_esc = prog.fn(stacked, *aux_batches)
-    overflow = bool(np.asarray(ovf))
-    info = {"cache_hit": hit, "compile_ns": 0}
-    if not hit:
-        # the flag fetch above blocked on the result: first-call time is
-        # trace+compile, same attribution as drive_program_info
-        info["compile_ns"] = build_ns + (_time.perf_counter_ns() - t0)
-    ex_np = np.asarray(ex_rows)
-    lane_counts = [[int(x) for x in ex_np[b]] for b in range(R)]
-    if overflow:
-        return None, lane_counts, info
-    _radix_attribution(prog, jc, np.asarray(radix_esc), info)
-    chunk = decode_outputs(merged, np.asarray(mvalid), prog.out_fts)
+    (merged, mvalid, ex_rows, _ovf, radix_esc), overflow, first_ns = launch.run_program(
+        prog.fn, (stacked, *aux_batches), first_call=not hit, flags=lambda o: bool(np.asarray(o[3])))
+    # the flag fetch blocked on the result: first-call time is
+    # trace+compile, same attribution as drive_program_info
+    info = {"cache_hit": hit, "compile_ns": 0 if hit else build_ns + first_ns}
+    with launch.read_back() as to_host:
+        ex_np = to_host(ex_rows)
+        lane_counts = [[int(x) for x in ex_np[b]] for b in range(R)]
+        if overflow:
+            return None, lane_counts, info
+        _radix_attribution(prog, jc, radix_esc, info, to_host)
+        chunk = decode_outputs(merged, mvalid, prog.out_fts, to_host)
     return chunk, lane_counts, info
 
 

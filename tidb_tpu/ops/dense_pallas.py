@@ -426,6 +426,7 @@ def group_aggregate_dense_pallas(group_bys, aggs, row_valid, g_cap: int, mode: s
                 pltpu.SMEM((G,), jnp.int32),
             ],
             interpret=(mode == "interpret"),
+            name="group_aggregate_dense",
         )(*lanes)
 
     # ---- epilogue (x64 world): reconstruct int64 states per group

@@ -141,6 +141,7 @@ def probe_tables_pallas(b_key_tbl, b_slot_ok, p_key_tbl, p_slot_ok,
             ),
             scratch_shapes=[pltpu.VMEM((8, LANES), jnp.int32)],
             interpret=interpret,
+            name="join_probe_tables",
         )(*ins)
     bpos = bpos2.reshape(P, probe_cap)
     dup = jnp.sum(meta[0].astype(jnp.int64)) != 0

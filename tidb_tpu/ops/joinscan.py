@@ -241,6 +241,7 @@ def postsort_segscan(spk, lanes_s, bad_lane, nw_s=None, nn_bits=(),
                 pltpu.VMEM((8, LANES), jnp.int32),
             ],
             interpret=interpret,
+            name="join_postsort_segscan",
         )(*ins)
 
     # Emission happens at e for the run that ended at e-1; shifting every
@@ -375,6 +376,7 @@ def membership_segscan(spk, bad_lane, interpret: bool = False):
                 pltpu.VMEM((8, LANES), jnp.int32),
             ],
             interpret=interpret,
+            name="join_membership_segscan",
         )(spk2, bad2)
     ok_out = ok2.reshape(np2)[:n] != 0
     overflow = jnp.sum(meta[0].astype(jnp.int64)) != 0

@@ -242,15 +242,14 @@ def run_sharded_grouped_agg(
         return agg_exchange_phases(agg, input_fts, cvals, valid, n_parts, group_capacity, bcap)
 
     spec_batch = jax.tree.map(lambda _: P(REGION_AXIS), stacked)
-    from ..mpp.exchange_op import cached_exchange_program
+    from ..mpp.exchange_op import run_exchange_program
     from .mesh import decode_group_mesh_outputs, group_mesh_out_spec
 
-    fn = cached_exchange_program(
-        dag, mesh,
+    outs = run_exchange_program(
+        "mesh_exchange_group_agg", dag, mesh,
         lambda: jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_batch,),
                               out_specs=group_mesh_out_spec(agg), check_vma=False),
-        group_capacity, bcap)
-    outs = fn(stacked)
+        (group_capacity, bcap), (stacked,))
     # decode: [agg results..., group keys...] with Complete-mode fts —
     # the shared seam (mesh.py) both grouped paths use
     return decode_group_mesh_outputs(outs, agg)

@@ -103,7 +103,9 @@ def run_sharded_partial_agg(dag, stacked: DeviceBatch, mesh: Mesh):
         dag, (cap,), mesh_lanes=R, mesh_devices=int(mesh.devices.size),
         mesh_kind="scalar",
     )
-    merged, _valid, _ex, _ovf, _esc = prog.fn(stacked)
+    from ..exec import launch
+
+    (merged, _valid, _ex, _ovf, _esc), _, _ = launch.run_program(prog.fn, (stacked,), first_call=True)
     return [tuple(out) for out in merged]
 
 
@@ -248,18 +250,20 @@ def decode_group_mesh_outputs(outs, agg):
     concatenated the per-device group tables along axis 0. Returns
     (chunk, overflow) in the Complete-mode layout [aggs..., group keys...].
     """
+    from ..exec import launch
     from ..exec.executor import decode_outputs
 
-    group_valid = np.asarray(outs[0]).reshape(-1)
-    overflow = bool(np.asarray(outs[-1]).reshape(-1)[0])
-    flat_out = outs[1:-1]
-    out_fts = [d.ft for d in agg.aggs] + [g.ft for g in agg.group_by]
-    packed = []
-    for i, _ft in enumerate(out_fts):
-        v = np.asarray(flat_out[2 * i])
-        nl = np.asarray(flat_out[2 * i + 1]).reshape(-1)
-        packed.append((v, nl))
-    return decode_outputs(packed, group_valid, out_fts), overflow
+    with launch.read_back() as to_host:
+        group_valid = to_host(outs[0]).reshape(-1)
+        overflow = bool(to_host(outs[-1]).reshape(-1)[0])
+        flat_out = outs[1:-1]
+        out_fts = [d.ft for d in agg.aggs] + [g.ft for g in agg.group_by]
+        packed = []
+        for i, _ft in enumerate(out_fts):
+            v = to_host(flat_out[2 * i])
+            nl = to_host(flat_out[2 * i + 1]).reshape(-1)
+            packed.append((v, nl))
+        return decode_outputs(packed, group_valid, out_fts), overflow
 
 
 def group_mesh_out_spec(agg):

@@ -59,6 +59,7 @@ class PacketIO:
     def __init__(self, sock):
         self.sock = sock
         self.seq = 0
+        self.packets_out = 0  # packets written since the connection opened
 
     def reset(self):
         self.seq = 0
@@ -76,6 +77,7 @@ class PacketIO:
             chunk, payload = payload[: 0xFFFFFF], payload[0xFFFFFF:]
             self.sock.sendall(struct.pack("<I", len(chunk))[:3] + bytes([self.seq]) + chunk)
             self.seq = (self.seq + 1) & 0xFF
+            self.packets_out += 1
             if len(chunk) < 0xFFFFFF:
                 break
 

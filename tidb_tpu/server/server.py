@@ -12,12 +12,14 @@ from __future__ import annotations
 import socket
 import socketserver
 import threading
+import time
 
 from ..sql import Session, SQLError
 from ..sql.catalog import Catalog, CatalogError
 from ..sql.planner import PlanError
 from ..store import TPUStore
 from ..types import Datum, DatumKind, Flag
+from ..util import metrics, tracing
 from . import protocol as P
 
 
@@ -88,6 +90,9 @@ class Connection:
 
     # ------------------------------------------------------------------
     def run(self):
+        """The command loop.  A command is clocked from its packet's read
+        until its last reply byte is handed to the socket; the wait for the
+        client's next packet is not."""
         while True:
             self.io.reset()
             try:
@@ -96,24 +101,27 @@ class Connection:
                 return
             if not pkt:
                 continue
-            cmd, payload = pkt[0], pkt[1:]
-            if cmd == P.COM_QUIT:
+            if pkt[0] == P.COM_QUIT:
                 return
-            if cmd == P.COM_PING:
-                self.io.write(P.ok_packet(status=self._status()))
-                continue
-            if cmd == P.COM_INIT_DB:
-                self.io.write(P.ok_packet(status=self._status()))
-                continue
-            if cmd == P.COM_FIELD_LIST:
-                self.io.write(P.eof_packet(self._status()))
-                continue
-            if cmd == P.COM_QUERY:
-                self.handle_query(payload.decode("utf-8", "replace"))
-                continue
-            if cmd in (P.COM_STMT_PREPARE, P.COM_STMT_EXECUTE, P.COM_STMT_CLOSE):
-                self.io.write(P.err_packet(1295, "binary protocol not supported; use text PREPARE/EXECUTE"))
-                continue
+            t0 = time.perf_counter_ns()
+            sent = self.io.packets_out
+            try:
+                self.dispatch(pkt[0], pkt[1:])
+            finally:
+                metrics.SERVER_PACKETS_OUT.inc(self.io.packets_out - sent)
+                metrics.SERVER_HANDLE_NS.inc(time.perf_counter_ns() - t0)
+                metrics.SERVER_COMMANDS.inc()  # last: a reader that sees the command sees its time and packets
+
+    def dispatch(self, cmd: int, payload: bytes):
+        if cmd in (P.COM_PING, P.COM_INIT_DB):
+            self.io.write(P.ok_packet(status=self._status()))
+        elif cmd == P.COM_FIELD_LIST:
+            self.io.write(P.eof_packet(self._status()))
+        elif cmd == P.COM_QUERY:
+            self.handle_query(payload.decode("utf-8", "replace"))
+        elif cmd in (P.COM_STMT_PREPARE, P.COM_STMT_EXECUTE, P.COM_STMT_CLOSE):
+            self.io.write(P.err_packet(1295, "binary protocol not supported; use text PREPARE/EXECUTE"))
+        else:
             self.io.write(P.err_packet(1047, f"unknown command {cmd}"))
 
     def handle_query(self, sql: str):
@@ -132,7 +140,10 @@ class Connection:
             except Exception as exc:  # noqa: BLE001 — wire must answer
                 self.io.write(P.err_packet(1105, f"internal error: {exc}"))
                 return
-            self.write_result(res, more=i + 1 < len(stmts))
+            t0 = time.perf_counter_ns()
+            with tracing.span("server.write"):
+                self.write_result(res, more=i + 1 < len(stmts))
+            metrics.SERVER_WRITE_NS.inc(time.perf_counter_ns() - t0)
 
     SERVER_MORE_RESULTS = 0x0008
 

@@ -334,9 +334,28 @@ PROGRAM_LAUNCHES = REGISTRY.counter("tidb_tpu_program_launches_total", "fused XL
 PROGRAM_CACHE_HITS = REGISTRY.counter("tidb_tpu_program_cache_hits_total", "program-cache hits (compile skipped)")
 PROGRAM_CACHE_ENTRIES = REGISTRY.gauge("tidb_tpu_program_cache_entries", "compiled programs resident in the cache")
 PROGRAM_COMPILE_DURATION = REGISTRY.histogram(
-    "tidb_tpu_program_compile_seconds", "XLA trace+compile time per program",
+    "tidb_tpu_program_compile_seconds",
+    "wall time of a program call in which JAX traced, lowered or compiled (exec/launch.py)",
     buckets=(0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 30.0),
 )
+# the launch boundary (exec/launch.py): what JAX's monitoring reports while a
+# program is called, and the host's wait and read-back after it; ns as integers
+XLA_COMPILES = REGISTRY.counter("tidb_tpu_xla_compiles_total", "XLA backend compiles (or persistent-cache loads) of programs")
+XLA_EAGER_COMPILES = REGISTRY.counter("tidb_tpu_xla_eager_compiles_total", "XLA backend compiles of eager jnp operations, outside any program")
+XLA_TRACE_LOWER_NS = REGISTRY.counter("tidb_tpu_xla_trace_lower_ns_total", "program calls that compiled: wall ns less the backend compile (jaxpr tracing, MLIR lowering)")
+XLA_BACKEND_COMPILE_NS = REGISTRY.counter("tidb_tpu_xla_backend_compile_ns_total", "ns in XLA backend compiles, programs and eager operations alike")
+XLA_PERSISTENT_CACHE_HITS = REGISTRY.counter("tidb_tpu_xla_persistent_cache_hits_total", "executables loaded from JAX's persistent compile cache")
+XLA_PERSISTENT_CACHE_MISSES = REGISTRY.counter("tidb_tpu_xla_persistent_cache_misses_total", "executables compiled and written to JAX's persistent compile cache")
+PROGRAM_WAIT_NS = REGISTRY.counter("tidb_tpu_program_wait_ns_total", "ns from a program call's return until its overflow flags are on the host")
+PROGRAM_READBACK_NS = REGISTRY.counter("tidb_tpu_program_readback_ns_total", "ns reading program outputs back and decoding them")
+PROGRAM_READBACK_TRANSFERS = REGISTRY.counter("tidb_tpu_program_readback_transfers_total", "device arrays converted to host arrays in read-back")
+PROGRAM_READBACK_BYTES = REGISTRY.counter("tidb_tpu_program_readback_bytes_total", "bytes of the device arrays converted in read-back")
+# the wire server (server/server.py): from a command's packet read until its
+# last reply byte is handed to the socket; waiting for the client is not counted
+SERVER_COMMANDS = REGISTRY.counter("tidb_tpu_server_commands_total", "wire commands handled")
+SERVER_HANDLE_NS = REGISTRY.counter("tidb_tpu_server_handle_ns_total", "ns handling wire commands, replies included")
+SERVER_WRITE_NS = REGISTRY.counter("tidb_tpu_server_write_ns_total", "ns encoding and sending result sets")
+SERVER_PACKETS_OUT = REGISTRY.counter("tidb_tpu_server_packets_out_total", "wire packets sent in reply to commands")
 STATEMENTS = REGISTRY.counter_vec(
     "tidb_tpu_statements_total", "statements executed by type and outcome",
     labelnames=("type", "status"),

@@ -19,6 +19,18 @@ Design:
 
 Durations are perf_counter_ns; a span still inside `with` reports the
 elapsed time so a partial tree (failing statement) renders consistently.
+A rendered span also carries its start as an offset from the root's, the
+thread it ran on and that thread's CPU time between start and finish, so
+the tree can be laid on a timeline and `wall - cpu` reads as time spent
+waiting: for the GIL, a lock or the device.
+
+The shared clock: the spans named in `HOST_STATES` also enter a
+`jax.profiler.TraceAnnotation`, for every statement, traced or not.  The
+profiler stamps them itself, on the clock of the device's `XLA Ops` line,
+so a `jax.profiler` session shows what the engine was doing in each of the
+device's idle gaps.  They are the states that do not contain one another;
+enclosing spans (`session.execute`, `distsql.execute_root`, `cop.execute`)
+are left out, or every label would be its ancestors.
 """
 
 from __future__ import annotations
@@ -29,17 +41,28 @@ import threading
 import time
 from contextlib import contextmanager
 
+from jax.profiler import TraceAnnotation
+
+HOST_STATES = frozenset({
+    "session.parse", "session.plan_cache", "planner.plan", "cop.decode",
+    "exec.compile", "exec.launch", "exec.wait", "exec.readback",
+    "distsql.root_merge", "server.write",
+})
+
 _current: contextvars.ContextVar = contextvars.ContextVar("tidb_tpu_span", default=None)
 
 
 class Span:
     """One timed operation with attributes and children."""
 
-    __slots__ = ("name", "attrs", "start_ns", "end_ns", "children", "_lock")
+    __slots__ = ("name", "attrs", "start_ns", "end_ns", "thread", "cpu_ns", "_cpu0", "children", "_lock")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs: dict = dict(attrs)
+        self.thread = threading.get_native_id()
+        self.cpu_ns: int | None = None  # the thread's CPU time inside the span, once finished
+        self._cpu0 = time.thread_time_ns()
         self.start_ns = time.perf_counter_ns()
         self.end_ns: int | None = None
         self.children: list[Span] = []  # guarded_by: _lock
@@ -59,6 +82,14 @@ class Span:
     def finish(self) -> None:
         if self.end_ns is None:
             self.end_ns = time.perf_counter_ns()
+            self.cpu_ns = time.thread_time_ns() - self._cpu0
+
+    def child_at(self, name: str, start_ns: int, end_ns: int) -> "Span":
+        """A finished child from times taken elsewhere (a duration that a
+        listener was handed): no CPU time is known for it."""
+        sp = self.child(name)
+        sp.start_ns, sp.end_ns = max(start_ns, self.start_ns), end_ns
+        return sp
 
     # -- reading -----------------------------------------------------------
     @property
@@ -87,14 +118,18 @@ class Span:
                 total += v
         return int(total)
 
-    def to_dict(self) -> dict:
+    def to_dict(self, _t0: int | None = None) -> dict:
+        t0 = self.start_ns if _t0 is None else _t0
         with self._lock:
             kids = list(self.children)
-        d: dict = {"name": self.name, "duration_ns": self.duration_ns}
+        d: dict = {"name": self.name, "start_ns": self.start_ns - t0, "duration_ns": self.duration_ns,
+                   "thread": self.thread}
+        if self.cpu_ns is not None:
+            d["cpu_ns"] = self.cpu_ns
         if self.attrs:
             d["attrs"] = dict(self.attrs)
         if kids:
-            d["children"] = [c.to_dict() for c in kids]
+            d["children"] = [c.to_dict(t0) for c in kids]
         return d
 
     def to_json(self) -> str:
@@ -136,23 +171,40 @@ def trace(name: str, **attrs):
         _current.reset(token)
 
 
-@contextmanager
-def span(name: str, parent: Span | None = None, **attrs):
+class span:
     """Child span of `parent` (explicit cross-thread handoff) or of the
-    ambient span; yields None — and skips all bookkeeping — when neither
-    exists. Exceptions are recorded on the span and re-raised, so a failing
-    statement leaves a partial tree with `error` attributes."""
-    cur = parent if parent is not None else _current.get()
-    if cur is None:
-        yield None
-        return
-    sp = cur.child(name, **attrs)
-    token = _current.set(sp)
-    try:
-        yield sp
-    except BaseException as exc:
-        sp.attrs["error"] = f"{type(exc).__name__}: {exc}"
-        raise
-    finally:
-        sp.finish()
-        _current.reset(token)
+    ambient span; yields None — and builds no Span — when neither exists.
+    Exceptions are recorded on the span and re-raised, so a failing
+    statement leaves a partial tree with `error` attributes.  A name in
+    `HOST_STATES` is put on the profiler's clock too, trace or no trace.
+
+    A class and not a generator: it is entered some 130 times an operation
+    whether or not anything is traced, by threads that share one GIL."""
+
+    __slots__ = ("_name", "_parent", "_attrs", "_note", "_sp", "_token")
+
+    def __init__(self, name: str, parent: Span | None = None, **attrs):
+        self._name, self._parent, self._attrs = name, parent, attrs
+        self._note = self._sp = self._token = None
+
+    def __enter__(self) -> Span | None:
+        if self._name in HOST_STATES:
+            self._note = TraceAnnotation(self._name)
+            self._note.__enter__()
+        cur = self._parent if self._parent is not None else _current.get()
+        if cur is None:
+            return None
+        sp = self._sp = cur.child(self._name, **self._attrs)
+        self._token = _current.set(sp)
+        return sp
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        sp = self._sp
+        if sp is not None:
+            if exc is not None:
+                sp.attrs["error"] = f"{exc_type.__name__}: {exc}"
+            sp.finish()
+            _current.reset(self._token)
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        return False
