@@ -403,7 +403,7 @@ def bench_config(cfg, device, n, iters, loop_k=None, peak_gbs=None):
                 # reduce-free q3 program SIGSEGV'd the TPU compiler
                 # (2026-07-31, not repeated since)
             )
-            out = jax.block_until_ready(prog.fn(*batches))
+            out = jax.block_until_ready(prog.fn(*batches, *dag.program_operands()))
             packed, valid, _, (g_ovf, j_ovf, t_ovf, g_need, j_need, _esc), _ = out
             g_ovf, j_ovf, t_ovf = bool(g_ovf), bool(j_ovf), bool(t_ovf)
             if not (g_ovf or j_ovf or t_ovf):
@@ -1103,7 +1103,7 @@ def _join_bench_main():
             prog = build_program(dag, caps, group_capacity=128,
                                  join_capacity=jc, unique_joins=uj,
                                  radix_joins=rj)
-            out = jax.block_until_ready(prog.fn(*batches))
+            out = jax.block_until_ready(prog.fn(*batches, *dag.program_operands()))
             _p, _v, _n, (g_ovf, j_ovf, t_ovf, _gn, j_need, esc), _e = out
             if not (bool(g_ovf) or bool(j_ovf) or bool(t_ovf)):
                 break
@@ -1118,7 +1118,7 @@ def _join_bench_main():
         times = []
         for _ in range(reps):
             t0 = time.perf_counter()
-            jax.block_until_ready(prog.fn(*batches))
+            jax.block_until_ready(prog.fn(*batches, *dag.program_operands()))
             times.append(time.perf_counter() - t0)
         med = statistics.median(times)
         rows = sum(int(b.n_rows) for b in batches)
@@ -1167,7 +1167,7 @@ def _join_bench_main():
     for rung in rungs:
         t0 = time.perf_counter()
         prog = cache.get(dag, caps, group_capacity=rung, join_capacity=jc)
-        jax.block_until_ready(prog.fn(*batches))
+        jax.block_until_ready(prog.fn(*batches, *dag.program_operands()))
         rung_compile_s.append(round(time.perf_counter() - t0, 2))
     stats0 = cache.stats()
     drive_program_info(cache, dag, batches, group_capacity=64)
@@ -1176,7 +1176,7 @@ def _join_bench_main():
     t0 = time.perf_counter()
     mono = build_program(dag, caps, group_capacity=1024, join_capacity=jc,
                          radix_joins=False)
-    jax.block_until_ready(mono.fn(*batches))
+    jax.block_until_ready(mono.fn(*batches, *dag.program_operands()))
     mono_compile_s = round(time.perf_counter() - t0, 2)
     print(json.dumps({
         "metric": "join_radix_vs_monolithic",

@@ -73,7 +73,7 @@ def assert_children_inside(node) -> None:
 
 # ------------------------------------------------------------------ spans
 class TestLaunchSpans:
-    def test_fresh_literal_compiles_and_its_repeat_launches(self, sess):
+    def test_fresh_shape_compiles_and_a_fresh_literal_launches(self, sess):
         with Moved(LAUNCH_COUNTERS) as first:
             tree = traced(sess, "SELECT SUM(k) FROM sb WHERE id BETWEEN 11 AND 110")
         (cop,) = find(tree, "cop.execute")
@@ -93,13 +93,16 @@ class TestLaunchSpans:
         assert first.by["XLA_BACKEND_COMPILE_NS"] >= sum(c["attrs"]["xla_ns"] for c in find(tree, "exec.compile"))
         assert first.by["XLA_TRACE_LOWER_NS"] > 0 and first.by["PROGRAM_WAIT_NS"] > 0
 
-        # the repeat, with the cop result cache out of its way (a write moved the table's version)
-        sess.execute("INSERT INTO sb VALUES (1000, 1, 'z')")
-        with Moved(LAUNCH_COUNTERS) as again:
-            tree = traced(sess, "SELECT SUM(k) FROM sb WHERE id BETWEEN 11 AND 110")
+        assert comp["attrs"]["params"] == 2   # BETWEEN's two literals, handed over as operands
+
+        # the same shape with fresh literals calls the program that is there:
+        # a literal is an operand, not part of the program's key
+        with Moved(LAUNCH_COUNTERS + ("PROGRAM_PARAMS_BOUND",)) as again:
+            tree = traced(sess, "SELECT SUM(k) FROM sb WHERE id BETWEEN 57 AND 156")
         (cop,) = find(tree, "cop.execute")
         assert names_under(cop) == ["exec.program", "exec.launch", "exec.wait", "exec.readback"]
-        assert find(cop, "exec.launch")[0]["attrs"] == {"program": "cop_scan_sel_agg"}
+        assert find(cop, "exec.launch")[0]["attrs"] == {"program": "cop_scan_sel_agg", "params": 2}
+        assert again.by["PROGRAM_PARAMS_BOUND"] == 2   # the root merge's program has none
         assert not find(tree, "exec.compile")
         assert again.by["XLA_COMPILES"] == again.by["PROGRAM_COMPILES"] == 0
         assert again.by["XLA_TRACE_LOWER_NS"] == 0
@@ -205,7 +208,8 @@ def test_program_names_come_from_the_shape_not_the_literals(sess):
     a = programs("SELECT SUM(k) FROM sb WHERE id BETWEEN 21 AND 120")
     b = programs("SELECT SUM(k) FROM sb WHERE id BETWEEN 131 AND 230")
     d = programs("SELECT DISTINCT c FROM sb WHERE id BETWEEN 21 AND 120 ORDER BY c")
-    assert a and b and a[0] == b[0] == "cop_scan_sel_agg"   # the push program, rebuilt per literal
+    assert a and a[0] == "cop_scan_sel_agg"
+    assert b == []   # the same shape with other literals: the program that is there is called
     assert d[0] == "cop_scan_sel_distinct"
     assert not set(a) & set(d)
 

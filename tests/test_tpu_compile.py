@@ -107,7 +107,9 @@ def _q6(shape):
     dag, fts = ge._q6_dag()
     small = to_device_batch(ge._rand_chunk(fts, 8), capacity=8)
     batch = jax.tree.map(lambda x: shape((ROWS,) + x.shape[1:] if x.ndim else (), x.dtype), small)
-    return build_program(dag, ROWS, group_capacity=16).fn, (batch,)
+    # the literals follow the batch as operands (exec/dag.py program_operands)
+    operands = tuple(shape(o.shape, o.dtype) for o in dag.program_operands())
+    return build_program(dag, ROWS, group_capacity=16).fn, (batch, *operands)
 
 
 @pytest.mark.parametrize("case,pallas_calls", [

@@ -111,9 +111,10 @@ class TestFixtureCorpus:
     def test_jax_audit_flags_fixture(self):
         found = analysis.run_pass("jax-audit", [_fixture("jaxaudit_bad.py")])
         msgs = _messages(found)
-        assert len(found) == 2, msgs
+        assert len(found) == 3, msgs
         assert any("float64 leaked into an integer-only program" in m for m in msgs)
         assert any("DIFFERENT jaxprs" in m and "closure-captured" in m for m in msgs)
+        assert any("'baked-literal'" in m and "two different literal values" in m for m in msgs)
 
     def test_metrics_flags_fixture(self):
         found = analysis.run_pass("metrics", [_fixture("metrics_bad.py")])
@@ -380,6 +381,18 @@ class TestJaxAudit:
 
     def test_live_catalog_is_clean(self):
         assert jaxaudit.run() == []
+
+    def test_live_audit_fires_when_literals_are_baked(self, monkeypatch):
+        """With the rule that makes a constant an operand switched off,
+        every catalog program with a literal bakes it, and the
+        two-literal build says so."""
+        from tidb_tpu.expr.ir import Const
+
+        monkeypatch.setattr(Const, "operand", lambda self: None)
+        monkeypatch.setattr(jaxaudit, "_LIVE_MEMO", None)
+        msgs = _messages(jaxaudit.run())
+        baked = {m.split("'")[1] for m in msgs if "two different literal values" in m}
+        assert baked == {"selection/single", "topn/single", "columnar_scan/single"}, msgs
 
     def test_vmap_axis_checker_fires_on_drift(self):
         class _A:

@@ -1,6 +1,8 @@
-"""jax-audit true positives: an integer program that leaks float64, and
-a builder whose closure captures a mutating Python scalar (every build
-traces a different jaxpr — the ProgramCache multiplies silently)."""
+"""jax-audit true positives: an integer program that leaks float64, a
+builder whose closure captures a mutating Python scalar (every build
+traces a different jaxpr — the ProgramCache multiplies silently), and a
+program that bakes a statement's literal into its trace (every fresh
+literal is a program of its own)."""
 
 import itertools
 
@@ -32,7 +34,19 @@ def _closure_scalar():
     return fn, _args()
 
 
+def _baked_literal(literal):
+    def make():
+        def fn(x):
+            # BAD: the statement's literal is a constant of the trace, not an operand
+            return (x > literal).sum()
+
+        return fn, _args()
+
+    return make
+
+
 JAX_AUDIT_CATALOG = [
     {"name": "f64-leak", "make": _f64_leak, "line": 17},
     {"name": "closure-scalar", "make": _closure_scalar, "line": 27},
+    {"name": "baked-literal", "make": _baked_literal(2), "make_other": _baked_literal(7), "line": 37},
 ]

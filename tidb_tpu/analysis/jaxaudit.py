@@ -26,11 +26,17 @@ traced BOTH single-region and vmap-batched — goes through four checks:
     ProgramCache miss then compiles a NEW entry (the cache key can't see
     the closure), silently multiplying entries and compile time. Large
     baked consts (>4 KiB) are flagged for the same reason: operand data
-    belongs in arguments, not in the program.
+    belongs in arguments, not in the program.  The same holds for a
+    statement's literals: every catalog program is also built for a
+    second set of literal values, and the two jaxprs must be
+    byte-identical — a parameterisable literal is an operand of the
+    program (`DAGRequest.program_operands`), and one baked into the
+    trace would compile a program per fresh literal.
 
 Fixture mode (`--files`): a fixture module exports `JAX_AUDIT_CATALOG`,
 a list of `{"name": str, "make": callable}` entries where `make()`
-returns `(fn, args)`; each is traced through the same checks.
+returns `(fn, args)`; each is traced through the same checks.  An entry
+may add `"make_other"`: the same program built for other literal values.
 """
 
 from __future__ import annotations
@@ -138,10 +144,12 @@ def audit_jaxpr(name: str, closed, anchor: tuple) -> list:
     return findings
 
 
-def audit_stability(name: str, make, anchor: tuple) -> tuple:
+def audit_stability(name: str, make, anchor: tuple, make_other=None) -> tuple:
     """Trace `make()` twice; differing jaxprs mean a closure-captured
-    value changed between builds. Returns (findings, first_closed_jaxpr,
-    args) so callers reuse the trace."""
+    value changed between builds.  `make_other()` builds the program for
+    other literal values; a jaxpr that differs from the first means a
+    literal was baked into the trace.  Returns (findings,
+    first_closed_jaxpr, args) so callers reuse the trace."""
     import jax
 
     rel, line = anchor
@@ -158,6 +166,15 @@ def audit_stability(name: str, make, anchor: tuple) -> tuple:
             f"id) is baked into the trace; every build multiplies "
             f"ProgramCache entries with programs the cache key cannot tell "
             f"apart"))
+    if make_other is not None:
+        fn3, args3 = make_other()
+        if str(jx1) != str(jax.make_jaxpr(fn3)(*args3)):
+            findings.append(Finding(
+                rel, line, PASS,
+                f"program {name!r}: builds for two different literal values "
+                f"traced to DIFFERENT jaxprs — a literal is baked into the "
+                f"trace instead of riding as an operand, so every fresh "
+                f"literal traces and compiles a program of its own"))
     return findings, jx1, args1
 
 
@@ -178,16 +195,18 @@ def _scan(table_id: int, I):
     return TableScan(table_id, (ColumnInfo(1, I), ColumnInfo(2, I)))
 
 
-def live_catalog() -> list:
+def live_catalog(literal: int = 2) -> list:
     """(name, dag, n_batches) for every exec-op builder path — the
-    acceptance set: selection, hashagg, streamagg, topn, hashjoin."""
+    acceptance set: selection, hashagg, streamagg, topn, hashjoin.
+    `literal` is the value of the catalog's parameterisable constants:
+    the trace-stability check builds every program for two of them."""
     from ..exec.dag import Aggregation, ColumnInfo, DAGRequest, Join, Selection, TableScan, TopN
     from ..expr import AggDesc, col, func, lit
 
     _ch, I = _int_chunk()
     scan = _scan(31, I)
     sel = DAGRequest(
-        (scan, Selection((func("gt", I, col(1, I), lit(2, I)),))),
+        (scan, Selection((func("gt", I, col(1, I), lit(literal, I)),))),
         output_offsets=(0, 1))
     hashagg = DAGRequest(
         (scan, Aggregation(group_by=(col(0, I),),
@@ -198,8 +217,10 @@ def live_catalog() -> list:
         (scan, Aggregation(group_by=(col(0, I),),
                            aggs=(AggDesc("max", (col(1, I),)),), stream=True)),
         output_offsets=(0, 1))
+    # a literal in the order expression: the mesh variant's re-top-k
+    # compiles it a second time, outside the per-region pipeline
     topn = DAGRequest(
-        (scan, TopN(order_by=((col(1, I), True),), limit=4)),
+        (scan, TopN(order_by=((func("mod", I, col(1, I), lit(literal + 5, I)), True),), limit=4)),
         output_offsets=(0, 1))
     join = DAGRequest(
         (scan, Join(build=(_scan(32, I),), probe_keys=(col(0, I),),
@@ -235,7 +256,7 @@ def live_catalog() -> list:
     # over the replica's device-resident stable chunk (columnar/route.py
     # `_run`), no partial/final split, no region axis
     columnar_scan = DAGRequest(
-        (scan, Selection((func("gt", I, col(1, I), lit(2, I)),)),
+        (scan, Selection((func("gt", I, col(1, I), lit(literal, I)),)),
          Aggregation(group_by=(col(0, I),),
                      aggs=(AggDesc("sum", (col(1, I),)),
                            AggDesc("count", ())))),
@@ -285,7 +306,7 @@ def _make_builder(dag, n_batches: int, vmap: bool, caps=None):
             dag, _entry_caps(n_batches, caps),
             group_capacity=_GROUP_CAPACITY,
             vmap_batch=_VMAP_BATCH if vmap else None)
-        return cd.fn, _batches(n_batches, vmap, caps)
+        return cd.fn, _batches(n_batches, vmap, caps) + list(dag.program_operands())
     return make
 
 
@@ -303,6 +324,7 @@ def audit_live() -> list:
     findings: list = []
     import jax
 
+    other_literals = {n: d for n, d, _nb, _caps in live_catalog(literal=7)}
     for name, dag, n_batches, caps in live_catalog():
         single_out = None
         for vmap in (False, True):
@@ -317,7 +339,9 @@ def audit_live() -> list:
                     closed = jax.make_jaxpr(fn)(*args)
                     fs = []
                 else:
-                    fs, closed, _args = audit_stability(variant, make, anchor)
+                    fs, closed, _args = audit_stability(
+                        variant, make, anchor,
+                        make_other=_make_builder(other_literals[name], n_batches, vmap, caps))
             except Exception as exc:  # noqa: BLE001 — a trace failure IS a finding
                 findings.append(Finding(
                     anchor[0], anchor[1], PASS,
@@ -401,7 +425,7 @@ def _audit_mesh_variant(name: str, dag, n_batches: int, anchor, caps=None) -> li
         ch, _I = _int_chunk()
         stacked = to_stacked_device_batch([ch] * lanes, entry_caps[0])
         aux = _batches(n_batches, False, caps)[1:]
-        closed = jax.make_jaxpr(cd.fn)(stacked, *aux)
+        closed = jax.make_jaxpr(cd.fn)(stacked, *aux, *dag.program_operands())
     except Exception as exc:  # noqa: BLE001 — a trace failure IS a finding
         return [Finding(anchor[0], anchor[1], PASS,
                         f"program {variant!r} failed to trace: {exc}")]
@@ -460,7 +484,7 @@ def audit_files(files) -> list:
             make = entry["make"]
             anchor = (sf.rel, entry.get("line", 1))
             try:
-                fs, closed, _args = audit_stability(name, make, anchor)
+                fs, closed, _args = audit_stability(name, make, anchor, entry.get("make_other"))
             except Exception as exc:  # noqa: BLE001
                 findings.append(Finding(
                     sf.rel, entry.get("line", 1), PASS,

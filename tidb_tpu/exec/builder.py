@@ -7,9 +7,15 @@ masked selection -> sort-based aggregation / topn / projection — XLA fuses
 the lot, which is the TPU analog of the legacy fused closure executor
 (ref: unistore/cophandler/closure_exec.go:165 buildClosureExecutor).
 
-Programs cache by (DAG fingerprint, capacity, group capacity) — the XLA
-compile is the expensive part, amortized exactly like the reference's
-coprocessor cache (ref: pkg/store/copr/coprocessor_cache.go).
+Programs cache by (program key, capacities, group/join capacity, tier) —
+the XLA compile is the expensive part, amortized exactly like the
+reference's coprocessor cache (ref: pkg/store/copr/coprocessor_cache.go).
+The program key (`DAGRequest.program_key`, exec/dag.py) is the plan's
+shape: what is traced is `dag.parameterized()`'s shape DAG, in which a
+parameterisable literal is a `Param` seat and a scan names no table, and
+the statement's values follow the batches as at most two operand arrays
+(`dag.program_operands()`).  One program per plan shape and capacity
+rung serves every literal and every table of one DDL.
 
 A program returns per-output-column (value, null[, raw bytes + lengths]),
 plus row validity, row count and an overflow flag; on overflow (group/join
@@ -19,7 +25,7 @@ back to the reference evaluator (SURVEY.md §7 "hard parts").
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +44,7 @@ from ..ops import joinscan as _eager_joinscan  # noqa: F401
 # later trace (jax UnexpectedTracerError, order-dependent).
 from ..ops.aggregate import GatherState, finalize_agg
 from ..types import FieldType
+from . import launch
 from .dag import Aggregation, DAGRequest, IndexScan, Join, Limit, Projection, Selection, Sort, TableScan, TopN, Window, collect_scans, current_schema_fts
 
 DEFAULT_GROUP_CAPACITY = 4096
@@ -66,6 +73,8 @@ class CompiledDAG:
     # call to emit the `join_radix` span/summary (partitions, rung,
     # escapes) — see exec/executor.py.
     radix_info: dict = None  # type: ignore[assignment]
+    # single-flight over the first call, where JAX traces and compiles
+    gate: launch.FirstCallGate = field(default_factory=launch.FirstCallGate)
 
 
 class _TraceState:
@@ -81,7 +90,7 @@ class _TraceState:
     join plan is more than the sorts cost — the bench path runs without
     them, production keeps them (EXPLAIN ANALYZE needs the numbers)."""
 
-    def __init__(self, summaries: bool = True):
+    def __init__(self, summaries: bool = True, params: dict | None = None):
         self.group_overflow = jnp.bool_(False)
         self.join_overflow = jnp.bool_(False)
         self.topn_overflow = jnp.bool_(False)
@@ -95,6 +104,7 @@ class _TraceState:
         self.radix_escapes = jnp.int64(0)
         self.radix_meta: dict = {}  # filled at trace time (partitions)
         self.radix_joins = True  # builder knob: False = monolithic only
+        self.params = params or {}  # lane -> the traced operand array that `Param` seats read (expr/ir.py)
         self.summaries = summaries
         self.ex_rows: list = []
 
@@ -211,7 +221,7 @@ def _run_pipeline(executors, batches, cursor, group_capacity, join_capacity, sta
     ei = 1
     while ei < len(executors):
         ex = executors[ei]
-        comp = ExprCompiler(fts)
+        comp = ExprCompiler(fts, state.params)
         with jax.named_scope(stage_name(ex)):
             if isinstance(ex, Selection):
                 conds = comp.run(list(ex.conditions), cols)
@@ -252,7 +262,7 @@ def _run_pipeline(executors, batches, cursor, group_capacity, join_capacity, sta
                         ei += 2
                         continue
                 bcols, bvalid, bfts = _run_pipeline(ex.build, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins)
-                bcomp = ExprCompiler(bfts)
+                bcomp = ExprCompiler(bfts, state.params)
                 bkeys = bcomp.run(list(ex.build_keys), bcols)
                 pkeys = comp.run(list(ex.probe_keys), cols)
                 _check_join_key_types(pkeys, bkeys)
@@ -504,7 +514,7 @@ def _trace_packed_chain(ex, agg, comp, cols, valid, batches, cursor, group_capac
         outer_execs, ij = chain
         ocols, ovalid, ofts = _run_pipeline(outer_execs, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins)
         icols, ivalid, ifts = _run_pipeline(list(ij.build), batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins)
-        ocomp, icomp = ExprCompiler(ofts), ExprCompiler(ifts)
+        ocomp, icomp = ExprCompiler(ofts, state.params), ExprCompiler(ifts, state.params)
         okey = ocomp.run([ij.probe_keys[0]], ocols)[0]
         ckey = icomp.run([ij.build_keys[0]], icols)[0]
         payload = ocomp.run([bk_e], ocols)[0]
@@ -517,7 +527,7 @@ def _trace_packed_chain(ex, agg, comp, cols, valid, batches, cursor, group_capac
         state.rows(hay_ok)  # inner join rows
     else:
         bcols, bvalid, bfts = _run_pipeline(list(ex.build), batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins)
-        bcomp = ExprCompiler(bfts)
+        bcomp = ExprCompiler(bfts, state.params)
         bkv = bcomp.run([bk_e], bcols)[0]
         hay_key = bkv.value
         hay_ok = bvalid & ~bkv.null
@@ -673,11 +683,16 @@ def build_program(
     n_scans = len(collect_scans(dag.executors))
     assert len(capacities) == n_scans, f"need {n_scans} batch capacities, got {len(capacities)}"
     join_capacity = join_capacity or max(capacities)
+    # what is traced is the plan's shape; the values of the DAG that
+    # happens to build the program are arguments like any later DAG's
+    dag, _key, operands = dag.parameterized()
+    lanes = tuple(o.dtype.kind for o in operands)  # "i", "f": which operand a `Param`'s lane names
 
     radix_info: dict = {}
 
-    def program(*batches):
-        state = _TraceState(summaries)
+    def program(*args):
+        batches = args[:n_scans]
+        state = _TraceState(summaries, params=dict(zip(lanes, args[n_scans:])))
         state.radix_joins = radix_joins
         cursor = [0]
         cols, valid, _ = _run_pipeline(dag.executors, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins, out_offsets=dag.output_offsets)
@@ -696,11 +711,13 @@ def build_program(
         return packed, valid, n_out, ovfs, ex
 
     if mesh_lanes is not None:
-        fn = _build_mesh_fn(dag, program, n_scans, mesh_lanes,
+        fn = _build_mesh_fn(dag, program, n_scans, lanes, mesh_lanes,
                             mesh_devices or 1, mesh_kind, group_capacity)
     elif vmap_batch is not None:
-        # region axis on the probe batch only; aux/build batches broadcast
-        fn = jax.vmap(program, in_axes=(0,) + (None,) * (n_scans - 1))
+        # region axis on the probe batch only; aux/build batches and the
+        # operands broadcast: a group holds requests of one fingerprint,
+        # so one set of values serves every lane
+        fn = jax.vmap(program, in_axes=(0,) + (None,) * (n_scans - 1 + len(lanes)))
     else:
         fn = program
     fn.__name__ = fn.__qualname__ = program_name(dag, vmap_batch, mesh_lanes, mesh_devices)
@@ -709,7 +726,7 @@ def build_program(
                        radix_info=radix_info)
 
 
-def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, lanes: int,
+def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, param_lanes: tuple, lanes: int,
                    n_devices: int, kind: str, group_capacity: int):
     """shard_map wrapper: vmap the per-region program over each device's
     local lanes, then merge the per-region results on device (psum of
@@ -727,6 +744,7 @@ def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, lanes: int,
     out_fts = dag.output_fts()
 
     def device_fn(local, *aux):
+        params = dict(zip(param_lanes, aux[n_scans - 1:]))
         packed, valid, _n, ovfs, ex = jax.vmap(lambda b: program(b, *aux))(local)
         local_ovf = ovfs[0].any() | ovfs[1].any() | ovfs[2].any()
         # radix escape total over the region axis (join_radix attribution
@@ -742,9 +760,9 @@ def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, lanes: int,
             cols, gvalid = _gather_mesh_outputs(packed, valid, out_fts)
             if kind == "group":
                 out_cols, mvalid, m_ovf = _mesh_merge_group(
-                    last, out_fts, cols, gvalid, group_capacity)
+                    last, out_fts, cols, gvalid, group_capacity, params)
             else:
-                out_cols, mvalid, m_ovf = _mesh_merge_topn(last, out_fts, cols, gvalid)
+                out_cols, mvalid, m_ovf = _mesh_merge_topn(last, out_fts, cols, gvalid, params)
             merged = _pack_cols(out_cols)
         ovf = jax.lax.pmax((local_ovf | m_ovf).astype(jnp.int32), REGION_AXIS) > 0
         return merged, mvalid, ex, ovf, radix_esc
@@ -753,8 +771,9 @@ def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, lanes: int,
         device_fn,
         mesh=mesh,
         # prefix specs: the whole stacked probe batch shards its leading
-        # region axis; aux (join build) batches replicate to every device
-        in_specs=(P(REGION_AXIS),) + (P(),) * (n_scans - 1),
+        # region axis; aux (join build) batches and the operands replicate
+        # to every device
+        in_specs=(P(REGION_AXIS),) + (P(),) * (n_scans - 1 + len(param_lanes)),
         # merged cols / valid / overflow / escape count are replicated in
         # fact (psum / all_gather-then-identical-local-work) but not
         # statically inferrable by the vma check; ex_rows keep their
@@ -789,7 +808,7 @@ def _gather_mesh_outputs(packed, valid, out_fts):
     return cols, gvalid
 
 
-def _mesh_merge_group(agg, state_fts, cols, valid, group_capacity: int):
+def _mesh_merge_group(agg, state_fts, cols, valid, group_capacity: int, params: dict):
     """Device-side merge of the gathered per-region group tables: the root
     Final merge's Partial2 re-group (root.py _merge_aggregation, partial
     output) traced INTO the mesh program — the output schema is the push
@@ -800,7 +819,7 @@ def _mesh_merge_group(agg, state_fts, cols, valid, group_capacity: int):
     from ..distsql.root import _merge_aggregation
 
     p2 = _replace(_merge_aggregation(agg), partial=True)
-    comp = ExprCompiler(state_fts)
+    comp = ExprCompiler(state_fts, params)
     gvals = comp.run(list(p2.group_by), cols)
     garg_exprs = [a for d in p2.aggs for a in d.args]
     avals = comp.run(garg_exprs, cols) if garg_exprs else []
@@ -817,13 +836,13 @@ def _mesh_merge_group(agg, state_fts, cols, valid, group_capacity: int):
     return new_cols, res.group_valid, res.overflow
 
 
-def _mesh_merge_topn(ex, fts, cols, valid):
+def _mesh_merge_topn(ex, fts, cols, valid, params: dict):
     """Device-side re-top-k over the gathered per-region candidates
     (global top-k ⊆ union of per-region top-k): the order expressions
     recompute over the candidate rows — TopN preserves its input schema,
     so the same exprs apply. full_sort: the candidate block is tiny
     (R*k rows) and the exact variant never overflows."""
-    comp = ExprCompiler(fts)
+    comp = ExprCompiler(fts, params)
     order_vals = comp.run([e for e, _ in ex.order_by], cols)
     by = list(zip(order_vals, [d for _, d in ex.order_by]))
     idx, out_valid, _ovf = topn(by, valid, ex.limit, full_sort=True)
@@ -853,7 +872,9 @@ def _agg_result_cols(a, av: list[CompVal], st, group_valid, partial: bool) -> li
 
 
 class ProgramCache:
-    """Fingerprint -> CompiledDAG (ref: coprocessor cache keying).
+    """Program key -> CompiledDAG (ref: coprocessor cache keying).  The
+    key is `dag.program_key()`, the plan's shape: requests that differ in
+    parameterisable literals or in the table they scan share an entry.
 
     The key includes the region-batch size (`vmap_batch`): a vmapped
     program is specialized to its leading axis, so a new batch shape is an
@@ -928,8 +949,8 @@ class ProgramCache:
         # buffer counts at execution)
         # mesh programs are specialized to their lane count AND device
         # count (shard_map shapes both into the trace); mesh_kind is
-        # derivable from the fingerprint but cheap to carry explicitly
-        key = (dag.fingerprint(), capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch, pallas_mode(), mesh_lanes, mesh_devices, mesh_kind, radix_joins)
+        # derivable from the key but cheap to carry explicitly
+        key = (dag.program_key(), capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch, pallas_mode(), mesh_lanes, mesh_devices, mesh_kind, radix_joins)
         import threading
 
         while True:

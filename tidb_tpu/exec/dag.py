@@ -4,16 +4,24 @@ Mirrors the executor-list shape of the reference wire format
 (ref: pingcap/tipb DAGRequest; built by pkg/planner/core/plan_to_pb.go and
 consumed by unistore/cophandler/cop_handler.go:319 buildDAG): a scan-first
 pipeline of executors plus output offsets and encode options. Everything is
-immutable and fingerprintable so compiled XLA programs cache per plan shape
+immutable and has two identities. `fingerprint()` names the request, values
+and tables included: the key of everything whose result depends on them
 (ref: the coprocessor-cache keying idea, pkg/store/copr/coprocessor_cache.go).
+`DAGRequest.program_key()` names the plan's shape, which is all a compiled
+XLA program depends on: every executor's `seated(seats)` gives its copy for
+the shape DAG, parameterisable constants replaced by `Param` seats
+(expr/ir.py) and the scans' table and index ids dropped, since the rows
+arrive as an argument.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
+
+import numpy as np
 
 from ..expr.agg import AggDesc
-from ..expr.ir import Expr
+from ..expr.ir import Expr, ParamSeats, seated_all
 from ..types import FieldType
 
 
@@ -44,6 +52,9 @@ class TableScan:
     def fingerprint(self):
         return ("scan", self.table_id, self.desc) + tuple(c.fingerprint() for c in self.columns)
 
+    def seated(self, seats):
+        return replace(self, table_id=0)
+
 
 @dataclass(frozen=True)
 class IndexScan:
@@ -65,6 +76,9 @@ class IndexScan:
             c.fingerprint() for c in self.columns
         )
 
+    def seated(self, seats):
+        return replace(self, table_id=0, index_id=0)
+
 
 @dataclass(frozen=True)
 class Selection:
@@ -75,6 +89,9 @@ class Selection:
     def fingerprint(self):
         return ("sel",) + tuple(c.fingerprint() for c in self.conditions)
 
+    def seated(self, seats):
+        return Selection(seated_all(self.conditions, seats))
+
 
 @dataclass(frozen=True)
 class Projection:
@@ -84,6 +101,9 @@ class Projection:
 
     def fingerprint(self):
         return ("proj",) + tuple(e.fingerprint() for e in self.exprs)
+
+    def seated(self, seats):
+        return Projection(seated_all(self.exprs, seats))
 
 
 @dataclass(frozen=True)
@@ -109,6 +129,10 @@ class Aggregation:
             + tuple(g.fingerprint() for g in self.group_by)
             + tuple(a.fingerprint() for a in self.aggs)
         )
+
+    def seated(self, seats):
+        return replace(self, group_by=seated_all(self.group_by, seats),
+                       aggs=tuple(a.seated(seats) for a in self.aggs))
 
     def output_fts(self) -> list[FieldType]:
         out = []
@@ -162,6 +186,11 @@ class Join:
             + ("bk",) + tuple(k.fingerprint() for k in self.build_keys)
         )
 
+    def seated(self, seats):
+        return replace(self, build=tuple(e.seated(seats) for e in self.build),
+                       probe_keys=seated_all(self.probe_keys, seats),
+                       build_keys=seated_all(self.build_keys, seats))
+
 
 @dataclass(frozen=True)
 class WinDesc:
@@ -182,6 +211,10 @@ class WinDesc:
         d = self.default.fingerprint() if self.default is not None else None
         return ("win", self.name, self.offset, d) + tuple(a.fingerprint() for a in self.args)
 
+    def seated(self, seats):
+        default = None if self.default is None else self.default.seated(seats)
+        return replace(self, default=default, args=seated_all(self.args, seats))
+
 
 @dataclass(frozen=True)
 class Window:
@@ -201,6 +234,10 @@ class Window:
             + ("fn",) + tuple(f.fingerprint() for f in self.funcs)
         )
 
+    def seated(self, seats):
+        return Window(seated_all(self.partition_by, seats), _seated_order(self.order_by, seats),
+                      tuple(f.seated(seats) for f in self.funcs))
+
 
 @dataclass(frozen=True)
 class TopN:
@@ -211,6 +248,9 @@ class TopN:
 
     def fingerprint(self):
         return ("topn", self.limit) + tuple((e.fingerprint(), d) for e, d in self.order_by)
+
+    def seated(self, seats):
+        return TopN(_seated_order(self.order_by, seats), self.limit)
 
 
 @dataclass(frozen=True)
@@ -226,6 +266,9 @@ class Sort:
     def fingerprint(self):
         return ("sort",) + tuple((e.fingerprint(), d) for e, d in self.order_by)
 
+    def seated(self, seats):
+        return Sort(_seated_order(self.order_by, seats))
+
 
 @dataclass(frozen=True)
 class Limit:
@@ -235,6 +278,9 @@ class Limit:
 
     def fingerprint(self):
         return ("limit", self.limit)
+
+    def seated(self, seats):
+        return self
 
 
 @dataclass(frozen=True)
@@ -253,6 +299,37 @@ class DAGRequest:
     def fingerprint(self):
         return tuple(e.fingerprint() for e in self.executors) + ("out",) + tuple(self.output_offsets)
 
+    def parameterized(self) -> tuple:
+        """(shape DAG, program key, operands): this request split into
+        what a compiled program depends on and what it is handed.  One
+        walk in executor order (a Join's build pipeline at the Join's
+        place) makes all three, so they cannot disagree: the shape DAG has
+        `Param` seats where `Const.operand()` says a constant is
+        parameterisable and no table or index id; the key is the shape
+        DAG's fingerprint; the operands are the seated values as host
+        arrays, the int64 one before the float64 one, an empty one left
+        out (never Python scalars, whose weak types would retrace, and
+        never one array per constant).  Kept on the instance: the cache
+        and the driver both ask."""
+        got = self.__dict__.get("_parameterized")
+        if got is None:
+            seats = ParamSeats()
+            shape = replace(self, executors=tuple(e.seated(seats) for e in self.executors))
+            operands = tuple(np.array(vals, dtype) for vals, dtype in
+                             ((seats.ints, np.int64), (seats.floats, np.float64)) if vals)
+            got = (shape, shape.fingerprint(), operands)
+            object.__setattr__(self, "_parameterized", got)
+        return got
+
+    def program_key(self) -> tuple:
+        """The identity of the compiled program that serves this request:
+        `fingerprint()` without what the program does not depend on."""
+        return self.parameterized()[1]
+
+    def program_operands(self) -> tuple:
+        """The arguments that follow the batches in a call of that program."""
+        return self.parameterized()[2]
+
     def scan(self):
         assert isinstance(self.executors[0], (TableScan, IndexScan))
         return self.executors[0]
@@ -260,6 +337,10 @@ class DAGRequest:
     def output_fts(self) -> list[FieldType]:
         fts = current_schema_fts(self.executors)
         return [fts[i] for i in self.output_offsets]
+
+
+def _seated_order(order_by: tuple, seats) -> tuple:
+    return tuple((e.seated(seats), d) for e, d in order_by)
 
 
 def current_schema_fts(executors) -> list[FieldType]:

@@ -137,10 +137,12 @@ def drive_program_info(cache: ProgramCache, dag: DAGRequest, batches, group_capa
     uj = True
     rj = True
     info = {"cache_hit": True, "compile_ns": 0}
+    operands = dag.program_operands()
     for _ in range(max_retries + 1):
         prog, hit, build_ns = cache.get_info(dag, caps, gc, jc, tf, smg, uj, radix_joins=rj)
         out, (g_ovf, j_ovf, t_ovf), first_ns = launch.run_program(
-            prog.fn, batches, first_call=not hit, flags=lambda o: tuple(bool(f) for f in o[3][:3]))
+            prog.fn, batches, operands, first_call=not hit, gate=prog.gate,
+            flags=lambda o: tuple(bool(f) for f in o[3][:3]))
         packed, valid, _n, (_g, _j, _t, g_need, j_need, radix_esc), ex_rows = out
         if not hit:
             info["cache_hit"] = False
@@ -205,7 +207,7 @@ def drive_batched_program_info(
         dag, caps, rung_for(group_capacity), jc, False, small_groups, True, vmap_batch=B
     )
     out, (g_ovf, j_ovf, t_ovf), first_ns = launch.run_program(
-        prog.fn, (stacked, *aux_batches), first_call=not hit,
+        prog.fn, (stacked, *aux_batches), dag.program_operands(), first_call=not hit, gate=prog.gate,
         flags=lambda o: tuple(np.asarray(f) for f in o[3][:3]))
     packed, valid, _n, (_g, _j, _t, _g_need, _j_need, radix_esc), ex_rows = out
     # the flag fetch blocked on the result: first-call time is
@@ -280,7 +282,8 @@ def drive_mesh_program_info(
         mesh_lanes=R, mesh_devices=mesh_devices, mesh_kind=kind,
     )
     (merged, mvalid, ex_rows, _ovf, radix_esc), overflow, first_ns = launch.run_program(
-        prog.fn, (stacked, *aux_batches), first_call=not hit, flags=lambda o: bool(np.asarray(o[3])))
+        prog.fn, (stacked, *aux_batches), dag.program_operands(), first_call=not hit, gate=prog.gate,
+        flags=lambda o: bool(np.asarray(o[3])))
     # the flag fetch blocked on the result: first-call time is
     # trace+compile, same attribution as drive_program_info
     info = {"cache_hit": hit, "compile_ns": 0 if hit else build_ns + first_ns}

@@ -102,8 +102,36 @@ jax.monitoring.register_event_duration_secs_listener(_on_duration)
 jax.monitoring.register_event_listener(_on_event)
 
 
-def run_program(fn, args, *, first_call: bool, flags=None):
-    """Call the jitted `fn(*args)`; where `flags` is given, fetch the
+class FirstCallGate:
+    """Single-flight over a program's first call.  `ProgramCache`'s claim
+    covers `build_program`, the Python closure; JAX traces and compiles
+    when the jitted function is first CALLED, and threads that call an
+    un-traced function at once each trace and compile it.  With one
+    program per plan shape every client of a cold server meets the same
+    function at once, so calls go through here until one has returned:
+    the first holds the lock while JAX traces and compiles, the others
+    then find the executable in the function's own cache."""
+
+    __slots__ = ("_mu", "done")
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        self.done = False  # a call has returned; set once, read without the lock
+
+    def call(self, fn, args):
+        if self.done:
+            return fn(*args)
+        with self._mu:
+            out = fn(*args)
+        self.done = True
+        return out
+
+
+def run_program(fn, args, operands=(), *, first_call: bool, flags=None, gate: FirstCallGate | None = None):
+    """Call the jitted `fn(*args, *operands)`: `args` are the batches,
+    `operands` the statement's values (`DAGRequest.program_operands()`),
+    counted in `PROGRAM_PARAMS_BOUND` and as the span's `params`.  `gate`
+    is the program's `FirstCallGate`.  Where `flags` is given, fetch the
     overflow flags with `flags(outputs)`, which blocks until the device is
     through.  Returns (outputs, flags on the host or None, first-call ns):
     the last is the wall time of call and wait when `first_call` says the
@@ -117,10 +145,14 @@ def run_program(fn, args, *, first_call: bool, flags=None):
     program = getattr(fn, "__name__", type(fn).__name__)
     heard = _calling.heard = _Heard()
     metrics.PROGRAM_LAUNCHES.inc()
+    n_params = sum(len(o) for o in operands)
+    if n_params:
+        metrics.PROGRAM_PARAMS_BOUND.inc(n_params)
+    args = (*args, *operands)
     t0 = time.perf_counter_ns()
     try:
-        with tracing.span("exec.compile" if first_call else "exec.launch", program=program) as sp:
-            out = fn(*args)
+        with tracing.span("exec.compile" if first_call else "exec.launch", program=program, params=n_params) as sp:
+            out = fn(*args) if gate is None else gate.call(fn, args)
             t1 = time.perf_counter_ns()
             if sp is not None:
                 _describe(sp, heard)

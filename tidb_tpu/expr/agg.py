@@ -22,10 +22,10 @@ Partial-state schemas (what crosses regions and what psum reduces):
 from __future__ import annotations
 
 import enum
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 from ..types import FieldType, TypeCode, new_longlong
-from .ir import Expr
+from .ir import Expr, seated_all
 
 AGG_FUNCS = frozenset({
     "count", "sum", "avg", "min", "max", "first_row", "bit_and", "bit_or", "bit_xor",
@@ -144,3 +144,7 @@ class AggDesc:
             self.ft.tp,
             self.ft.decimal,
         ) + tuple(a.fingerprint() for a in self.args)
+
+    def seated(self, seats) -> "AggDesc":
+        """(see `Expr.seated`)"""
+        return replace(self, args=seated_all(self.args, seats))

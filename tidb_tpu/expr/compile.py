@@ -32,8 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..chunk.device import DeviceColumn, pack_string_words
-from ..types import Datum, DatumKind, FieldType, MyDecimal, MyTime, TypeCode
-from .ir import ColumnRef, Const, Expr, ScalarFunc
+from ..types import FieldType, TypeCode
+from .ir import ColumnRef, Const, Expr, Param, ScalarFunc, lane_value
 
 # numpy (not jnp) scalar: created at import with no trace/x64-mode
 # capture — the jit-purity vet pass enforces this for module constants
@@ -226,10 +226,13 @@ def normalize_device_column(c: DeviceColumn) -> CompVal:
 
 
 class ExprCompiler:
-    """Compiles Expr trees against a fixed input schema."""
+    """Compiles Expr trees against a fixed input schema.  `params` maps a
+    lane ("i", "f") to the program's operand array that `Param` nodes read
+    their slot from (exec/builder.py passes the traced arguments)."""
 
-    def __init__(self, input_fts: list[FieldType]):
+    def __init__(self, input_fts: list[FieldType], params: dict | None = None):
         self.input_fts = input_fts
+        self._params = params or {}
 
     # -- entry ---------------------------------------------------------------
     def run(self, exprs: list[Expr], cols: list[DeviceColumn]) -> list[CompVal]:
@@ -245,6 +248,9 @@ class ExprCompiler:
             return self._column(e)
         if isinstance(e, Const):
             return self._const(e)
+        if isinstance(e, Param):
+            v = jnp.broadcast_to(self._params[e.lane][e.slot], (self._n,))
+            return CompVal(v, jnp.zeros(self._n, bool), e.ft)
         if isinstance(e, ScalarFunc):
             fn = getattr(self, f"_op_{e.op}", None)
             if fn is None:
@@ -272,18 +278,8 @@ class ExprCompiler:
             dt = jnp.float64 if et == "real" else jnp.int64
             return CompVal(jnp.zeros(n, dt), jnp.ones(n, bool), e.ft)
         et = e.ft.eval_type()
-        if et == "real":
-            v = jnp.full(n, float(d.val), jnp.float64)
-        elif et == "decimal":
-            dec = d.val if isinstance(d.val, MyDecimal) else MyDecimal(d.val)
-            v = jnp.full(n, dec.to_scaled_int(_scale(e.ft)), jnp.int64)
-        elif et == "time":
-            packed = d.val.packed if isinstance(d.val, MyTime) else int(d.val)
-            v = jnp.full(n, packed, jnp.int64)
-        elif et == "string":
+        if et == "string":
             b = d.val.encode() if isinstance(d.val, str) else bytes(d.val)
-            import numpy as np
-
             w = max(1, len(b))
             data = np.zeros((1, w), np.uint8)
             data[0, : len(b)] = np.frombuffer(b, np.uint8)
@@ -292,8 +288,9 @@ class ExprCompiler:
             return CompVal(v, jnp.zeros(n, bool), e.ft,
                            raw=(jnp.broadcast_to(jnp.asarray(data), (n, w)), jnp.full(n, len(b), jnp.int32)),
                            const_bytes=b)
-        else:
-            v = jnp.full(n, int(d.val), jnp.int64)
+        # a constant that stayed in the trace (`Const.operand()` is None, or
+        # the caller did not parameterise): the same host value, baked
+        v = jnp.full(n, lane_value(d, e.ft), jnp.float64 if et == "real" else jnp.int64)
         return CompVal(v, jnp.zeros(n, bool), e.ft)
 
     # -- coercion ------------------------------------------------------------

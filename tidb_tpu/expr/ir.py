@@ -4,7 +4,11 @@ The reference serializes planner expressions to protobuf (ref:
 pkg/expression/expr_to_pb.go:37 ExpressionsToPBList) and rebuilds them on the
 coprocessor side (ref: pkg/expression/distsql_builtin.go). Here the IR *is*
 the wire/plan form: immutable, hashable nodes carrying a result FieldType, so
-a whole DAG fingerprints to a cache key for compiled XLA programs
+a whole DAG fingerprints to a key for everything whose result depends on
+its values (result cache, batch grouping, plan digest), and `seated`
+(below, with `exec/dag.py` `DAGRequest.parameterized`) gives the second
+identity that compiled XLA programs are keyed by: the plan's shape, with
+the statement's literals handed to the program as operands
 (SURVEY.md §7 layer 4).
 
 Ops use generic names; the eval class of the *arguments* selects the concrete
@@ -17,7 +21,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ..types import Datum, FieldType
+from ..types import Datum, DatumKind, FieldType, MyDecimal, MyTime
 
 # Canonical op names understood by the compiler (compile.py OP table) and the
 # reference evaluator (eval_ref.py). Mirrors the pushdown whitelist idea of
@@ -72,6 +76,11 @@ class Expr:
     def fingerprint(self) -> tuple:
         raise NotImplementedError
 
+    def seated(self, seats: "ParamSeats") -> "Expr":
+        """This tree with every parameterisable `Const` replaced by the
+        `Param` that `seats` gave it."""
+        return self
+
 
 @dataclass(frozen=True)
 class ColumnRef(Expr):
@@ -85,11 +94,42 @@ class ColumnRef(Expr):
         return ("col", self.index, self.ft.tp, int(self.ft.flag), self.ft.flen, self.ft.decimal)
 
 
+def lane_value(d: Datum, ft: FieldType):
+    """The host scalar that stands in every lane of a non-NULL constant of
+    a fixed-width class, as `ExprCompiler._const` bakes it and as a `Param`
+    hands it over: a float for reals, else an int (decimals scaled by
+    10^ft.decimal, times in the packed layout)."""
+    et = ft.eval_type()
+    if et == "real":
+        return float(d.val)
+    if et == "decimal":
+        dec = d.val if isinstance(d.val, MyDecimal) else MyDecimal(d.val)
+        return dec.to_scaled_int(max(ft.decimal, 0))
+    if et == "time":
+        return d.val.packed if isinstance(d.val, MyTime) else int(d.val)
+    return int(d.val)
+
+
+_PARAM_CLASSES = ("int", "real", "decimal", "time")
+_I64_MIN, _I64_MAX = -(1 << 63), (1 << 63) - 1
+
+# The argument positions whose constant the compiler reads while it traces
+# (compile.py `_op_round`, `_op_like`, `_date_shift`, `_op_extract`): a
+# constant there shapes the program and keeps its value in the program's
+# key.  An `_op_*` that comes to need a value at trace time joins this
+# table.
+TRACE_TIME_ARGS = {"round": (1,), "like": (1,), "date_add": (2,), "date_sub": (2,), "extract": (0,)}
+
+
 @dataclass(frozen=True)
 class Const(Expr):
-    """A literal. The datum participates in the fingerprint so constant
-    folding differences recompile (mirrors plan-cache parameterization —
-    heavy reuse should parameterize instead; see exec/builder.py)."""
+    """A literal. The datum is part of `fingerprint()`, the identity of
+    everything whose result depends on the value. A compiled program does
+    not, where the constant is parameterisable (`operand()`): the program's
+    key (`DAGRequest.program_key`) holds its type alone and the value is
+    handed in as an operand, so statements that differ in such literals
+    share one program. Strings, NULLs and the positions of
+    `TRACE_TIME_ARGS` shape the trace and keep their value in that key."""
 
     datum: Datum
     ft: FieldType
@@ -98,6 +138,65 @@ class Const(Expr):
         v = self.datum.val
         key = str(v) if not isinstance(v, (int, float, str, bytes, type(None))) else v
         return ("const", self.datum.kind, key, self.ft.tp, self.ft.decimal)
+
+    def operand(self):
+        """(lane, value) where a program takes this constant as an operand,
+        lane "i" for the int64 array (ints, scaled decimals, packed times)
+        and "f" for the float64 one; None where the constant stays in the
+        trace: NULL, a class without a fixed width, or a value that the
+        lane's dtype does not hold.  The one rule that the program's key
+        and the operands are both made from (`seated`)."""
+        if self.datum.is_null() or self.ft.eval_type() not in _PARAM_CLASSES:
+            return None
+        try:
+            v = lane_value(self.datum, self.ft)
+        except (TypeError, ValueError, ArithmeticError, AttributeError):
+            return None
+        if isinstance(v, float):
+            return ("f", v)
+        return ("i", v) if _I64_MIN <= v <= _I64_MAX else None
+
+    def seated(self, seats: "ParamSeats") -> Expr:
+        op = self.operand()
+        return self if op is None else seats.seat(self, *op)
+
+
+@dataclass(frozen=True)
+class Param(Expr):
+    """A parameterisable constant's seat in a compiled program: slot
+    `slot` of the program's int64 (`lane` "i") or float64 ("f") operand.
+    Exists only inside `DAGRequest.parameterized()`'s shape DAG, which is
+    what `exec/builder.py` traces and what the program cache keys on."""
+
+    lane: str
+    slot: int
+    kind: DatumKind
+    ft: FieldType
+
+    def fingerprint(self) -> tuple:
+        # the flag too: signedness picks the compare (compile.py `_cmp`)
+        return ("param", self.lane, self.slot, self.kind, self.ft.tp, int(self.ft.flag), self.ft.decimal)
+
+
+class ParamSeats:
+    """Hands out `Param` seats in the order of one walk over a DAG and
+    keeps the values seated, per lane."""
+
+    __slots__ = ("ints", "floats")
+
+    def __init__(self):
+        self.ints: list = []
+        self.floats: list = []
+
+    def seat(self, c: Const, lane: str, value) -> Param:
+        vals = self.ints if lane == "i" else self.floats
+        vals.append(value)
+        return Param(lane, len(vals) - 1, c.datum.kind, c.ft)
+
+
+def seated_all(exprs: tuple, seats: ParamSeats) -> tuple:
+    """`seated` over a tuple of expressions."""
+    return tuple(e.seated(seats) for e in exprs)
 
 
 @dataclass(frozen=True)
@@ -117,6 +216,11 @@ class ScalarFunc(Expr):
         return ("fn", self.op, self.ft.tp, int(self.ft.flag), self.ft.decimal) + tuple(
             a.fingerprint() for a in self.args
         )
+
+    def seated(self, seats: ParamSeats) -> Expr:
+        fixed = TRACE_TIME_ARGS.get(self.op, ())
+        args = tuple(a if i in fixed else a.seated(seats) for i, a in enumerate(self.args))
+        return ScalarFunc(self.op, args, self.ft)
 
 
 # ---- convenience constructors ---------------------------------------------

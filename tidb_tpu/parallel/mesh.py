@@ -105,7 +105,8 @@ def run_sharded_partial_agg(dag, stacked: DeviceBatch, mesh: Mesh):
     )
     from ..exec import launch
 
-    (merged, _valid, _ex, _ovf, _esc), _, _ = launch.run_program(prog.fn, (stacked,), first_call=True)
+    (merged, _valid, _ex, _ovf, _esc), _, _ = launch.run_program(
+        prog.fn, (stacked,), dag.program_operands(), first_call=True)
     return [tuple(out) for out in merged]
 
 

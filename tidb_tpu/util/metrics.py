@@ -331,6 +331,7 @@ REPLICA_QUORUM_FAILS = REGISTRY.counter(
     "tidb_tpu_replica_quorum_fail_total", "write proposals that failed to reach quorum ack")
 PROGRAM_COMPILES = REGISTRY.counter("tidb_tpu_program_compiles_total", "fused XLA programs built")
 PROGRAM_LAUNCHES = REGISTRY.counter("tidb_tpu_program_launches_total", "fused XLA program executions dispatched (batched counts once)")
+PROGRAM_PARAMS_BOUND = REGISTRY.counter("tidb_tpu_program_params_bound_total", "constants handed to compiled programs as operands, summed over launches")
 PROGRAM_CACHE_HITS = REGISTRY.counter("tidb_tpu_program_cache_hits_total", "program-cache hits (compile skipped)")
 PROGRAM_CACHE_ENTRIES = REGISTRY.gauge("tidb_tpu_program_cache_entries", "compiled programs resident in the cache")
 PROGRAM_COMPILE_DURATION = REGISTRY.histogram(
