@@ -123,3 +123,50 @@ def test_compiles_for_v5e(case, pallas_calls, one_chip, monkeypatch):
     compiled = jax.jit(fn).lower(*args).compile()
     # a kernel that gave way to the XLA branch beside it would still compile
     assert compiled.as_text().count("tpu_custom_call") == pallas_calls
+
+
+# ------------------------------------------------------------------------
+# What JAX's persistent-cache key is made of for a program that carries a
+# Pallas kernel (ROADMAP S6, settled in PR 28).  The key hashes the module
+# with its own debug info stripped, but the Mosaic kernel travels inside
+# `tpu_custom_call`'s backend_config as bytecode that KEEPS its source
+# locations: the files and lines of the Python call stack above the kernel.
+# Two processes of one tree at one path agree on those (the chip showed the
+# second run of a checkout hitting); a moved line in any caller, or another
+# checkout directory, is another key for every Pallas-bearing program while
+# the pure-XLA programs keep theirs: what PR 22 saw.
+def _computation_hash(lowered) -> str:
+    import hashlib
+
+    from jax._src import cache_key
+
+    module = lowered.compiler_ir("stablehlo")
+    return hashlib.sha256(cache_key._canonicalize_ir(module, cache_key.IgnoreCallbacks.NO)).hexdigest()
+
+
+def _lower_dense(one_chip, pad: int = 0):
+    """The dense group-by lowered for the v5e, called through a function
+    that sits `pad` lines further down its (made-up) file."""
+    fn, args = _dense(lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip))
+    scope = {"fn": fn}
+    exec(compile("\n" * pad + "def call(*a):\n    return fn(*a)\n", "/caller_of_the_kernel.py", "exec"), scope)
+    return jax.jit(scope["call"]).lower(*args)
+
+
+def test_pallas_cache_key_is_the_same_for_two_builds_of_one_tree(one_chip, monkeypatch):
+    """Nothing of the program's own goes into the key that differs from one
+    build to the next: no counter or `id()` in the kernel's name, no
+    argument order taken from a set, no closure over a fresh object."""
+    monkeypatch.setenv("TIDB_TPU_PALLAS", "tpu")
+    first, second = [_lower_dense(one_chip) for _ in range(2)]   # one call site: a location is file, line and column
+    assert first.as_text().count("tpu_custom_call") == 1
+    assert 'kernel_name = "group_aggregate_dense"' in first.as_text()
+    assert _computation_hash(first) == _computation_hash(second)
+
+
+def test_pallas_cache_key_holds_the_lines_of_the_kernels_callers(one_chip, monkeypatch):
+    """Pins the finding, not a wish: should this fail, JAX no longer keeps
+    source locations in the Mosaic bytecode, an edit above the kernel no
+    longer costs Q1's ~100 s compile again, and PERF.md section 7 can say so."""
+    monkeypatch.setenv("TIDB_TPU_PALLAS", "tpu")
+    assert _computation_hash(_lower_dense(one_chip)) != _computation_hash(_lower_dense(one_chip, pad=7))

@@ -43,7 +43,7 @@ PASS = "failpoints"
 DESCRIPTIONS = {
     "cop-region-error": "injects `epoch_not_match` at the coprocessor RPC seam — exercises the re-split retry path",
     "cop-other-error": "injects a non-retryable `other_error` cop response — surfaces as CopInternalError / MySQL 1105",
-    "cop-debug-raise": "re-raises store-side execution errors with a stack instead of folding them into `other_error`",
+    "cop-debug-raise": "re-raises store-side execution errors with a stack instead of folding them into `other_error`, and errors on the columnar replica's path instead of falling back to the row store",
     "distsql.before_task": "hook before every cop-task send — tests raise or count here to probe the dispatch loop",
     "ddl_index_delete_only": "pauses online index DDL in the delete-only state so tests can write concurrently",
     "ddl_index_write_only": "pauses online index DDL in the write-only state",

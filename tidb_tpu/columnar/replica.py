@@ -109,6 +109,7 @@ class ColumnarTable:
         self._stable_chunk: Chunk | None = None  # sorted by handle; guarded_by: _mu
         self._stable_handles: list = []  # sorted handles; guarded_by: _mu
         self._stable_batch = None  # device-resident stable; guarded_by: _mu
+        self.device_bytes = 0  # of _stable_batch, as counted in COLUMNAR_DEVICE_BYTES; guarded_by: _mu
         self.applied_events = 0  # guarded_by: _mu
         self.compactions = 0  # guarded_by: _mu
         self.last_error = ""  # last compaction failure (GIL-atomic str swap)
@@ -119,6 +120,23 @@ class ColumnarTable:
         place, and views/routing must follow it (review finding: a
         name-keyed registry orphaned the feed across a rename)."""
         return self.meta.name
+
+    # ----------------------------------------------------------- device
+    def _hold(self, batch) -> None:  # requires: _mu
+        """Put `batch` (or nothing) in the device-resident stable batch's
+        place, and keep COLUMNAR_DEVICE_BYTES at what the replicas hold."""
+        import jax
+
+        from ..util import metrics
+
+        nbytes = 0 if batch is None else sum(x.nbytes for x in jax.tree_util.tree_leaves(batch))
+        metrics.COLUMNAR_DEVICE_BYTES.inc(nbytes - self.device_bytes)
+        self._stable_batch, self.device_bytes = batch, nbytes
+
+    def release(self) -> None:
+        """The table left the replica: its device batch is no longer held."""
+        with self._mu:
+            self._hold(None)
 
     # ------------------------------------------------------------ delta
     def apply(self, commit_ts: int, handle: int, row: list | None) -> None:
@@ -172,7 +190,7 @@ class ColumnarTable:
                 self.fts, [self._stable_rows[h] for h in self._stable_handles])
             # the host chunk serves until the next compact re-uploads;
             # a stale-shape device batch must never outlive the remap
-            self._stable_batch = None
+            self._hold(None)
             return True
 
     # ------------------------------------------------------- compaction
@@ -220,7 +238,7 @@ class ColumnarTable:
                 batch = None
             self._stable_chunk = chunk
             self._stable_handles = handles
-            self._stable_batch = batch
+            self._hold(batch)
             self.stable_ts = fold_ts
             self.compactions += 1
             return len(take)
@@ -231,17 +249,22 @@ class ColumnarTable:
         with self._mu:
             return self.applied_ts, self.stable_ts
 
-    def scan(self, start_ts: int, intervals: list | None):
+    def scan(self, start_ts: int, intervals: list | None, layers: dict | None = None):
         """Rows visible at `start_ts` as (chunk, device_batch|None).
         `intervals` is a list of inclusive (lo, hi) handle bounds (None =
         the whole table). The fast path — no unfolded delta at this
         snapshot, full-range scan — returns the cached stable chunk and
         its device-resident batch untouched; otherwise the delta overlay
-        merges on the host (still typed datums, never rowcodec)."""
+        merges on the host (still typed datums, never rowcodec). `layers`,
+        where given, has the rows read from each layer added to its
+        `stable_rows` and `delta_rows` (the route's span attributes)."""
         with self._mu:
             if start_ts < self.stable_ts or start_ts > self.applied_ts:
                 raise ColumnarNotReady(self.name, start_ts, self.applied_ts, self.stable_ts)
             overlay = [e for e in self.delta if e[0] <= start_ts]
+            if layers is not None:
+                layers["stable_rows"] += len(self._stable_handles)
+                layers["delta_rows"] += len(overlay)
             full = intervals is None or any(
                 lo <= I64_MIN and hi >= I64_MAX for lo, hi in intervals)
             if not overlay and full and self._stable_chunk is not None:
@@ -269,6 +292,7 @@ class ColumnarTable:
                 "stable_rows": len(self._stable_handles),
                 "stable_chunk": self._stable_chunk is not None,
                 "on_device": self._stable_batch is not None,
+                "device_bytes": self.device_bytes,
                 "applied_ts": self.applied_ts,
                 "stable_ts": self.stable_ts,
                 "applied_events": self.applied_events,
@@ -333,8 +357,10 @@ class ColumnarReplica:
         with self._mu:
             feed_name = self._feeds.pop(meta.table_id, None)
             last_label = self._gauge_names.pop(meta.table_id, None)
-            for pid in meta.physical_ids():
-                self._by_pid.pop(pid, None)
+            dropped = [self._by_pid.pop(pid, None) for pid in meta.physical_ids()]
+        for t in dropped:
+            if t is not None:
+                t.release()
         if last_label is not None and last_label != meta.name:
             from ..util import metrics
 

@@ -25,9 +25,16 @@ Staleness: a scan at `start_ts` needs the replica frontier to cover it
 DataIsNotReady shape: one wait on the `data_not_ready` backoff budget
 (PR 8's replication budget — a background tick may advance the frontier),
 one re-check, then a counted fallback to the row store. Never a torn
-prefix."""
+prefix.
+
+Errors: an exception on the replica's path degrades to the row store (a
+counted fallback) — unless the `cop-debug-raise` failpoint is armed, which
+makes it fail the statement as it does for the row store's cop path, so a
+run that must prove this path cannot be served by the other one."""
 
 from __future__ import annotations
+
+import time
 
 from ..codec import tablecodec
 from .replica import I64_MAX, I64_MIN, ColumnarNotReady, _schema_sig
@@ -118,7 +125,7 @@ def try_columnar_select(store, dag, ranges, start_ts: int, aux_chunks: list,
     cover the snapshot after one data_not_ready wait (a counted fallback —
     the caller dispatches to the row store as if routing never happened)."""
     from ..exec.builder import DEFAULT_GROUP_CAPACITY
-    from ..util import metrics, tracing
+    from ..util import failpoint, metrics, tracing
 
     rep = getattr(store, "columnar", None)
     if rep is None or not rep.has_tables() or not _analytical(dag):
@@ -147,8 +154,8 @@ def try_columnar_select(store, dag, ranges, start_ts: int, aux_chunks: list,
                       start_ts=start_ts, snapshot_ts=ts_eff,
                       pids=len(tables)) as sp:
         try:
-            out = _run(store, dag, plan, tables, ts_eff, aux_chunks,
-                       cache, group_capacity, small_groups)
+            out, read = _run(store, dag, plan, tables, ts_eff, aux_chunks,
+                             cache, group_capacity, small_groups)
         except ColumnarNotReady:
             # a compaction advanced the floor between the gate and the
             # scan: fall back rather than serve a torn snapshot
@@ -156,16 +163,38 @@ def try_columnar_select(store, dag, ranges, start_ts: int, aux_chunks: list,
             return None
         except Exception:  # noqa: BLE001 — degrade, never fail the query:
             # the row store still owns the authoritative answer
+            if failpoint.eval("cop-debug-raise"):
+                raise  # loud-failure gate, as on the row store's cop path
             metrics.COLUMNAR_FALLBACKS.inc()
             return None
         if sp is not None:
             sp.set("rows", out.num_rows())
+            sp.attrs.update(read)
     metrics.COLUMNAR_SCANS.inc()
+    if read["resident"]:
+        metrics.COLUMNAR_RESIDENT_SCANS.inc()
     return out
 
 
 def _wait_ready(store, tables, start_ts: int, backoff_weight: int, checker):
-    """The staleness gate. Returns the snapshot the replica serves at —
+    """`_gate` under the `columnar.gate` span (attrs `waited`: the
+    data_not_ready back-off was taken; `snapshot_ts`: what it answered),
+    its time counted in COLUMNAR_GATE_WAIT_NS."""
+    from ..util import metrics, tracing
+
+    t0 = time.perf_counter_ns()
+    with tracing.span("columnar.gate", start_ts=start_ts) as sp:
+        ts, waited = _gate(store, tables, start_ts, backoff_weight, checker)
+        if sp is not None:
+            sp.set("waited", waited)
+            sp.set("snapshot_ts", ts)
+    metrics.COLUMNAR_GATE_WAIT_NS.inc(time.perf_counter_ns() - t0)
+    return ts
+
+
+def _gate(store, tables, start_ts: int, backoff_weight: int, checker) -> tuple:
+    """The staleness gate. Returns (snapshot, waited): `waited` says that
+    the data_not_ready back-off was taken; the snapshot the replica serves at —
     `min(start_ts, applied_ts)` — or None for a counted row-store
     fallback. The served snapshot is provably EQUIVALENT to `start_ts`:
     it is either `start_ts` itself (the frontier covers it), or the
@@ -209,11 +238,11 @@ def _wait_ready(store, tables, start_ts: int, backoff_weight: int, checker):
 
     ts = gate()
     if ts is not None:
-        return ts
+        return ts, False
     if start_ts < max(t.frontier()[1] for t in tables):
         # below the compaction floor: floors only advance, so waiting
         # can never make this snapshot servable — fail fast
-        return None
+        return None, False
     applied = min(t.frontier()[0] for t in tables)
     boff = Backoffer(weight=backoff_weight, checker=checker)
     try:
@@ -221,17 +250,20 @@ def _wait_ready(store, tables, start_ts: int, backoff_weight: int, checker):
             "data_not_ready",
             f"columnar data_is_not_ready: applied_ts={applied} start_ts={start_ts}")
     except BackoffExhausted:
-        return None
-    return gate()
+        return None, True
+    return gate(), True
 
 
 def _run(store, dag, plan: dict, tables: list, start_ts: int, aux_chunks,
-         cache, group_capacity: int, small_groups):
+         cache, group_capacity: int, small_groups) -> tuple:
     """Execute the DAG over the replica's chunks. Single-table full scans
     with a folded delta ride the DEVICE-RESIDENT stable batch straight
     into the fused program (zero upload, zero decode); everything else
     merges the delta overlay on the host and takes the standard
-    chunk-execution path (spill + oracle fallbacks included)."""
+    chunk-execution path (spill + oracle fallbacks included). Returns
+    (chunk, what was read): `resident` says which of the two answered,
+    `stable_rows` and `delta_rows` what the scans met in each layer (the
+    `columnar.scan` span's attributes)."""
     from ..chunk import Chunk
     from ..exec.executor import (
         OverflowRetryError,
@@ -239,9 +271,10 @@ def _run(store, dag, plan: dict, tables: list, start_ts: int, aux_chunks,
         run_dag_on_chunks,
     )
 
+    read = {"resident": False, "stable_rows": 0, "delta_rows": 0}
     scans = []
     for pid, t in zip(plan, tables):
-        scans.append(t.scan(start_ts, plan[pid]))
+        scans.append(t.scan(start_ts, plan[pid], read))
     if len(scans) == 1 and scans[0][1] is not None:
         batch = scans[0][1]
         try:
@@ -249,11 +282,12 @@ def _run(store, dag, plan: dict, tables: list, start_ts: int, aux_chunks,
             chunk, _rows, _info = drive_program_info(
                 store.programs, dag, batches, group_capacity,
                 small_groups=small_groups)
-            return chunk
+            read["resident"] = True
+            return chunk, read
         except (OverflowRetryError, NotImplementedError):
             pass  # the chunk path below owns the retry/oracle ladder
     merged = scans[0][0] if len(scans) == 1 else Chunk.concat([c for c, _b in scans])
     return run_dag_on_chunks(dag, [merged] + list(aux_chunks),
                              cache=cache or store.programs,
                              group_capacity=group_capacity,
-                             small_groups=small_groups)
+                             small_groups=small_groups), read

@@ -450,6 +450,12 @@ COLUMNAR_RESOLVED_LAG = REGISTRY.gauge_vec(
     "tidb_tpu_columnar_resolved_ts_lag", "latest commit watermark minus the replica's applied resolved frontier, per table (ts units)",
     labelnames=("table",),
 )
+COLUMNAR_RESIDENT_SCANS = REGISTRY.counter(
+    "tidb_tpu_columnar_resident_scans_total", "columnar scans whose program read the device-resident stable batch (no host merge, no upload)")
+COLUMNAR_GATE_WAIT_NS = REGISTRY.counter(
+    "tidb_tpu_columnar_gate_wait_ns_total", "ns columnar reads spent in the staleness gate, the data_not_ready back-off included")
+COLUMNAR_DEVICE_BYTES = REGISTRY.gauge(
+    "tidb_tpu_columnar_device_bytes", "bytes of stable column batches the columnar replicas hold on the device")
 COLUMNAR_RESHAPES = REGISTRY.counter(
     "tidb_tpu_columnar_reshapes_total", "mid-feed ALTERs applied to columnar replicas by col_id remap (zero parks; ISSUE 20)")
 
