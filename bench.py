@@ -403,7 +403,7 @@ def bench_config(cfg, device, n, iters, loop_k=None, peak_gbs=None):
                 # reduce-free q3 program SIGSEGV'd the TPU compiler
                 # (2026-07-31, not repeated since)
             )
-            out = jax.block_until_ready(prog.fn(*batches, *dag.program_operands()))
+            out = prog.host(*batches, *dag.program_operands())
             packed, valid, _, (g_ovf, j_ovf, t_ovf, g_need, j_need, _esc), _ = out
             g_ovf, j_ovf, t_ovf = bool(g_ovf), bool(j_ovf), bool(t_ovf)
             if not (g_ovf or j_ovf or t_ovf):
@@ -430,7 +430,7 @@ def bench_config(cfg, device, n, iters, loop_k=None, peak_gbs=None):
             raise RuntimeError(f"{cfg.name}: overflow not resolved after retries")
         chunk = decode_outputs(packed, valid, prog.out_fts)
         K = loop_k or LOOP_K.get(cfg.name, 128)
-        loop = _make_loop(prog.fn, batches, K)
+        loop = _make_loop(prog.program, batches, K)
         t0 = time.perf_counter()
         jax.block_until_ready(loop(*batches))
         compile_s = time.perf_counter() - t0  # trace+compile dominate call 1
@@ -1103,7 +1103,7 @@ def _join_bench_main():
             prog = build_program(dag, caps, group_capacity=128,
                                  join_capacity=jc, unique_joins=uj,
                                  radix_joins=rj)
-            out = jax.block_until_ready(prog.fn(*batches, *dag.program_operands()))
+            out = prog.host(*batches, *dag.program_operands())
             _p, _v, _n, (g_ovf, j_ovf, t_ovf, _gn, j_need, esc), _e = out
             if not (bool(g_ovf) or bool(j_ovf) or bool(t_ovf)):
                 break

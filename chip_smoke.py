@@ -391,7 +391,7 @@ def watch_mesh_programs() -> None:
         prog = build(*a, **k)
         if k.get("mesh_lanes") is None:
             return prog
-        fn = prog.fn
+        fn = prog.outputs.fn
 
         def launch(*args):
             out = fn(*args)
@@ -404,7 +404,7 @@ def watch_mesh_programs() -> None:
                 })
             return out
 
-        prog.fn = launch
+        prog.outputs.fn = launch
         return prog
 
     builder.build_program = watched

@@ -190,7 +190,7 @@ def test_filtered_runs_do_not_trip_capacity():
     dag = _dag([AggDesc("sum", (col(1, LL),))])
     batches = [to_device_batch(c, capacity=256) for c in (probe, build)]
     prog = build_program(dag, tuple(b.capacity for b in batches), group_capacity=8)
-    packed, valid, n_out, (g_ovf, j_ovf, t_ovf, *_needs), _ = prog.fn(*batches)
+    packed, valid, n_out, (g_ovf, j_ovf, t_ovf, *_needs), _ = prog.host(*batches)
     assert not bool(g_ovf) and not bool(j_ovf)
     assert int(n_out) == 4
 

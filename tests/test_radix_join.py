@@ -230,7 +230,7 @@ class TestRadixThroughDAG:
         dag = _join_dag(jt)
         batches = [to_device_batch(c, capacity=_pow2(c.num_rows())) for c in (probe, build)]
         prog = build_program(dag, tuple(b.capacity for b in batches), group_capacity=64)
-        packed, valid, _n, ovfs, _ex = prog.fn(*batches)
+        packed, valid, _n, ovfs, _ex = prog.host(*batches)
         assert prog.radix_info, "eligible join must ride the radix kernel"
         assert not any(bool(x) for x in ovfs[:3])
         got = _canon(decode_outputs(packed, valid, prog.out_fts).rows())
@@ -242,7 +242,7 @@ class TestRadixThroughDAG:
         dag = _join_dag()
         batches = [to_device_batch(c, capacity=64) for c in (probe, build)]
         prog = build_program(dag, (64, 64), group_capacity=64)
-        packed, valid, _n, ovfs, _ex = prog.fn(*batches)
+        packed, valid, _n, ovfs, _ex = prog.host(*batches)
         assert not prog.radix_info  # ratio gate: monolithic kernel
         assert not any(bool(x) for x in ovfs[:3])
 

@@ -306,6 +306,7 @@ def _make_builder(dag, n_batches: int, vmap: bool, caps=None):
             dag, _entry_caps(n_batches, caps),
             group_capacity=_GROUP_CAPACITY,
             vmap_batch=_VMAP_BATCH if vmap else None)
+        make.outputs = cd.outputs  # of the last build: `leaf_avals()` once the build was traced
         return cd.fn, _batches(n_batches, vmap, caps) + list(dag.program_operands())
     return make
 
@@ -349,10 +350,12 @@ def audit_live() -> list:
                 continue
             findings.extend(fs)
             findings.extend(audit_jaxpr(variant, closed, anchor))
+            # the region axis is checked on the leaves the host reads, before
+            # the program's epilogue lays them into its one byte buffer
             if not vmap:
-                single_out = closed.out_avals
+                single_out = make.outputs.leaf_avals()
             else:
-                findings.extend(_check_vmap_axis(name, single_out, closed.out_avals, anchor))
+                findings.extend(_check_vmap_axis(name, single_out, make.outputs.leaf_avals(), anchor))
         findings.extend(_audit_mesh_variant(name, dag, n_batches, anchor, caps))
     findings.extend(_audit_exchange_variant(anchor))
     _LIVE_MEMO = list(findings)

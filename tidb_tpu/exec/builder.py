@@ -62,7 +62,16 @@ def _gather(cols: list[CompVal], idx) -> list[CompVal]:
 
 @dataclass
 class CompiledDAG:
-    fn: object  # jitted (DeviceBatch, ...) -> (outputs, valid, n_rows, overflow, ex_rows)
+    # what the launch boundary calls: `outputs.fn` is jitted, (DeviceBatch,
+    # ..., operands) -> one byte buffer (exec/launch.py `HostOutputs`),
+    # whose `read()` gives, as host arrays, (output_leaves, valid, n_rows,
+    # overflow, ex_rows); the mesh variant (output_leaves, valid, ex_rows,
+    # overflow, escapes)
+    outputs: launch.HostOutputs
+    # the same program as a traceable function with its outputs as device
+    # arrays, `packed` in place of `output_leaves`: for a caller that
+    # traces it into a program of its own (bench.py's loop)
+    program: object
     out_fts: list[FieldType]
     capacities: tuple  # one per scan, canonical order (dag.collect_scans)
     group_capacity: int
@@ -75,6 +84,15 @@ class CompiledDAG:
     radix_info: dict = None  # type: ignore[assignment]
     # single-flight over the first call, where JAX traces and compiles
     gate: launch.FirstCallGate = field(default_factory=launch.FirstCallGate)
+
+    @property
+    def fn(self):
+        return self.outputs.fn
+
+    def host(self, *args):
+        """Call the program and read its outputs, outside the launch
+        boundary's spans and counters (tests, tools)."""
+        return self.outputs.fn(*args).read()
 
 
 class _TraceState:
@@ -593,6 +611,16 @@ def _check_join_key_types(pkeys: list[CompVal], bkeys: list[CompVal]):
             raise TypeError("join key signedness mismatch (insert casts)")
 
 
+def output_leaves(packed) -> list[tuple]:
+    """Per output column of a program's `packed`, the leaves the host
+    reads: a string column with raw bytes comes back as (null, bytes,
+    lengths) and its packed compare words stay on the device; every other
+    column as (values or packed words, null).  The program's epilogue
+    sends these and `executor.decode_outputs` decodes these: both ask
+    here."""
+    return [tuple(out[1:]) if len(out) == 4 else tuple(out) for out in packed]
+
+
 def _pack_cols(cols: list[CompVal]) -> list[tuple]:
     """CompVals -> the program's packed output tuples: (value, null) per
     column, raw string bytes + lengths riding along when present."""
@@ -721,8 +749,9 @@ def build_program(
     else:
         fn = program
     fn.__name__ = fn.__qualname__ = program_name(dag, vmap_batch, mesh_lanes, mesh_devices)
-    jit_fn = jax.jit(fn)
-    return CompiledDAG(jit_fn, dag.output_fts(), capacities, group_capacity, join_capacity,
+    # in every variant the output columns come first, and the host reads all the rest
+    outputs = launch.HostOutputs(fn, lambda out: (output_leaves(out[0]), *out[1:]))
+    return CompiledDAG(outputs, fn, dag.output_fts(), capacities, group_capacity, join_capacity,
                        radix_info=radix_info)
 
 

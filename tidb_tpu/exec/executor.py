@@ -17,7 +17,7 @@ from ..chunk import Chunk, Column, to_device_batch
 from ..expr.agg import AggDesc
 from ..expr.eval_ref import RefEvaluator, compare, _truth
 from ..types import Datum, DatumKind, FieldType, MyDecimal, MyTime
-from .builder import DEFAULT_GROUP_CAPACITY, CompiledDAG, ProgramCache, build_program
+from .builder import DEFAULT_GROUP_CAPACITY, CompiledDAG, ProgramCache, build_program, output_leaves
 from .dag import Aggregation, DAGRequest, Join, Limit, Projection, Selection, Sort, TableScan, TopN, Window, current_schema_fts
 
 
@@ -29,17 +29,17 @@ def _pow2(n: int) -> int:
 
 
 def decode_outputs(packed, valid, out_fts, to_host=np.asarray) -> Chunk:
-    """Program outputs -> host Chunk.  `to_host` converts each leaf; the
-    drivers pass the counting one of `launch.read_back`."""
-    valid = to_host(valid)
-    idx = np.nonzero(valid)[0]
+    """Program outputs -> host Chunk: `packed` as the program lays it out
+    or as a launch read it (`output_leaves`, host arrays).  `to_host`
+    converts each leaf; the drivers pass the counting one of
+    `launch.read_back`, to which a leaf that is still on the device is a
+    late transfer."""
+    idx = np.nonzero(to_host(valid))[0]
     cols = []
-    for ft, out in zip(out_fts, packed):
-        if len(out) == 4:  # string: words, null, raw bytes, lengths
-            _, null, data, length = out
-            null = to_host(null)[idx]
-            data = to_host(data)[idx]
-            length = to_host(length)[idx]
+    for ft, leaves in zip(out_fts, output_leaves(packed)):
+        leaves = tuple(to_host(a)[idx] for a in leaves)
+        if len(leaves) == 3:  # string: null, raw bytes, lengths
+            null, data, length = leaves
             offs = np.zeros(len(idx) + 1, np.int64)
             np.cumsum(np.where(null, 0, length), out=offs[1:])
             blob = np.zeros(int(offs[-1]), np.uint8)
@@ -47,12 +47,11 @@ def decode_outputs(packed, valid, out_fts, to_host=np.asarray) -> Chunk:
                 if not null[j]:
                     blob[offs[j] : offs[j + 1]] = data[j, : length[j]]
             cols.append(Column(ft, None, null, offs, blob))
-        elif ft.is_string() and out[0].ndim == 2:
+        elif ft.is_string() and leaves[0].ndim == 2:
             # string column without raw bytes (e.g. CASE/IF over string
             # operands): reconstruct from the packed compare words — covers
             # the first STRING_WORDS*8 bytes, the packed-key contract
-            words, null = to_host(out[0]), to_host(out[1])
-            words, null = words[idx], null[idx]
+            words, null = leaves
             w = words.shape[1] - 1
             payload = (words[:, :w].astype(np.uint64) ^ np.uint64(1 << 63))
             length = np.minimum(np.maximum(words[:, w], 0), w * 8).astype(np.int64)
@@ -68,9 +67,7 @@ def decode_outputs(packed, valid, out_fts, to_host=np.asarray) -> Chunk:
                 blob[offs[j] : offs[j + 1]] = byte_mat[j, : length[j]]
             cols.append(Column(ft, None, null.copy(), offs, blob))
         else:
-            v, null = out
-            v = to_host(v)[idx]
-            null = to_host(null)[idx]
+            v, null = leaves
             if ft.is_unsigned() or ft.is_time():
                 v = v.view(np.uint64) if v.dtype == np.int64 else v.astype(np.uint64)
             cols.append(Column(ft, v.copy(), null.copy()))
@@ -140,19 +137,18 @@ def drive_program_info(cache: ProgramCache, dag: DAGRequest, batches, group_capa
     operands = dag.program_operands()
     for _ in range(max_retries + 1):
         prog, hit, build_ns = cache.get_info(dag, caps, gc, jc, tf, smg, uj, radix_joins=rj)
-        out, (g_ovf, j_ovf, t_ovf), first_ns = launch.run_program(
-            prog.fn, batches, operands, first_call=not hit, gate=prog.gate,
-            flags=lambda o: tuple(bool(f) for f in o[3][:3]))
-        packed, valid, _n, (_g, _j, _t, g_need, j_need, radix_esc), ex_rows = out
+        out, fetch, first_ns = launch.run_program(prog.outputs, batches, operands, first_call=not hit, gate=prog.gate)
+        cols, valid, _n, (g_ovf, j_ovf, t_ovf, g_need, j_need, radix_esc), ex_rows = out
+        g_ovf, j_ovf, t_ovf = bool(g_ovf), bool(j_ovf), bool(t_ovf)
         if not hit:
             info["cache_hit"] = False
-            # the flag fetch blocked on the result: first-call = trace+compile
+            # the fetch blocked on the result: first-call = trace+compile
             info["compile_ns"] += build_ns + first_ns
-        with launch.read_back() as to_host:
+        with launch.read_back(fetch) as to_host:
             if not g_ovf and not j_ovf and not t_ovf:
                 counts = [int(x) for x in to_host(ex_rows)]
                 _radix_attribution(prog, jc, radix_esc, info, to_host)
-                return decode_outputs(packed, valid, prog.out_fts, to_host), counts, info
+                return decode_outputs(cols, valid, prog.out_fts, to_host), counts, info
             g_need, j_need = int(to_host(g_need)), int(to_host(j_need))
         if g_ovf:
             # also drop a wrong stats hint in the same retry: the driver
@@ -206,21 +202,19 @@ def drive_batched_program_info(
     prog, hit, build_ns = cache.get_info(
         dag, caps, rung_for(group_capacity), jc, False, small_groups, True, vmap_batch=B
     )
-    out, (g_ovf, j_ovf, t_ovf), first_ns = launch.run_program(
-        prog.fn, (stacked, *aux_batches), dag.program_operands(), first_call=not hit, gate=prog.gate,
-        flags=lambda o: tuple(np.asarray(f) for f in o[3][:3]))
-    packed, valid, _n, (_g, _j, _t, _g_need, _j_need, radix_esc), ex_rows = out
-    # the flag fetch blocked on the result: first-call time is
+    out, fetch, first_ns = launch.run_program(
+        prog.outputs, (stacked, *aux_batches), dag.program_operands(), first_call=not hit, gate=prog.gate)
+    cols, valid, _n, (g_ovf, j_ovf, t_ovf, _g_need, _j_need, radix_esc), ex_rows = out
+    # the fetch blocked on the result: first-call time is
     # trace+compile, same attribution as drive_program_info
     info = {"cache_hit": hit, "compile_ns": 0 if hit else build_ns + first_ns}
-    with launch.read_back() as to_host:
+    with launch.read_back(fetch) as to_host:
         valid_np = to_host(valid)
         ex_np = to_host(ex_rows)
         per_region: list = []
         esc_np = to_host(radix_esc)
         served_esc = 0
         esc_by_lane: list = []
-        packed_np = None  # the stacked outputs on the host, once a lane is served
         for b in range(B):
             if bool(g_ovf[b]) or bool(j_ovf[b]) or bool(t_ovf[b]):
                 per_region.append(None)
@@ -228,12 +222,10 @@ def drive_batched_program_info(
                 continue
             served_esc += int(esc_np[b])
             esc_by_lane.append(int(esc_np[b]))
-            if packed_np is None:
-                packed_np = [tuple(to_host(a) for a in out_col) for out_col in packed]
             # region lane b: each leaf loses its leading region axis,
             # recovering the single-region layout decode_outputs consumes
-            lane = [tuple(a[b] for a in out_col) for out_col in packed_np]
-            chunk = decode_outputs(lane, valid_np[b], prog.out_fts)
+            lane = [tuple(a[b] for a in leaves) for leaves in cols]
+            chunk = decode_outputs(lane, valid_np[b], prog.out_fts, to_host)
             per_region.append((chunk, [int(x) for x in ex_np[b]]))
         _radix_attribution(prog, jc, served_esc, info, to_host)
     if "radix" in info:
@@ -281,13 +273,12 @@ def drive_mesh_program_info(
         dag, caps, rung_for(group_capacity), jc, False, small_groups, True,
         mesh_lanes=R, mesh_devices=mesh_devices, mesh_kind=kind,
     )
-    (merged, mvalid, ex_rows, _ovf, radix_esc), overflow, first_ns = launch.run_program(
-        prog.fn, (stacked, *aux_batches), dag.program_operands(), first_call=not hit, gate=prog.gate,
-        flags=lambda o: bool(np.asarray(o[3])))
-    # the flag fetch blocked on the result: first-call time is
+    (merged, mvalid, ex_rows, overflow, radix_esc), fetch, first_ns = launch.run_program(
+        prog.outputs, (stacked, *aux_batches), dag.program_operands(), first_call=not hit, gate=prog.gate)
+    # the fetch blocked on the result: first-call time is
     # trace+compile, same attribution as drive_program_info
     info = {"cache_hit": hit, "compile_ns": 0 if hit else build_ns + first_ns}
-    with launch.read_back() as to_host:
+    with launch.read_back(fetch) as to_host:
         ex_np = to_host(ex_rows)
         lane_counts = [[int(x) for x in ex_np[b]] for b in range(R)]
         if overflow:

@@ -5,12 +5,30 @@ outputs come back to the host.
 the states that the blob `cop.execute` used to hide: `exec.compile` (a
 call in which JAX traced, lowered or compiled), `exec.launch` (a call of
 an already compiled program: dispatch only) and `exec.wait` (from the
-call's return until the overflow flags are on the host: device queue,
-execution, the flags' transfer).  `read_back` covers every further
-device-to-host transfer and the decoding, as `exec.readback`.  Each is a
+call's return until the launch's outputs are on the host: device queue,
+execution, and the launch's one transfer).  `read_back` covers what the
+host then does with them, the decoding, as `exec.readback`.  Each is a
 span under the ambient one when a `TRACE` is active, a
 `jax.profiler.TraceAnnotation` always (`util/tracing.py`), and a counter
 of `util/metrics.py` always.
+
+One device-to-host round trip per launch, in two steps.  The program
+itself makes one array of everything the host reads (`HostOutputs`: its
+epilogue bit-casts the overflow flags, the need hints, the row counts,
+the validity mask and the output columns' leaves to bytes and
+concatenates them, offsets from the static shapes), so a launch hands
+back one device array where it handed back one per leaf: on the chip a
+result array costs ~70 us of dispatch and ~35 us of fetch each, whatever
+its size (PERF.md, PR 29).  And as soon as the call has returned, before
+anything is waited for, `run_program` starts `copy_to_host_async()` on
+what the call returned (the `Fetch`): that buffer, and beside it the
+leaves that stay arrays of their own: floats, because the TPU keeps no
+IEEE float64 to bit-cast, and leaves of a megabyte and more, which a copy
+into the buffer would only double on the device.  `exec.wait` then
+converts them, which blocks until the device is through, and the drivers
+decode views of the one host array.  A device array converted in
+`read_back` was in no launch's fetch, pays a round trip of its own and is
+counted as `late` (`PROGRAM_READBACK_LATE`; 0 on every driver).
 
 What JAX did inside a call is heard, not guessed: one `jax.monitoring`
 listener, registered when this module is imported, receives the durations
@@ -24,10 +42,12 @@ to a program or to an eager `jnp` operation outside any program
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..util import metrics, tracing
@@ -127,21 +147,133 @@ class FirstCallGate:
         return out
 
 
-def run_program(fn, args, operands=(), *, first_call: bool, flags=None, gate: FirstCallGate | None = None):
-    """Call the jitted `fn(*args, *operands)`: `args` are the batches,
-    `operands` the statement's values (`DAGRequest.program_operands()`),
-    counted in `PROGRAM_PARAMS_BOUND` and as the span's `params`.  `gate`
-    is the program's `FirstCallGate`.  Where `flags` is given, fetch the
-    overflow flags with `flags(outputs)`, which blocks until the device is
-    through.  Returns (outputs, flags on the host or None, first-call ns):
-    the last is the wall time of call and wait when `first_call` says the
-    program was just built, else 0 — what the exec summaries and Top SQL
-    attribute to compilation.
+# A leaf of this many bytes rides beside the buffer as an array of its own:
+# its transfer outweighs the ~0.1 ms that an array costs, and laying it into
+# the buffer would hold a second copy of it on the device (a full-table
+# scan's columns).
+_BESIDE_BYTES = 1 << 20
+
+
+class HostOutputs:
+    """What the host reads of a program, made one array by the program.
+
+    `program` is the traceable function; `reads(outputs)` names, as any
+    pytree, the arrays of its outputs that the host converts (all of them
+    where it is left out).  `fn` is the jitted function to launch: the
+    program with an epilogue that lays those leaves into one `uint8`
+    buffer (8-byte aligned offsets, in the tree's order) and returns a
+    `Returned`: the buffer, beside it the leaves that stay arrays of their
+    own (floats, and leaves of `_BESIDE_BYTES` and more), and as static
+    data of the pytree the layout that its trace saw.  JAX keeps a
+    function's output tree per compiled signature, so a call gets the
+    layout of the executable that served it, whichever thread traced it
+    and whatever other shapes the function was traced for since (a string
+    column's byte width follows the batch)."""
+
+    __slots__ = ("fn", "_last")
+
+    def __init__(self, program, reads=None):
+        self._last = None  # the latest trace's layout
+
+        def with_epilogue(*args):
+            out = program(*args)
+            leaves, treedef = jax.tree.flatten(out if reads is None else reads(out))
+            parts, own, layout, offset = [], [], [], 0
+            for a in leaves:
+                nbytes = math.prod(a.shape) * a.dtype.itemsize
+                if jnp.issubdtype(a.dtype, jnp.floating) or nbytes >= _BESIDE_BYTES:
+                    layout.append((a.shape, np.dtype(a.dtype), None, len(own)))
+                    own.append(a)
+                    continue
+                layout.append((a.shape, np.dtype(a.dtype), offset, offset + nbytes))
+                parts.append(_as_bytes(a))
+                pad = -nbytes % 8
+                if pad:
+                    parts.append(jnp.zeros(pad, jnp.uint8))
+                offset += nbytes + pad
+            self._last = tuple(layout)
+            return Returned(jnp.concatenate(parts), tuple(own), treedef, self._last)
+
+        with_epilogue.__name__ = with_epilogue.__qualname__ = getattr(program, "__name__", "program")
+        self.fn = jax.jit(with_epilogue)
+
+    def leaf_avals(self) -> list:
+        """Shape and dtype of each leaf the host reads, as the latest
+        trace saw them (the jaxpr auditor checks the region axis on
+        these)."""
+        return [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype, _start, _stop in self._last]
+
+
+@jax.tree_util.register_pytree_node_class
+class Returned:
+    """What a call of `HostOutputs.fn` returns: the buffer and the arrays
+    beside it, on the device, and, static, how to read them: per leaf
+    (shape, dtype, start, stop) in the buffer, or (shape, dtype, None,
+    index) among the arrays beside it."""
+
+    __slots__ = ("buf", "own", "treedef", "layout")
+
+    def __init__(self, buf, own, treedef, layout):
+        self.buf, self.own, self.treedef, self.layout = buf, own, treedef, layout
+
+    def tree_flatten(self):
+        return (self.buf, self.own), (self.treedef, self.layout)
+
+    @classmethod
+    def tree_unflatten(cls, static, arrays):
+        return cls(*arrays, *static)
+
+    def read(self):
+        """The tree that `reads` named, of host arrays: views of the one
+        buffer.  Blocks until the device is through with the call."""
+        buf = np.asarray(self.buf)
+        leaves = [np.asarray(self.own[stop]) if start is None else buf[start:stop].view(dtype).reshape(shape)
+                  for shape, dtype, start, stop in self.layout]
+        return self.treedef.unflatten(leaves)
+
+
+def _as_bytes(a):
+    """A non-float array as flat `uint8`, in the host's byte order."""
+    if a.dtype == jnp.bool_:
+        return a.astype(jnp.uint8).reshape(-1)
+    if a.dtype.itemsize == 1:
+        return a.view(jnp.uint8).reshape(-1)
+    return jax.lax.bitcast_convert_type(a, jnp.uint8).reshape(-1)
+
+
+class Fetch:
+    """The device-to-host copies that one launch started, for the
+    `read_back` that follows it: how many arrays, how many bytes."""
+
+    __slots__ = ("transfers", "bytes")
+
+    def __init__(self, returned: Returned):
+        arrays = (returned.buf, *returned.own)
+        for a in arrays:
+            a.copy_to_host_async()
+        self.transfers = len(arrays)
+        self.bytes = sum(a.nbytes for a in arrays)
+        metrics.PROGRAM_FETCHES.inc()
+
+
+def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, gate: FirstCallGate | None = None):
+    """Call the jitted `outputs.fn(*args, *operands)`: `args` are the
+    batches, `operands` the statement's values
+    (`DAGRequest.program_operands()`), counted in `PROGRAM_PARAMS_BOUND`
+    and as the span's `params`.  `gate` is the program's `FirstCallGate`.
+    The copy of what the call returned is started at once, and converted
+    under `exec.wait`, which blocks until the device is through and the
+    transfer has landed.  Returns (the outputs that the program's `reads`
+    named, as host arrays; the `Fetch`; first-call ns): the last is the
+    wall time of call and wait when `first_call` says the program was
+    just built, else 0 — what the exec summaries and Top SQL attribute to
+    compilation.
 
     `first_call` names the state on the profiler's clock, which has to be
     named before the call begins; the span and the counters go by what
     the listener heard during the call, so a retrace of an old program for
     a new argument shape is an `exec.compile` too."""
+    fn = outputs.fn
     program = getattr(fn, "__name__", type(fn).__name__)
     heard = _calling.heard = _Heard()
     metrics.PROGRAM_LAUNCHES.inc()
@@ -152,7 +284,7 @@ def run_program(fn, args, operands=(), *, first_call: bool, flags=None, gate: Fi
     t0 = time.perf_counter_ns()
     try:
         with tracing.span("exec.compile" if first_call else "exec.launch", program=program, params=n_params) as sp:
-            out = fn(*args) if gate is None else gate.call(fn, args)
+            returned = fn(*args) if gate is None else gate.call(fn, args)
             t1 = time.perf_counter_ns()
             if sp is not None:
                 _describe(sp, heard)
@@ -161,12 +293,11 @@ def run_program(fn, args, operands=(), *, first_call: bool, flags=None, gate: Fi
     if heard.compiled:
         metrics.XLA_TRACE_LOWER_NS.inc(max(t1 - t0 - heard.xla_ns, 0))
         metrics.PROGRAM_COMPILE_DURATION.observe((t1 - t0) / 1e9)
-    on_host = None
-    if flags is not None:
-        with tracing.span("exec.wait"):
-            on_host = flags(out)
-        metrics.PROGRAM_WAIT_NS.inc(time.perf_counter_ns() - t1)
-    return out, on_host, (time.perf_counter_ns() - t0 if first_call else 0)
+    with tracing.span("exec.wait"):
+        fetch = Fetch(returned)
+        out = returned.read()
+    metrics.PROGRAM_WAIT_NS.inc(time.perf_counter_ns() - t1)
+    return out, fetch, (time.perf_counter_ns() - t0 if first_call else 0)
 
 
 def _describe(sp: tracing.Span, heard: _Heard) -> None:
@@ -187,14 +318,18 @@ def _describe(sp: tracing.Span, heard: _Heard) -> None:
 
 
 class read_back:
-    """The device-to-host side of a launch, as a context manager.  Yields
-    `to_host`, which is `np.asarray` counting the device arrays it
-    converts and their bytes."""
+    """The host side of a launch after its `Fetch` has landed, as a
+    context manager: the decoding.  The span's `transfers` and `bytes` are
+    the fetch's.  Yields `to_host`, which is `np.asarray`; a device array
+    that reaches it was in no fetch, waits for a round trip of its own
+    and is counted, as a transfer and as `late`."""
 
-    __slots__ = ("transfers", "bytes", "_t0", "_span", "_sp")
+    __slots__ = ("transfers", "bytes", "late", "_t0", "_span", "_sp")
+
+    def __init__(self, fetch: Fetch):
+        self.transfers, self.bytes, self.late = fetch.transfers, fetch.bytes, 0
 
     def __enter__(self):
-        self.transfers = self.bytes = 0
         self._t0 = time.perf_counter_ns()
         self._span = tracing.span("exec.readback")
         self._sp = self._span.__enter__()
@@ -204,13 +339,16 @@ class read_back:
         if isinstance(x, jax.Array):
             self.transfers += 1
             self.bytes += x.nbytes
+            self.late += 1
         return np.asarray(x)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         metrics.PROGRAM_READBACK_NS.inc(time.perf_counter_ns() - self._t0)
         metrics.PROGRAM_READBACK_TRANSFERS.inc(self.transfers)
         metrics.PROGRAM_READBACK_BYTES.inc(self.bytes)
+        metrics.PROGRAM_READBACK_LATE.inc(self.late)
         if self._sp is not None:
             self._sp.set("transfers", self.transfers)
             self._sp.set("bytes", self.bytes)
+            self._sp.set("late", self.late)
         return self._span.__exit__(exc_type, exc, tb)

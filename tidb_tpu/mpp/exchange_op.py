@@ -336,22 +336,24 @@ _PROGRAM_CACHE_CAP = 64
 
 def run_exchange_program(name: str, dag, mesh, build, cap_key: tuple, args: tuple):
     """`build() -> fn`, jitted under `name` and cached under the DAG's wire
-    identity, called with `args` through the launch boundary."""
+    identity, called with `args` through the launch boundary.  The host
+    decodes every output, so all of them ride the program's one buffer.
+    Returns (outputs as host arrays, the launch's `Fetch`)."""
     from ..codec.wire import encode_dag
     from ..exec import launch
 
     key = (encode_dag(dag),
            tuple(int(d.id) for d in mesh.devices.flat), *cap_key)
-    fn = _PROGRAM_CACHE.get(key)
-    first_call = fn is None
+    outputs = _PROGRAM_CACHE.get(key)
+    first_call = outputs is None
     if first_call:
         if len(_PROGRAM_CACHE) >= _PROGRAM_CACHE_CAP:
             _PROGRAM_CACHE.pop(next(iter(_PROGRAM_CACHE)))
         body = build()
         body.__name__ = body.__qualname__ = name
-        fn = jax.jit(body)
-        _PROGRAM_CACHE[key] = fn
-    return launch.run_program(fn, args, first_call=first_call)[0]
+        outputs = _PROGRAM_CACHE[key] = launch.HostOutputs(body)
+    outs, fetch, _ = launch.run_program(outputs, args, first_call=first_call)
+    return outs, fetch
 
 
 def run_exchange_join_agg(
@@ -382,9 +384,9 @@ def run_exchange_join_agg(
     n_stages = len(split_join_dag(dag)[2])
     assert len(stacked_builds) == n_stages, "one build batch per join stage"
     agg = dag.executors[-1]
-    outs = run_exchange_program(
+    outs, fetch = run_exchange_program(
         "mpp_exchange_join_agg", dag, mesh,
         lambda: exchange_join_program(dag, mesh, group_capacity=group_capacity, scale=scale),
         (group_capacity, scale), (stacked_probe, *stacked_builds))
     # decode via the shared seam (parallel/mesh.py) — same layout as grouped
-    return decode_group_mesh_outputs(outs, agg)
+    return decode_group_mesh_outputs(outs, fetch, agg)

@@ -245,11 +245,11 @@ def run_sharded_grouped_agg(
     from ..mpp.exchange_op import run_exchange_program
     from .mesh import decode_group_mesh_outputs, group_mesh_out_spec
 
-    outs = run_exchange_program(
+    outs, fetch = run_exchange_program(
         "mesh_exchange_group_agg", dag, mesh,
         lambda: jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_batch,),
                               out_specs=group_mesh_out_spec(agg), check_vma=False),
         (group_capacity, bcap), (stacked,))
     # decode: [agg results..., group keys...] with Complete-mode fts —
     # the shared seam (mesh.py) both grouped paths use
-    return decode_group_mesh_outputs(outs, agg)
+    return decode_group_mesh_outputs(outs, fetch, agg)

@@ -106,8 +106,8 @@ def run_sharded_partial_agg(dag, stacked: DeviceBatch, mesh: Mesh):
     from ..exec import launch
 
     (merged, _valid, _ex, _ovf, _esc), _, _ = launch.run_program(
-        prog.fn, (stacked,), dag.program_operands(), first_call=True)
-    return [tuple(out) for out in merged]
+        prog.outputs, (stacked,), dag.program_operands(), first_call=True)
+    return merged  # host arrays
 
 
 # --------------------------------------------------------- the merge seam
@@ -244,17 +244,18 @@ def _merge_first_row(has_state, val_state, axis: str):
     return [(any_has.astype(jnp.int64), jnp.zeros_like(null)), (val, null)]
 
 
-def decode_group_mesh_outputs(outs, agg):
+def decode_group_mesh_outputs(outs, fetch, agg):
     """Shared host-side decode for the grouped shard_map programs
     (grouped.py / joinmesh.py): flat output tuple [group_valid,
     (value, null)*, overflow] with out_specs P(REGION_AXIS) having already
-    concatenated the per-device group tables along axis 0. Returns
+    concatenated the per-device group tables along axis 0, as the launch
+    read them (host arrays) with the launch's `fetch`. Returns
     (chunk, overflow) in the Complete-mode layout [aggs..., group keys...].
     """
     from ..exec import launch
     from ..exec.executor import decode_outputs
 
-    with launch.read_back() as to_host:
+    with launch.read_back(fetch) as to_host:
         group_valid = to_host(outs[0]).reshape(-1)
         overflow = bool(to_host(outs[-1]).reshape(-1)[0])
         flat_out = outs[1:-1]

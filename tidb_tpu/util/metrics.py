@@ -347,10 +347,12 @@ XLA_TRACE_LOWER_NS = REGISTRY.counter("tidb_tpu_xla_trace_lower_ns_total", "prog
 XLA_BACKEND_COMPILE_NS = REGISTRY.counter("tidb_tpu_xla_backend_compile_ns_total", "ns in XLA backend compiles, programs and eager operations alike")
 XLA_PERSISTENT_CACHE_HITS = REGISTRY.counter("tidb_tpu_xla_persistent_cache_hits_total", "executables loaded from JAX's persistent compile cache")
 XLA_PERSISTENT_CACHE_MISSES = REGISTRY.counter("tidb_tpu_xla_persistent_cache_misses_total", "executables compiled and written to JAX's persistent compile cache")
-PROGRAM_WAIT_NS = REGISTRY.counter("tidb_tpu_program_wait_ns_total", "ns from a program call's return until its overflow flags are on the host")
-PROGRAM_READBACK_NS = REGISTRY.counter("tidb_tpu_program_readback_ns_total", "ns reading program outputs back and decoding them")
-PROGRAM_READBACK_TRANSFERS = REGISTRY.counter("tidb_tpu_program_readback_transfers_total", "device arrays converted to host arrays in read-back")
-PROGRAM_READBACK_BYTES = REGISTRY.counter("tidb_tpu_program_readback_bytes_total", "bytes of the device arrays converted in read-back")
+PROGRAM_FETCHES = REGISTRY.counter("tidb_tpu_program_fetches_total", "device-to-host fetches started, one per program call as the call returns (equals the launches)")
+PROGRAM_WAIT_NS = REGISTRY.counter("tidb_tpu_program_wait_ns_total", "ns from a program call's return until its outputs are on the host: queue, execution, the launch's one transfer")
+PROGRAM_READBACK_NS = REGISTRY.counter("tidb_tpu_program_readback_ns_total", "ns decoding program outputs on the host")
+PROGRAM_READBACK_TRANSFERS = REGISTRY.counter("tidb_tpu_program_readback_transfers_total", "device arrays converted to host arrays: per launch the program's one buffer, each float leaf beside it, and the late ones")
+PROGRAM_READBACK_BYTES = REGISTRY.counter("tidb_tpu_program_readback_bytes_total", "bytes of the device arrays converted")
+PROGRAM_READBACK_LATE = REGISTRY.counter("tidb_tpu_program_readback_late_total", "device arrays converted in read-back that were in no launch's fetch: a round trip each (0 on every driver)")
 # the wire server (server/server.py): from a command's packet read until its
 # last reply byte is handed to the socket; waiting for the client is not counted
 SERVER_COMMANDS = REGISTRY.counter("tidb_tpu_server_commands_total", "wire commands handled")
