@@ -5,8 +5,9 @@ wire client, `LOAD DATA INFILE`, `ANALYZE`, TPC-H Q6/Q1/Q3 plus a point
 get, an indexed range and an update/read-back from the row store, then Q6
 and Q1 again from the columnar replica.  Every answer is compared with a
 plain numpy computation over the generated arrays (scaled int64 for the
-decimal sums), never with the engine's own oracle.  The first failed
-phase ends the run with a non-zero exit code.
+decimal sums; generator and references are those of the benchmark's
+`tpch_sf0p02` deployment), never with the engine's own oracle.  The first
+failed phase ends the run with a non-zero exit code.
 
     python chip_smoke.py [--seed N] [--rows N] [--chips 4]
 
@@ -20,8 +21,8 @@ from __future__ import annotations
 
 import argparse
 import collections
-import decimal
 import functools
+import importlib.util
 import json
 import os
 import shutil
@@ -33,71 +34,30 @@ os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(HERE, ".xla_cach
 
 import numpy as np  # noqa: E402
 
-# lineitem rows; orders = rows/4, customer = rows/32 (TPC-H's ratios, SF~0.044).
+# lineitem rows; orders = rows/4, customer = orders/10 (TPC-H's ratios, SF~0.044).
 # The host bounds it, not the chip: LOAD DATA, ANALYZE and the replica's
 # backfill are per-row Python, and a cold run must fit the smoke's time limit
 DEFAULT_ROWS = 1 << 18
 OUT_DIR = os.path.join(HERE, "chiprun_out")
 WIRE_TIMEOUT = 1100.0  # a first execution compiles; the wire must wait
 
-D = decimal.Decimal
-EPOCH = np.datetime64("1992-01-01")
-SEGMENTS = ["AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD"]
-PRIORITIES = ["1-URGENT", "2-HIGH", "3-MEDIUM", "4-NOT SPECIFIED", "5-LOW"]
-INSTRUCTS = ["DELIVER IN PERSON", "COLLECT COD", "NONE", "TAKE BACK RETURN"]
-MODES = ["REG AIR", "AIR", "RAIL", "SHIP", "TRUCK", "MAIL", "FOB"]
-WORDS = ("furiously quickly carefully blithely slyly final regular special express "
-         "pending ironic bold even unusual silent packages deposits requests accounts "
-         "theodolites pinto beans foxes ideas instructions dependencies platelets").split()
-
-DDL = [
-    """create table customer (
-        c_custkey bigint not null, c_name varchar(25) not null,
-        c_address varchar(40) not null, c_nationkey bigint not null,
-        c_phone char(15) not null, c_acctbal decimal(15,2) not null,
-        c_mktsegment char(10) not null, c_comment varchar(117) not null,
-        primary key (c_custkey))""",
-    """create table orders (
-        o_orderkey bigint not null, o_custkey bigint not null,
-        o_orderstatus char(1) not null, o_totalprice decimal(15,2) not null,
-        o_orderdate date not null, o_orderpriority char(15) not null,
-        o_clerk char(15) not null, o_shippriority bigint not null,
-        o_comment varchar(79) not null,
-        primary key (o_orderkey), key idx_orderdate (o_orderdate))""",
-    """create table lineitem (
-        l_orderkey bigint not null, l_partkey bigint not null,
-        l_suppkey bigint not null, l_linenumber bigint not null,
-        l_quantity decimal(15,2) not null, l_extendedprice decimal(15,2) not null,
-        l_discount decimal(15,2) not null, l_tax decimal(15,2) not null,
-        l_returnflag char(1) not null, l_linestatus char(1) not null,
-        l_shipdate date not null, l_commitdate date not null,
-        l_receiptdate date not null, l_shipinstruct char(25) not null,
-        l_shipmode char(10) not null, l_comment varchar(44) not null,
-        primary key (l_orderkey, l_linenumber))""",
-]
-
-Q6 = """select sum(l_extendedprice * l_discount) as revenue from lineitem
- where l_shipdate >= date '1994-01-01'
-   and l_shipdate < date '1994-01-01' + interval '1' year
-   and l_discount between 0.06 - 0.01 and 0.06 + 0.01 and l_quantity < 24"""
-
-Q1 = """select l_returnflag, l_linestatus, sum(l_quantity) as sum_qty,
-   sum(l_extendedprice) as sum_base_price,
-   sum(l_extendedprice * (1 - l_discount)) as sum_disc_price,
-   sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) as sum_charge,
-   avg(l_quantity) as avg_qty, avg(l_extendedprice) as avg_price,
-   avg(l_discount) as avg_disc, count(*) as count_order
- from lineitem where l_shipdate <= date '1998-12-01' - interval '90' day
- group by l_returnflag, l_linestatus order by l_returnflag, l_linestatus"""
-
-Q3 = """select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue,
-   o_orderdate, o_shippriority
- from customer, orders, lineitem
- where c_mktsegment = 'BUILDING' and c_custkey = o_custkey
-   and l_orderkey = o_orderkey and o_orderdate < date '1995-03-15'
-   and l_shipdate > date '1995-03-15'
- group by l_orderkey, o_orderdate, o_shippriority
- order by revenue desc, o_orderdate limit 10"""
+# the benchmark's TPC-H deployment, by file path as its harness loads it:
+# generator, `.tbl` writer, DDL, statement texts and the numpy references
+# have one home
+TPCH_DIR = os.path.join(HERE, "benchmarks", "configs", "tpch_sf0p02")
+_spec = importlib.util.spec_from_file_location(
+    "tpch_sf0p02_deployment", os.path.join(TPCH_DIR, "deployment.py"))
+TPCH = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(TPCH)
+# the validation parameters of TPC-H 2.4.1.3 / 2.4.3.3 / 2.4.6.3: the
+# statements here carry fixed literals, the benchmark's cells draw theirs
+PARAMS = {
+    "q1": {"delta": 90},
+    "q3": {"segment": "BUILDING", "date": "1995-03-15"},
+    "q6": {"date": "1994-01-01", "discount": "0.06", "quantity": 24},
+}
+with open(os.path.join(TPCH_DIR, "statements.json")) as _f:
+    SQL = {name: text.format(**PARAMS[name]) for name, text in json.load(_f).items()}
 
 RANGE_SQL = """select o_orderkey, o_orderdate from orders
  where o_orderdate >= '1995-03-01' and o_orderdate < '1995-03-08'
@@ -117,242 +77,23 @@ def emit(**line) -> None:
     print(json.dumps(line), flush=True)
 
 
-# --------------------------------------------------------------------------
-# data: dbgen's value rules for every column the statements read
-# --------------------------------------------------------------------------
-
-def _texts(rng, n: int, max_len: int) -> list:
-    """Random comment text, at most `max_len` characters."""
-    picks = rng.integers(0, len(WORDS), size=(n, 6))
-    return [" ".join(WORDS[j] for j in row)[:max_len].rstrip() for row in picks]
-
-
-def _cents(a) -> list:
-    """Scaled-int64 cents -> decimal(15,2) text."""
-    out = []
-    for v in a.tolist():
-        s, v = ("-", -v) if v < 0 else ("", v)
-        out.append(f"{s}{v // 100}.{v % 100:02d}")
-    return out
-
-
-def generate(rows: int, seed: int) -> dict:
-    """TPC-H lineitem/orders/customer as numpy arrays (money in cents,
-    dates as days since 1992-01-01)."""
-    rng = np.random.default_rng(seed)
-    n_ord, n_cust = max(rows // 4, 1), max(rows // 32, 3)
-
-    # customer
-    c = {
-        "custkey": np.arange(1, n_cust + 1, dtype=np.int64),
-        "nationkey": rng.integers(0, 25, n_cust),
-        "acctbal": rng.integers(-99999, 1000000, n_cust),
-        "segment": rng.integers(0, len(SEGMENTS), n_cust),
-    }
-
-    # orders: sparse keys (dbgen uses 8 of every 32), custkey never a
-    # multiple of 3, 1..7 lines each summing to `rows`
-    i = np.arange(n_ord, dtype=np.int64)
-    okey = (i // 8) * 32 + i % 8 + 1
-    ocust = rng.integers(1, n_cust + 1, n_ord)
-    ocust = np.where(ocust % 3 == 0, ocust - 1, ocust)
-    ocust = np.where(ocust < 1, 1, ocust)
-    odate = rng.integers(0, (np.datetime64("1998-08-02") - EPOCH).astype(int) + 1, n_ord)
-    nlines = rng.integers(1, 8, n_ord)
-    while (diff := rows - int(nlines.sum())) != 0:
-        room = np.flatnonzero(nlines < 7 if diff > 0 else nlines > 1)
-        pick = rng.choice(room, size=min(abs(diff), len(room)), replace=False)
-        nlines[pick] += 1 if diff > 0 else -1
-
-    # lineitem
-    oidx = np.repeat(i, nlines)
-    first = np.cumsum(nlines) - nlines
-    l = {
-        "oidx": oidx,
-        "orderkey": okey[oidx],
-        "linenumber": np.arange(rows, dtype=np.int64) - first[oidx] + 1,
-        "partkey": rng.integers(1, max(rows // 30, 200) + 1, rows),
-        "quantity": rng.integers(1, 51, rows),
-        "discount": rng.integers(0, 11, rows),
-        "tax": rng.integers(0, 9, rows),
-    }
-    l["suppkey"] = l["partkey"] % max(rows // 600, 10) + 1
-    pk = l["partkey"]
-    retail = 90000 + (pk // 10) % 20001 + 100 * (pk % 1000)  # cents
-    l["extendedprice"] = l["quantity"] * retail
-    l["shipdate"] = odate[oidx] + rng.integers(1, 122, rows)
-    l["commitdate"] = odate[oidx] + rng.integers(30, 91, rows)
-    l["receiptdate"] = l["shipdate"] + rng.integers(1, 31, rows)
-    current = (np.datetime64("1995-06-17") - EPOCH).astype(int)
-    l["returnflag"] = np.where(
-        l["receiptdate"] <= current, np.where(rng.integers(0, 2, rows) == 0, "R", "A"), "N")
-    l["linestatus"] = np.where(l["shipdate"] > current, "O", "F")
-    l["shipinstruct"] = rng.integers(0, len(INSTRUCTS), rows)
-    l["shipmode"] = rng.integers(0, len(MODES), rows)
-
-    n_open = np.bincount(oidx, weights=(l["linestatus"] == "O"), minlength=n_ord).astype(np.int64)
-    total = np.zeros(n_ord, np.int64)
-    np.add.at(total, oidx, l["extendedprice"] * (100 + l["tax"]) * (100 - l["discount"]) // 10000)
-    o = {
-        "orderkey": okey, "custkey": ocust, "orderdate": odate, "totalprice": total,
-        "status": np.where(n_open == nlines, "O", np.where(n_open == 0, "F", "P")),
-        "priority": rng.integers(0, len(PRIORITIES), n_ord),
-        "clerk": rng.integers(1, max(rows // 6000, 1) + 1, n_ord),
-        "shippriority": np.zeros(n_ord, np.int64),
-    }
-    return {"customer": c, "orders": o, "lineitem": l, "rng": rng}
+def _day(s: str) -> int:
+    return int((np.datetime64(s) - TPCH.EPOCH).astype(int))
 
 
 def _dates(days) -> list:
-    return np.datetime_as_string(EPOCH + days.astype("timedelta64[D]")).tolist()
+    return np.datetime_as_string(TPCH.EPOCH + days.astype("timedelta64[D]")).tolist()
 
 
-def write_files(data: dict, out_dir: str) -> dict:
-    """One '|'-separated file per table, every column of the DDL."""
-    rng = data["rng"]
-    c, o, l = data["customer"], data["orders"], data["lineitem"]
-    os.makedirs(out_dir, exist_ok=True)
-    cols = {
-        "customer": [
-            c["custkey"].tolist(),
-            [f"Customer#{k:09d}" for k in c["custkey"].tolist()],
-            _texts(rng, len(c["custkey"]), 40),
-            c["nationkey"].tolist(),
-            [f"{10 + n}-{k % 900 + 100}-{k % 800 + 100}-{k % 9000 + 1000}"
-             for n, k in zip(c["nationkey"].tolist(), c["custkey"].tolist())],
-            _cents(c["acctbal"]),
-            [SEGMENTS[s] for s in c["segment"].tolist()],
-            _texts(rng, len(c["custkey"]), 117),
-        ],
-        "orders": [
-            o["orderkey"].tolist(), o["custkey"].tolist(), o["status"].tolist(),
-            _cents(o["totalprice"]), _dates(o["orderdate"]),
-            [PRIORITIES[p] for p in o["priority"].tolist()],
-            [f"Clerk#{k:09d}" for k in o["clerk"].tolist()],
-            o["shippriority"].tolist(),
-            _texts(rng, len(o["orderkey"]), 79),
-        ],
-        "lineitem": [
-            l["orderkey"].tolist(), l["partkey"].tolist(), l["suppkey"].tolist(),
-            l["linenumber"].tolist(),
-            [f"{q}.00" for q in l["quantity"].tolist()],
-            _cents(l["extendedprice"]),
-            [f"0.{d:02d}" for d in l["discount"].tolist()],
-            [f"0.{t:02d}" for t in l["tax"].tolist()],
-            l["returnflag"].tolist(), l["linestatus"].tolist(),
-            _dates(l["shipdate"]), _dates(l["commitdate"]), _dates(l["receiptdate"]),
-            [INSTRUCTS[s] for s in l["shipinstruct"].tolist()],
-            [MODES[m] for m in l["shipmode"].tolist()],
-            _texts(rng, len(l["orderkey"]), 44),
-        ],
-    }
-    paths = {}
-    for table, columns in cols.items():
-        paths[table] = os.path.join(out_dir, f"{table}.tbl")
-        with open(paths[table], "w") as f:
-            f.writelines("|".join(map(str, row)) + "\n" for row in zip(*columns))
-    return paths
+def check_tpch(name: str):
+    """run_twice's `check` for a TPC-H statement: the served rows against
+    the deployment's numpy reference at this file's fixed parameters."""
+    def check(rows, data) -> dict:
+        bad = TPCH.mismatch(name, TPCH.reference(name, PARAMS[name], data), rows)
+        assert bad is None, bad
+        return {"rows": len(rows)}
 
-
-# --------------------------------------------------------------------------
-# the plain reference: numpy over the generated arrays, exact
-# --------------------------------------------------------------------------
-
-def _day(s: str) -> int:
-    return int((np.datetime64(s) - EPOCH).astype(int))
-
-
-def _scaled(v: int, scale: int) -> D:
-    return D(int(v)).scaleb(-scale)
-
-
-def ref_q6(data) -> D:
-    l = data["lineitem"]
-    m = ((l["shipdate"] >= _day("1994-01-01")) & (l["shipdate"] < _day("1995-01-01"))
-         & (l["discount"] >= 5) & (l["discount"] <= 7) & (l["quantity"] < 24))
-    return _scaled((l["extendedprice"][m] * l["discount"][m]).sum(), 4)
-
-
-def ref_q1(data) -> list:
-    """[(flag, status, sum_qty, sum_price, sum_disc_price, sum_charge,
-    (avg numerators...), count)] in key order; averages stay exact
-    fractions (sum, count) for the caller to round at the engine's scale."""
-    l = data["lineitem"]
-    m = l["shipdate"] <= _day("1998-09-02")
-    disc_price = l["extendedprice"] * (100 - l["discount"])
-    charge = disc_price * (100 + l["tax"])
-    out = []
-    for flag in "ANR":
-        for status in "FO":
-            g = m & (l["returnflag"] == flag) & (l["linestatus"] == status)
-            n = int(g.sum())
-            if not n:
-                continue
-            qty, price, disc = (int(l[k][g].sum()) for k in ("quantity", "extendedprice", "discount"))
-            out.append((flag, status, D(qty), _scaled(price, 2),
-                        _scaled(disc_price[g].sum(), 4), _scaled(charge[g].sum(), 6),
-                        (D(qty), n), (_scaled(price, 2), n), (_scaled(disc, 2), n), n))
-    return out
-
-
-def ref_q3(data) -> dict:
-    """orderkey -> (revenue, orderdate text, shippriority) for every
-    qualifying group; the caller applies ORDER BY ... LIMIT 10."""
-    c, o, l = data["customer"], data["orders"], data["lineitem"]
-    building = c["segment"] == SEGMENTS.index("BUILDING")
-    o_ok = (o["orderdate"] < _day("1995-03-15")) & building[o["custkey"] - 1]
-    l_ok = (l["shipdate"] > _day("1995-03-15")) & o_ok[l["oidx"]]
-    rev = np.zeros(len(o["orderkey"]), np.int64)
-    np.add.at(rev, l["oidx"][l_ok], (l["extendedprice"] * (100 - l["discount"]))[l_ok])
-    hit = np.zeros(len(o["orderkey"]), bool)
-    hit[l["oidx"][l_ok]] = True
-    dates = _dates(o["orderdate"][hit])
-    return {
-        int(k): (_scaled(r, 4), d, int(p))
-        for k, r, d, p in zip(o["orderkey"][hit], rev[hit], dates, o["shippriority"][hit])
-    }
-
-
-def check_q6(rows, data) -> dict:
-    assert len(rows) == 1, rows
-    want = ref_q6(data)
-    assert D(rows[0][0]) == want, (rows, want)
-    return {"revenue": str(want)}
-
-
-def _round_like(frac, text: str) -> D:
-    """Exact sum/count rounded half-up to the scale the engine printed."""
-    s, n = frac
-    scale = len(text.partition(".")[2])
-    assert scale >= 4, f"avg printed at scale {scale}: {text!r}"
-    with decimal.localcontext() as ctx:
-        ctx.prec = 60
-        return (s / n).quantize(D(1).scaleb(-scale), rounding=decimal.ROUND_HALF_UP)
-
-
-def check_q1(rows, data) -> dict:
-    want = ref_q1(data)
-    assert len(rows) == len(want), (rows, want)
-    for got, w in zip(rows, want):
-        assert (got[0], got[1]) == (w[0], w[1]), (got, w)
-        for j in (2, 3, 4, 5):
-            assert D(got[j]) == w[j], (j, got, w)
-        for j in (6, 7, 8):
-            assert D(got[j]) == _round_like(w[j], got[j]), (j, got, w)
-        assert int(got[9]) == w[9], (got, w)
-    return {"groups": len(want)}
-
-
-def check_q3(rows, data) -> dict:
-    ref = ref_q3(data)
-    top = sorted(ref.values(), key=lambda v: (-v[0], v[1]))[:10]
-    assert len(rows) == len(top), (len(rows), len(top))
-    for got, w in zip(rows, top):
-        # ties on (revenue, orderdate) may order either way: the sort keys
-        # must match position by position, the row itself its own group
-        assert (D(got[1]), got[2]) == (w[0], w[1]), (got, w)
-        assert ref[int(got[0])] == (D(got[1]), got[2], int(got[3])), (got, ref[int(got[0])])
-    return {"groups": len(ref), "returned": len(rows)}
+    return check
 
 
 # --------------------------------------------------------------------------
@@ -524,9 +265,10 @@ def phase_load(ctx: Ctx) -> None:
 
     p = Probe()
     t0 = time.perf_counter()
-    ctx.data = generate(ctx.rows, ctx.seed)
+    ctx.data = TPCH.generate({"lineitem_rows": ctx.rows}, ctx.seed)
     data_dir = os.path.join(OUT_DIR, f"chip_smoke_data_{os.getpid()}")
-    paths = write_files(ctx.data, data_dir)
+    os.makedirs(data_dir)
+    paths = TPCH.write_files(ctx.data, data_dir)
     gen_s = time.perf_counter() - t0
 
     ctx.srv = MySQLServer(port=0)
@@ -534,7 +276,7 @@ def phase_load(ctx: Ctx) -> None:
     ctx.client, ctx.client2 = ctx.connect(), ctx.connect()
     loaded = {}
     t0 = time.perf_counter()
-    for ddl in DDL:
+    for ddl in TPCH.DDL:
         ctx.client.query(ddl)
     for table in ("customer", "orders", "lineitem"):
         n = ctx.client.query(
@@ -557,15 +299,16 @@ def phase_load(ctx: Ctx) -> None:
 def phase_row_store(ctx: Ctx) -> None:
     c, data, q = ctx.client, ctx.data, ctx.runner
     p = Probe()
-    run_twice("q6", q(Q6), check_q6, data)
-    run_twice("q1", q(Q1), check_q1, data, want_pallas=True)
-    run_twice("q3", q(Q3), check_q3, data, want_pallas=True)
+    run_twice("q6", q(SQL["q6"]), check_tpch("q6"), data)
+    run_twice("q1", q(SQL["q1"]), check_tpch("q1"), data, want_pallas=True)
+    run_twice("q3", q(SQL["q3"]), check_tpch("q3"), data, want_pallas=True)
 
     o = data["orders"]
     k = len(o["orderkey"]) // 2
 
     def check_point(rows, _d):
-        want = [[str(o["orderkey"][k]), str(o["custkey"][k]), _cents(o["totalprice"][k:k + 1])[0],
+        price = TPCH.D(int(o["totalprice"][k])).scaleb(-2)  # cents -> decimal(15,2) text
+        want = [[str(o["orderkey"][k]), str(o["custkey"][k]), str(price),
                  _dates(o["orderdate"][k:k + 1])[0]]]
         assert rows == want, (rows, want)
         return {"rows": 1}
@@ -613,8 +356,8 @@ def phase_columnar(ctx: Ctx) -> None:
         assert ticks < 8, f"columnar replica not available after {ticks} ticks: {view}"
     fill_s = time.perf_counter() - p.t0
     scans0, fallbacks0 = metrics.COLUMNAR_SCANS.value, metrics.COLUMNAR_FALLBACKS.value
-    run_twice("columnar_q6", ctx.runner(Q6), check_q6, data)
-    run_twice("columnar_q1", ctx.runner(Q1), check_q1, data, want_pallas=True)
+    run_twice("columnar_q6", ctx.runner(SQL["q6"]), check_tpch("q6"), data)
+    run_twice("columnar_q1", ctx.runner(SQL["q1"]), check_tpch("q1"), data, want_pallas=True)
     scans = metrics.COLUMNAR_SCANS.value - scans0
     fallbacks = metrics.COLUMNAR_FALLBACKS.value - fallbacks0
     assert scans >= 4 and fallbacks == 0, (scans, fallbacks)
@@ -645,7 +388,7 @@ def phase_mesh(ctx: Ctx, n_devices: int) -> None:
     emit(phase="split", ticks=ticks, regions=n_regions)
 
     counters = ("MPP_SELECTS", "MESH_COP_BATCHES", "MPP_FALLBACKS", "MESH_COP_FALLBACKS")
-    checks = (("q6", Q6, check_q6), ("q1", Q1, check_q1), ("q3", Q3, check_q3))
+    checks = tuple((name, SQL[name], check_tpch(name)) for name in ("q6", "q1", "q3"))
     for mode in ("mesh", "single_device"):
         if mode == "single_device":
             c.query("set tidb_enable_tpu_mesh = OFF")

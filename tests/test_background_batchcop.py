@@ -57,7 +57,7 @@ def test_broadcast_exchange():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tidb_tpu.parallel.exchange import broadcast_exchange
+    from tidb_tpu.mpp.exchange_op import broadcast_exchange
 
     mesh = _mesh8()
     n = 4
@@ -79,7 +79,7 @@ def test_passthrough_exchange():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tidb_tpu.parallel.exchange import passthrough_exchange
+    from tidb_tpu.mpp.exchange_op import passthrough_exchange
 
     mesh = _mesh8()
     n = 4

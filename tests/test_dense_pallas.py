@@ -1,6 +1,6 @@
 """Parity tests for the one-pass Pallas small-G kernel (ops/dense_pallas.py)
 against the sort kernel, run in Pallas interpret mode on CPU (the compiled
-path is exercised on real TPU by bench.py's parity gate)."""
+path runs on the TPU in chip_smoke.py's Q1 and in the cell tpch_q1q6_params)."""
 
 import numpy as np
 import pytest

@@ -2,7 +2,7 @@
 the row-at-a-time oracle AND vs the general hash_join+group_aggregate path,
 plus the overflow contracts (duplicate build keys -> join overflow -> the
 driver's unique-hint drop lands on the general kernel; group capacity ->
-grow) — the shapes the bench q3 config rides (ref:
+grow) — the shapes TPC-H Q3 rides in chip_smoke.py (ref:
 pkg/executor/join/hash_join_v2.go, agg_stream_executor.go)."""
 
 import numpy as np

@@ -1,6 +1,6 @@
 """Parity: the Pallas post-sort segscan path of packed_join_groupsum vs
 the XLA scan path, in interpret mode on CPU (ref coverage mirrors
-tests/test_joinagg.py; the compiled path runs on TPU via bench.py)."""
+tests/test_joinagg.py; the compiled path runs on the TPU in chip_smoke.py's Q3)."""
 
 import numpy as np
 import pytest

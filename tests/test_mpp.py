@@ -162,13 +162,17 @@ class TestMppDispatch:
         s.execute("set tidb_enable_tpu_mesh = OFF")
         assert _canon(mpp_rows) == _canon(s.execute(Q3_SQL).rows)
 
-    def test_allow_mpp_off_takes_the_mesh_shortcut(self):
+    def test_allow_mpp_off_is_served_by_execute_root(self):
+        """The sysvar selects no second exchange implementation: OFF sends
+        the statement to execute_root, and no exchange program runs."""
         s = _q3_session()
         s.execute("set tidb_allow_mpp = OFF")
         m0, e0 = M.MPP_SELECTS.value, M.MESH_SELECTS.value
         rows = s.execute(Q3_SQL).rows
         assert M.MPP_SELECTS.value == m0
-        assert M.MESH_SELECTS.value == e0 + 1
+        assert M.MESH_SELECTS.value == e0
+        tree = s.execute("TRACE FORMAT='json' " + Q3_SQL).values()[0][0]
+        assert "distsql.execute_root" in tree and "mpp.dispatch" not in tree
         s.execute("set tidb_enable_tpu_mesh = OFF")
         assert _canon(rows) == _canon(s.execute(Q3_SQL).rows)
 

@@ -11,7 +11,7 @@ from tidb_tpu.chunk import Chunk
 from tidb_tpu.expr import AggDesc, col, func, lit
 from tidb_tpu.exec import Aggregation, ColumnInfo, DAGRequest, Selection, TableScan, run_dag_reference
 from tidb_tpu.parallel import region_mesh, run_sharded_partial_agg, stack_region_batches
-from tidb_tpu.parallel.exchange import exchange_group_aggregate, hash_partition_ids, scatter_to_buckets
+from tidb_tpu.mpp.exchange_op import exchange_group_aggregate, hash_partition_ids, scatter_to_buckets
 from tidb_tpu.expr.compile import CompVal, normalize_device_column
 
 BOOL = new_longlong(notnull=True)
@@ -486,7 +486,7 @@ class TestMeshShuffleJoin:
         assert mesh == tp == [("x", "300")]
 
     def test_multidevice_mesh_eligibility_kinds(self):
-        from tidb_tpu.parallel.sql import mesh_eligible
+        from tidb_tpu.mpp.fragment import mesh_eligible
         from tidb_tpu.parser import parse_one
         from tidb_tpu.sql.planner import plan_select
 

@@ -231,7 +231,7 @@ def test_load_data_lock_conflict_is_a_sql_error(sess, tmp_path):
 
 def test_failpoint_check_repo_is_clean():
     """Tier-1 gate (ISSUE 6 satellite): every failpoint name armed in
-    tests/tools/bench resolves to a real eval/is_armed/peek site in
+    tests/ and tools/ resolves to a real eval/is_armed/peek site in
     tidb_tpu/, and every site carries a catalog description — a typo'd
     name silently never fires, so this is the only guard."""
     import sys

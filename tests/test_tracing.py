@@ -106,10 +106,9 @@ class TestTraceStatement:
         assert tree["name"] == "session"
         assert self._find(tree, "session.execute")
         assert self._find(tree, "planner.plan")
-        # dispatch level: the thread-pool path, the device-mesh path, or
-        # the mpp fragment path — whichever the gate picked on this host
+        # dispatch level: execute_root or the mpp fragment path —
+        # whichever the gate picked on this host
         dispatch = (self._find(tree, "distsql.execute_root")
-                    + self._find(tree, "parallel.mesh_select")
                     + self._find(tree, "mpp.dispatch"))
         assert dispatch
         cop = self._find(tree, "distsql.cop_task")
@@ -132,7 +131,6 @@ class TestTraceStatement:
 
         check(tree)
         dispatch = (self._find(tree, "distsql.execute_root")
-                    + self._find(tree, "parallel.mesh_select")
                     + self._find(tree, "mpp.dispatch"))[0]
         cop = self._find(tree, "distsql.cop_task")
         assert cop and all(c["duration_ns"] <= dispatch["duration_ns"] for c in cop)

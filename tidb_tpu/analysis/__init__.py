@@ -68,7 +68,7 @@ class PassSpec:
           "corpus" — findings need the whole scope at once (cache per
                      (pass, corpus digest))
           "plain"  — self-scoped, uncached (failpoints: its inputs span
-                     tests//tools//bench.py which aren't loaded here)
+                     tests/ and tools/, which aren't loaded here)
     """
 
     run: object  # callable(files) -> [Finding]

@@ -7,8 +7,8 @@ that "exercises" a fault path then passes by exercising nothing (the
 reference avoids this with compile-time failpoint rewriting; a runtime
 registry has no such guard). Statically:
 
-  * every `failpoint.enable/enabled/disable("name")` in tests/, tools/
-    and bench.py must reference a SITE — a `failpoint.eval/is_armed/
+  * every `failpoint.enable/enabled/disable("name")` in tests/ and
+    tools/ must reference a SITE — a `failpoint.eval/is_armed/
     peek("name")` call — defined in `tidb_tpu/` (or in the same file, for
     the failpoint module's own unit tests);
   * every site defined in `tidb_tpu/` must carry a one-line description
@@ -53,7 +53,7 @@ DESCRIPTIONS = {
     "cdc/sink-stall": "skips a tick's sink emission — the sorter keeps the backlog and the emitted checkpoint holds until the stall clears",
     "columnar/apply-stall": "wedges the columnar replica's apply sink — the feeding changefeed parks in `error` with the backlog re-queued below its held checkpoint; RESUME (ColumnarReplica.resume_all) replays it, absorbed by the idempotent delta fold",
     "columnar/compact-stall": "skips the pd.columnar tick's delta-to-stable compaction — delta layers grow and the stable floor stops advancing; scans keep serving through the delta overlay",
-    "mpp/dispatch-lost": "loses an MPP task dispatch before launch — the coordinator abandons the fragment run as a counted fallback (MPP_FALLBACKS) and the statement re-dispatches on the non-MPP tiers, byte-identically",
+    "mpp/dispatch-lost": "loses an MPP task dispatch before launch — the coordinator abandons the fragment run as a counted fallback (MPP_FALLBACKS) and the statement re-dispatches through execute_root, byte-identically",
     "mpp/exchange-stall": "stalls the fragment exchange mid-run — the coordinator abandons the MPP attempt after sourcing the probe scan; a counted fallback, never a torn result",
     "server/admission-full": "forces the admission gate's saturated answer — every statement/dispatch arriving at an armed gate sheds as typed ServerIsBusy{backoff_ms} without consuming a slot, so tests exercise backpressure without real load",
     "pd/heartbeat-lost": "drops one tick's region-heartbeat interval on the floor (a lost heartbeat stream)",
@@ -78,9 +78,6 @@ _USE = re.compile(r"""(?:failpoint|_fp|fp)\s*\.\s*(?:enable|enabled|disable)\(\s
 def _py_files(*rel_dirs: str):
     for rel in rel_dirs:
         root = os.path.join(REPO, rel)
-        if os.path.isfile(root):
-            yield root
-            continue
         for dirpath, _dirs, files in os.walk(root):
             if "vet_fixtures" in dirpath:
                 continue  # true-positive corpora are scanned EXPLICITLY by
@@ -139,8 +136,8 @@ def _unresolved_uses(sites: dict, uses: dict, local_sites: dict) -> list:
 def analyze() -> tuple[list, dict[str, list[str]]]:
     """Finding-shaped variant of check() for the vet driver."""
     sites = _scan(_SITE, _py_files("tidb_tpu"))
-    uses = _scan(_USE, _py_files("tests", "tools", "bench.py"))
-    local_sites = _scan(_SITE, _py_files("tests", "tools", "bench.py"))
+    uses = _scan(_USE, _py_files("tests", "tools"))
+    local_sites = _scan(_SITE, _py_files("tests", "tools"))
     findings = _unresolved_uses(sites, uses, local_sites)
     for name in sorted(sites):
         if name not in DESCRIPTIONS:
@@ -155,7 +152,7 @@ def analyze() -> tuple[list, dict[str, list[str]]]:
 
 def run(files=None) -> list:
     """Vet-pass entry point. With no `files` the pass owns its scoping
-    (sites in tidb_tpu/, uses in tests//tools//bench.py); with an explicit
+    (sites in tidb_tpu/, uses in tests/ and tools/); with an explicit
     list (the driver's --files mode) the GIVEN files' arms are checked
     against the live tree's sites — a fixture corpus must report, not
     silently fall back to a clean full-tree scan."""

@@ -97,7 +97,7 @@ def _analytical(dag) -> bool:
 def columnar_would_serve(store, dag, ranges, engines) -> bool:
     """Cheap routing predicate (no execution, no waiting): is this plan
     the columnar replica's to serve? The session uses it to keep the
-    whole-plan mesh shortcut from preempting engine routing; readiness is
+    statement-level mpp tier from preempting engine routing; readiness is
     NOT checked here — a lagging frontier is `try_columnar_select`'s
     fallback decision, made at execution time."""
     if "columnar" not in engines:

@@ -461,8 +461,8 @@ def _run_one_task(store, req, task, summaries, retries=MAX_RETRY,
                 continue
             metrics.DISTSQL_TASKS.inc()
             # authoritative placement lookup (a miss routes through the
-            # PD, never a modulo guess) — the per-store counts are what
-            # bench.py's skew scenario reads before/after PD balancing
+            # PD, never a modulo guess); the per-store counts show on
+            # /metrics how evenly PD has balanced the leaders
             metrics.DISTSQL_STORE_TASKS.labels(str(sid)).inc()
             creq = CopRequest(
                 req.dag, ranges, req.start_ts, task.region_id, task.epoch,

@@ -22,9 +22,9 @@ batch-cop vs MPP in planner/core's task-type decision, mpp_gather.go:40):
           partial/final agg -> psum).
 
 The mesh tier is the paper's north star collective on the STANDARD
-`distsql.select` path; `parallel/sql.py`'s mesh_select plans (grouped
-exchange, shuffle joins) ride their own shard_map programs above this
-seam. Every tier shares the same up-front epoch checks, typed region
+`distsql.select` path; the statement-level exchange plans of `mpp/`
+(grouped exchange, shuffle joins) ride their own shard_map programs above
+this seam. Every tier shares the same up-front epoch checks, typed region
 errors, breakers and replica routing — a task can fall from mesh to
 batch to single without changing semantics, only launch shape.
 """
@@ -47,7 +47,7 @@ MESH_MERGEABLE_AGGS = frozenset({
 @dataclass(frozen=True)
 class TierDecision:
     # per-request tiers: "single" | "pool" | "batch" | "mesh"
-    # statement-level tiers (choose_statement_tier): "root" | "mesh" | "mpp"
+    # statement-level tiers (choose_statement_tier): "root" | "mpp"
     tier: str
     # mesh merge kind ("scalar" | "group" | "topn") for the request tiers;
     # exchange plan kind ("agg" | "join") for the statement tiers
@@ -129,31 +129,26 @@ def choose_statement_tier(dag, *, allow_mpp: bool, allow_mesh: bool,
               replica probe sourcing. Joins take this tier even when the
               columnar replica covers the plan — the fragments SOURCE from
               the replica instead of ceding the whole statement to it.
-      "mesh"  the whole-plan mesh shortcut (parallel/sql.try_mesh_select)
-              without the fragment/dispatch layer (tidb_allow_mpp=OFF).
-      "root"  no statement-level shortcut: execute_root owns dispatch
-              (its own per-request tiers + columnar engine routing).
+      "root"  execute_root owns dispatch (its own per-request tiers +
+              columnar engine routing): tidb_allow_mpp or
+              tidb_enable_tpu_mesh OFF, one device, an ineligible shape.
 
     `columnar_routed` is a thunk so the engine-routing walk only runs when
-    a shortcut is actually on the table (review finding on the original
-    mesh gate: no double walk when mesh is off)."""
-    if not allow_mesh or _n_devices() < 2:
+    the mpp tier is actually on the table."""
+    if not allow_mpp or not allow_mesh or _n_devices() < 2:
         return TierDecision("root")
-    from ..parallel.sql import mesh_eligible
+    from ..mpp.fragment import mesh_eligible
 
     kind = mesh_eligible(dag)
     if kind is None:
         return TierDecision("root")
-    if allow_mpp and kind == "join":
-        # shuffle joins are the mpp tier's raison d'être: the replica
-        # serves the probe scan INSIDE the fragment plan, so columnar
-        # engine routing must not preempt the statement
-        return TierDecision("mpp", kind)
-    if columnar_routed():
-        # the columnar replica owns this plan (engine routing, ISSUE 12):
-        # the whole-statement shortcut must not preempt it
+    # shuffle joins are the mpp tier's raison d'être: the replica serves
+    # the probe scan INSIDE the fragment plan, so columnar engine routing
+    # does not preempt a join. A grouped agg that the columnar replica owns
+    # (engine routing, ISSUE 12) stays with execute_root
+    if kind != "join" and columnar_routed():
         return TierDecision("root")
-    return TierDecision("mpp" if allow_mpp else "mesh", kind)
+    return TierDecision("mpp", kind)
 
 
 def choose_tier(store, req, tasks) -> TierDecision:

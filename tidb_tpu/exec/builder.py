@@ -70,7 +70,7 @@ class CompiledDAG:
     outputs: launch.HostOutputs
     # the same program as a traceable function with its outputs as device
     # arrays, `packed` in place of `output_leaves`: for a caller that
-    # traces it into a program of its own (bench.py's loop)
+    # traces it into a program of its own (__graft_entry__.py's entry step)
     program: object
     out_fts: list[FieldType]
     capacities: tuple  # one per scan, canonical order (dag.collect_scans)
@@ -100,15 +100,9 @@ class _TraceState:
 
     Group and join overflow are SEPARATE flags so the retry driver grows
     only the capacity that actually overflowed (a 4x-per-retry growth on
-    the wrong knob wastes HBM and compile time).
+    the wrong knob wastes HBM and compile time)."""
 
-    summaries=False drops the per-executor produced-row counts: each one
-    is a full-array reduce with a ~1.5-3ms dispatch floor on the v5e
-    (2026-07-31 measurement, not repeated since), which for a 9-executor
-    join plan is more than the sorts cost — the bench path runs without
-    them, production keeps them (EXPLAIN ANALYZE needs the numbers)."""
-
-    def __init__(self, summaries: bool = True, params: dict | None = None):
+    def __init__(self, params: dict | None = None):
         self.group_overflow = jnp.bool_(False)
         self.join_overflow = jnp.bool_(False)
         self.topn_overflow = jnp.bool_(False)
@@ -123,8 +117,7 @@ class _TraceState:
         self.radix_meta: dict = {}  # filled at trace time (partitions)
         self.radix_joins = True  # builder knob: False = monolithic only
         self.params = params or {}  # lane -> the traced operand array that `Param` seats read (expr/ir.py)
-        self.summaries = summaries
-        self.ex_rows: list = []
+        self.ex_rows: list = []  # per-executor produced rows (EXPLAIN ANALYZE)
 
     def note_group(self, need):
         if need is not None:
@@ -135,10 +128,8 @@ class _TraceState:
             self.join_need = jnp.maximum(self.join_need, need.astype(jnp.int64))
 
     def rows(self, arr_or_scalar):
-        """Record a produced-row count (lazy: no-op when summaries off).
-        Accepts a precomputed scalar or a bool/int mask to sum."""
-        if not self.summaries:
-            return
+        """Record a produced-row count. Accepts a precomputed scalar or a
+        bool/int mask to sum."""
         v = arr_or_scalar
         if getattr(v, "ndim", 0) > 0:
             v = v.sum()
@@ -671,7 +662,6 @@ def build_program(
     topn_full: bool = False,
     small_groups: int | None = None,
     unique_joins: bool = True,
-    summaries: bool = True,
     vmap_batch: int | None = None,
     mesh_lanes: int | None = None,
     mesh_devices: int | None = None,
@@ -720,15 +710,16 @@ def build_program(
 
     def program(*args):
         batches = args[:n_scans]
-        state = _TraceState(summaries, params=dict(zip(lanes, args[n_scans:])))
+        state = _TraceState(params=dict(zip(lanes, args[n_scans:])))
         state.radix_joins = radix_joins
         cursor = [0]
         cols, valid, _ = _run_pipeline(dag.executors, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins, out_offsets=dag.output_offsets)
         packed = _pack_cols([cols[i] for i in dag.output_offsets])
         n_out = valid.sum()
-        # summaries off: no constant/empty-shaped stand-in — both a
-        # 0-length output and a folded-constant output have SIGSEGV'd the
-        # TPU compiler (2026-07-31); reuse the (data-dependent) row count
+        # a plan that recorded no count: no constant/empty-shaped stand-in
+        # — both a 0-length output and a folded-constant output have
+        # SIGSEGV'd the TPU compiler (2026-07-31); reuse the
+        # (data-dependent) row count
         ex = jnp.stack(state.ex_rows) if state.ex_rows else n_out[None].astype(jnp.int64)
         radix_info.update(state.radix_meta)  # trace-time side channel
         # the flag tuple carries the capacity NEED hints and the radix

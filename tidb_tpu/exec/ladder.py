@@ -45,10 +45,8 @@ def next_rung(c: int, factor: int = 4) -> int:
 
 def overflow_step(gc: int, jc: int, g_ovf: bool, j_ovf: bool,
                   g_need: int, j_need: int) -> tuple:
-    """ONE overflow-retry policy step — shared by the executor driver and
-    both bench loops so the bench certifies the policy production runs
-    (BENCH_JOIN's retry_recompiles_after_warm number is only meaningful
-    if the loops agree).  Returns (gc, jc, drop_join_hints):
+    """ONE overflow-retry policy step of the executor driver
+    (exec/executor.py).  Returns (gc, jc, drop_join_hints):
 
       * a need hint ABOVE the current rung is a pure capacity miss — jump
         straight to its rung and keep every fast-path hint;
@@ -76,8 +74,9 @@ def overflow_step(gc: int, jc: int, g_ovf: bool, j_ovf: bool,
 
 
 def rungs_up_to(n: int) -> list[int]:
-    """Every rung from RUNG_BASE through rung_for(n) — the precompile set
-    bench.py warms so overflow retries never trace a new program."""
+    """Every rung from RUNG_BASE through rung_for(n) — the set to
+    precompile so that overflow retries never trace a new program
+    (tests/test_radix_join.py warms it)."""
     out = [RUNG_BASE]
     while out[-1] < n and out[-1] < RUNG_MAX:
         out.append(out[-1] * 2)

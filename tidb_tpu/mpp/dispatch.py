@@ -18,7 +18,7 @@ to the row-store scan pushdown otherwise, and (3) launch the exchange
 program with the overflow capacity ladder.
 
 Failure discipline mirrors `columnar/route.py`: every decline is a COUNTED
-fallback (`MPP_FALLBACKS`) and the caller dispatches to the next tier as
+fallback (`MPP_FALLBACKS`) and the caller dispatches to `execute_root` as
 if routing never happened — degrade, never fail; the row store still owns
 the authoritative answer. Typed region errors and epoch fall-out surface
 from the row-store scan path itself (`distsql.dispatch.select`), so a
@@ -27,7 +27,7 @@ the per-region path raises.
 
 Failpoints:
   mpp/dispatch-lost   a task dispatch is lost before launch — counted
-                      fallback to the non-MPP tiers.
+                      fallback to `execute_root`.
   mpp/exchange-stall  an exchange never delivers mid-run — the
                       coordinator abandons the run (counted fallback).
 """
@@ -36,9 +36,7 @@ from __future__ import annotations
 
 from ..chunk import Chunk
 from ..exec.dag import DAGRequest
-from .fragment import chunks_exchange_safe, fragment_kind, fragment_plan
-
-MPP_SYSVAR = "tidb_allow_mpp"
+from .fragment import chunks_exchange_safe, fragment_plan, mesh_eligible, split_join_dag
 
 # (encoded dag, n devices, base group capacity) -> last successful
 # (gc, scale) ladder rung; bounded FIFO, see execute_exchange_plan
@@ -55,8 +53,7 @@ def _chunks_nbytes(chunks) -> int:
 
 def execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
                           group_capacity: int = 1024) -> Chunk | None:
-    """Launch the exchange program over already-scanned chunks — the
-    shared execution core of the mesh tier and the mpp tier. Region
+    """Launch the exchange program over already-scanned chunks. Region
     chunks play the task lanes; build tables are sliced across devices so
     each slice plays a region shard. Overflow (too many groups / join
     fan-out / hash collision) retries with 4x capacity — the capacity
@@ -86,8 +83,6 @@ def execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
 
     stacked_builds = None
     if kind == "join":
-        from .fragment import split_join_dag
-
         n_stages = len(split_join_dag(dag)[2])
         if aux_chunks is None or len(aux_chunks) < n_stages:
             return None
@@ -218,9 +213,9 @@ def try_mpp_select(
     checker=None,
 ) -> Chunk | None:
     """Plan and run an eligible DAG as an MPP fragment graph; None = not
-    taken (counted fallback — the caller dispatches to the mesh shortcut /
-    per-region tiers as if MPP routing never happened)."""
-    kind = fragment_kind(dag)
+    taken (counted fallback — the caller dispatches to `execute_root` as
+    if MPP routing never happened)."""
+    kind = mesh_eligible(dag)
     if kind is None:
         return None
     if kind == "join" and not aux_chunks:

@@ -238,8 +238,8 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
         # n * lane_rows — so the fair share IS the lane size. Capacities
         # derive from this estimate, NOT from the previous stage's padded
         # slot count: slot-derived caps compound `2*scale` per stage
-        # (scale^2 across a chain — the 8-device bench paid 500K-slot
-        # exchanges for a 16K-row table). Skew past the 2x headroom is the
+        # (scale^2 across a chain: 500K-slot exchanges for a 16K-row
+        # table on eight devices). Skew past the 2x headroom is the
         # ladder's job, and `scale` grows est linearly, never quadratically.
         est = valid.shape[0]
 
@@ -324,8 +324,8 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
 
 # compiled exchange programs, keyed by (wire-encoded DAG, mesh devices,
 # capacities). A fresh `jax.jit(closure)` per query re-traces the whole
-# shard_map program every time — at bench scale the re-trace dominates the
-# query by ~20x. The wire encoding is the plan identity (same bytes = same
+# shard_map program every time, and the re-trace outweighs the query.
+# The wire encoding is the plan identity (same bytes = same
 # device program), so repeated statements hit XLA's executable cache; the
 # jitted callable itself still keys on input shapes, so shape changes only
 # re-trace, never collide. Bounded FIFO — a digest-churning workload evicts,

@@ -19,10 +19,10 @@ byte-exactly.
 The string width gate lives here because it is a property of the EXCHANGE,
 not of any one tier: packed compare words carry the first
 STRING_WORDS*8 bytes across the all_to_all; longer values would silently
-truncate, so every exchange consumer (mesh tier, mpp tier) shares this
-check. flen counts CHARACTERS (utf8mb4: up to 4 bytes each) and inserts do
-not enforce it, so the static gate is advisory only — the authoritative
-check measures actual bytes in the scanned chunks (chunks_exchange_safe).
+truncate, so every exchange plan passes this check. flen counts CHARACTERS
+(utf8mb4: up to 4 bytes each) and inserts do not enforce it, so the static
+gate is advisory only — the authoritative check measures actual bytes in
+the scanned chunks (chunks_exchange_safe).
 """
 
 from __future__ import annotations
@@ -123,13 +123,52 @@ def split_join_dag(dag: DAGRequest):
     return exs[0], pre, stages, exs[i]
 
 
-def fragment_kind(dag: DAGRequest) -> str | None:
-    """Exchange-shape eligibility — "agg" | "join" | None. Delegates to the
-    shared gate (parallel/sql.py mesh_eligible: DAG shape + host-only-expr
-    refusal), which both the mesh shortcut and the mpp tier consult."""
-    from ..parallel.sql import mesh_eligible
+def _agg_mesh_ok(agg) -> bool:
+    if not isinstance(agg, Aggregation) or not agg.group_by or agg.merge:
+        return False
+    # DISTINCT rides the raw-row exchange (parallel/grouped.py
+    # _distinct_exchange_phases); group_concat stays root-only
+    return not any(d.name == "group_concat" for d in agg.aggs)
 
-    return mesh_eligible(dag)
+
+def mesh_eligible(dag: DAGRequest) -> str | None:
+    """The exchange-shape gate of the statement tier (ref: the reference's
+    per-operator CanPushToTiFlash checks in exhaust_physical_plans).
+    Returns the exchange plan kind:
+
+      "agg"  — TableScan [Selection]* Aggregation(GROUP BY)
+      "join" — TableScan [Sel]* Join(scan [Sel]*) [Sel]* Aggregation(...)
+               (the hash-shuffle repartition join, split_join_dag)
+      None   — ineligible (host-only exprs, group_concat, merge mode, ...)
+    """
+    from ..distsql.root import host_only_exprs
+
+    exs = dag.executors
+    if len(exs) < 2 or not isinstance(exs[0], TableScan):
+        return None
+    agg = exs[-1]
+    if not _agg_mesh_ok(agg):
+        return None
+    agg_exprs = list(agg.group_by) + [a for d in agg.aggs for a in d.args]
+
+    if all(isinstance(e, Selection) for e in exs[1:-1]):
+        exprs = [c for e in exs[1:-1] for c in e.conditions] + agg_exprs
+        # the device ExprCompiler cannot trace host-only ops (json_*,
+        # regexp, extensions) — execute_root keeps them at root, so the
+        # exchange program must refuse them rather than fail inside the trace
+        return None if host_only_exprs(exprs) else "agg"
+
+    parts = split_join_dag(dag)
+    if parts is None:
+        return None
+    _, pre, stages, _ = parts
+    exprs = [c for e in pre for c in e.conditions] + agg_exprs
+    for join, post in stages:
+        exprs += [c for e in list(join.build[1:]) + post for c in e.conditions]
+        exprs += list(join.probe_keys) + list(join.build_keys)
+    if host_only_exprs(exprs):
+        return None
+    return "join"
 
 
 def fragment_plan(dag: DAGRequest, n_tasks: int) -> FragmentPlan | None:

@@ -1,5 +1,4 @@
 from .mesh import region_mesh, stack_region_batches, run_sharded_partial_agg
-from .exchange import hash_partition_ids, exchange_group_aggregate
 from .grouped import run_sharded_grouped_agg
 
 __all__ = [
@@ -7,6 +6,4 @@ __all__ = [
     "stack_region_batches",
     "run_sharded_partial_agg",
     "run_sharded_grouped_agg",
-    "hash_partition_ids",
-    "exchange_group_aggregate",
 ]

@@ -67,7 +67,7 @@ def agg_exchange_phases(agg, schema_fts, cvals, valid, n_parts: int, group_capac
     """The MPP partial/exchange/final pipeline given the pre-agg schema —
     phases 1-3 of the module docstring. Called inside shard_map by both the
     scan+sel path (run_sharded_grouped_agg) and the hash-shuffle join path
-    (joinmesh.run_sharded_join_agg). Returns the flat output tuple
+    (mpp/exchange_op.py run_exchange_join_agg). Returns the flat output tuple
     [group_valid, (value, null)*, overflow]."""
     comp = ExprCompiler(schema_fts)
     gvals = comp.run(list(agg.group_by), cvals)

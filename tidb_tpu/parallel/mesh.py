@@ -16,7 +16,7 @@ This module owns the SHARED merge seam: `partial_merge_plan` +
 flipped unsigned domain, all_gather for bit/first states) are consumed both
 by the standalone `run_sharded_partial_agg` entry point and by
 `exec/builder.py`'s mesh-tier programs, so the standard `distsql.select`
-dispatch and the parallel/sql.py mesh_select path merge states with ONE
+dispatch and the exchange programs of mpp/ merge states with ONE
 implementation. Region stacking likewise delegates to the chunk layer's
 `to_stacked_device_batch` — the same host-side stacking the batch
 coprocessor uses — instead of a second device-side stack.

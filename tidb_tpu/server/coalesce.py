@@ -1,10 +1,9 @@
 """Cross-session fused execution (ISSUE 19) — the per-store session
 coalescer.
 
-BENCH_CONCURRENT showed the engine stops being the bottleneck at 256
-sessions: p99 is scheduling-bound because every session still pays its
-own device launch and its own quorum proposal. The paper's north star is
-sessions AS vmap lanes — this module makes that literal:
+With many concurrent sessions every session still pays its own device
+launch and its own quorum proposal. The paper's north star is sessions AS
+vmap lanes — this module makes that literal:
 
   reads   concurrent plan-cache-hit point-gets park in a short
           micro-batch window (bounded by `tidb_tpu_coalesce_wait_us`

@@ -831,7 +831,6 @@ class Session:
                 sql, dur_ms, rows, ok, err,
                 slow_threshold_ms=thr,
                 summary_enabled=self.sysvars.get_bool("tidb_enable_stmt_summary"),
-                cpu_ms=cpu_ms,
                 plan_digest=getattr(self, "_last_plan_digest", ""),
                 # EXECUTE records under the UNDERLYING prepared statement's
                 # digest (set by _execute_prepared), joining its summary row
@@ -2043,13 +2042,11 @@ class Session:
 
                     def _columnar_routed():
                         # engine routing (ISSUE 12): when the columnar
-                        # replica is this plan's engine, the whole-plan
-                        # mesh shortcut must not preempt it — the consult
-                        # itself lives in execute_root. Evaluated LAST in
-                        # the mesh condition so the eligibility walk only
-                        # runs when a mesh attempt is actually on the
-                        # table (review finding: no double walk when mesh
-                        # is off or EXPLAIN ANALYZE pinned the cop path)
+                        # replica is this plan's engine, the statement
+                        # tier must not preempt it — the consult itself
+                        # lives in execute_root. A thunk, so the
+                        # eligibility walk only runs when an mpp attempt
+                        # is actually on the table
                         from ..columnar.route import columnar_would_serve
 
                         return columnar_would_serve(
@@ -2060,9 +2057,9 @@ class Session:
                         # which only the per-region path produces.
                         # Statement tier (ref: mpp_gather.go:40): "mpp"
                         # plans exchange-linked fragments through the
-                        # dispatch layer, "mesh" is the whole-plan
-                        # shard_map shortcut, "root" defers to
-                        # execute_root (per-request tiers + columnar)
+                        # dispatch layer, "root" defers to execute_root
+                        # (per-request tiers + columnar); a declined mpp
+                        # attempt (counted fallback) lands there too
                         from ..distsql.planner import choose_statement_tier
 
                         decision = choose_statement_tier(
@@ -2071,30 +2068,16 @@ class Session:
                             allow_mesh=self.sysvars.get_bool("tidb_enable_tpu_mesh"),
                             columnar_routed=_columnar_routed,
                         )
-                        gc = self.sysvars.get_int("tidb_tpu_group_capacity")
                         if decision.tier == "mpp":
                             from ..mpp.dispatch import try_mpp_select
 
                             chunk = try_mpp_select(
                                 self.store, plan.dag, ranges, ts,
-                                group_capacity=gc,
+                                group_capacity=self.sysvars.get_int("tidb_tpu_group_capacity"),
                                 aux_chunks=aux,
                                 engines=engines,
                                 backoff_weight=self.sysvars.get_int("tidb_backoff_weight"),
                                 checker=self._runaway_checker(),
-                            )
-                        if (chunk is None
-                                and decision.tier in ("mpp", "mesh")
-                                and not (decision.tier == "mpp" and _columnar_routed())):
-                            # mpp declined (counted fallback): the mesh
-                            # shortcut still applies unless the columnar
-                            # replica owns the plan (engine routing)
-                            from ..parallel.sql import try_mesh_select
-
-                            chunk = try_mesh_select(
-                                self.store, plan.dag, ranges, ts,
-                                group_capacity=gc,
-                                aux_chunks=aux,
                             )
                     if chunk is None:
                         kwargs = dict(

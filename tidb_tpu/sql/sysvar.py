@@ -103,8 +103,8 @@ DEFINITIONS = {
         # the MPP tier above the mesh (ISSUE 18): plan eligible statements
         # as exchange-linked fragment graphs (mpp/fragment.py) dispatched
         # through the wire seam, probe scans served from the columnar
-        # replica when it covers the snapshot. OFF falls back to the
-        # whole-plan mesh shortcut (ref: sysvar.go TiDBAllowMPPExecution)
+        # replica when it covers the snapshot. OFF sends every statement
+        # to execute_root (ref: sysvar.go TiDBAllowMPPExecution)
         SysVar("tidb_allow_mpp", "ON", "both", _bool_validator),
         # data-size floor for the mesh DISPATCH tier (distsql/planner.py):
         # below this estimated row count the vmapped batch tier serves

@@ -257,8 +257,7 @@ class Registry:
     def labeled_samples(self, family: str) -> dict:
         """First-label-value -> numeric sample for one labeled family
         (e.g. "tidb_tpu_replica_read_total" -> {"leader": 3.0, ...}) —
-        THE shared parser for bench/chaos-style per-label readouts (three
-        call sites used to hand-roll the same sample_lines() split)."""
+        the one parser for per-label readouts (tools/chaos.py)."""
         out: dict[str, float] = {}
         for series, value in self.sample_lines():
             if series.startswith(family + "{"):

@@ -81,9 +81,6 @@ class StmtSummary:
     sum_rows: int = 0
     errors: int = 0
     last_seen: float = 0.0
-    sum_cpu_ms: float = 0.0  # thread CPU time (the Top SQL attribution,
-    # ref: pkg/util/topsql/collector — per-digest CPU sampling; in-process
-    # the exact thread_time delta replaces statistical sampling)
     # resource-tag attribution (ISSUE 17): the Top SQL sinks' per-statement
     # totals, folded here so statements_summary answers avg/max device and
     # wait costs per digest without a join against the windowed reporter
@@ -122,7 +119,6 @@ class StmtLog:
         error: str = "",
         slow_threshold_ms: float | None = 300.0,
         summary_enabled: bool = True,
-        cpu_ms: float = 0.0,
         plan_digest: str = "",
         norm_digest: tuple[str, str] | None = None,
         attr: dict | None = None,
@@ -156,7 +152,6 @@ class StmtLog:
                 s.min_latency_ms = min(s.min_latency_ms, duration_ms)
                 s.sum_rows += rows
                 s.errors += 0 if success else 1
-                s.sum_cpu_ms += cpu_ms
                 if attr is not None:  # the statement's resource-tag totals
                     s.sum_device_ns += attr.get("device_ns", 0)
                     s.max_device_ns = max(s.max_device_ns, attr.get("device_ns", 0))
@@ -171,12 +166,6 @@ class StmtLog:
                 )
                 if len(self.slow) > self.slow_capacity:
                     del self.slow[: len(self.slow) - self.slow_capacity]
-
-    def top_sql(self, n: int = 30) -> list[StmtSummary]:
-        """Top digests by cumulative CPU time (ref: pkg/util/topsql's
-        top-N reporter over the per-digest CPU attribution)."""
-        with self._lock:
-            return sorted(self.summaries.values(), key=lambda s: -s.sum_cpu_ms)[:n]
 
     def slow_entries(self) -> list[SlowLogEntry]:
         with self._lock:

@@ -6,16 +6,11 @@ converges back to all-breakers-closed once the storm passes (ref: the
 failpoint-driven chaos suites around pingcap/failpoint, and chaos-mesh's
 invariant checking over TiDB clusters).
 
-Two modes share one workload generator:
-
-  * `run_chaos(...)` (default schedule) — storm phases at fixed statement
-    indices: a store outage mid-run (batched dispatch fails over through a
-    PD re-placement), a server-busy storm, a PD heartbeat blackout, counted
-    not-leader flaps, and an operator-timeout window; the PD ticks every
-    `tick_every` statements, exactly like its background timer.
-  * `run_chaos(..., fault_rate=0.1)` — bench mode: each statement rolls the
-    seeded dice and runs under a one-shot fault with that probability
-    (BENCH_CHAOS=1 compares p50/p99 vs a clean run).
+`run_chaos(...)` runs storm phases at fixed statement indices: a store
+outage mid-run (batched dispatch fails over through a PD re-placement), a
+server-busy storm, a PD heartbeat blackout, counted not-leader flaps, and
+an operator-timeout window; the PD ticks every `tick_every` statements,
+exactly like its background timer.
 
 Oracle answers are precomputed on a pristine single-region session BEFORE
 any fault is armed, so the comparison itself can never be polluted by the
@@ -28,7 +23,6 @@ import json
 import os
 import random
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -177,7 +171,7 @@ def _apply(actions, sess, fp) -> None:
             sess.execute(f"SET {action[1]} = '{action[2]}'")
 
 
-def run_chaos(seed: int = 7, statements: int = 200, fault_rate: float | None = None,
+def run_chaos(seed: int = 7, statements: int = 200,
               tick_every: int = 10, admission_flicker: float = 0.0,
               cost_classed: bool = False, coalesce: bool = False) -> dict:
     """Run the workload under the fault schedule; returns the invariant
@@ -215,11 +209,11 @@ def run_chaos(seed: int = 7, statements: int = 200, fault_rate: float | None = N
         s.execute("SET tidb_enable_top_sql = ON")
         store.admission.configure(max_inflight=8, cost_classed=True)
     rng = random.Random(seed * 31 + 1)
-    schedule = {} if fault_rate is not None else default_schedule(statements)
+    schedule = default_schedule(statements)
 
     def breaker_trips_total() -> float:
         """Sum of the labeled trip counters via the public sampling API
-        (never _Vec internals — same rule bench.py follows)."""
+        (never _Vec internals)."""
         return sum(metrics.REGISTRY.labeled_samples(
             "tidb_tpu_store_breaker_trips_total").values())
 
@@ -229,7 +223,6 @@ def run_chaos(seed: int = 7, statements: int = 200, fault_rate: float | None = N
     wrong: list = []
     untyped: list = []
     by_code: dict[int, int] = {}
-    lat_ms: list[float] = []
     failovers0 = metrics.PD_FAILOVERS.value
     transfers0 = metrics.PD_TRANSFER_LEADER.value
     replica0 = labeled_total("tidb_tpu_replica_read_total")
@@ -241,25 +234,14 @@ def run_chaos(seed: int = 7, statements: int = 200, fault_rate: float | None = N
             if admission_flicker and rng.random() < admission_flicker:
                 fp.enable("server/admission-full", 1)  # fire once: this
                 # statement sheds at the gate, the next runs normally
-            one_shot = fault_rate is not None and rng.random() < fault_rate
-            if one_shot:
-                sid = rng.randrange(1, N_STORES)  # store 0 spared: the
-                # oracle comparison stays possible even at rate 1.0
-                if rng.random() < 0.7:
-                    fp.enable("store/server-busy", {"stores": {sid}, "backoff_ms": 2})
-                else:
-                    fp.enable("store/not-leader", 1)  # one counted flap
-            t0 = time.monotonic()
             try:
                 got = s.execute(sql).values()
-                lat_ms.append((time.monotonic() - t0) * 1000.0)
                 if got != oracle[i]:
                     wrong.append({"stmt": i, "sql": sql, "got": repr(got)[:200],
                                   "want": repr(oracle[i])[:200]})
                 else:
                     ok += 1
             except SQLError as exc:
-                lat_ms.append((time.monotonic() - t0) * 1000.0)
                 code = getattr(exc, "code", 0)
                 if code in (9005, 1105, 3024, 1317, 9003):
                     # 9003: admission shed — typed ServerIsBusy backpressure
@@ -269,13 +251,8 @@ def run_chaos(seed: int = 7, statements: int = 200, fault_rate: float | None = N
                 else:
                     untyped.append({"stmt": i, "sql": sql, "error": str(exc)[:200]})
             except Exception as exc:  # noqa: BLE001 — the exact bug class we hunt
-                lat_ms.append((time.monotonic() - t0) * 1000.0)
                 untyped.append({"stmt": i, "sql": sql,
                                 "error": f"{type(exc).__name__}: {str(exc)[:200]}"})
-            finally:
-                if one_shot:
-                    fp.disable("store/server-busy")
-                    fp.disable("store/not-leader")
             if (i + 1) % tick_every == 0:
                 store.pd.tick()
     finally:
@@ -290,11 +267,6 @@ def run_chaos(seed: int = 7, statements: int = 200, fault_rate: float | None = N
         store.pd.tick()
         if store.breakers.all_closed():
             break
-
-    lat_sorted = sorted(lat_ms)
-
-    def pct(p: float) -> float:
-        return round(lat_sorted[min(int(len(lat_sorted) * p), len(lat_sorted) - 1)], 2) if lat_sorted else 0.0
 
     return {
         "seed": seed,
@@ -320,8 +292,6 @@ def run_chaos(seed: int = 7, statements: int = 200, fault_rate: float | None = N
         "breakers": {str(k): v for k, v in sorted(store.breakers.states().items())},
         "breakers_all_closed": store.breakers.all_closed(),
         "store_health": [d["state"] for d in store.pd.stores_view()],
-        "p50_ms": pct(0.50),
-        "p99_ms": pct(0.99),
     }
 
 
