@@ -10,6 +10,7 @@ and shapes only: no timing thresholds."""
 import glob
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +33,8 @@ LAUNCH_COUNTERS = ("PROGRAM_COMPILES", "PROGRAM_LAUNCHES", "XLA_COMPILES", "XLA_
                    "XLA_TRACE_LOWER_NS", "XLA_BACKEND_COMPILE_NS", "PROGRAM_WAIT_NS", "PROGRAM_READBACK_NS",
                    "PROGRAM_READBACK_TRANSFERS", "PROGRAM_READBACK_BYTES", "PROGRAM_FETCHES",
                    "PROGRAM_READBACK_LATE")
-SERVER_COUNTERS = ("SERVER_COMMANDS", "SERVER_HANDLE_NS", "SERVER_WRITE_NS", "SERVER_PACKETS_OUT")
+SERVER_COUNTERS = ("SERVER_COMMANDS", "SERVER_HANDLE_NS", "SERVER_WRITE_NS", "SERVER_PACKETS_OUT",
+                   "SERVER_SOCKET_SENDS")
 
 
 class Moved:
@@ -423,18 +425,17 @@ def test_program_names_come_from_the_shape_not_the_literals(sess):
 
 
 # ------------------------------------------------------------- the wire server
+def handled(n: int, since: int) -> None:
+    """The server books a command once its last reply byte is out, which
+    the client may see first: wait until `n` commands are booked."""
+    deadline = time.monotonic() + 10
+    while metrics.SERVER_COMMANDS.value - since < n and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert metrics.SERVER_COMMANDS.value - since == n
+
+
 def test_one_query_moves_the_server_counters():
-    import time
-
     from tidb_tpu.server import MiniClient, MySQLServer
-
-    def handled(n: int, since: int) -> None:
-        """The server books a command once its last reply byte is out, which
-        the client may see first: wait until `n` commands are booked."""
-        deadline = time.monotonic() + 10
-        while metrics.SERVER_COMMANDS.value - since < n and time.monotonic() < deadline:
-            time.sleep(0.002)
-        assert metrics.SERVER_COMMANDS.value - since == n
 
     srv = MySQLServer(port=0)
     srv.start_background()
@@ -457,6 +458,97 @@ def test_one_query_moves_the_server_counters():
         assert m.by["SERVER_PACKETS_OUT"] == 1  # one OK packet
         c.close()
     finally:
+        srv.close()
+
+
+@pytest.fixture(scope="module")
+def wire():
+    """A served table of 400 rows of 126 bytes on the wire, and a client."""
+    from tidb_tpu.server import MiniClient, MySQLServer
+
+    srv = MySQLServer(port=0)
+    srv.start_background()
+    start = metrics.SERVER_COMMANDS.value
+    c = MiniClient(srv.host, srv.port, timeout=120)
+    c.query("CREATE TABLE ws (id INT PRIMARY KEY, c CHAR(120))")
+    c.query("INSERT INTO ws VALUES " + ",".join(f"({i},'{str(1000 + i) * 30}')" for i in range(1, 401)))
+    handled(2, start)
+    yield c
+    c.close()
+    srv.close()
+
+
+WIRE_COMMANDS = {   # a command -> (its reply's packets, the sends that carry them)
+    "select": ("SELECT id, c FROM ws WHERE id <= 4", 1 + 2 + 1 + 4 + 1, 1),
+    "insert": ("INSERT INTO ws VALUES (1001, 'n')", 1, 1),
+    "error": ("SELECT * FROM no_such_ws", 1, 1),
+    "two_statements": ("SELECT 1; SELECT id FROM ws WHERE id <= 2", (1 + 1 + 1 + 1 + 1) + (1 + 1 + 1 + 2 + 1), 1),
+    "ping": (None, 1, 1),
+    "hundred_rows": ("SELECT c FROM ws WHERE id BETWEEN 101 AND 200", 1 + 1 + 1 + 100 + 1, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(WIRE_COMMANDS))
+def test_a_command_s_reply_is_one_send_and_one_receive(wire, case):
+    from tidb_tpu.server.client import ClientError
+    from tidb_tpu.server.protocol import FLUSH_BYTES, RECV_BYTES
+
+    sql, packets, sends = WIRE_COMMANDS[case]
+    start, recvs = metrics.SERVER_COMMANDS.value, wire.io.recvs
+    with Moved(SERVER_COUNTERS) as m:
+        if sql is None:
+            assert wire.ping()
+        elif case == "error":
+            with pytest.raises(ClientError):
+                wire.query(sql)
+        else:
+            wire.query(sql)
+        handled(1, start)
+    assert (m.by["SERVER_PACKETS_OUT"], m.by["SERVER_SOCKET_SENDS"]) == (packets, sends)
+    assert 100 * 125 < min(FLUSH_BYTES, RECV_BYTES)  # the hundred rows fit both buffers
+    assert wire.io.recvs - recvs == 1
+
+
+def test_a_result_larger_than_the_buffer_leaves_in_several_sends(wire):
+    from tidb_tpu.server.protocol import FLUSH_BYTES
+
+    start = metrics.SERVER_COMMANDS.value
+    with Moved(SERVER_COUNTERS) as m:
+        _, rows = wire.query("SELECT id, c FROM ws WHERE id <= 400 ORDER BY id")
+        handled(1, start)
+    assert [r[0] for r in rows] == [str(i) for i in range(1, 401)] and all(len(r[1]) == 120 for r in rows)
+    assert m.by["SERVER_PACKETS_OUT"] == 1 + 2 + 1 + 400 + 1
+    # the buffer hands itself over each time it passes its size, and the command's flush sends the rest
+    wire_bytes = 400 * (4 + 1 + len(rows[0][0]) + 1 + 120)
+    assert 2 <= wire_bytes // FLUSH_BYTES <= m.by["SERVER_SOCKET_SENDS"] <= wire_bytes // FLUSH_BYTES + 1
+
+
+def test_server_write_span_says_what_left_and_in_how_many_sends():
+    """`server.write` closes over the send: packets, bytes and sends of one
+    statement's result are its attributes (the server's threads have no
+    ambient trace, so the connection is driven here, under one)."""
+    import socket
+
+    from tidb_tpu.server import MySQLServer
+    from tidb_tpu.server.server import Connection
+
+    srv = MySQLServer(port=0)
+    ours, theirs = socket.socketpair()
+    try:
+        conn = Connection(theirs, srv, 1)
+        conn.session.execute("CREATE TABLE sp (a BIGINT PRIMARY KEY, b BIGINT, c BIGINT)")
+        conn.session.execute("INSERT INTO sp VALUES (1,2,3),(2,3,4),(3,4,5),(4,5,6),(5,6,7)")
+        with tracing.trace("command") as root:
+            conn.handle_query("INSERT INTO sp VALUES (6,7,8); SELECT a, b, c FROM sp WHERE a <= 4")
+        first, last = root.find("server.write")
+        # the first statement's OK waits in the buffer and leaves with the last statement's result
+        assert (first.attrs["packets"], first.attrs["bytes"], first.attrs["sends"]) == (1, 4 + 7, 0)
+        assert (last.attrs["packets"], last.attrs["sends"]) == (1 + 3 + 1 + 4 + 1, 1)
+        ours.settimeout(10)
+        assert len(ours.recv(1 << 16)) == first.attrs["bytes"] + last.attrs["bytes"] == conn.io.bytes_out
+    finally:
+        ours.close()
+        theirs.close()
         srv.close()
 
 

@@ -4,6 +4,11 @@ client library in this environment, so the framework ships its own).
 
 Implements HandshakeResponse41 + mysql_native_password and the text result
 set decode; enough to validate the server against the real wire format.
+
+The socket is read and written through `PacketIO`'s buffers, as every MySQL
+client library does: a command is flushed as soon as it is written (`_send`),
+before its reply is waited for, and a reply's packets are taken from what one
+receive brought.
 """
 
 from __future__ import annotations
@@ -25,6 +30,7 @@ class MiniClient:
     def __init__(self, host: str, port: int, user: str = "root", password: str = "",
                  database: str = "", timeout: float = 10.0):
         self.sock = socket.create_connection((host, port), timeout=timeout)
+        self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self.io = P.PacketIO(self.sock)
         self._handshake(user, password.encode(), database)
 
@@ -51,7 +57,8 @@ class MiniClient:
         if database:
             payload += database.encode() + b"\x00"
         payload += b"mysql_native_password\x00"
-        self.io.write(payload)
+        self.io.write(payload)  # continues the greeting's sequence
+        self.io.flush()
         resp = self.io.read()
         if resp[0] == 0xFF:
             code, msg = self._parse_err(resp)
@@ -66,12 +73,17 @@ class MiniClient:
         return code, msg.decode("utf-8", "replace")
 
     # ------------------------------------------------------------------
+    def _send(self, payload: bytes):
+        """One command onto the socket, whole, before its reply is read."""
+        self.io.reset()
+        self.io.write(payload)
+        self.io.flush()
+
     def query(self, sql: str):
         """Run one statement; returns (columns, rows) for result sets or
         affected-row count for OK responses. Multi-statement payloads
         return the LAST result."""
-        self.io.reset()
-        self.io.write(bytes([P.COM_QUERY]) + sql.encode())
+        self._send(bytes([P.COM_QUERY]) + sql.encode())
         result = None
         while True:
             result = self._read_result()
@@ -125,14 +137,12 @@ class MiniClient:
         return columns, rows
 
     def ping(self) -> bool:
-        self.io.reset()
-        self.io.write(bytes([P.COM_PING]))
+        self._send(bytes([P.COM_PING]))
         return self.io.read()[0] == 0x00
 
     def close(self):
         try:
-            self.io.reset()
-            self.io.write(bytes([P.COM_QUIT]))
+            self._send(bytes([P.COM_QUIT]))
         except OSError:
             pass
         self.sock.close()

@@ -8,6 +8,17 @@ Covers what a standard client needs to connect and run queries:
   - mysql_native_password auth (SHA1 scramble check; empty password OK)
   - OK / ERR / EOF packets (CLIENT_PROTOCOL_41 shapes)
   - column definition 41 + text-protocol result rows (length-encoded)
+
+I/O is buffered in both directions (ref: the bufio reader and writer of
+pkg/server/internal/packetio.go).  `PacketIO.write` frames a packet into an
+output buffer, and `flush` hands the buffer to the socket in one send; the
+buffer flushes itself once it holds `FLUSH_BYTES`.  Whoever is about to wait
+for the peer flushes first: the server once per command (server.py:
+`Connection.handle_query` after the last statement's reply, `Connection.run`
+for every other command and for error replies, the handshake before each
+read), the client after each command it writes (client.py).  `PacketIO.read`
+takes packets out of an input buffer that one `recv` of `RECV_BYTES`
+refills; bytes past the packet stay for the next read.
 """
 
 from __future__ import annotations
@@ -52,43 +63,76 @@ COM_STMT_CLOSE = 0x19
 CHARSET_UTF8MB4 = 255  # utf8mb4_0900_ai_ci
 
 
+# the reference's buffered reader and writer hold 16 KiB each; a 100-row
+# sysbench range reply is 13 KiB and leaves in one send
+FLUSH_BYTES = 16 * 1024   # the output buffer flushes itself once it holds this much
+RECV_BYTES = 16 * 1024    # what one recv asks the socket for
+
+
 class PacketIO:
-    """Framed packet reader/writer over a socket (ref: conn.go readPacket /
-    writePacket; sequence ids reset per command)."""
+    """Framed, buffered packet reader/writer over a socket (ref: conn.go
+    readPacket / writePacket over packetio.go's bufio pair; sequence ids
+    reset per command).  Written packets wait in the output buffer until
+    `flush`, or until the buffer passes `FLUSH_BYTES`."""
 
     def __init__(self, sock):
         self.sock = sock
         self.seq = 0
-        self.packets_out = 0  # packets written since the connection opened
+        # since the connection opened
+        self.packets_out = 0  # packets written
+        self.bytes_out = 0    # their bytes, headers included
+        self.sends = 0        # socket sends that carried them
+        self.recvs = 0        # socket receives
+        self._out = bytearray()
+        self._in = b""        # received and not yet read: _in[_pos:]
+        self._pos = 0
 
     def reset(self):
         self.seq = 0
 
     def read(self) -> bytes:
-        header = self._read_exact(4)
-        length = header[0] | header[1] << 8 | header[2] << 16
+        header = self._take(4)
         self.seq = (header[3] + 1) & 0xFF
-        return self._read_exact(length)
+        return self._take(header[0] | header[1] << 8 | header[2] << 16)
 
     def write(self, payload: bytes):
-        # 16MB+ splitting is not needed for this server's result sizes, but
-        # keep the loop for protocol correctness
-        while True:
+        out = self._out
+        while True:  # a payload of 16 MB or more goes out as several packets
             chunk, payload = payload[: 0xFFFFFF], payload[0xFFFFFF:]
-            self.sock.sendall(struct.pack("<I", len(chunk))[:3] + bytes([self.seq]) + chunk)
+            out += (len(chunk) | self.seq << 24).to_bytes(4, "little")
+            out += chunk
             self.seq = (self.seq + 1) & 0xFF
             self.packets_out += 1
+            self.bytes_out += 4 + len(chunk)
+            if len(out) >= FLUSH_BYTES:
+                self.flush()
             if len(chunk) < 0xFFFFFF:
                 break
 
-    def _read_exact(self, n: int) -> bytes:
-        buf = b""
-        while len(buf) < n:
-            part = self.sock.recv(n - len(buf))
-            if not part:
-                raise ConnectionError("peer closed")
-            buf += part
-        return buf
+    def flush(self):
+        """Hand what was written to the socket, in one send."""
+        if self._out:
+            self.sock.sendall(self._out)
+            self.sends += 1
+            self._out.clear()
+
+    def _take(self, n: int) -> bytes:
+        """The next `n` received bytes; what one receive brought beyond
+        them stays for the next call."""
+        pos = self._pos
+        if len(self._in) - pos < n:
+            parts = [self._in[pos:]]
+            have = len(parts[0])
+            while have < n:
+                part = self.sock.recv(RECV_BYTES)
+                if not part:
+                    raise ConnectionError("peer closed")
+                self.recvs += 1
+                parts.append(part)
+                have += len(part)
+            self._in, pos = b"".join(parts), 0
+        self._pos = pos + n
+        return self._in[pos : pos + n]
 
 
 # ---------------------------------------------------------------- lenenc

@@ -60,8 +60,11 @@ class Connection:
 
     # ------------------------------------------------------------------
     def handshake(self) -> bool:
+        """Greeting, the client's response, OK or ERR 1045; each reply is
+        flushed before the peer is waited for or the socket is closed."""
         salt = P.new_salt()
         self.io.write(P.handshake_v10(self.conn_id, salt))
+        self.io.flush()
         resp = P.parse_handshake_response(self.io.read())
         user = resp["user"]
         if self.server.users:
@@ -69,18 +72,17 @@ class Connection:
         else:
             # CREATE USER records (ref: privilege cache feeding auth)
             stored = self.server.catalog.privileges.password_of(user)
-        if stored is None:
+        granted = stored is not None and P.check_auth(stored, salt, resp["auth"])
+        if granted:
+            if not self.server.users:
+                # privilege-store users run as themselves; the explicit override
+                # map is a test shortcut whose users bypass privilege checks
+                self.session.user = user.lower()
+            self.io.write(P.ok_packet(status=self._status()))
+        else:
             self.io.write(P.err_packet(1045, f"Access denied for user '{user}'", "28000"))
-            return False
-        if not P.check_auth(stored, salt, resp["auth"]):
-            self.io.write(P.err_packet(1045, f"Access denied for user '{user}'", "28000"))
-            return False
-        if not self.server.users:
-            # privilege-store users run as themselves; the explicit override
-            # map is a test shortcut whose users bypass privilege checks
-            self.session.user = user.lower()
-        self.io.write(P.ok_packet(status=self._status()))
-        return True
+        self.io.flush()
+        return granted
 
     def _status(self) -> int:
         st = P.SERVER_STATUS_AUTOCOMMIT
@@ -92,11 +94,15 @@ class Connection:
     def run(self):
         """The command loop.  A command is clocked from its packet's read
         until its last reply byte is handed to the socket; the wait for the
-        client's next packet is not."""
+        client's next packet is not.  Replies are buffered (protocol.py):
+        a query's leave with its last statement's result, whatever another
+        command or an error path wrote leaves here, before the command is
+        booked and the next one read."""
+        io = self.io
         while True:
-            self.io.reset()
+            io.reset()
             try:
-                pkt = self.io.read()
+                pkt = io.read()
             except (ConnectionError, OSError):
                 return
             if not pkt:
@@ -104,11 +110,13 @@ class Connection:
             if pkt[0] == P.COM_QUIT:
                 return
             t0 = time.perf_counter_ns()
-            sent = self.io.packets_out
+            packets, sends = io.packets_out, io.sends
             try:
                 self.dispatch(pkt[0], pkt[1:])
+                io.flush()
             finally:
-                metrics.SERVER_PACKETS_OUT.inc(self.io.packets_out - sent)
+                metrics.SERVER_PACKETS_OUT.inc(io.packets_out - packets)
+                metrics.SERVER_SOCKET_SENDS.inc(io.sends - sends)
                 metrics.SERVER_HANDLE_NS.inc(time.perf_counter_ns() - t0)
                 metrics.SERVER_COMMANDS.inc()  # last: a reader that sees the command sees its time and packets
 
@@ -140,9 +148,17 @@ class Connection:
             except Exception as exc:  # noqa: BLE001 — wire must answer
                 self.io.write(P.err_packet(1105, f"internal error: {exc}"))
                 return
+            last = i + 1 == len(stmts)
+            io = self.io
             t0 = time.perf_counter_ns()
-            with tracing.span("server.write"):
-                self.write_result(res, more=i + 1 < len(stmts))
+            with tracing.span("server.write") as sp:
+                packets, nbytes, sends = io.packets_out, io.bytes_out, io.sends
+                self.write_result(res, more=not last)
+                if last:
+                    io.flush()  # the command's whole reply, in one send unless it outgrew the buffer
+                if sp is not None:
+                    sp.attrs.update(packets=io.packets_out - packets, bytes=io.bytes_out - nbytes,
+                                    sends=io.sends - sends)
             metrics.SERVER_WRITE_NS.inc(time.perf_counter_ns() - t0)
 
     SERVER_MORE_RESULTS = 0x0008
@@ -229,6 +245,7 @@ class MySQLServer:
                 sock, _ = self._sock.accept()
             except OSError:
                 return
+            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)  # as Go's net does under the reference
             t = threading.Thread(target=self._serve_conn, args=(sock,), daemon=True)
             t.start()
             self._threads.append(t)

@@ -358,6 +358,7 @@ SERVER_COMMANDS = REGISTRY.counter("tidb_tpu_server_commands_total", "wire comma
 SERVER_HANDLE_NS = REGISTRY.counter("tidb_tpu_server_handle_ns_total", "ns handling wire commands, replies included")
 SERVER_WRITE_NS = REGISTRY.counter("tidb_tpu_server_write_ns_total", "ns encoding and sending result sets")
 SERVER_PACKETS_OUT = REGISTRY.counter("tidb_tpu_server_packets_out_total", "wire packets sent in reply to commands")
+SERVER_SOCKET_SENDS = REGISTRY.counter("tidb_tpu_server_socket_sends_total", "socket sends that carried those packets: one a command unless a reply outgrew the output buffer")
 STATEMENTS = REGISTRY.counter_vec(
     "tidb_tpu_statements_total", "statements executed by type and outcome",
     labelnames=("type", "status"),

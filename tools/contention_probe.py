@@ -6,8 +6,10 @@ Runs in the checkout given as the working directory, so that variants of the tre
 run in turn on one machine (PERF.md section 6, PR 26, off-cost): starts `MySQLServer`, loads one table of 16,384
 sysbench rows, switches JAX's persistent compile cache off, then lets 16 `MiniClient` threads repeat the
 oltp_read_only transaction with fresh literals for `seconds` (default 40).  Prints one JSON line: operations per
-second, median latency, process CPU seconds per operation, statement medians, the device.  Not part of the
-benchmark and read by nothing: its numbers compare two trees in one call, no more."""
+second, median latency, process CPU seconds per operation, the socket sends and receives per operation of both
+ends of the wire together (every `PacketIO` of the process; null on a checkout whose `PacketIO` does not count
+them) beside the reply packets, statement medians, the device.  Not part of the benchmark and read by nothing:
+its numbers compare two trees in one call, no more."""
 
 import json
 import os
@@ -45,7 +47,20 @@ def main(label: str, seconds: float) -> None:
     from jax.experimental.compilation_cache import compilation_cache
 
     import tidb_tpu  # noqa: F401  (enables x64)
-    from tidb_tpu.server import MiniClient, MySQLServer
+    from tidb_tpu.server import MiniClient, MySQLServer, protocol
+    from tidb_tpu.util import metrics
+
+    wires: list = []  # every PacketIO of the process, the server's and the clients'
+    made = protocol.PacketIO.__init__
+
+    def counted(io, sock) -> None:
+        made(io, sock)
+        wires.append(io)
+
+    protocol.PacketIO.__init__ = counted
+
+    def socket_calls() -> dict:
+        return {k: sum(getattr(io, k) for io in wires) if hasattr(wires[0], k) else None for k in ("sends", "recvs")}
 
     srv = MySQLServer(port=0)
     srv.start_background()
@@ -73,6 +88,7 @@ def main(label: str, seconds: float) -> None:
             done.append(time.perf_counter() - t)
             n += 1
 
+    calls0, packets0 = socket_calls(), metrics.SERVER_PACKETS_OUT.value
     cpu0, t0 = time.process_time(), time.perf_counter()
     threads = [threading.Thread(target=loop, args=(i,)) for i in range(CLIENTS)]
     for t in threads:
@@ -80,9 +96,13 @@ def main(label: str, seconds: float) -> None:
     for t in threads:
         t.join()
     wall, cpu = time.perf_counter() - t0, time.process_time() - cpu0
+    calls = socket_calls()
     print(json.dumps({
         "label": label, "clients": CLIENTS, "ops": len(done), "ops_per_s": round(len(done) / wall, 4),
         "op_p50_ms": round(statistics.median(done) * 1e3, 1), "process_cpu_s_per_op": round(cpu / len(done), 3),
+        "reply_packets_per_op": round((metrics.SERVER_PACKETS_OUT.value - packets0) / len(done), 1),
+        **{f"socket_{k}_per_op": None if n is None else round((n - calls0[k]) / len(done), 1)
+           for k, n in calls.items()},
         "p50_ms": {k: round(statistics.median(v), 2) for k, v in latencies.items()},
         "device": jax.devices()[0].platform}), flush=True)
     for conn in conns + [admin]:
