@@ -57,7 +57,12 @@ PARAMS = {
     "q6": {"date": "1994-01-01", "discount": "0.06", "quantity": 24},
 }
 with open(os.path.join(TPCH_DIR, "statements.json")) as _f:
-    SQL = {name: text.format(**PARAMS[name]) for name, text in json.load(_f).items()}
+    TEMPLATES = json.load(_f)
+SQL = {name: text.format(**PARAMS[name]) for name, text in TEMPLATES.items()}
+# Q3 again with other substitution parameters (2.4.3.3): the program the
+# first execution built has to serve them
+Q3_DRAWS = ({"segment": "MACHINERY", "date": "1995-03-15"}, {"segment": "MACHINERY", "date": "1995-03-29"},
+            {"segment": "AUTOMOBILE", "date": "1995-03-02"})
 
 RANGE_SQL = """select o_orderkey, o_orderdate from orders
  where o_orderdate >= '1995-03-01' and o_orderdate < '1995-03-08'
@@ -216,6 +221,28 @@ def run_twice(name: str, run, check, data, want_pallas: bool = False) -> None:
          kernels=a["kernels"], **first)
 
 
+def q3_other_parameters(ctx) -> None:
+    """A second SEGMENT and a second DATE call the program that Q3's
+    first execution built: the literals are its operands, strings too.
+    Fails where `PROGRAM_COMPILES` or XLA's compile count moves."""
+    from tidb_tpu.util import metrics
+
+    names = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_PARAMS_BOUND", "PROGRAM_STR_PARAMS_BOUND",
+             "COP_AUX_UPLOADS", "COP_CACHE_HITS", "PROGRAM_LAUNCHES")
+    for params in Q3_DRAWS:
+        before = {n: getattr(metrics, n).value for n in names}
+        p = Probe()
+        rows = ctx.client.query(TEMPLATES["q3"].format(**params))[1]
+        got = p.done()
+        bad = TPCH.mismatch("q3", TPCH.reference("q3", params, ctx.data), rows)
+        assert bad is None, (params, bad)
+        moved = {n.lower(): getattr(metrics, n).value - before[n] for n in names}
+        assert moved["program_compiles"] == 0 and moved["xla_compiles"] == 0 and got["compile_s"] == 0, (
+            f"q3 {params}: another SEGMENT or DATE built a program: {moved} {got}")
+        assert moved["program_str_params_bound"] == 1 and got["oracle_fallbacks"] == 0, (params, moved, got)
+        emit(stmt="q3_params", wall_s=got["wall_s"], rows=len(rows), **params, **moved)
+
+
 class Ctx:
     """What the phases share: the server, two connections, the data."""
 
@@ -302,6 +329,7 @@ def phase_row_store(ctx: Ctx) -> None:
     run_twice("q6", q(SQL["q6"]), check_tpch("q6"), data)
     run_twice("q1", q(SQL["q1"]), check_tpch("q1"), data, want_pallas=True)
     run_twice("q3", q(SQL["q3"]), check_tpch("q3"), data, want_pallas=True)
+    q3_other_parameters(ctx)
 
     o = data["orders"]
     k = len(o["orderkey"]) // 2

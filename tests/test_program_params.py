@@ -2,7 +2,8 @@
 is an operand of the cop program and the scan's identity is not in the
 program's key, so requests that differ in either share one program; what
 shapes the trace (LIKE's pattern, ROUND's digits, an interval's unit, a
-string, a NULL, LIMIT) still splits the key.  Counts and answers only."""
+string's width rung, a NULL, LIMIT) still splits the key.  Counts and
+answers only."""
 
 import decimal
 import random
@@ -261,7 +262,8 @@ STRUCTURAL = {
     "round_digits": (li_chunk, lambda v: _proj(func("round", new_decimal(15, 2), col(2, DEC), lit(v, LL)), LI_FTS), 0, 1),
     "date_add_unit": (li_chunk, lambda v: _proj(func("date_add", DT, col(0, DT), lit(3, LL), lit(v, STR)), LI_FTS),
                       "day", "month"),
-    "string_compare": (sb_chunk, lambda v: _sel(func("ge", BOOL, col(2, STR), lit(v, STR))), "c010", "c025"),
+    # a string's bytes are an operand since PR 32 (tests/test_str_operands.py); its width rung is a shape
+    "string_width_rung": (sb_chunk, lambda v: _sel(func("ge", BOOL, col(2, STR), lit(v, STR))), "c010", "c0100000000000025"),
     "null_literal": (sb_chunk, lambda v: _sel(func("gt", BOOL, col(1, LL), lit(v, LL))), None, 500),
     "limit": (sb_chunk, lambda v: DAGRequest((scan(5, SB_FTS), Limit(v)), output_offsets=(0,)), 3, 9),
 }

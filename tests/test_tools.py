@@ -311,4 +311,8 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, monkeypatch, capsys):
     lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
     assert [x["phase"] for x in lines if "phase" in x] == ["load", "row_store", "columnar"]
     assert {x["stmt"] for x in lines if "stmt" in x} >= {"q6", "q1", "q3", "columnar_q1"}
+    # Q3 again with another SEGMENT and another DATE: the string rode as an operand, nothing was built
+    draws = [x for x in lines if x.get("stmt") == "q3_params"]
+    assert [(x["segment"], x["date"]) for x in draws] == [(p["segment"], p["date"]) for p in cs.Q3_DRAWS]
+    assert all(x["program_compiles"] == x["xla_compiles"] == 0 and x["program_str_params_bound"] == 1 for x in draws)
     assert not os.listdir(tmp_path)  # the data files are gone again

@@ -258,6 +258,12 @@ class SelectResult:
     batch_stats: dict | None = None
 
     def merged(self) -> Chunk:
+        """The regions' chunks as one; a lone chunk as it is (chunks are
+        not mutated), so that a one-region scan answered from the result
+        cache is the same object for every statement: a join's build side
+        is then found uploaded (`TPUStore._aux_batch` keys by the object)."""
+        if len(self.chunks) == 1:
+            return self.chunks[0]
         return Chunk.concat(self.chunks) if self.chunks else None
 
 

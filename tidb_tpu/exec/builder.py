@@ -13,9 +13,10 @@ reference's coprocessor cache (ref: pkg/store/copr/coprocessor_cache.go).
 The program key (`DAGRequest.program_key`, exec/dag.py) is the plan's
 shape: what is traced is `dag.parameterized()`'s shape DAG, in which a
 parameterisable literal is a `Param` seat and a scan names no table, and
-the statement's values follow the batches as at most two operand arrays
-(`dag.program_operands()`).  One program per plan shape and capacity
-rung serves every literal and every table of one DDL.
+the statement's values follow the batches as at most four operand arrays
+(`dag.program_operands()`: int64, float64, string bytes and lengths).
+One program per plan shape and capacity rung serves every literal and
+every table of one DDL.
 
 A program returns per-output-column (value, null[, raw bytes + lengths]),
 plus row validity, row count and an overflow flag; on overflow (group/join
@@ -45,7 +46,8 @@ from ..ops import joinscan as _eager_joinscan  # noqa: F401
 from ..ops.aggregate import GatherState, finalize_agg
 from ..types import FieldType
 from . import launch
-from .dag import Aggregation, DAGRequest, IndexScan, Join, Limit, Projection, Selection, Sort, TableScan, TopN, Window, collect_scans, current_schema_fts
+from .dag import Aggregation, DAGRequest, IndexScan, Join, Limit, Projection, Selection, Sort, TableScan, TopN, Window, collect_scans, current_schema_fts, operand_lanes
+from .ladder import rung_for
 
 DEFAULT_GROUP_CAPACITY = 4096
 
@@ -704,7 +706,7 @@ def build_program(
     # what is traced is the plan's shape; the values of the DAG that
     # happens to build the program are arguments like any later DAG's
     dag, _key, operands = dag.parameterized()
-    lanes = tuple(o.dtype.kind for o in operands)  # "i", "f": which operand a `Param`'s lane names
+    lanes = operand_lanes(operands)  # which operand a `Param`'s lane names
 
     radix_info: dict = {}
 
@@ -918,6 +920,25 @@ class ProgramCache:
         self.compiles = 0  # guarded_by: _stats_mu
         self.hits = 0  # guarded_by: _stats_mu
         self._inflight: dict = {}  # key -> Event, guarded_by: _stats_mu
+        self._input_rungs: dict = {}  # (program key, input) -> rows; unguarded like _cache: a lost race costs one more rung
+
+    def input_capacity(self, dag: DAGRequest, i: int, rows: int) -> int:
+        """The row capacity at which input `i` of a plan shape is handed
+        to its program by a driver whose input is another program's
+        output (`run_dag_on_chunks`: a root merge over the regions'
+        groups), so that its size moves with the statement's literals.
+        The rung is sticky and picked with room: the first input of
+        `rows` rows gets the ladder's rung (exec/ladder.py) that holds
+        twice as many, and every later input that fits rides the same
+        rung, so statements of one shape whose row counts stay within a
+        factor of two of the first build one program (TPC-H Q3's 212-283
+        groups at SF 0.02 straddle 256)."""
+        key = (dag.program_key(), i)
+        have = self._input_rungs.get(key)
+        if have is not None and rows <= have:
+            return have
+        cap = self._input_rungs[key] = rung_for(2 * rows)
+        return cap
 
     def get(
         self,

@@ -21,7 +21,7 @@ from dataclasses import dataclass, field, replace
 import numpy as np
 
 from ..expr.agg import AggDesc
-from ..expr.ir import Expr, ParamSeats, seated_all
+from ..expr.ir import Expr, ParamSeats, seated_all, str_width_rung
 from ..types import FieldType
 
 
@@ -307,17 +307,15 @@ class DAGRequest:
         `Param` seats where `Const.operand()` says a constant is
         parameterisable and no table or index id; the key is the shape
         DAG's fingerprint; the operands are the seated values as host
-        arrays, the int64 one before the float64 one, an empty one left
-        out (never Python scalars, whose weak types would retrace, and
-        never one array per constant).  Kept on the instance: the cache
-        and the driver both ask."""
+        arrays (`_operand_arrays`), an empty lane left out (never Python
+        scalars, whose weak types would retrace, and never one array per
+        constant).  Kept on the instance: the cache and the driver both
+        ask."""
         got = self.__dict__.get("_parameterized")
         if got is None:
             seats = ParamSeats()
             shape = replace(self, executors=tuple(e.seated(seats) for e in self.executors))
-            operands = tuple(np.array(vals, dtype) for vals, dtype in
-                             ((seats.ints, np.int64), (seats.floats, np.float64)) if vals)
-            got = (shape, shape.fingerprint(), operands)
+            got = (shape, shape.fingerprint(), _operand_arrays(seats))
             object.__setattr__(self, "_parameterized", got)
         return got
 
@@ -337,6 +335,30 @@ class DAGRequest:
     def output_fts(self) -> list[FieldType]:
         fts = current_schema_fts(self.executors)
         return [fts[i] for i in self.output_offsets]
+
+
+# which lane of `Param` seats an operand array serves, by its dtype: the
+# int64 and float64 values, then the strings' bytes and their lengths
+OPERAND_LANES = {"int64": "i", "float64": "f", "uint8": "s", "int32": "n"}
+
+
+def operand_lanes(operands: tuple) -> tuple:
+    return tuple(OPERAND_LANES[o.dtype.name] for o in operands)
+
+
+def _operand_arrays(seats: ParamSeats) -> tuple:
+    """The seated values as host arrays in the order of OPERAND_LANES:
+    int64 [slots], float64 [slots], then the strings as zero-padded
+    uint8 [slots, W] with int32 [slots] lengths, W being the widest
+    seat's rung."""
+    out = [np.array(vals, dtype) for vals, dtype in
+           ((seats.ints, np.int64), (seats.floats, np.float64)) if vals]
+    if seats.strs:
+        rows = np.zeros((len(seats.strs), str_width_rung(max(map(len, seats.strs)))), np.uint8)
+        for i, b in enumerate(seats.strs):
+            rows[i, :len(b)] = np.frombuffer(b, np.uint8)
+        out += [rows, np.array([len(b) for b in seats.strs], np.int32)]
+    return tuple(out)
 
 
 def _seated_order(order_by: tuple, seats) -> tuple:

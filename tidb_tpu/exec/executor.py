@@ -404,7 +404,7 @@ def run_dag_on_chunks(
     the reference evaluator is the last resort (host-only operators)."""
     cache = cache or DEFAULT_PROGRAM_CACHE
     try:
-        batches = [to_device_batch(c, capacity=_pow2(max(c.num_rows(), 1))) for c in chunks]
+        batches = [to_device_batch(c, capacity=cache.input_capacity(dag, i, c.num_rows())) for i, c in enumerate(chunks)]
         return drive_program(cache, dag, batches, group_capacity, max_retries, small_groups=small_groups)[0]
     except OverflowRetryError:
         try:

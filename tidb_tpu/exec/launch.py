@@ -260,7 +260,8 @@ def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, ga
     """Call the jitted `outputs.fn(*args, *operands)`: `args` are the
     batches, `operands` the statement's values
     (`DAGRequest.program_operands()`), counted in `PROGRAM_PARAMS_BOUND`
-    and as the span's `params`.  `gate` is the program's `FirstCallGate`.
+    (the strings among them in `PROGRAM_STR_PARAMS_BOUND` too) and as the
+    span's `params`.  `gate` is the program's `FirstCallGate`.
     The copy of what the call returned is started at once, and converted
     under `exec.wait`, which blocks until the device is through and the
     transfer has landed.  Returns (the outputs that the program's `reads`
@@ -277,9 +278,13 @@ def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, ga
     program = getattr(fn, "__name__", type(fn).__name__)
     heard = _calling.heard = _Heard()
     metrics.PROGRAM_LAUNCHES.inc()
-    n_params = sum(len(o) for o in operands)
+    # one constant a slot; a string takes a row of the bytes and a length
+    n_strs = sum(len(o) for o in operands if o.ndim == 2)
+    n_params = sum(len(o) for o in operands) - n_strs
     if n_params:
         metrics.PROGRAM_PARAMS_BOUND.inc(n_params)
+    if n_strs:
+        metrics.PROGRAM_STR_PARAMS_BOUND.inc(n_strs)
     args = (*args, *operands)
     t0 = time.perf_counter_ns()
     try:

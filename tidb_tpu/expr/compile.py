@@ -227,8 +227,9 @@ def normalize_device_column(c: DeviceColumn) -> CompVal:
 
 class ExprCompiler:
     """Compiles Expr trees against a fixed input schema.  `params` maps a
-    lane ("i", "f") to the program's operand array that `Param` nodes read
-    their slot from (exec/builder.py passes the traced arguments)."""
+    lane (exec/dag.py `OPERAND_LANES`) to the program's operand array that
+    `Param` nodes read their slot from (exec/builder.py passes the traced
+    arguments)."""
 
     def __init__(self, input_fts: list[FieldType], params: dict | None = None):
         self.input_fts = input_fts
@@ -249,6 +250,8 @@ class ExprCompiler:
         if isinstance(e, Const):
             return self._const(e)
         if isinstance(e, Param):
+            if e.lane == "s":
+                return self._string_const(self._params["s"][e.slot][None, :], self._params["n"][e.slot][None], e.ft)
             v = jnp.broadcast_to(self._params[e.lane][e.slot], (self._n,))
             return CompVal(v, jnp.zeros(self._n, bool), e.ft)
         if isinstance(e, ScalarFunc):
@@ -280,18 +283,24 @@ class ExprCompiler:
         et = e.ft.eval_type()
         if et == "string":
             b = d.val.encode() if isinstance(d.val, str) else bytes(d.val)
-            w = max(1, len(b))
-            data = np.zeros((1, w), np.uint8)
+            data = np.zeros((1, max(1, len(b))), np.uint8)
             data[0, : len(b)] = np.frombuffer(b, np.uint8)
-            words = pack_string_words(jnp.asarray(data), jnp.asarray(np.array([len(b)], np.int32)))
-            v = jnp.broadcast_to(words, (n, words.shape[1]))
-            return CompVal(v, jnp.zeros(n, bool), e.ft,
-                           raw=(jnp.broadcast_to(jnp.asarray(data), (n, w)), jnp.full(n, len(b), jnp.int32)),
-                           const_bytes=b)
+            return self._string_const(jnp.asarray(data), jnp.asarray(np.array([len(b)], np.int32)), e.ft, b)
         # a constant that stayed in the trace (`Const.operand()` is None, or
         # the caller did not parameterise): the same host value, baked
         v = jnp.full(n, lane_value(d, e.ft), jnp.float64 if et == "real" else jnp.int64)
         return CompVal(v, jnp.zeros(n, bool), e.ft)
+
+    def _string_const(self, data, length, ft: FieldType, const_bytes: bytes | None = None) -> CompVal:
+        """One string ([1, W] bytes, [1] length) in every lane: a baked
+        constant's, with the bytes for the CI guards to read, or a `Param`
+        seat's row of the string operand, which `Const.operand()` screened
+        where it was bound."""
+        n = self._n
+        words = pack_string_words(data, length)
+        return CompVal(jnp.broadcast_to(words, (n, words.shape[1])), jnp.zeros(n, bool), ft,
+                       raw=(jnp.broadcast_to(data, (n, data.shape[1])), jnp.broadcast_to(length, (n,))),
+                       const_bytes=const_bytes)
 
     # -- coercion ------------------------------------------------------------
     @staticmethod

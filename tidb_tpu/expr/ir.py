@@ -110,8 +110,20 @@ def lane_value(d: Datum, ft: FieldType):
     return int(d.val)
 
 
-_PARAM_CLASSES = ("int", "real", "decimal", "time")
+_PARAM_CLASSES = ("int", "real", "decimal", "time", "string")
 _I64_MIN, _I64_MAX = -(1 << 63), (1 << 63) - 1
+STR_WIDTH_FLOOR = 16
+
+
+def str_width_rung(nbytes: int) -> int:
+    """The byte width a string operand of `nbytes` rides at: the smallest
+    rung of a ladder that starts at STR_WIDTH_FLOOR and doubles.  The rung,
+    not the bytes, is in the program's key, so literals of one rung share
+    a program."""
+    w = STR_WIDTH_FLOOR
+    while w < nbytes:
+        w *= 2
+    return w
 
 # The argument positions whose constant the compiler reads while it traces
 # (compile.py `_op_round`, `_op_like`, `_date_shift`, `_op_extract`): a
@@ -128,7 +140,7 @@ class Const(Expr):
     not, where the constant is parameterisable (`operand()`): the program's
     key (`DAGRequest.program_key`) holds its type alone and the value is
     handed in as an operand, so statements that differ in such literals
-    share one program. Strings, NULLs and the positions of
+    share one program. NULLs, non-ASCII strings and the positions of
     `TRACE_TIME_ARGS` shape the trace and keep their value in that key."""
 
     datum: Datum
@@ -141,13 +153,22 @@ class Const(Expr):
 
     def operand(self):
         """(lane, value) where a program takes this constant as an operand,
-        lane "i" for the int64 array (ints, scaled decimals, packed times)
-        and "f" for the float64 one; None where the constant stays in the
-        trace: NULL, a class without a fixed width, or a value that the
-        lane's dtype does not hold.  The one rule that the program's key
-        and the operands are both made from (`seated`)."""
-        if self.datum.is_null() or self.ft.eval_type() not in _PARAM_CLASSES:
+        lane "i" for the int64 array (ints, scaled decimals, packed times),
+        "f" for the float64 one and "s" for the byte rows (strings, the
+        value their bytes); None where the constant stays in the trace:
+        NULL, a class that rides in no lane, a value that the lane's dtype
+        does not hold, or a string with a byte outside ASCII (the CI
+        compares fold ASCII alone and screen a constant's bytes while they
+        trace, compile.py `_ci_ascii_guard`: such a literal stays where
+        they can read it).  The one rule that the program's key and the
+        operands are both made from (`seated`)."""
+        et = self.ft.eval_type()
+        if self.datum.is_null() or et not in _PARAM_CLASSES:
             return None
+        if et == "string":
+            v = self.datum.val
+            b = v.encode() if isinstance(v, str) else bytes(v)   # as compile.py `_const` bakes it
+            return ("s", b) if b.isascii() else None
         try:
             v = lane_value(self.datum, self.ft)
         except (TypeError, ValueError, ArithmeticError, AttributeError):
@@ -164,34 +185,41 @@ class Const(Expr):
 @dataclass(frozen=True)
 class Param(Expr):
     """A parameterisable constant's seat in a compiled program: slot
-    `slot` of the program's int64 (`lane` "i") or float64 ("f") operand.
-    Exists only inside `DAGRequest.parameterized()`'s shape DAG, which is
-    what `exec/builder.py` traces and what the program cache keys on."""
+    `slot` of the program's int64 (`lane` "i"), float64 ("f") or string
+    ("s") operand.  A string seat also holds `width`, the rung of its
+    literal's byte length (`str_width_rung`): the widest seat sets the
+    operand's row width, a shape of the program.  Exists only inside
+    `DAGRequest.parameterized()`'s shape DAG, which is what
+    `exec/builder.py` traces and what the program cache keys on."""
 
     lane: str
     slot: int
     kind: DatumKind
     ft: FieldType
+    width: int = 0
 
     def fingerprint(self) -> tuple:
-        # the flag too: signedness picks the compare (compile.py `_cmp`)
-        return ("param", self.lane, self.slot, self.kind, self.ft.tp, int(self.ft.flag), self.ft.decimal)
+        # the flag too: signedness picks the compare (compile.py `_cmp`),
+        # as a string's collation does
+        return ("param", self.lane, self.slot, self.kind, self.ft.tp, int(self.ft.flag), self.ft.decimal,
+                self.width, self.ft.collate if self.lane == "s" else None)
 
 
 class ParamSeats:
     """Hands out `Param` seats in the order of one walk over a DAG and
     keeps the values seated, per lane."""
 
-    __slots__ = ("ints", "floats")
+    __slots__ = ("ints", "floats", "strs")
 
     def __init__(self):
         self.ints: list = []
         self.floats: list = []
+        self.strs: list = []   # bytes
 
     def seat(self, c: Const, lane: str, value) -> Param:
-        vals = self.ints if lane == "i" else self.floats
+        vals = self.ints if lane == "i" else self.floats if lane == "f" else self.strs
         vals.append(value)
-        return Param(lane, len(vals) - 1, c.datum.kind, c.ft)
+        return Param(lane, len(vals) - 1, c.datum.kind, c.ft, str_width_rung(len(value)) if lane == "s" else 0)
 
 
 def seated_all(exprs: tuple, seats: ParamSeats) -> tuple:

@@ -1960,6 +1960,7 @@ class Session:
         normal pipeline and dag-tier plan-cache hits (which arrive with a
         re-bound plan and no rewriter — cacheable shapes reference real
         tables only). Returns (column names, output fts, rows)."""
+        from ..util import tracing
         from ..util.memory import MemTracker, QuotaExceeded
 
         # plan digest: access path + executor-shape fingerprint, the join
@@ -1996,10 +1997,17 @@ class Session:
         gate_on = self.sysvars.get_bool("tidb_enable_tpu_coprocessor")
         aux = []
         try:
-            for t in plan.build_tables:
-                c = self._table_chunk(t, ts, rw)
-                tracker.consume(c.nbytes())
-                aux.append(c)
+            if plan.build_tables:
+                # the join's build sides, whole: each a literal-free scan of
+                # its own (`execute_root` children of this span)
+                with tracing.span("session.join_build", tables=len(plan.build_tables)) as bsp:
+                    for t in plan.build_tables:
+                        c = self._table_chunk(t, ts, rw)
+                        tracker.consume(c.nbytes())
+                        aux.append(c)
+                    if bsp is not None:
+                        bsp.set("rows", sum(c.num_rows() for c in aux))
+                        bsp.set("bytes", sum(c.nbytes() for c in aux))
             if plan.probe_table.table_id < 0:
                 # materialized probe (CTE/derived table): the whole DAG runs
                 # over in-memory chunks — device path or oracle by the gate

@@ -670,19 +670,26 @@ class TPUStore:
         Bounded LRU keyed by the chunk token (never-reused identity); the
         entry pins the chunk so the device batch and its source live and
         die together."""
+        from ..util import metrics, tracing
+
         key = self._chunk_token(chunk)
-        with self._aux_lock:
-            cached = self._aux_batch_cache.get(key)
+        with tracing.span("cop.aux_batch", rows=chunk.num_rows()) as sp:
+            with self._aux_lock:
+                cached = self._aux_batch_cache.get(key)
+                if cached is not None:
+                    self._aux_batch_cache.pop(key)  # refresh LRU position
+                    self._aux_batch_cache[key] = cached
+            if sp is not None:
+                sp.set("hit", cached is not None)
             if cached is not None:
-                self._aux_batch_cache.pop(key)  # refresh LRU position
-                self._aux_batch_cache[key] = cached
                 return cached[1]
-        batch = to_device_batch(chunk, capacity=_pow2(max(chunk.num_rows(), 1)))
-        with self._aux_lock:
-            self._aux_batch_cache[key] = (chunk, batch)
-            while len(self._aux_batch_cache) > self._AUX_CACHE_MAX:
-                self._aux_batch_cache.pop(next(iter(self._aux_batch_cache)))
-        return batch
+            metrics.COP_AUX_UPLOADS.inc()
+            batch = to_device_batch(chunk, capacity=_pow2(max(chunk.num_rows(), 1)))
+            with self._aux_lock:
+                self._aux_batch_cache[key] = (chunk, batch)
+                while len(self._aux_batch_cache) > self._AUX_CACHE_MAX:
+                    self._aux_batch_cache.pop(next(iter(self._aux_batch_cache)))
+            return batch
 
     # -- coprocessor result cache (ref: copr/coprocessor_cache.go) ----------
     _COP_CACHE_MAX = 128

@@ -275,6 +275,7 @@ REGISTRY = Registry()
 COP_REQUESTS = REGISTRY.counter("tidb_tpu_cop_requests_total", "coprocessor requests served")
 COP_ERRORS = REGISTRY.counter("tidb_tpu_cop_errors_total", "coprocessor requests failed")
 COP_FALLBACKS = REGISTRY.counter("tidb_tpu_cop_oracle_fallbacks_total", "cop requests served by the oracle fallback")
+COP_AUX_UPLOADS = REGISTRY.counter("tidb_tpu_cop_aux_uploads_total", "join build sides converted and uploaded to the device (misses of the store's aux-batch cache)")
 COP_CACHE_HITS = REGISTRY.counter("tidb_tpu_cop_cache_hits_total", "cop requests served from the coprocessor result cache")
 BATCH_COP_BATCHES = REGISTRY.counter("tidb_tpu_batch_cop_batches_total", "vmapped multi-region coprocessor launches")
 BATCH_COP_REGIONS = REGISTRY.counter("tidb_tpu_batch_cop_regions_total", "regions served by batched coprocessor launches")
@@ -331,6 +332,7 @@ REPLICA_QUORUM_FAILS = REGISTRY.counter(
 PROGRAM_COMPILES = REGISTRY.counter("tidb_tpu_program_compiles_total", "fused XLA programs built")
 PROGRAM_LAUNCHES = REGISTRY.counter("tidb_tpu_program_launches_total", "fused XLA program executions dispatched (batched counts once)")
 PROGRAM_PARAMS_BOUND = REGISTRY.counter("tidb_tpu_program_params_bound_total", "constants handed to compiled programs as operands, summed over launches")
+PROGRAM_STR_PARAMS_BOUND = REGISTRY.counter("tidb_tpu_program_str_params_bound_total", "string constants among them: handed over as rows of the byte operand, summed over launches")
 PROGRAM_CACHE_HITS = REGISTRY.counter("tidb_tpu_program_cache_hits_total", "program-cache hits (compile skipped)")
 PROGRAM_CACHE_ENTRIES = REGISTRY.gauge("tidb_tpu_program_cache_entries", "compiled programs resident in the cache")
 PROGRAM_COMPILE_DURATION = REGISTRY.histogram(
