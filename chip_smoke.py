@@ -223,12 +223,14 @@ def run_twice(name: str, run, check, data, want_pallas: bool = False) -> None:
 
 def q3_other_parameters(ctx) -> None:
     """A second SEGMENT and a second DATE call the program that Q3's
-    first execution built: the literals are its operands, strings too.
-    Fails where `PROGRAM_COMPILES` or XLA's compile count moves."""
+    first execution built: the literals are its operands, strings too,
+    and find `lineitem` decoded and on the device. Fails where
+    `PROGRAM_COMPILES` or XLA's compile count moves, or where the row
+    store decodes again."""
     from tidb_tpu.util import metrics
 
     names = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_PARAMS_BOUND", "PROGRAM_STR_PARAMS_BOUND",
-             "COP_AUX_UPLOADS", "COP_CACHE_HITS", "PROGRAM_LAUNCHES")
+             "COP_AUX_UPLOADS", "COP_CACHE_HITS", "PROGRAM_LAUNCHES", "COP_DECODE_HITS", "COP_DECODE_MISSES")
     for params in Q3_DRAWS:
         before = {n: getattr(metrics, n).value for n in names}
         p = Probe()
@@ -240,6 +242,8 @@ def q3_other_parameters(ctx) -> None:
         assert moved["program_compiles"] == 0 and moved["xla_compiles"] == 0 and got["compile_s"] == 0, (
             f"q3 {params}: another SEGMENT or DATE built a program: {moved} {got}")
         assert moved["program_str_params_bound"] == 1 and got["oracle_fallbacks"] == 0, (params, moved, got)
+        assert (moved["cop_decode_hits"], moved["cop_decode_misses"]) == (1, 0), (
+            f"q3 {params}: lineitem was decoded or uploaded again: {moved}")
         emit(stmt="q3_params", wall_s=got["wall_s"], rows=len(rows), **params, **moved)
 
 

@@ -8,12 +8,14 @@ for both configurations) over the arrays made from the seed.  The benchmark's ce
 the same comparison on the chip at 131,072 rows; here it is 4,096 on the
 CPU.  One join program serves every SEGMENT and DATE (the literals are
 its operands, the string too), the build sides come from the cop result
-cache and stay uploaded, nothing falls back to the oracle.  The deployment
-module, the statements and the mix are loaded by path."""
+cache and stay uploaded, `lineitem`'s decoded chunk and device batch stay
+resident from one statement to the next (PR 33), nothing falls back to the
+oracle.  The deployment module, the statements and the mix are loaded by path."""
 
 import json
 import os
 
+import numpy as np
 import pytest
 
 from test_tpch_columnar_reference import BENCH, _json, _load
@@ -27,7 +29,8 @@ SEED = 2147483777   # one past 32 signed bits, as the driver's are
 SEGMENTS = ("AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD")
 DATES = ("1995-03-01", "1995-03-15", "1995-03-31")
 NAMES = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_LAUNCHES", "PROGRAM_PARAMS_BOUND", "PROGRAM_STR_PARAMS_BOUND",
-         "COP_AUX_UPLOADS", "COP_CACHE_HITS", "COP_REQUESTS", "COP_FALLBACKS")
+         "COP_AUX_UPLOADS", "COP_CACHE_HITS", "COP_REQUESTS", "COP_FALLBACKS", "COP_DECODE_HITS", "COP_DECODE_MISSES",
+         "NATIVE_DECODES")
 
 
 class Served:
@@ -96,6 +99,18 @@ def test_build_sides_come_from_the_result_cache_and_stay_uploaded(served):
         assert (m["COP_REQUESTS"], m["COP_CACHE_HITS"], m["COP_AUX_UPLOADS"]) == (3, 2, 0), (key, m)
 
 
+def test_lineitem_is_decoded_once_and_found_resident_by_every_later_draw(served):
+    """The probe scan's result depends on the drawn literals and cannot be
+    cached; its input, `lineitem`'s decoded columns on the device, is the
+    same for every draw: the first Q3 decodes the three tables, every
+    later one finds `lineitem` resident."""
+    first = served.first["moved"]
+    assert (first["COP_DECODE_MISSES"], first["COP_DECODE_HITS"], first["NATIVE_DECODES"]) == (3, 0, 3)
+    for key, got in served.cases.items():
+        m = got["moved"]
+        assert (m["COP_DECODE_HITS"], m["COP_DECODE_MISSES"], m["NATIVE_DECODES"]) == (1, 0, 0), (key, m)
+
+
 def test_the_groups_straddle_a_rung_of_the_root_merge(served):
     """What the sticky rung is for (`ProgramCache.input_capacity`): the
     number of groups that reach the root moves with SEGMENT and DATE."""
@@ -134,3 +149,49 @@ def test_the_mix_draws_the_specs_parameters():
     (step,) = _json(os.path.join(BENCH, "traffic", "q3_params.json"))["operation"]
     assert step["statement"] == "q3" and sorted(step["params"]["segment"]["choice"]) == sorted(SEGMENTS)
     assert step["params"]["date"]["choice"] == [f"1995-03-{d:02d}" for d in range(1, 32)]
+
+
+def _find(node, name):
+    return ([node] if node["name"] == name else []) + [n for c in node.get("children", ()) for n in _find(c, name)]
+
+
+def test_three_draws_hit_then_an_insert_misses_once_and_the_answer_follows_the_data(served):
+    """Last in the file: it writes to `lineitem` (and takes the row out
+    again)."""
+    draws = [{"segment": "FURNITURE", "date": "1995-03-05"}, {"segment": "HOUSEHOLD", "date": "1995-03-22"},
+             {"segment": "AUTOMOBILE", "date": "1995-03-29"}]
+    for p in draws:
+        plain, traced = served.run(p), served.run(p, trace=True)
+        want = served.dep.reference("q3", p, served.data)
+        assert served.dep.mismatch("q3", want, plain["rows"]) is None, p
+        (decode,) = _find(json.loads(traced["rows"][0][0]), "cop.decode")     # the build scans end above it
+        assert decode["attrs"]["hit"] is True and decode["attrs"]["rows"] == ROWS
+        for m in (plain["moved"], traced["moved"]):
+            assert (m["COP_DECODE_MISSES"], m["COP_DECODE_HITS"], m["COP_AUX_UPLOADS"], m["NATIVE_DECODES"]) == (0, 1, 0, 0), m
+
+    # one more line on the order that leads the first draw's answer, large enough to be seen
+    p = draws[0]
+    want = served.dep.reference("q3", p, served.data)
+    lead = max(want, key=lambda k: want[k][0])
+    o, l = served.data["orders"], served.data["lineitem"]
+    oidx = int(np.flatnonzero(o["orderkey"] == lead)[0])
+    line = {"oidx": oidx, "extendedprice": 9_999_999_00, "discount": 5,
+            "shipdate": int((np.datetime64("1998-01-01") - served.dep.EPOCH).astype(int))}
+    served.conn.query(
+        f"insert into lineitem values ({lead}, 1, 1, 8, 1.00, 9999999.00, 0.05, 0.00, 'N', 'O', "
+        "'1998-01-01', '1998-01-01', '1998-01-02', 'NONE', 'AIR', 'the row store keeps what it decoded')")
+    try:
+        grown = dict(served.data, lineitem={k: np.append(v, line[k]) if k in line else v for k, v in l.items()})
+        want_new = served.dep.reference("q3", p, grown)
+        assert want_new[lead][0] > want[lead][0]
+        fourth, fifth = served.run(p, trace=True), served.run(p)
+        decodes = _find(json.loads(fourth["rows"][0][0]), "cop.decode")
+        assert sorted((d["attrs"]["rows"], d["attrs"]["hit"]) for d in decodes) == sorted(
+            [(len(served.data["customer"]["custkey"]), False), (len(o["orderkey"]), False), (ROWS + 1, False)])
+        assert (fourth["moved"]["COP_DECODE_MISSES"], fourth["moved"]["COP_DECODE_HITS"]) == (3, 0)   # each table once
+        assert (fifth["moved"]["COP_DECODE_MISSES"], fifth["moved"]["COP_DECODE_HITS"]) == (0, 1)
+        assert served.dep.mismatch("q3", want_new, fifth["rows"]) is None
+        assert served.dep.mismatch("q3", want, fifth["rows"]) is not None
+    finally:
+        served.conn.query(f"delete from lineitem where l_orderkey = {lead} and l_linenumber = 8")
+    assert served.dep.mismatch("q3", want, served.run(p)["rows"]) is None

@@ -64,6 +64,7 @@ DESCRIPTIONS = {
     "store/transfer-leader-timeout": "times out leader-transfer attempts (breaker failover and the PD transfer-leader operator) — the operator retires as timeout and the caller backs off",
     "store/server-busy": "injects ServerIsBusy with an optional `backoff_ms` suggestion for armed stores",
     "store/unreachable": "injects StoreUnavailable for armed stores and fails their liveness probe (ping_store)",
+    "store/before-bump-write-ver": "hook between a commit's apply (its rows are in the kv) and the bump of the store write version that drops the version-keyed caches — arm with a callable that reads: a snapshot drawn there is past the commit_ts and must see the commit through the result cache and both decode caches",
     "coalesce/window-stall": "wedges the coalescer window's leader past its deadline (arm with a float to choose the hold seconds) — followers outwait their patience, withdraw their unclaimed lanes, and fall back to the single path as counted `window_stall` fallbacks",
     "coalesce/flush-lost": "loses a coalescer window's flush before any lane is answered — every lane falls out as a counted `flush_lost` fallback and re-runs its single path; no statement is lost, none launches twice",
     "cdc/segment-crash": "kills a segment flush between the tmp write and the rename (typed SinkError, tmp left behind) — the kill-mid-flush drill: consumers must see only whole renamed-in segments, and the feed re-queues the window for exactly-once redelivery",

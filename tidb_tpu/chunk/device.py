@@ -84,6 +84,11 @@ class DeviceBatch:
     def capacity(self) -> int:
         return self.row_valid.shape[0]
 
+    def nbytes(self) -> int:
+        """Device bytes held by this batch: every leaf, masks and lengths
+        included (what the device-byte gauges count)."""
+        return sum(x.nbytes for x in jax.tree_util.tree_leaves(self))
+
 
 def _pad(arr: np.ndarray, capacity: int, fill=0) -> np.ndarray:
     n = len(arr)

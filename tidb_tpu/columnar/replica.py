@@ -125,11 +125,9 @@ class ColumnarTable:
     def _hold(self, batch) -> None:  # requires: _mu
         """Put `batch` (or nothing) in the device-resident stable batch's
         place, and keep COLUMNAR_DEVICE_BYTES at what the replicas hold."""
-        import jax
-
         from ..util import metrics
 
-        nbytes = 0 if batch is None else sum(x.nbytes for x in jax.tree_util.tree_leaves(batch))
+        nbytes = 0 if batch is None else batch.nbytes()
         metrics.COLUMNAR_DEVICE_BYTES.inc(nbytes - self.device_bytes)
         self._stable_batch, self.device_bytes = batch, nbytes
 

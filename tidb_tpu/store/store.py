@@ -138,6 +138,80 @@ def _apply_radix_attribution(summaries: list, walk, info) -> None:
             return
 
 
+class _SnapshotCache:
+    """What the store read from regions at one data version, oldest use
+    first under a budget: whole cop responses (the budget counts entries),
+    decoded chunks (host bytes) and their device batches (device bytes).
+
+    The snapshot rule of all three, in its two halves. `TPUStore._may_file`
+    lets an entry be filed only for a snapshot that saw every committed
+    version, the store's write version being the one taken before the region
+    was read; the entry records that start_ts. `get` then answers a request
+    whose key matches (region, epoch, write version, what was read) only at
+    start_ts >= the entry's: with the write version unchanged such a
+    snapshot sees byte-identical data, while an OLDER one might predate a
+    version the entry includes and must miss (ref:
+    pkg/store/copr/coprocessor_cache.go keying responses by region data
+    version). And only while no version is committed above the entry's
+    start_ts: a commit's rows are in the kv before its bump of the write
+    version (txn.commit applies, delivers, then bumps), and a snapshot drawn
+    in between sees them where the entry does not.
+
+    Not locked: every method is called under the store's `_cop_lock`, which
+    also guards the write version (`get` takes kv.lock inside it for
+    max_committed: the one-way order `_may_file` names)."""
+
+    __slots__ = ("_entries", "used", "_max_committed", "_gauge")
+
+    def __init__(self, max_committed, gauge=None):
+        self._entries: dict = {}  # key -> (value, entry start_ts, cost)
+        self.used = 0  # sum of the entries' costs
+        self._max_committed = max_committed  # () -> the kv's newest commit ts
+        self._gauge = gauge  # follows `used`, summed over the process's stores
+
+    def _account(self, delta: int) -> None:
+        self.used += delta
+        if self._gauge is not None and delta:
+            self._gauge.inc(delta)
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def get(self, key, start_ts: int):
+        ent = self._entries.get(key)
+        if ent is None or start_ts < ent[1] or self._max_committed() > ent[1]:
+            return None
+        self._entries[key] = self._entries.pop(key)  # refresh LRU position
+        return ent[0]
+
+    def put(self, key, value, start_ts: int, cost: int, budget: int) -> list:
+        """File `value`, then make room. Returns the values that left, for
+        the caller to count and to let go of outside the lock. A value
+        over the whole budget is not kept: it serves its own request."""
+        if cost > budget:
+            return []
+        old = self._entries.pop(key, None)
+        if old is not None and old[1] <= start_ts:
+            self._entries[key] = old  # racing readers of one key: the earlier snapshot's stays
+            return []
+        self._entries[key] = (value, start_ts, cost)
+        delta = cost - (old[2] if old is not None else 0)
+        gone = []
+        while self.used + delta > budget:
+            evicted, _ts, c = self._entries.pop(next(iter(self._entries)))
+            delta -= c
+            gone.append(evicted)
+        self._account(delta)
+        return gone
+
+    def clear(self) -> list:
+        """Empty the cache; returns the values it held."""
+        gone = [v for v, _ts, _cost in self._entries.values()]
+        self._entries.clear()
+        self._account(-self.used)
+        return gone
+
+
 def _fault_matches(value, store_id: int) -> bool:
     """Per-store failpoint arming: True fires for every store; a
     set/list/tuple of ids fires for those stores; a dict
@@ -203,14 +277,20 @@ class TPUStore:
         self._tso_lock = threading.Lock()
         self._active_snapshots: dict[int, int] = {}  # guarded_by: _tso_lock
         self._write_ver = 0  # guarded_by: _cop_lock
-        self._chunk_cache: dict = {}
-        self._batch_cache: dict = {}
+        # what a region's rows decode to, host and device, keyed like the
+        # result cache below by the region's data version and governed by
+        # the same snapshot rule (_SnapshotCache)
+        from ..util import metrics
+
+        self._chunk_cache = _SnapshotCache(self.kv.max_committed)  # guarded_by: _cop_lock
+        self._batch_cache = _SnapshotCache(self.kv.max_committed, metrics.COP_DECODE_DEVICE_BYTES)  # guarded_by: _cop_lock
+        self._device_budget: int | None = None  # of _batch_cache; read off the device once
         self._aux_batch_cache: dict = {}  # token -> (chunk, DeviceBatch); guarded_by: _aux_lock
         self._aux_lock = threading.Lock()  # select() fans tasks over threads
         self._chunk_tokens = itertools.count(1)  # monotonic chunk identity; guarded_by: _aux_lock
         # coprocessor RESULT cache (ref: pkg/store/copr/coprocessor_cache.go):
         # a whole region response keyed by the region's data version
-        self._cop_cache: dict = {}  # guarded_by: _cop_lock
+        self._cop_cache = _SnapshotCache(self.kv.max_committed)  # guarded_by: _cop_lock
         self._cop_lock = threading.Lock()
         self._row_encoder = RowEncoder()
         # fault switches: logical placement stores marked down answer every
@@ -281,23 +361,26 @@ class TPUStore:
         return not _fault_matches(failpoint.peek("store/unreachable"), store_id)
 
     def evict_caches(self) -> int:
-        """Drop the decoded-chunk and device-batch caches — the first OOM
-        action in the chain (ref: pkg/util/memory ActionOnExceed
+        """Empty the three version-keyed caches (cop responses, decoded
+        region chunks, their device batches) and the aux-batch cache — the
+        first OOM action in the chain (ref: pkg/util/memory ActionOnExceed
         SoftLimit/spill ordering: free reclaimable buffers before killing
-        the query). Returns an approximate byte count freed."""
-        freed = 0
-        for c in self._chunk_cache.values():
-            freed += c.nbytes()
+        the query). The next read of any region scans, decodes and uploads
+        again. Returns an approximate count of the host bytes freed."""
         with self._cop_lock:
-            for resp, _ts, _flow in self._cop_cache.values():
-                if resp.chunk is not None:
-                    freed += resp.chunk.nbytes()
-            self._cop_cache.clear()
-        self._chunk_cache.clear()
-        self._batch_cache.clear()
+            freed = self._chunk_cache.used
+            responses, _batches = self._drop_version_caches()
+        freed += sum(r.chunk.nbytes() for r, _flow in responses if r.chunk is not None)
         with self._aux_lock:  # select() uploads aux batches from pool threads
             self._aux_batch_cache.clear()
         return freed
+
+    def _drop_version_caches(self) -> tuple:  # requires: _cop_lock
+        """Empty the result cache and both decode caches. Returns the cop
+        responses and the device batches they held: the caller lets go of
+        them after the lock, which is where the batches' HBM is returned."""
+        self._chunk_cache.clear()
+        return self._cop_cache.clear(), self._batch_cache.clear()
 
     def next_ts(self) -> int:
         """Store-global TSO (ref: PD timestamp oracle; mock unistore/pd.go).
@@ -349,21 +432,40 @@ class TPUStore:
         return self.kv.gc(sp)
 
     def _bump_write_ver(self):
-        # the bump rides the cache's own lock (vet finding: the unlocked
+        # the bump rides the caches' own lock (vet finding: the unlocked
         # `+= 1` could lose an increment between two racing writers, and
-        # the TOCTOU guard in _cop_cache_put compares EXACT versions).
-        # every cop-cache key embeds the old write version, so entries can
-        # never serve stale data — the clear just stops dead weight from
-        # crowding live entries out of the LRU window
+        # the TOCTOU guard in _may_file compares EXACT versions).
+        # every key of the three caches embeds the old write version, so
+        # entries can never serve stale data — the clear just frees dead
+        # weight: host bytes, HBM, and places in the LRU windows
+        from ..util import failpoint
+
+        failpoint.eval("store/before-bump-write-ver")  # the commit's rows are in the kv, the version is the old one
         with self._cop_lock:
             self._write_ver += 1
-            self._cop_cache.clear()
+            dead = self._drop_version_caches()
+        del dead  # outside the lock
 
     def _snapshot_write_ver(self) -> int:
         """Locked read of the store write version — the pre-read snapshot
         every cache key embeds."""
         with self._cop_lock:
             return self._write_ver
+
+    def _may_file(self, write_ver: int, start_ts: int) -> bool:  # requires: _cop_lock
+        """The filing half of the snapshot rule (_SnapshotCache), one place
+        for the three caches: may what a snapshot at `start_ts` read be
+        filed under `write_ver`, the caller's snapshot of _write_ver taken
+        BEFORE it read the region? Not if a write landed since (version
+        moved, or a half-applied commit already raised kv.max_version) —
+        a pre-write read could be filed under the post-write key and serve
+        stale rows. And not for a snapshot that predates some committed
+        version: it would cache a view NEWER snapshots must not inherit
+        (MVCC: same write_ver, different visibility) — only the all-seeing
+        snapshot caches. Asked and acted on in one hold of _cop_lock
+        (max_committed takes kv.lock INSIDE it; that order is one-way —
+        nothing holding kv.lock ever takes _cop_lock)."""
+        return write_ver == self._write_ver and start_ts >= self.kv.max_committed()
 
     def _record_write_flow(self, key: bytes, value: bytes | None, prev_live: bool,
                            ts: int, placement: tuple | None = None):
@@ -490,25 +592,92 @@ class TPUStore:
         return ts
 
     # -- scan/decode with caching -------------------------------------------
-    def region_chunk(self, region: Region, ranges: list, dag: DAGRequest, start_ts: int) -> Chunk:
-        """Rows of `region` ∩ `ranges` decoded to a columnar chunk.
+    # Byte budgets of the two decode caches (PERF.md section 3). Host: what
+    # a store may keep decoded beside the MVCC data it was decoded from.
+    # Device: one part in `_DECODE_DEVICE_PART` of the chip's `bytes_limit`,
+    # so that a program's sort and gather temporaries over a resident batch
+    # (a few times the batch) and the columnar replica fit beside the cache;
+    # a backend that reports no limit (the CPU) keeps its "device" batches
+    # in host memory and gets the host budget.
+    _DECODE_HOST_BYTES = 1 << 30
+    _DECODE_DEVICE_PART = 8
 
-        Cache key includes the store write version: any write invalidates
-        (coarse, but correct; per-region versions later)."""
+    def region_chunk(self, region: Region, ranges: list, dag: DAGRequest, start_ts: int) -> Chunk:
+        """Rows of `region` ∩ `ranges` visible at `start_ts`, decoded to a
+        columnar chunk: the resident one where `_region_read` finds it."""
+        return self._region_read(region, ranges, dag, start_ts)[0]
+
+    def _region_read(self, region: Region, ranges: list, dag: DAGRequest, start_ts: int, device: bool = False) -> tuple:
+        """-> (chunk, its DeviceBatch or None, hit): a region's rows as the
+        programs take them, from the decode caches where they hold them.
+
+        Both caches are keyed by the region's data version (epoch and store
+        write version: any write to the store invalidates — coarse, but
+        correct; per-region versions later) and by what was read (table,
+        ranges, and each column as the result cache's fingerprint names it:
+        id, type, flag, flen, decimal, default — MODIFY COLUMN changes how
+        stored bytes decode and writes nothing), never by the statement's
+        timestamp, and both follow the result cache's snapshot rule
+        (_SnapshotCache): a later statement finds what an earlier one
+        decoded, a transaction whose snapshot predates a commit misses,
+        decodes at its own timestamp and files nothing. `hit`: everything
+        asked for was resident; nothing was scanned, decoded or uploaded.
+        No program donates or writes into a batch it is handed, so one
+        batch serves concurrent statements."""
+        from ..util import metrics
+
         scan = dag.scan()
-        col_ids = tuple(c.col_id for c in scan.columns)
-        rkey = (
+        what = (
             region.region_id,
             region.epoch,
-            self._snapshot_write_ver(),
-            start_ts,
             scan.table_id,
-            col_ids,
+            tuple(c.fingerprint() for c in scan.columns),
             tuple((r.start, r.end) for r in ranges),
         )
-        cached = self._chunk_cache.get(rkey)
-        if cached is not None:
-            return cached
+        batch = None
+        with self._cop_lock:
+            ver = self._write_ver  # the pre-read snapshot: in the keys, and gates the filing
+            ch = self._chunk_cache.get((ver, what), start_ts)
+            if ch is not None and device:
+                batch = self._batch_cache.get((ver, what), start_ts)
+        hit = ch is not None and (batch is not None or not device)
+        (metrics.COP_DECODE_HITS if hit else metrics.COP_DECODE_MISSES).inc()
+        if hit:
+            return ch, batch, True
+        decoded = uploaded = None
+        try:
+            if ch is None:
+                ch = decoded = self._decode_region(region, ranges, scan, start_ts)
+            if device:
+                batch = uploaded = to_device_batch(ch, capacity=_pow2(max(ch.num_rows(), 1)))
+        finally:  # a chunk whose upload raised is the oracle fall-back's input: filed too
+            self._file_decoded(ver, what, start_ts, decoded, uploaded)
+        return ch, batch, False
+
+    def _file_decoded(self, ver: int, what: tuple, start_ts: int, chunk: Chunk | None, batch: DeviceBatch | None) -> None:
+        """File what a read at `start_ts` decoded and uploaded (either may
+        be None) under the data version `ver` it started from, if the
+        snapshot rule allows, and count what leaves for the budgets."""
+        from ..util import metrics
+
+        if batch is not None and self._device_budget is None:
+            import jax
+
+            limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+            self._device_budget = limit // self._DECODE_DEVICE_PART if limit else self._DECODE_HOST_BYTES
+        gone = []  # let go of after the lock: that is where a batch's HBM is returned
+        with self._cop_lock:
+            if self._may_file(ver, start_ts):
+                if chunk is not None:
+                    gone += self._chunk_cache.put((ver, what), chunk, start_ts, chunk.nbytes(), self._DECODE_HOST_BYTES)
+                if batch is not None:
+                    gone += self._batch_cache.put((ver, what), batch, start_ts, batch.nbytes(), self._device_budget)
+        if gone:
+            metrics.COP_DECODE_EVICTIONS.inc(len(gone))
+
+    def _decode_region(self, region: Region, ranges: list, scan, start_ts: int) -> Chunk:
+        """Scan region ∩ ranges out of the MVCC store at the snapshot and
+        decode the rows to columns: natively where the shape allows."""
         fts = [c.ft for c in scan.columns]
         fts_by_id = {c.col_id: c.ft for c in scan.columns}
         ch = None
@@ -523,7 +692,6 @@ class TPUStore:
                 if row is not None:
                     rows.append(row)
             ch = Chunk.from_rows(fts, rows)
-        self._chunk_cache[rkey] = ch
         return ch
 
     def _scan_region_kvs(self, region: Region, ranges: list, start_ts: int):
@@ -627,27 +795,6 @@ class TPUStore:
                     rows.append(row)
         return Chunk.from_rows(fts, rows), None
 
-    def region_device_batch(self, region: Region, ranges, dag: DAGRequest, start_ts: int, capacity: int | None = None) -> DeviceBatch:
-        ch = self.region_chunk(region, ranges, dag, start_ts)
-        cap = capacity or _pow2(max(ch.num_rows(), 1))
-        scan = dag.scan()
-        bkey = (
-            region.region_id,
-            region.epoch,
-            self._snapshot_write_ver(),
-            start_ts,
-            scan.table_id,
-            tuple(c.col_id for c in scan.columns),
-            tuple((r.start, r.end) for r in ranges),
-            cap,
-        )
-        cached = self._batch_cache.get(bkey)
-        if cached is not None:
-            return cached
-        batch = to_device_batch(ch, capacity=cap)
-        self._batch_cache[bkey] = batch
-        return batch
-
     _AUX_CACHE_MAX = 16
 
     def _chunk_token(self, chunk: Chunk) -> int:
@@ -712,27 +859,18 @@ class TPUStore:
     def _cop_cache_get(self, req: CopRequest) -> CopResponse | None:
         """Serve a whole region response from the result cache when the
         region's data version — (epoch, store write version) — and the DAG
-        fingerprint match (ref: coprocessor_cache.go keying responses by
-        region data version). Entries are only CREATED for snapshots that
-        already see every committed version (start_ts >= kv.max_version at
-        put time), so with the write version unchanged any request at
-        start_ts >= the entry's sees byte-identical data; an OLDER snapshot
-        might predate a version the entry includes and must miss. A hit
-        still records read flow — the region logically served the rows, and
-        hiding cached traffic from the PD would blind the hot-region
-        scheduler to exactly the hottest (most re-read) regions."""
+        fingerprint match, under the snapshot rule the decode caches share
+        (_SnapshotCache). A hit still records read flow — the region
+        logically served the rows, and hiding cached traffic from the PD
+        would blind the hot-region scheduler to exactly the hottest (most
+        re-read) regions."""
         if not self._cop_cacheable(req):
             return None
         with self._cop_lock:
-            key = self._cop_cache_key(req, self._write_ver)
-            ent = self._cop_cache.get(key)
-            if ent is None:
-                return None
-            resp, entry_ts, flow = ent
-            if req.start_ts < entry_ts:
-                return None
-            self._cop_cache.pop(key)  # refresh LRU position
-            self._cop_cache[key] = ent
+            ent = self._cop_cache.get(self._cop_cache_key(req, self._write_ver), req.start_ts)
+        if ent is None:
+            return None
+        resp, flow = ent
         from ..topsql import record_cop_cache_hit
         from ..util import metrics
 
@@ -748,10 +886,8 @@ class TPUStore:
         the PD heartbeat on every hit so flow stats see cached traffic.
 
         write_ver is the caller's snapshot of _write_ver taken BEFORE it
-        read the region: the insert is refused under _cop_lock if a write
-        landed since (version moved, or a half-applied commit already
-        raised kv.max_version) — otherwise a pre-write response could be
-        filed under the post-write key and serve stale rows."""
+        read the region: `_may_file` refuses the insert if a write landed
+        since."""
         if (
             not self._cop_cacheable(req)
             or resp.chunk is None
@@ -762,19 +898,8 @@ class TPUStore:
             return
         with self._cop_lock:
             ver = self._write_ver if write_ver is None else write_ver
-            key = self._cop_cache_key(req, ver)
-            if ver != self._write_ver:
-                return  # a write raced the read: the response may predate it
-            # a snapshot that predates some committed version would cache a
-            # view NEWER snapshots must not inherit (MVCC: same write_ver,
-            # different visibility) — only the all-seeing snapshot caches
-            # (max_committed takes kv.lock INSIDE _cop_lock; that order is
-            # one-way — nothing holding kv.lock ever takes _cop_lock)
-            if req.start_ts < self.kv.max_committed():
-                return
-            self._cop_cache[key] = (resp, req.start_ts, flow)
-            while len(self._cop_cache) > self._COP_CACHE_MAX:
-                self._cop_cache.pop(next(iter(self._cop_cache)))
+            if self._may_file(ver, req.start_ts):
+                self._cop_cache.put(self._cop_cache_key(req, ver), (resp, flow), req.start_ts, 1, self._COP_CACHE_MAX)
 
     def _count_replica_read(self, req: CopRequest) -> None:
         """tidb_tpu_replica_read_total{target=} — one count per routed
@@ -883,7 +1008,7 @@ class TPUStore:
         ver = self._snapshot_write_ver()  # pre-read snapshot: gates the cache insert
         t0 = time.monotonic_ns()
         last_range = None
-        page = None
+        page = rc = None
         in_bytes, in_rows = 0, 0
         info = {"cache_hit": False, "compile_ns": 0}
         try:
@@ -901,16 +1026,18 @@ class TPUStore:
                         region, req.ranges, req.dag, req.start_ts, req.paging_size
                     )
                     in_bytes, in_rows = page.nbytes(), page.num_rows()
-                    batch = to_device_batch(page, capacity=_pow2(max(page.num_rows(), 1)))
+                    batch, hit = to_device_batch(page, capacity=_pow2(max(page.num_rows(), 1))), False
                 else:
-                    rc = self.region_chunk(region, req.ranges, req.dag, req.start_ts)
+                    rc, batch, hit = self._region_read(region, req.ranges, req.dag, req.start_ts, device=True)
                     in_bytes, in_rows = rc.nbytes(), rc.num_rows()
-                    batch = self.region_device_batch(region, req.ranges, req.dag, req.start_ts)
                 # read flow into the PD heartbeat (ref: TiKV flow observer
-                # -> pdpb.RegionHeartbeat bytes/keys_read)
+                # -> pdpb.RegionHeartbeat bytes/keys_read); a resident
+                # region logically served its rows too
                 self.pd.flow.record_read(region.region_id, in_bytes, in_rows)
                 if dsp is not None:
                     dsp.set("bytes_to_device", in_bytes)
+                    dsp.set("rows", in_rows)
+                    dsp.set("hit", hit)
             batches = [batch] + [self._aux_batch(c) for c in req.aux_chunks]
             with tracing.span("cop.execute", region_id=req.region_id) as xsp:
                 chunk, ex_rows, info = drive_program_info(self.programs, req.dag, batches, group_capacity,
@@ -927,7 +1054,9 @@ class TPUStore:
             _m.COP_FALLBACKS.inc()
             try:
                 with tracing.span("cop.oracle_fallback", region_id=req.region_id):
-                    region_chunk = page if page is not None else self.region_chunk(region, req.ranges, req.dag, req.start_ts)
+                    region_chunk = page if page is not None else rc
+                    if region_chunk is None:  # the read itself raised (an upload the device cannot take)
+                        region_chunk = self.region_chunk(region, req.ranges, req.dag, req.start_ts)
                     rows = run_dag_reference(req.dag, [region_chunk] + list(req.aux_chunks))
                     chunk = Chunk.from_rows(req.dag.output_fts(), rows)
                 # fallback summaries: aligned with the device path's

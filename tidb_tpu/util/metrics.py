@@ -277,6 +277,10 @@ COP_ERRORS = REGISTRY.counter("tidb_tpu_cop_errors_total", "coprocessor requests
 COP_FALLBACKS = REGISTRY.counter("tidb_tpu_cop_oracle_fallbacks_total", "cop requests served by the oracle fallback")
 COP_AUX_UPLOADS = REGISTRY.counter("tidb_tpu_cop_aux_uploads_total", "join build sides converted and uploaded to the device (misses of the store's aux-batch cache)")
 COP_CACHE_HITS = REGISTRY.counter("tidb_tpu_cop_cache_hits_total", "cop requests served from the coprocessor result cache")
+COP_DECODE_HITS = REGISTRY.counter("tidb_tpu_cop_decode_hits_total", "region reads that found everything they needed resident: the decoded chunk and, where one was asked for, its device batch")
+COP_DECODE_MISSES = REGISTRY.counter("tidb_tpu_cop_decode_misses_total", "region reads that scanned and decoded the region, or uploaded its batch again")
+COP_DECODE_EVICTIONS = REGISTRY.counter("tidb_tpu_cop_decode_evictions_total", "decoded chunks and device batches that left the store's decode caches for their byte budgets")
+COP_DECODE_DEVICE_BYTES = REGISTRY.gauge("tidb_tpu_cop_decode_device_bytes", "bytes of region batches the stores' decode caches hold on the device")
 BATCH_COP_BATCHES = REGISTRY.counter("tidb_tpu_batch_cop_batches_total", "vmapped multi-region coprocessor launches")
 BATCH_COP_REGIONS = REGISTRY.counter("tidb_tpu_batch_cop_regions_total", "regions served by batched coprocessor launches")
 BATCH_COP_LAUNCHES_SAVED = REGISTRY.counter("tidb_tpu_batch_cop_launches_saved_total", "per-region XLA launches avoided by batching (regions - launches)")
