@@ -221,16 +221,17 @@ def run_twice(name: str, run, check, data, want_pallas: bool = False) -> None:
          kernels=a["kernels"], **first)
 
 
-def q3_other_parameters(ctx) -> None:
+def q3_other_parameters(ctx, mesh: bool = False) -> None:
     """A second SEGMENT and a second DATE call the program that Q3's
-    first execution built: the literals are its operands, strings too,
-    and find `lineitem` decoded and on the device. Fails where
-    `PROGRAM_COMPILES` or XLA's compile count moves, or where the row
-    store decodes again."""
+    first execution built: the literals are its operands, strings too.
+    Fails where `PROGRAM_COMPILES` or XLA's compile count moves; on one
+    chip also where the row store decodes or uploads `lineitem` again,
+    on the mesh where the draw is not one cross-chip launch."""
     from tidb_tpu.util import metrics
 
-    names = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_PARAMS_BOUND", "PROGRAM_STR_PARAMS_BOUND",
-             "COP_AUX_UPLOADS", "COP_CACHE_HITS", "PROGRAM_LAUNCHES", "COP_DECODE_HITS", "COP_DECODE_MISSES")
+    names = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_PARAMS_BOUND", "PROGRAM_STR_PARAMS_BOUND", "PROGRAM_LAUNCHES") + (
+        ("MESH_COP_BATCHES", "MPP_SELECTS", "MESH_COP_FALLBACKS", "MPP_FALLBACKS") if mesh
+        else ("COP_AUX_UPLOADS", "COP_CACHE_HITS", "COP_DECODE_HITS", "COP_DECODE_MISSES"))
     for params in Q3_DRAWS:
         before = {n: getattr(metrics, n).value for n in names}
         p = Probe()
@@ -242,9 +243,13 @@ def q3_other_parameters(ctx) -> None:
         assert moved["program_compiles"] == 0 and moved["xla_compiles"] == 0 and got["compile_s"] == 0, (
             f"q3 {params}: another SEGMENT or DATE built a program: {moved} {got}")
         assert moved["program_str_params_bound"] == 1 and got["oracle_fallbacks"] == 0, (params, moved, got)
-        assert (moved["cop_decode_hits"], moved["cop_decode_misses"]) == (1, 0), (
-            f"q3 {params}: lineitem was decoded or uploaded again: {moved}")
-        emit(stmt="q3_params", wall_s=got["wall_s"], rows=len(rows), **params, **moved)
+        if mesh:
+            assert moved["mesh_cop_batches"] + moved["mpp_selects"] == 1, (params, moved)
+            assert moved["mesh_cop_fallbacks"] == moved["mpp_fallbacks"] == 0, (params, moved)
+        else:
+            assert (moved["cop_decode_hits"], moved["cop_decode_misses"]) == (1, 0), (
+                f"q3 {params}: lineitem was decoded or uploaded again: {moved}")
+        emit(stmt="mesh_q3_params" if mesh else "q3_params", wall_s=got["wall_s"], rows=len(rows), **params, **moved)
 
 
 class Ctx:
@@ -405,19 +410,16 @@ def phase_mesh(ctx: Ctx, n_devices: int) -> None:
     from tidb_tpu.util import metrics as m
 
     c, data, store = ctx.client, ctx.data, ctx.srv.store
-    # the mesh tier shards REGIONS over the devices: let PD's split checker
-    # cut the loaded tables (64Ki keys / 4 MiB a region) until it is done
-    ticks, n_regions = 0, len(store.cluster.regions())
-    while True:
-        store.pd.tick()
-        ticks += 1
-        now = len(store.cluster.regions())
-        if now == n_regions:
-            break
-        n_regions = now
-        assert ticks < 16, f"regions still splitting after {ticks} ticks: {now}"
-    assert n_regions >= n_devices, f"{n_regions} region(s) cannot span {n_devices} devices"
-    emit(phase="split", ticks=ticks, regions=n_regions)
+    # the mesh tier shards REGIONS over the devices, and a freshly loaded
+    # table is one region: cut lineitem and orders the way a client of the
+    # served path does (two lanes a device, one for the join's build side)
+    regions = {}
+    for table, n in (("lineitem", 2 * n_devices), ("orders", n_devices)):
+        columns, rows = c.query(f"split table {table} between (1) and ({ctx.rows} + 1) regions {n}")
+        assert columns == ["TOTAL_SPLIT_REGION", "SCATTER_FINISH_RATIO"] and rows == [[str(n), "1"]], (table, columns, rows)
+        regions[table] = n
+    assert len(store.cluster.regions()) == 1 + sum(regions.values()), store.cluster.regions()
+    emit(phase="split", regions=regions)
 
     counters = ("MPP_SELECTS", "MESH_COP_BATCHES", "MPP_FALLBACKS", "MESH_COP_FALLBACKS")
     checks = tuple((name, SQL[name], check_tpch(name)) for name in ("q6", "q1", "q3"))
@@ -443,6 +445,8 @@ def phase_mesh(ctx: Ctx, n_devices: int) -> None:
             else:
                 assert not any(moved.values()) and not progs, (name, moved, progs)
             emit(stmt=f"{mode}_{name}", tiers=moved, mesh_programs=progs, **line, **result)
+        if mode == "mesh":
+            q3_other_parameters(ctx, mesh=True)
 
 
 def main(argv=None) -> int:

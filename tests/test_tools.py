@@ -316,3 +316,42 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, monkeypatch, capsys):
     assert [(x["segment"], x["date"]) for x in draws] == [(p["segment"], p["date"]) for p in cs.Q3_DRAWS]
     assert all(x["program_compiles"] == x["xla_compiles"] == 0 and x["program_str_params_bound"] == 1 for x in draws)
     assert not os.listdir(tmp_path)  # the data files are gone again
+
+
+def test_chip_smoke_mesh_phase_cpu_rehearsal(tmp_path, monkeypatch, capsys):
+    """chip_smoke.py's `--chips 4` phase at 4,096 rows on the suite's eight
+    host devices: `SPLIT TABLE` over the wire lays the regions out, Q6, Q1
+    and Q3 are one cross-chip launch each with collectives in the program's
+    text and no fall-back, three more (SEGMENT, DATE) draws build
+    nothing, and the single-device run answers the same."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    import chip_smoke as cs
+
+    from tidb_tpu.exec import builder
+    from tidb_tpu.util import failpoint
+
+    monkeypatch.setattr(cs, "OUT_DIR", str(tmp_path))
+    monkeypatch.setattr(cs, "MESH_PROGRAMS", [])
+    monkeypatch.setattr(builder, "build_program", builder.build_program)   # the watcher's wrapper goes with the test
+    ctx = cs.Ctx(rows=4096, seed=1)
+    try:
+        with failpoint.enabled("cop-debug-raise"):
+            cs.phase_load(ctx)
+            cs.watch_mesh_programs()
+            cs.phase_mesh(ctx, 8)
+    finally:
+        ctx.close()
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    (split,) = [x for x in lines if x.get("phase") == "split"]
+    assert split["regions"] == {"lineitem": 16, "orders": 8}
+    stmts = {x["stmt"]: x for x in lines if "stmt" in x}
+    for name in ("q6", "q1", "q3"):
+        on, off = stmts[f"mesh_{name}"], stmts[f"single_device_{name}"]
+        assert on["tiers"]["MESH_COP_BATCHES"] + on["tiers"]["MPP_SELECTS"] == 1 and not any(off["tiers"].values())
+        assert on["rows"] == off["rows"]
+        assert all(p["devices"] == 8 and p["collectives"] for p in on["mesh_programs"]) and on["mesh_programs"]
+    draws = [x for x in lines if x.get("stmt") == "mesh_q3_params"]
+    assert [(x["segment"], x["date"]) for x in draws] == [(p["segment"], p["date"]) for p in cs.Q3_DRAWS]
+    assert all(x["program_compiles"] == x["xla_compiles"] == 0 and x["mesh_cop_batches"] == 1 for x in draws)

@@ -1,0 +1,162 @@
+"""The `tpch_sf0p02_mesh4` deployment against its plain reference, in tier-1
+(ISSUE 34): TPC-H Q1, Q6 and Q3 with drawn substitution parameters, served
+over the wire from the row store of tables that the deployment's own `load`
+cut into regions with `SPLIT TABLE` (lineitem 8, orders 4), on the suite's
+eight host devices with `tidb_enable_tpu_mesh` and `tidb_allow_mpp` at their
+defaults.  Every statement is one cross-chip program: the region lanes
+sharded over the devices, the partial states merged on the device (psum for
+Q6, all_gather and a merge-mode re-group for Q1 and Q3; the three end in
+ORDER BY or have no GROUP BY, so the statement tier leaves them to the
+per-request mesh tier).  Compared exactly with the numpy reference and with
+the same statements under both sysvars OFF; one program a statement shape
+whatever the draw; no fall-back; `cop-debug-raise` reaches the tier; the
+spans a traced run is reduced by.  The benchmark's cell `tpch_q1q6q3_mesh4`
+makes the same comparison on four chips at 131,072 rows; here it is 4,096."""
+
+import json
+import os
+
+import pytest
+
+from test_tpch_columnar_reference import BENCH, _json, _load
+
+from tidb_tpu.server import MiniClient, MySQLServer
+from tidb_tpu.util import failpoint, metrics
+
+CONFIG_DIR = os.path.join(BENCH, "configs", "tpch_sf0p02_mesh4")
+ROWS = 4096
+SEED = 2147483777   # one past 32 signed bits, as the driver's are
+DRAWS = {
+    "q1": [{"delta": d} for d in (90, 60, 120, 77)],
+    "q6": [{"date": d, "discount": x, "quantity": q} for d, x, q in (
+        ("1994-01-01", "0.06", 24), ("1993-01-01", "0.02", 25), ("1997-01-01", "0.09", 24), ("1995-01-01", "0.05", 25))],
+    "q3": [{"segment": s, "date": d} for s, d in (
+        ("BUILDING", "1995-03-15"), ("MACHINERY", "1995-03-01"), ("AUTOMOBILE", "1995-03-31"), ("HOUSEHOLD", "1995-03-09"))],
+}
+NAMES = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_LAUNCHES", "MESH_COP_BATCHES", "MESH_COP_LANES", "MPP_SELECTS",
+         "MESH_COP_FALLBACKS", "MPP_FALLBACKS", "COP_FALLBACKS")
+
+
+class Served:
+    def __init__(self):
+        self.dep = _load(os.path.join(CONFIG_DIR, "deployment.py"), "tpch_sf0p02_mesh4_deployment")
+        self.config = dict(_json(os.path.join(CONFIG_DIR, "config.json")), lineitem_rows=ROWS)
+        self.sql = _json(os.path.join(CONFIG_DIR, "statements.json"))
+        self.mix = _json(os.path.join(BENCH, "traffic", "q1q6q3_params.json"))
+        self.data = self.dep.generate(self.config, SEED)
+        self.srv = MySQLServer(port=0)
+        self.srv.start_background()
+        self.conn = MiniClient(self.srv.host, self.srv.port, timeout=600.0)
+        self.lines = []
+        self.dep.load(self.conn, self.data, self.config, lambda **line: self.lines.append(line))
+        self.conn.query(f"set tidb_isolation_read_engines = '{self.mix['read_engines']}'")
+        self.cases = {(name, i): self.run(name, p) for name, draws in DRAWS.items() for i, p in enumerate(draws)}
+        for var in ("tidb_enable_tpu_mesh", "tidb_allow_mpp"):
+            self.conn.query(f"set {var} = OFF")
+        self.single = {(name, i): self.run(name, p) for name, draws in DRAWS.items() for i, p in enumerate(draws)}
+        for var in ("tidb_enable_tpu_mesh", "tidb_allow_mpp"):
+            self.conn.query(f"set {var} = ON")
+
+    def run(self, name: str, params: dict, trace: bool = False) -> dict:
+        before = {n: getattr(metrics, n).value for n in NAMES}
+        _, rows = self.conn.query(("trace format='json' " if trace else "") + self.sql[name].format(**params))
+        return {"params": params, "rows": rows, "moved": {n: getattr(metrics, n).value - before[n] for n in NAMES}}
+
+    def close(self):
+        self.conn.close()
+        self.srv.close()
+
+
+@pytest.fixture(scope="module")
+def served():
+    s = Served()
+    yield s
+    s.close()
+
+
+def find(node: dict, name: str) -> list:
+    return ([node] if node["name"] == name else []) + [n for c in node.get("children", ()) for n in find(c, name)]
+
+
+CASES = [(name, i) for name in DRAWS for i in range(4)]
+
+
+def test_load_cut_the_tables_as_the_configuration_says(served):
+    (line,) = [ln for ln in served.lines if ln.get("phase") == "split"]
+    assert line["regions"] == {"customer": 1, "orders": 4, "lineitem": 8}
+    assert len(served.srv.store.cluster.regions()) == 13   # what lies before customer's records, then 1 + 4 + 8
+
+
+@pytest.mark.parametrize("name,i", CASES)
+def test_served_answer_equals_the_plain_reference_and_the_one_device_answer(served, name, i):
+    got = served.cases[name, i]
+    want = served.dep.reference(name, got["params"], served.data)
+    assert served.dep.mismatch(name, want, got["rows"]) is None, (got["params"], got["rows"])
+    assert got["rows"] == served.single[name, i]["rows"]
+
+
+@pytest.mark.parametrize("name,i", CASES)
+def test_every_statement_is_one_cross_chip_program_and_none_falls_back(served, name, i):
+    m = served.cases[name, i]["moved"]
+    assert m["MESH_COP_BATCHES"] + m["MPP_SELECTS"] == 1 and m["MESH_COP_LANES"] == 8, m
+    assert m["MESH_COP_FALLBACKS"] == m["MPP_FALLBACKS"] == m["COP_FALLBACKS"] == 0, m
+    off = served.single[name, i]["moved"]
+    assert off["MESH_COP_BATCHES"] == off["MPP_SELECTS"] == off["MESH_COP_LANES"] == 0, off
+
+
+@pytest.mark.parametrize("name", list(DRAWS))
+def test_the_first_draw_builds_the_programs_and_no_later_draw_builds_one(served, name):
+    first = served.cases[name, 0]["moved"]
+    assert first["PROGRAM_COMPILES"] >= 2 and first["XLA_COMPILES"] >= 1, first   # the mesh program and the root's merge
+    for i in (1, 2, 3):
+        m = served.cases[name, i]["moved"]
+        assert m["PROGRAM_COMPILES"] == m["XLA_COMPILES"] == 0, (name, i, m)
+        assert m["PROGRAM_LAUNCHES"] == 2, (name, i, m)   # the mesh program, the root's merge; Q3's build scans are cop results
+
+
+def test_a_traced_q3_lays_the_mesh_tier_under_the_dispatch_span(served):
+    got = served.run("q3", {"segment": "FURNITURE", "date": "1995-03-20"}, trace=True)
+    tree = json.loads(got["rows"][0][0])
+    roots = find(tree, "distsql.execute_root")
+    (probe,) = [r for r in roots if find(r, "cop.mesh_execute")]
+    (stack,) = find(probe, "mesh.stack")
+    assert stack["attrs"]["lanes"] == 8 and stack["attrs"]["devices"] == 8
+    assert stack["attrs"]["rows"] == ROWS and stack["attrs"]["bytes"] > 0
+    (execute,) = find(probe, "cop.mesh_execute")
+    assert find(execute, "mesh.stack") == [stack] and find(execute, "exec.launch") and not find(tree, "exec.compile")
+    assert [n["attrs"]["program"] for n in find(execute, "exec.launch")] == ["cop_scan_sel_join_join_groupagg_m8x8"]
+    assert find(probe, "cop.mesh_decode") and find(probe, "distsql.root_merge")
+    assert got["moved"]["MESH_COP_BATCHES"] == 1 and got["moved"]["PROGRAM_COMPILES"] == 0
+
+
+def test_cop_debug_raise_reaches_the_mesh_tier(served, monkeypatch):
+    """Unarmed, a failed launch degrades to the tier below and is counted;
+    armed (as the benchmark arms it for a whole run) it fails the
+    statement, so a four-chip cell cannot be measured on one chip."""
+    from tidb_tpu.store import store as store_mod
+
+    def broken(*_a, **_k):
+        raise RuntimeError("injected mesh launch failure")
+
+    monkeypatch.setattr(store_mod, "to_stacked_device_batch", broken)
+    p = {"date": "1996-01-01", "discount": "0.03", "quantity": 24}
+    got = served.run("q6", p)
+    assert served.dep.mismatch("q6", served.dep.reference("q6", p, served.data), got["rows"]) is None
+    assert got["moved"]["MESH_COP_FALLBACKS"] == 1 and got["moved"]["MESH_COP_BATCHES"] == 0, got["moved"]
+    failpoint.enable("cop-debug-raise")
+    try:
+        with pytest.raises(Exception, match="injected mesh launch failure"):
+            served.run("q6", dict(p, discount="0.04"))   # another literal: the lanes above left cop results behind
+    finally:
+        failpoint.disable("cop-debug-raise")
+    monkeypatch.undo()
+    # armed, a sound statement is served as before, and the size decline stays a counted decline
+    failpoint.enable("cop-debug-raise")
+    try:
+        assert served.run("q6", dict(p, discount="0.07"))["moved"]["MESH_COP_BATCHES"] == 1
+        served.conn.query("set tidb_tpu_mesh_min_rows = 100000000")
+        m = served.run("q6", dict(p, discount="0.08"))["moved"]
+        assert m["MESH_COP_BATCHES"] == 0 and m["COP_FALLBACKS"] == 0
+    finally:
+        failpoint.disable("cop-debug-raise")
+        served.conn.query("set tidb_tpu_mesh_min_rows = default")

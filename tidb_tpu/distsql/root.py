@@ -180,10 +180,19 @@ def execute_root(
     mesh: bool | None = None,
     mesh_min_rows: int = 0,
     isolation_engines: tuple = ("tpu",),
+    allow_mpp: bool = False,
 ) -> Chunk:
     """Run a logical (Complete-mode) DAG over the store: split, dispatch the
     pushdown half per region, merge at root. The caller-visible result is
     identical to running the whole DAG over all rows at once.
+
+    allow_mpp (tidb_allow_mpp) puts the statement tier first (ref:
+    mpp_gather.go:40 useMPPExecution, asked once a statement before task
+    planning): an exchange-eligible DAG is planned as a fragment graph and
+    run as one shard_map program over the mesh (`mpp/dispatch.py`); a
+    declined attempt is a counted fall-back onto the tiers below, as if it
+    had never been made. EXPLAIN ANALYZE (summary_sink) and the low-memory
+    degrade keep to the per-region path, which is what they are about.
 
     isolation_engines (tidb_isolation_read_engines) is the engine-routing
     consult (ref: kv.StoreType{TiKV,TiFlash} selection): when it includes
@@ -209,15 +218,45 @@ def execute_root(
 
     with tracing.span("distsql.execute_root", n_ranges=len(ranges),
                       start_ts=start_ts, low_memory=low_memory) as sp:
-        out = _execute_root(
-            store, dag, ranges, start_ts, aux_chunks, concurrency, cache,
-            group_capacity, paging_size, batch_cop, summary_sink, tracker,
-            low_memory, small_groups, checker, backoff_weight, replica_read,
-            mesh, mesh_min_rows, isolation_engines,
-        )
+        out = None
+        if allow_mpp and summary_sink is None and not low_memory:
+            out = _statement_tier(store, dag, ranges, start_ts, aux_chunks, group_capacity,
+                                  checker, backoff_weight, bool(mesh), isolation_engines)
+        if out is None:
+            out = _execute_root(
+                store, dag, ranges, start_ts, aux_chunks, concurrency, cache,
+                group_capacity, paging_size, batch_cop, summary_sink, tracker,
+                low_memory, small_groups, checker, backoff_weight, replica_read,
+                mesh, mesh_min_rows, isolation_engines,
+            )
         if sp is not None:
             sp.set("rows", out.num_rows())
         return out
+
+
+def _statement_tier(store, dag, ranges, start_ts, aux_chunks, group_capacity,
+                    checker, backoff_weight, allow_mesh: bool, engines: tuple) -> Chunk | None:
+    """The "mpp" statement tier where `choose_statement_tier` picks it,
+    else (or after its counted fall-back) None: the tiers below serve."""
+    from .planner import choose_statement_tier
+
+    def columnar_routed():
+        # engine routing (ISSUE 12): where the columnar replica is this
+        # plan's engine the statement tier must not preempt it; a thunk,
+        # so the walk runs only when an mpp attempt is on the table
+        from ..columnar.route import columnar_would_serve
+
+        return columnar_would_serve(store, dag, ranges, engines)
+
+    decision = choose_statement_tier(dag, allow_mpp=True, allow_mesh=allow_mesh,
+                                     columnar_routed=columnar_routed)
+    if decision.tier != "mpp":
+        return None
+    from ..mpp.dispatch import try_mpp_select
+
+    return try_mpp_select(store, dag, ranges, start_ts, group_capacity=group_capacity,
+                          aux_chunks=aux_chunks, engines=engines,
+                          backoff_weight=backoff_weight, checker=checker)
 
 
 def _execute_root(

@@ -977,13 +977,10 @@ class ProgramCache:
         """(program, cache_hit, compile_ns) — the attribution triple the
         exec summaries and the TRACE span tree surface (ref: the
         coprocessor-cache hit flag in copr responses)."""
-        import time as _t
-
         if isinstance(capacities, int):
             capacities = (capacities,)
         capacities = tuple(capacities)
         from ..ops.dense_pallas import pallas_mode
-        from ..util import metrics, tracing
 
         # pallas mode is read at TRACE time (env + backend): a program
         # traced under one mode must not serve another (mismatched
@@ -992,7 +989,23 @@ class ProgramCache:
         # count (shard_map shapes both into the trace); mesh_kind is
         # derivable from the key but cheap to carry explicitly
         key = (dag.program_key(), capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch, pallas_mode(), mesh_lanes, mesh_devices, mesh_kind, radix_joins)
+        return self.built(
+            key,
+            lambda: build_program(dag, capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch=vmap_batch,
+                                  mesh_lanes=mesh_lanes, mesh_devices=mesh_devices, mesh_kind=mesh_kind, radix_joins=radix_joins),
+            batch_size=vmap_batch, mesh_lanes=mesh_lanes)
+
+    def built(self, key: tuple, build, **attrs) -> tuple:
+        """(program, cache_hit, compile_ns) of `key`, `build()` being called
+        by the one thread that finds it missing: the counted, single-flight
+        half of `get_info`, which every compiled program of the engine goes
+        through — the cop programs above and the exchange programs of
+        `mpp/exchange_op.py`, whose key is the plan's shape too.  `attrs`
+        that are not None go onto the `exec.program` span of a miss."""
         import threading
+        import time as _t
+
+        from ..util import metrics, tracing
 
         while True:
             prog = self._cache.get(key)
@@ -1017,15 +1030,13 @@ class ProgramCache:
                     self.compiles += 1
                 metrics.PROGRAM_COMPILES.inc()
                 t0 = _t.perf_counter_ns()
-                prog = build_program(dag, capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch=vmap_batch,
-                                     mesh_lanes=mesh_lanes, mesh_devices=mesh_devices, mesh_kind=mesh_kind, radix_joins=radix_joins)
+                prog = build()
                 compile_ns = _t.perf_counter_ns() - t0  # the Python closure only: JAX traces and compiles at the first call (exec/launch.py)
                 if sp is not None:
                     sp.set("compile_ns", compile_ns)
-                    if vmap_batch is not None:
-                        sp.set("batch_size", vmap_batch)
-                    if mesh_lanes is not None:
-                        sp.set("mesh_lanes", mesh_lanes)
+                    for k, v in attrs.items():
+                        if v is not None:
+                            sp.set(k, v)
             self._cache[key] = prog
             metrics.PROGRAM_CACHE_ENTRIES.set(len(self._cache))
         finally:

@@ -18,9 +18,14 @@ to the row-store scan pushdown otherwise, and (3) launch the exchange
 program with the overflow capacity ladder.
 
 Failure discipline mirrors `columnar/route.py`: every decline is a COUNTED
-fallback (`MPP_FALLBACKS`) and the caller dispatches to `execute_root` as
-if routing never happened — degrade, never fail; the row store still owns
-the authoritative answer. Typed region errors and epoch fall-out surface
+fallback (`MPP_FALLBACKS`) and the caller (`distsql.execute_root`, which
+asks `choose_statement_tier` first) goes on with its own tiers as if
+routing never happened — degrade, never fail; the row store still owns
+the authoritative answer. With `cop-debug-raise` armed a failure of the
+exchange program fails the statement instead, as on the single-request
+path and the columnar route: a run that is there to measure this tier
+must not read another's numbers. Size, skew and eligibility declines stay
+counted declines either way. Typed region errors and epoch fall-out surface
 from the row-store scan path itself (`distsql.dispatch.select`), so a
 mid-query region split aborts the MPP attempt with the same typed shape
 the per-region path raises.
@@ -38,7 +43,7 @@ from ..chunk import Chunk
 from ..exec.dag import DAGRequest
 from .fragment import chunks_exchange_safe, fragment_plan, mesh_eligible, split_join_dag
 
-# (encoded dag, n devices, base group capacity) -> last successful
+# (program key, n devices, base group capacity) -> last successful
 # (gc, scale) ladder rung; bounded FIFO, see execute_exchange_plan
 _LADDER_HINTS: dict[tuple, tuple[int, int]] = {}
 
@@ -52,17 +57,18 @@ def _chunks_nbytes(chunks) -> int:
 
 
 def execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
-                          group_capacity: int = 1024) -> Chunk | None:
+                          group_capacity: int = 1024, programs=None) -> Chunk | None:
     """Launch the exchange program over already-scanned chunks. Region
     chunks play the task lanes; build tables are sliced across devices so
     each slice plays a region shard. Overflow (too many groups / join
     fan-out / hash collision) retries with 4x capacity — the capacity
     also salts the hash, mirroring drive_program's contract — reusing the
-    scanned chunks, not rescanning. Returns the projected result Chunk,
-    or None for a fallback to the per-region path."""
+    scanned chunks, not rescanning. The programs live in `programs` (the
+    store's ProgramCache). Returns the projected result Chunk, or None for
+    a fallback to the per-region path (a decline; a failure raises)."""
     from ..parallel.grouped import run_sharded_grouped_agg
     from ..parallel.mesh import region_mesh, stack_region_batches
-    from ..util import metrics
+    from ..util import metrics, tracing
 
     agg = dag.executors[-1]
     out_fts = agg.output_fts()
@@ -74,75 +80,79 @@ def execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
 
     n = len(devs)
     n_total = ((len(chunks) + n - 1) // n) * n
-    try:
-        stacked = stack_region_batches(chunks, n_total=n_total)
-    except NotImplementedError:
-        return None  # e.g. non-ASCII CI data: the per-region path's
-        # oracle fallback owns it (chunk/device.py guard)
     mesh = region_mesh(n)
-
     stacked_builds = None
-    if kind == "join":
-        n_stages = len(split_join_dag(dag)[2])
-        if aux_chunks is None or len(aux_chunks) < n_stages:
-            return None
-        stacked_builds = []
-        for build in aux_chunks[:n_stages]:
-            if not chunks_exchange_safe([build]):
-                return None
-            if build.num_rows() == 0:
-                bslices = [build]
-            else:
-                step = (build.num_rows() + n - 1) // n
-                bslices = [
-                    build.slice(i * step, min((i + 1) * step, build.num_rows()))
-                    for i in range(n)
-                    if i * step < build.num_rows()
-                ]
-            try:
-                stacked_builds.append(stack_region_batches(bslices, n_total=n))
-            except NotImplementedError:
-                return None  # non-ASCII CI build data -> per-region path
-
-    # the ladder's start rung is remembered per plan identity: a skewed key
-    # distribution that overflowed rung 1 last time will overflow it again —
-    # a repeated digest starts at the rung that last succeeded, so the
-    # steady state is ONE cached program, not a re-walk of the failed rungs
-    from ..codec.wire import encode_dag
-
-    hint_key = (encode_dag(dag), n, group_capacity)
-    gc, scale = _LADDER_HINTS.get(hint_key, (group_capacity, 1))
-    for _ in range(3):
+    # host-side stacking and upload of the task lanes, made anew for every
+    # statement: the probe's region chunks, then each build table sliced
+    # across the devices so that a slice plays a region shard
+    with tracing.span("mesh.stack", lanes=n_total, devices=n,
+                      rows=sum(c.num_rows() for c in chunks), bytes=_chunks_nbytes(chunks)):
         try:
+            stacked = stack_region_batches(chunks, n_total=n_total)
+        except NotImplementedError:
+            return None  # e.g. non-ASCII CI data: the per-region path's
+            # oracle fallback owns it (chunk/device.py guard)
+        if kind == "join":
+            n_stages = len(split_join_dag(dag)[2])
+            if aux_chunks is None or len(aux_chunks) < n_stages:
+                return None
+            stacked_builds = []
+            for build in aux_chunks[:n_stages]:
+                if not chunks_exchange_safe([build]):
+                    return None
+                if build.num_rows() == 0:
+                    bslices = [build]
+                else:
+                    step = (build.num_rows() + n - 1) // n
+                    bslices = [
+                        build.slice(i * step, min((i + 1) * step, build.num_rows()))
+                        for i in range(n)
+                        if i * step < build.num_rows()
+                    ]
+                try:
+                    stacked_builds.append(stack_region_batches(bslices, n_total=n))
+                except NotImplementedError:
+                    return None  # non-ASCII CI build data -> per-region path
+
+    # the ladder's start rung is remembered per plan SHAPE (the key of the
+    # program itself): a skewed key distribution that overflowed rung 1 last
+    # time will overflow it again — a repeated statement, whatever its
+    # literals, starts at the rung that last succeeded, so the steady state
+    # is ONE cached program, not a re-walk of the failed rungs
+    hint_key = (dag.program_key(), n, group_capacity)
+    gc, scale = _LADDER_HINTS.get(hint_key, (group_capacity, 1))
+    with tracing.span("mpp.exchange", kind=kind) as sp:
+        for retries in range(3):
+            # a failure in here (an op the device compiler refuses that
+            # slipped past the static gate, a failed launch) is the
+            # caller's to count or to raise: try_mpp_select
             if kind == "join":
                 from .exchange_op import run_exchange_join_agg
 
                 chunk, overflow = run_exchange_join_agg(
-                    dag, stacked, stacked_builds, mesh, group_capacity=gc, scale=scale
-                )
+                    dag, stacked, stacked_builds, mesh, group_capacity=gc, scale=scale,
+                    programs=programs)
             else:
-                chunk, overflow = run_sharded_grouped_agg(dag, stacked, mesh, group_capacity=gc)
-        except NotImplementedError:
-            # an op the device compiler refuses slipped past the static
-            # gate: fall back to the per-region thread-pool path, which
-            # keeps host-only work at root (mirrors store.coprocessor's
-            # oracle fallback)
-            return None
-        if not overflow:
-            if len(_LADDER_HINTS) >= 256:
-                _LADDER_HINTS.pop(next(iter(_LADDER_HINTS)))
-            _LADDER_HINTS[hint_key] = (gc, scale)
-            metrics.MESH_SELECTS.inc()
-            cols = [chunk.columns[i] for i in dag.output_offsets]
-            return Chunk(cols)
-        # one overflow flag covers groups, exchange buckets, and join
-        # fan-out. Exchange/fan-out skew (scale) is far more common than
-        # group-count overflow in chain shapes, and gc inflates the group
-        # tables of EVERY device — so the middle rung grows scale alone,
-        # and only the last rung grows both
-        if scale >= 4:
-            gc *= 4
-        scale *= 4
+                chunk, overflow = run_sharded_grouped_agg(dag, stacked, mesh, group_capacity=gc,
+                                                          programs=programs)
+            if sp is not None:
+                sp.set("rung", [gc, scale])
+                sp.set("retries", retries)
+            if not overflow:
+                if len(_LADDER_HINTS) >= 256:
+                    _LADDER_HINTS.pop(next(iter(_LADDER_HINTS)))
+                _LADDER_HINTS[hint_key] = (gc, scale)
+                metrics.MESH_SELECTS.inc()
+                cols = [chunk.columns[i] for i in dag.output_offsets]
+                return Chunk(cols)
+            # one overflow flag covers groups, exchange buckets, and join
+            # fan-out. Exchange/fan-out skew (scale) is far more common than
+            # group-count overflow in chain shapes, and gc inflates the group
+            # tables of EVERY device — so the middle rung grows scale alone,
+            # and only the last rung grows both
+            if scale >= 4:
+                gc *= 4
+            scale *= 4
     return None  # caller falls back to the per-region path
 
 
@@ -254,14 +264,22 @@ def try_mpp_select(
 
             scan = dag.executors[0]
             scan_dag = DAGRequest((scan,), output_offsets=tuple(range(len(scan.columns))))
-            res = select(store, KVRequest(scan_dag, ranges, start_ts))
+            with tracing.span("mpp.scan", table=scan.table_id):
+                res = select(store, KVRequest(scan_dag, ranges, start_ts))
             chunks = [c for c in res.chunks if c is not None and c.num_rows() > 0]
         if failpoint.eval("mpp/exchange-stall"):
             # an exchange never delivered mid-run: abandon the MPP run
             metrics.MPP_FALLBACKS.inc()
             return None
-        out = execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
-                                    group_capacity=group_capacity)
+        try:
+            out = execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
+                                        group_capacity=group_capacity, programs=store.programs)
+        except Exception:  # noqa: BLE001 — degrade, never fail: the
+            # per-region path still owns the answer (armed, the failure
+            # is the answer: see the module docstring)
+            if failpoint.eval("cop-debug-raise"):
+                raise
+            out = None
         if out is None:
             metrics.MPP_FALLBACKS.inc()
             return None

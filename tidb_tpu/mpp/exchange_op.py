@@ -212,20 +212,28 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
     chain DAG: `fn(stacked_probe, *stacked_builds) -> flat group outputs`.
     Split from `run_exchange_join_agg` so the jax-audit catalog can trace
     the exchange-join shape through the jaxpr checks without launching."""
+    from ..exec.dag import operand_lanes
     from ..parallel.grouped import _flatten_local, agg_exchange_phases
     from .fragment import split_join_dag
 
+    # what is traced is the plan's shape (exec/builder.py build_program):
+    # the parameterisable constants of the DAG that happens to build the
+    # program follow the batches as operands, like any later DAG's
+    dag, _key, operands = dag.parameterized()
+    lanes = operand_lanes(operands)
     parts = split_join_dag(dag)
     assert parts is not None, "not a shuffle-join DAG shape"
     probe_scan, pre_sels, stages, agg = parts
     pfts = [c.ft for c in probe_scan.columns]
     n_parts = mesh.devices.size
+    n_builds = len(stages)
 
-    def device_fn(lp, *lbs):
+    def device_fn(lp, *rest):
+        lbs, params = rest[:n_builds], dict(zip(lanes, rest[n_builds:]))
         pcols, pvalid = _flatten_local(lp)
         pc = [normalize_device_column(c) for c in pcols]
         for ex in pre_sels:
-            conds = ExprCompiler(pfts).run(list(ex.conditions), pc)
+            conds = ExprCompiler(pfts, params).run(list(ex.conditions), pc)
             pvalid = apply_selection(pvalid, conds)
         # drop raw string bytes: only packed words cross the exchange
         pc = [CompVal(c.value, c.null, c.ft) for c in pc]
@@ -248,13 +256,13 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
             bcols, bvalid = _flatten_local(lb)
             bc = [normalize_device_column(c) for c in bcols]
             for ex in join.build[1:]:
-                conds = ExprCompiler(bfts).run(list(ex.conditions), bc)
+                conds = ExprCompiler(bfts, params).run(list(ex.conditions), bc)
                 bvalid = apply_selection(bvalid, conds)
             bc = [CompVal(c.value, c.null, c.ft) for c in bc]
 
             # hash-partition both sides by THIS stage's join key
-            pkeys = ExprCompiler(schema).run(list(join.probe_keys), cols)
-            bkeys = ExprCompiler(bfts).run(list(join.build_keys), bc)
+            pkeys = ExprCompiler(schema, params).run(list(join.probe_keys), cols)
+            bkeys = ExprCompiler(bfts, params).run(list(join.build_keys), bc)
             # 2.5x the fair share: hash partitioning is balanced per KEY,
             # not per row — a few dozen fat keys per device routinely put
             # one partition ~2.5x over the row mean, and a whole ladder
@@ -267,8 +275,8 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
             bc2, bvalid2, bovf = exchange_compvals(bc, bvalid, bp, n_parts, bcap_)
 
             # local join on the owned partition (ref: joinExec above receivers)
-            pkeys2 = ExprCompiler(schema).run(list(join.probe_keys), pc2)
-            bkeys2 = ExprCompiler(bfts).run(list(join.build_keys), bc2)
+            pkeys2 = ExprCompiler(schema, params).run(list(join.probe_keys), pc2)
+            bkeys2 = ExprCompiler(bfts, params).run(list(join.build_keys), bc2)
             if join.join_type in ("semi", "anti"):
                 out_cap = pvalid2.shape[0]  # probe-shaped output
             else:
@@ -297,7 +305,7 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
                     if join.join_type == "left_outer" else bfts
                 )
             for ex in post_sels:
-                conds = ExprCompiler(schema).run(list(ex.conditions), cols)
+                conds = ExprCompiler(schema, params).run(list(ex.conditions), cols)
                 valid = apply_selection(valid, conds)
 
         # the state-exchange bucket cap is data-sized like the join
@@ -305,54 +313,48 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
         # phase 8x the whole join's work at the upper ladder rungs)
         return agg_exchange_phases(
             agg, schema, cols, valid, n_parts, group_capacity,
-            max(64, 2 * scale * est // n_parts), extra_overflow=extra,
+            max(64, 2 * scale * est // n_parts), extra_overflow=extra, params=params,
         )
 
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import group_mesh_out_spec
 
-    def wrap(stacked_probe, *stacked_builds):
-        spec_p = jax.tree.map(lambda _: P(REGION_AXIS), stacked_probe)
-        spec_bs = tuple(jax.tree.map(lambda _: P(REGION_AXIS), sb) for sb in stacked_builds)
-        fn = jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_p, *spec_bs),
+    def wrap(stacked_probe, *rest):
+        # the batches shard their region axis; the operands replicate
+        specs = tuple(jax.tree.map(lambda _: P(REGION_AXIS), b) for b in (stacked_probe, *rest[:n_builds]))
+        fn = jax.shard_map(device_fn, mesh=mesh, in_specs=specs + (P(),) * len(lanes),
                            out_specs=group_mesh_out_spec(agg), check_vma=False)
-        return fn(stacked_probe, *stacked_builds)
+        return fn(stacked_probe, *rest)
 
     return wrap
 
 
-# compiled exchange programs, keyed by (wire-encoded DAG, mesh devices,
-# capacities). A fresh `jax.jit(closure)` per query re-traces the whole
-# shard_map program every time, and the re-trace outweighs the query.
-# The wire encoding is the plan identity (same bytes = same
-# device program), so repeated statements hit XLA's executable cache; the
-# jitted callable itself still keys on input shapes, so shape changes only
-# re-trace, never collide. Bounded FIFO — a digest-churning workload evicts,
-# it doesn't grow without bound.
-_PROGRAM_CACHE: dict[tuple, object] = {}
-_PROGRAM_CACHE_CAP = 64
-
-
-def run_exchange_program(name: str, dag, mesh, build, cap_key: tuple, args: tuple):
-    """`build() -> fn`, jitted under `name` and cached under the DAG's wire
-    identity, called with `args` through the launch boundary.  The host
-    decodes every output, so all of them ride the program's one buffer.
-    Returns (outputs as host arrays, the launch's `Fetch`)."""
-    from ..codec.wire import encode_dag
+def run_exchange_program(name: str, dag, mesh, build, cap_key: tuple, args: tuple, programs=None):
+    """`build() -> fn(*args, *operands)`, jitted under `name`, kept in
+    `programs` (a `ProgramCache`: the store's, else the process's default)
+    under what it depends on — the DAG's `program_key()`, the mesh's
+    devices, the capacities — and called with `args` and the DAG's
+    `program_operands()` through the launch boundary: a fresh literal
+    calls the program that is there, and building one counts as every
+    other program's does (`PROGRAM_COMPILES`, `exec.program`).  A fresh
+    `jax.jit(closure)` per statement would re-trace the whole shard_map
+    program every time.  The host decodes every output, so all of them
+    ride the program's one buffer.  Returns (outputs as host arrays, the
+    launch's `Fetch`)."""
     from ..exec import launch
+    from ..exec.executor import DEFAULT_PROGRAM_CACHE
+    from ..ops.dense_pallas import pallas_mode
 
-    key = (encode_dag(dag),
-           tuple(int(d.id) for d in mesh.devices.flat), *cap_key)
-    outputs = _PROGRAM_CACHE.get(key)
-    first_call = outputs is None
-    if first_call:
-        if len(_PROGRAM_CACHE) >= _PROGRAM_CACHE_CAP:
-            _PROGRAM_CACHE.pop(next(iter(_PROGRAM_CACHE)))
+    def make():
         body = build()
         body.__name__ = body.__qualname__ = name
-        outputs = _PROGRAM_CACHE[key] = launch.HostOutputs(body)
-    outs, fetch, _ = launch.run_program(outputs, args, first_call=first_call)
+        return launch.HostOutputs(body)
+
+    cache = DEFAULT_PROGRAM_CACHE if programs is None else programs
+    key = (name, dag.program_key(), tuple(int(d.id) for d in mesh.devices.flat), *cap_key, pallas_mode())
+    outputs, hit, _ = cache.built(key, make)
+    outs, fetch, _ = launch.run_program(outputs, args, dag.program_operands(), first_call=not hit)
     return outs, fetch
 
 
@@ -363,6 +365,7 @@ def run_exchange_join_agg(
     mesh,
     group_capacity: int = 1024,
     scale: int = 1,
+    programs=None,
 ):
     """Execute scan [sel] (JOIN(scan [sel]) [sel])+ GROUP BY over the mesh
     as ONE shard_map program; returns (chunk, overflow flag). Output layout
@@ -387,6 +390,6 @@ def run_exchange_join_agg(
     outs, fetch = run_exchange_program(
         "mpp_exchange_join_agg", dag, mesh,
         lambda: exchange_join_program(dag, mesh, group_capacity=group_capacity, scale=scale),
-        (group_capacity, scale), (stacked_probe, *stacked_builds))
+        (group_capacity, scale), (stacked_probe, *stacked_builds), programs)
     # decode via the shared seam (parallel/mesh.py) — same layout as grouped
     return decode_group_mesh_outputs(outs, fetch, agg)

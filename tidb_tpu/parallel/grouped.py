@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..chunk.device import DeviceBatch
-from ..exec.dag import Aggregation, DAGRequest, Selection
+from ..exec.dag import Aggregation, DAGRequest, Selection, operand_lanes
 from ..expr.compile import CompVal, ExprCompiler, normalize_device_column
 from ..ops import apply_selection, group_aggregate
 from ..ops.aggregate import GatherState, finalize_agg
@@ -63,13 +63,15 @@ def _materialize_gather(desc, arg_vals, st: GatherState, final: bool = False):
     return [(val, null)]
 
 
-def agg_exchange_phases(agg, schema_fts, cvals, valid, n_parts: int, group_capacity: int, bcap: int, extra_overflow=None):
+def agg_exchange_phases(agg, schema_fts, cvals, valid, n_parts: int, group_capacity: int, bcap: int, extra_overflow=None,
+                        params: dict | None = None):
     """The MPP partial/exchange/final pipeline given the pre-agg schema —
     phases 1-3 of the module docstring. Called inside shard_map by both the
     scan+sel path (run_sharded_grouped_agg) and the hash-shuffle join path
-    (mpp/exchange_op.py run_exchange_join_agg). Returns the flat output tuple
+    (mpp/exchange_op.py run_exchange_join_agg); `params` are the program's
+    operands by lane (`ExprCompiler`). Returns the flat output tuple
     [group_valid, (value, null)*, overflow]."""
-    comp = ExprCompiler(schema_fts)
+    comp = ExprCompiler(schema_fts, params)
     gvals = comp.run(list(agg.group_by), cvals)
     arg_exprs = [a for d in agg.aggs for a in d.args]
     avals = comp.run(arg_exprs, cvals) if arg_exprs else []
@@ -213,6 +215,7 @@ def run_sharded_grouped_agg(
     mesh,
     group_capacity: int = 1024,
     bucket_cap: int | None = None,
+    programs=None,
 ):
     """Execute TableScan [Selection] Aggregation(group_by) over a
     region-sharded mesh; returns (chunk, overflow flag).
@@ -220,26 +223,29 @@ def run_sharded_grouped_agg(
     The Aggregation node is taken as the LOGICAL (Complete-mode) shape; the
     partial/final split happens inside. Output chunk layout matches the
     single-chip executor: [agg results..., group keys...]."""
-    executors = dag.executors
-    agg = executors[-1]
+    agg = dag.executors[-1]
     assert isinstance(agg, Aggregation) and agg.group_by, "grouped mesh agg needs GROUP BY"
     if any(d.name == "group_concat" for d in agg.aggs):
         raise NotImplementedError("group_concat on mesh (root-only, oracle-evaluated)")
     input_fts = [c.ft for c in dag.scan().columns]
     n_parts = mesh.devices.size
     bcap = bucket_cap or group_capacity
+    # the traced DAG is the plan's shape; its constants are operands
+    shape, _key, operands = dag.parameterized()
+    lanes = operand_lanes(operands)
 
-    def device_fn(local: DeviceBatch):
+    def device_fn(local: DeviceBatch, *ops):
+        params = dict(zip(lanes, ops))
         cols, valid = _flatten_local(local)
         cvals = [normalize_device_column(c) for c in cols]
-        for ex in executors[1:-1]:
-            comp = ExprCompiler(input_fts)
+        for ex in shape.executors[1:-1]:
             if isinstance(ex, Selection):
-                conds = comp.run(list(ex.conditions), cvals)
+                conds = ExprCompiler(input_fts, params).run(list(ex.conditions), cvals)
                 valid = apply_selection(valid, conds)
             else:
                 raise TypeError(f"mesh pipeline supports scan+selection+agg, got {ex}")
-        return agg_exchange_phases(agg, input_fts, cvals, valid, n_parts, group_capacity, bcap)
+        return agg_exchange_phases(shape.executors[-1], input_fts, cvals, valid, n_parts, group_capacity, bcap,
+                                   params=params)
 
     spec_batch = jax.tree.map(lambda _: P(REGION_AXIS), stacked)
     from ..mpp.exchange_op import run_exchange_program
@@ -247,9 +253,9 @@ def run_sharded_grouped_agg(
 
     outs, fetch = run_exchange_program(
         "mesh_exchange_group_agg", dag, mesh,
-        lambda: jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_batch,),
+        lambda: jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_batch,) + (P(),) * len(lanes),
                               out_specs=group_mesh_out_spec(agg), check_vma=False),
-        (group_capacity, bcap), (stacked,))
+        (group_capacity, bcap), (stacked,), programs)
     # decode: [agg results..., group keys...] with Complete-mode fts —
     # the shared seam (mesh.py) both grouped paths use
     return decode_group_mesh_outputs(outs, fetch, agg)

@@ -1218,6 +1218,8 @@ class Session:
             return self._admin(stmt)
         if isinstance(stmt, A.AnalyzeTableStmt):
             return self._analyze(stmt)
+        if isinstance(stmt, A.SplitTableStmt):
+            return self._split_table(stmt)
         if isinstance(stmt, A.ShowStmt):
             return self._show(stmt)
         if isinstance(stmt, A.ExplainStmt):
@@ -2045,85 +2047,44 @@ class Session:
                     # evaluate the whole plan with the row-at-a-time oracle
                     chunk = self._select_via_oracle(plan, ranges, aux, ts)
                 else:
-                    chunk = None
-                    engines = self._read_engines()
-
-                    def _columnar_routed():
-                        # engine routing (ISSUE 12): when the columnar
-                        # replica is this plan's engine, the statement
-                        # tier must not preempt it — the consult itself
-                        # lives in execute_root. A thunk, so the
-                        # eligibility walk only runs when an mpp attempt
-                        # is actually on the table
-                        from ..columnar.route import columnar_would_serve
-
-                        return columnar_would_serve(
-                            self.store, plan.dag, ranges, engines)
-
-                    if self._explain_sink is None:
-                        # EXPLAIN ANALYZE wants per-executor summaries,
-                        # which only the per-region path produces.
-                        # Statement tier (ref: mpp_gather.go:40): "mpp"
-                        # plans exchange-linked fragments through the
-                        # dispatch layer, "root" defers to execute_root
-                        # (per-request tiers + columnar); a declined mpp
-                        # attempt (counted fallback) lands there too
-                        from ..distsql.planner import choose_statement_tier
-
-                        decision = choose_statement_tier(
-                            plan.dag,
-                            allow_mpp=self.sysvars.get_bool("tidb_allow_mpp"),
-                            allow_mesh=self.sysvars.get_bool("tidb_enable_tpu_mesh"),
-                            columnar_routed=_columnar_routed,
+                    kwargs = dict(
+                        start_ts=ts,
+                        aux_chunks=aux,
+                        group_capacity=self.sysvars.get_int("tidb_tpu_group_capacity"),
+                        small_groups=plan.small_groups,
+                        concurrency=self.sysvars.get_int("tidb_distsql_scan_concurrency"),
+                        paging_size=(
+                            self.sysvars.get_int("tidb_max_chunk_size")
+                            if self.sysvars.get_bool("tidb_enable_paging")
+                            else None
+                        ),
+                        batch_cop=self.sysvars.get_bool("tidb_allow_batch_cop"),
+                        mesh=self.sysvars.get_bool("tidb_enable_tpu_mesh"),
+                        mesh_min_rows=self.sysvars.get_int("tidb_tpu_mesh_min_rows"),
+                        summary_sink=self._explain_sink,
+                        checker=self._runaway_checker(),
+                        backoff_weight=self.sysvars.get_int("tidb_backoff_weight"),
+                        replica_read=self.sysvars.get("tidb_replica_read"),
+                        isolation_engines=self._read_engines(),
+                        # the statement tier (mpp) is execute_root's to try first
+                        allow_mpp=self.sysvars.get_bool("tidb_allow_mpp"),
+                    )
+                    try:
+                        chunk = execute_root(
+                            self.store, plan.dag, ranges, tracker=tracker, **kwargs
                         )
-                        if decision.tier == "mpp":
-                            from ..mpp.dispatch import try_mpp_select
+                    except QuotaExceeded:
+                        # degrade: sequential dispatch + incremental
+                        # Partial2 fold keeps the working set bounded
+                        # (the spill analog; VERDICT r2 next #10)
+                        from ..util import metrics
 
-                            chunk = try_mpp_select(
-                                self.store, plan.dag, ranges, ts,
-                                group_capacity=self.sysvars.get_int("tidb_tpu_group_capacity"),
-                                aux_chunks=aux,
-                                engines=engines,
-                                backoff_weight=self.sysvars.get_int("tidb_backoff_weight"),
-                                checker=self._runaway_checker(),
-                            )
-                    if chunk is None:
-                        kwargs = dict(
-                            start_ts=ts,
-                            aux_chunks=aux,
-                            group_capacity=self.sysvars.get_int("tidb_tpu_group_capacity"),
-                            small_groups=plan.small_groups,
-                            concurrency=self.sysvars.get_int("tidb_distsql_scan_concurrency"),
-                            paging_size=(
-                                self.sysvars.get_int("tidb_max_chunk_size")
-                                if self.sysvars.get_bool("tidb_enable_paging")
-                                else None
-                            ),
-                            batch_cop=self.sysvars.get_bool("tidb_allow_batch_cop"),
-                            mesh=self.sysvars.get_bool("tidb_enable_tpu_mesh"),
-                            mesh_min_rows=self.sysvars.get_int("tidb_tpu_mesh_min_rows"),
-                            summary_sink=self._explain_sink,
-                            checker=self._runaway_checker(),
-                            backoff_weight=self.sysvars.get_int("tidb_backoff_weight"),
-                            replica_read=self.sysvars.get("tidb_replica_read"),
-                            isolation_engines=engines,
+                        metrics.MEM_DEGRADED_QUERIES.inc()
+                        tracker.release_all()
+                        chunk = execute_root(
+                            self.store, plan.dag, ranges,
+                            tracker=tracker, low_memory=True, **kwargs
                         )
-                        try:
-                            chunk = execute_root(
-                                self.store, plan.dag, ranges, tracker=tracker, **kwargs
-                            )
-                        except QuotaExceeded:
-                            # degrade: sequential dispatch + incremental
-                            # Partial2 fold keeps the working set bounded
-                            # (the spill analog; VERDICT r2 next #10)
-                            from ..util import metrics
-
-                            metrics.MEM_DEGRADED_QUERIES.inc()
-                            tracker.release_all()
-                            chunk = execute_root(
-                                self.store, plan.dag, ranges,
-                                tracker=tracker, low_memory=True, **kwargs
-                            )
             tracker.consume(chunk.nbytes())
         except QuotaExceeded as exc:
             raise SQLError(str(exc)) from exc
@@ -3172,6 +3133,67 @@ class Session:
             self.catalog.stats[meta.table_id] = tstats
             meta.row_count = len(rows)  # ANALYZE also repairs the stat
         return Result()
+
+    def _split_table(self, stmt: A.SplitTableStmt) -> Result:
+        """SPLIT TABLE t BETWEEN (lo) AND (hi) REGIONS n | BY (h), (h), ...:
+        cut the table's record keys into regions at integer row handles
+        (ref: pkg/executor/split.go SplitTableRegionExec; the even step of
+        getSplitTableKeys). Each new boundary bumps both sides' epochs
+        (`Cluster.split`), so whatever the store kept of the old region
+        (decoded chunks, device batches, cop results) misses once. Answers
+        upstream's row: the regions newly cut, and the scatter ratio, 1.0
+        because a child stays on its parent's store."""
+        from ..util import metrics, tracing
+
+        self._implicit_commit()
+        try:
+            meta = self.catalog.table(stmt.table.name)
+        except CatalogError as exc:
+            raise SQLError(str(exc)) from exc
+        if stmt.index:
+            raise SQLError("SPLIT TABLE ... INDEX is not supported yet")
+
+        def handle(row) -> int:
+            if len(row) != 1:
+                raise SQLError("SPLIT TABLE takes one integer row handle a point")
+            d = self._eval_const(row[0], new_longlong())
+            if d.is_null():
+                raise SQLError("SPLIT TABLE: a split point cannot be NULL")
+            return int(d.val)
+
+        if stmt.between is not None:
+            lo, hi, n = handle(stmt.between[0]), handle(stmt.between[1]), int(stmt.between[2])
+            if n < 1 or n > 1000:
+                raise SQLError(f"SPLIT TABLE: the region count has to be in [1, 1000], got {n}")
+            if lo >= hi:
+                raise SQLError(f"SPLIT TABLE: lower value {lo} has to be less than upper value {hi}")
+            step = (hi - lo) // n
+            if step < 1:
+                raise SQLError(f"SPLIT TABLE: [{lo}, {hi}) is too small a range for {n} regions")
+            points = [lo + step * i for i in range(1, n)]
+        elif stmt.by_points:
+            points = sorted({handle(row) for row in stmt.by_points})
+        else:
+            raise SQLError("SPLIT TABLE needs BETWEEN .. AND .. REGIONS n or BY (..)")
+
+        cluster = self.store.cluster
+        with tracing.span("ddl.split_table", table=meta.name) as sp:
+            known = {r.region_id for r in cluster.regions()}
+            new = 0
+            for pid in meta.physical_ids():
+                # a table's first split also cuts it off from what lies
+                # before its records (ref: split.go splitting at the
+                # record prefix first), so no region straddles two tables
+                for key in [full_table_ranges(pid)[0].start] + [tablecodec.encode_row_key(pid, h) for h in points]:
+                    region = cluster.split(key)
+                    if region.region_id not in known:
+                        known.add(region.region_id)
+                        new += 1
+            if sp is not None:
+                sp.set("regions", new)
+        metrics.SPLIT_TABLE_REGIONS.inc(new)
+        return Result(columns=["TOTAL_SPLIT_REGION", "SCATTER_FINISH_RATIO"],
+                      rows=[[Datum.i64(new), Datum.f64(1.0)]])
 
     # ------------------------------------------------------------------
     def _session_tracker(self):

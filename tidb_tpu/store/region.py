@@ -359,11 +359,6 @@ class Cluster:
                 self.cdc.on_merge(r.region_id, right.region_id)
             return r
 
-    def split_n(self, start: bytes, end: bytes, n: int, keyfn):
-        """Split [start, end) into n regions using keyfn(i) boundaries."""
-        for i in range(1, n):
-            self.split(keyfn(i))
-
     def _locate(self, key: bytes) -> int:  # requires: _mu
         starts = [r.start_key for r in self._regions]
         i = bisect.bisect_right(starts, key) - 1

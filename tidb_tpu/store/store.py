@@ -1196,13 +1196,16 @@ class TPUStore:
         Returns True when every lane was answered; False degrades the
         whole group to the vmapped batch tier (ineligible DAG, too few
         rows, overflow, or any trace/launch failure) — which owns the
-        per-lane capacity ladder and the oracle fallback."""
+        per-lane capacity ladder and the oracle fallback. With
+        `cop-debug-raise` armed a decode, trace or launch failure raises
+        instead, as on the single-request path: the size, skew and
+        overflow declines stay counted declines."""
         import jax
 
         from ..distsql.planner import mesh_merge_kind
         from ..exec.dag import executor_walk
         from ..exec.executor import drive_mesh_program_info
-        from ..util import metrics, tracing
+        from ..util import failpoint, metrics, tracing
 
         req0 = entries[0][1]
         dag = req0.dag
@@ -1220,6 +1223,8 @@ class TPUStore:
                     dsp.set("bytes_to_device", sum(ch.nbytes() for ch in chunks))
                 aux_batches = [self._aux_batch(c) for c in req0.aux_chunks]
         except Exception:  # noqa: BLE001 — degrade, never lose the group
+            if failpoint.eval("cop-debug-raise"):
+                raise
             return False
         floor = max(self.MESH_MIN_GROUP_ROWS, req0.mesh_min_rows)
         if sum(ch.num_rows() for ch in chunks) < floor:
@@ -1246,7 +1251,13 @@ class TPUStore:
         try:
             with tracing.span("cop.mesh_execute", regions=len(entries),
                               devices=D, kind=kind) as xsp:
-                stacked = to_stacked_device_batch(lanes, cap)
+                # the lanes are stacked on the host and uploaded anew for
+                # every statement (the decoded chunks are kept, the
+                # sharded batch made of them is not)
+                with tracing.span("mesh.stack", lanes=R_pad, devices=D,
+                                  rows=sum(ch.num_rows() for ch in chunks),
+                                  bytes=sum(ch.nbytes() for ch in chunks)):
+                    stacked = to_stacked_device_batch(lanes, cap)
                 merged, lane_counts, info = drive_mesh_program_info(
                     self.programs, dag, stacked, aux_batches, group_capacity,
                     kind, D, small_groups=req0.small_groups,
@@ -1254,6 +1265,8 @@ class TPUStore:
                 if xsp is not None:
                     xsp.set("cache_hit", info["cache_hit"])
         except Exception:  # noqa: BLE001 — degrade, never lose the group
+            if failpoint.eval("cop-debug-raise"):
+                raise
             metrics.MESH_COP_FALLBACKS.inc()
             return False
         if merged is None:

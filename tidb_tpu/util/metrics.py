@@ -298,6 +298,7 @@ DISTSQL_TASK_DURATION = REGISTRY.histogram_vec(
     "tidb_tpu_distsql_task_duration_seconds", "per-region cop task latency incl. paging+retries",
     labelnames=("scan",),
 )
+SPLIT_TABLE_REGIONS = REGISTRY.counter("tidb_tpu_split_table_regions_total", "regions newly cut by SPLIT TABLE statements")
 MESH_SELECTS = REGISTRY.counter("tidb_tpu_mesh_selects_total", "SQL plans executed over the device mesh")
 MESH_COP_BATCHES = REGISTRY.counter("tidb_tpu_mesh_cop_batches_total", "shard_map mesh-tier launches (one merged state per launch)")
 MESH_COP_LANES = REGISTRY.counter("tidb_tpu_mesh_cop_lanes_total", "region lanes whose partial states were psum-merged on device")

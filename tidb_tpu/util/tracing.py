@@ -46,7 +46,7 @@ from jax.profiler import TraceAnnotation
 HOST_STATES = frozenset({
     "session.parse", "session.plan_cache", "planner.plan", "cop.decode",
     "exec.compile", "exec.launch", "exec.wait", "exec.readback",
-    "distsql.root_merge", "server.write", "columnar.gate",
+    "distsql.root_merge", "server.write", "columnar.gate", "mesh.stack",
 })
 
 _current: contextvars.ContextVar = contextvars.ContextVar("tidb_tpu_span", default=None)
