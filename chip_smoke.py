@@ -226,11 +226,13 @@ def q3_other_parameters(ctx, mesh: bool = False) -> None:
     first execution built: the literals are its operands, strings too.
     Fails where `PROGRAM_COMPILES` or XLA's compile count moves; on one
     chip also where the row store decodes or uploads `lineitem` again,
-    on the mesh where the draw is not one cross-chip launch."""
+    on the mesh where the draw is not one cross-chip launch over lanes
+    found resident (the store keeps the stacked, sharded batch by data
+    version since PR 35)."""
     from tidb_tpu.util import metrics
 
     names = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_PARAMS_BOUND", "PROGRAM_STR_PARAMS_BOUND", "PROGRAM_LAUNCHES") + (
-        ("MESH_COP_BATCHES", "MPP_SELECTS", "MESH_COP_FALLBACKS", "MPP_FALLBACKS") if mesh
+        ("MESH_COP_BATCHES", "MPP_SELECTS", "MESH_COP_FALLBACKS", "MPP_FALLBACKS", "MESH_STACK_HITS", "MESH_STACK_MISSES") if mesh
         else ("COP_AUX_UPLOADS", "COP_CACHE_HITS", "COP_DECODE_HITS", "COP_DECODE_MISSES"))
     for params in Q3_DRAWS:
         before = {n: getattr(metrics, n).value for n in names}
@@ -246,6 +248,8 @@ def q3_other_parameters(ctx, mesh: bool = False) -> None:
         if mesh:
             assert moved["mesh_cop_batches"] + moved["mpp_selects"] == 1, (params, moved)
             assert moved["mesh_cop_fallbacks"] == moved["mpp_fallbacks"] == 0, (params, moved)
+            assert (moved["mesh_stack_hits"], moved["mesh_stack_misses"]) == (1, 0), (
+                f"q3 {params}: the lanes were stacked and put onto the devices again: {moved}")
         else:
             assert (moved["cop_decode_hits"], moved["cop_decode_misses"]) == (1, 0), (
                 f"q3 {params}: lineitem was decoded or uploaded again: {moved}")
@@ -404,7 +408,8 @@ def phase_columnar(ctx: Ctx) -> None:
 
 def phase_mesh(ctx: Ctx, n_devices: int) -> None:
     """Q6, Q1 and Q3 with the defaults — mesh and MPP tiers on — and again
-    with both off, which is what they are compared with.  Both cross-chip
+    with both off, which is what they are compared with; on the mesh each a
+    second time, which must find its lanes resident.  Both cross-chip
     tiers degrade in silence, so a right answer proves nothing alone: the
     counters, the devices and the collectives are asserted too."""
     from tidb_tpu.util import metrics as m
@@ -442,6 +447,12 @@ def phase_mesh(ctx: Ctx, n_devices: int) -> None:
                 for r in progs:
                     assert r["collectives"] and r["devices"] == n_devices, (name, r)
                 assert progs or moved["MPP_SELECTS"], f"{name}: no mesh program was launched"
+                # the shape's second draw finds the lanes stacked and sharded on the devices
+                stack0 = (m.MESH_STACK_HITS.value, m.MESH_STACK_MISSES.value)
+                check(c.query(sql)[1], data)
+                stack = (m.MESH_STACK_HITS.value - stack0[0], m.MESH_STACK_MISSES.value - stack0[1])
+                assert stack == (1, 0), f"{name}: the second draw stacked its lanes again: hits, misses = {stack}"
+                result["second_draw_stack"] = {"hits": stack[0], "misses": stack[1]}
             else:
                 assert not any(moved.values()) and not progs, (name, moved, progs)
             emit(stmt=f"{mode}_{name}", tiers=moved, mesh_programs=progs, **line, **result)

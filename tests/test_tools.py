@@ -322,8 +322,9 @@ def test_chip_smoke_mesh_phase_cpu_rehearsal(tmp_path, monkeypatch, capsys):
     """chip_smoke.py's `--chips 4` phase at 4,096 rows on the suite's eight
     host devices: `SPLIT TABLE` over the wire lays the regions out, Q6, Q1
     and Q3 are one cross-chip launch each with collectives in the program's
-    text and no fall-back, three more (SEGMENT, DATE) draws build
-    nothing, and the single-device run answers the same."""
+    text and no fall-back, each shape's second draw and three more
+    (SEGMENT, DATE) draws build nothing and find their lanes resident, and
+    the single-device run answers the same."""
     import sys
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -350,8 +351,10 @@ def test_chip_smoke_mesh_phase_cpu_rehearsal(tmp_path, monkeypatch, capsys):
     for name in ("q6", "q1", "q3"):
         on, off = stmts[f"mesh_{name}"], stmts[f"single_device_{name}"]
         assert on["tiers"]["MESH_COP_BATCHES"] + on["tiers"]["MPP_SELECTS"] == 1 and not any(off["tiers"].values())
+        assert on["second_draw_stack"] == {"hits": 1, "misses": 0} and "second_draw_stack" not in off
         assert on["rows"] == off["rows"]
         assert all(p["devices"] == 8 and p["collectives"] for p in on["mesh_programs"]) and on["mesh_programs"]
     draws = [x for x in lines if x.get("stmt") == "mesh_q3_params"]
     assert [(x["segment"], x["date"]) for x in draws] == [(p["segment"], p["date"]) for p in cs.Q3_DRAWS]
     assert all(x["program_compiles"] == x["xla_compiles"] == 0 and x["mesh_cop_batches"] == 1 for x in draws)
+    assert all((x["mesh_stack_hits"], x["mesh_stack_misses"]) == (1, 0) for x in draws)

@@ -38,6 +38,18 @@ def _json(path: str):
         return json.load(f)
 
 
+def forget_root_programs() -> None:
+    """The root's merges are built into the one cache that every store of a
+    process shares (`exec.executor.DEFAULT_PROGRAM_CACHE`): a file that ran
+    the same statement on this worker before has left its merge program and
+    its input rung there.  A module that counts what its first executions
+    build empties that cache first, as a fresh server's is."""
+    from tidb_tpu.exec.executor import DEFAULT_PROGRAM_CACHE
+
+    DEFAULT_PROGRAM_CACHE._cache.clear()
+    DEFAULT_PROGRAM_CACHE._input_rungs.clear()
+
+
 class Served:
     """One seed's deployment, loaded and replicated, behind a wire client."""
 

@@ -10,15 +10,19 @@ ORDER BY or have no GROUP BY, so the statement tier leaves them to the
 per-request mesh tier).  Compared exactly with the numpy reference and with
 the same statements under both sysvars OFF; one program a statement shape
 whatever the draw; no fall-back; `cop-debug-raise` reaches the tier; the
-spans a traced run is reduced by.  The benchmark's cell `tpch_q1q6q3_mesh4`
+spans a traced run is reduced by; the stacked lanes and Q3's build sides stay
+on the devices from one statement to the next (PR 35).  The benchmark's cell `tpch_q1q6q3_mesh4`
 makes the same comparison on four chips at 131,072 rows; here it is 4,096."""
 
 import json
 import os
 
+import jax
+import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec
 
-from test_tpch_columnar_reference import BENCH, _json, _load
+from test_tpch_columnar_reference import BENCH, _json, _load, forget_root_programs
 
 from tidb_tpu.server import MiniClient, MySQLServer
 from tidb_tpu.util import failpoint, metrics
@@ -34,7 +38,8 @@ DRAWS = {
         ("BUILDING", "1995-03-15"), ("MACHINERY", "1995-03-01"), ("AUTOMOBILE", "1995-03-31"), ("HOUSEHOLD", "1995-03-09"))],
 }
 NAMES = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_LAUNCHES", "MESH_COP_BATCHES", "MESH_COP_LANES", "MPP_SELECTS",
-         "MESH_COP_FALLBACKS", "MPP_FALLBACKS", "COP_FALLBACKS")
+         "MESH_COP_FALLBACKS", "MPP_FALLBACKS", "COP_FALLBACKS", "MESH_STACK_HITS", "MESH_STACK_MISSES", "COP_AUX_UPLOADS",
+         "COP_DECODE_DEVICE_BYTES", "COP_DECODE_HITS", "COP_DECODE_MISSES")
 
 
 class Served:
@@ -50,6 +55,7 @@ class Served:
         self.lines = []
         self.dep.load(self.conn, self.data, self.config, lambda **line: self.lines.append(line))
         self.conn.query(f"set tidb_isolation_read_engines = '{self.mix['read_engines']}'")
+        forget_root_programs()   # the first executions below are counted as a fresh server's
         self.cases = {(name, i): self.run(name, p) for name, draws in DRAWS.items() for i, p in enumerate(draws)}
         for var in ("tidb_enable_tpu_mesh", "tidb_allow_mpp"):
             self.conn.query(f"set {var} = OFF")
@@ -114,6 +120,50 @@ def test_the_first_draw_builds_the_programs_and_no_later_draw_builds_one(served,
         assert m["PROGRAM_LAUNCHES"] == 2, (name, i, m)   # the mesh program, the root's merge; Q3's build scans are cop results
 
 
+@pytest.mark.parametrize("name", list(DRAWS))
+def test_every_draw_after_the_first_finds_the_lanes_stacked_and_the_build_sides_uploaded(served, name):
+    """The three shapes read the same columns and ranges of `lineitem`, so
+    one resident batch serves them all: the module's first statement (Q1's
+    first draw) stacks it, every later one finds it on the devices."""
+    for i in range(4):
+        m = served.cases[name, i]["moved"]
+        first = (name, i) == ("q1", 0)
+        assert (m["MESH_STACK_HITS"], m["MESH_STACK_MISSES"]) == ((0, 1) if first else (1, 0)), (name, i, m)
+        assert (m["COP_DECODE_DEVICE_BYTES"] > 0) == (first or (name, i) == ("q3", 0)), (name, i, m)   # Q3's first: its build scans
+        assert m["COP_AUX_UPLOADS"] == (2 if (name, i) == ("q3", 0) else 0), (name, i, m)
+        assert m["COP_DECODE_HITS"] >= 8 or first, (name, i, m)      # the lanes' chunks, still looked up for read flow
+        off = served.single[name, i]["moved"]
+        assert off["MESH_STACK_HITS"] == off["MESH_STACK_MISSES"] == 0, off
+
+
+def test_the_resident_batch_is_sharded_over_the_region_axis_and_the_build_sides_are_replicated(served):
+    store = served.srv.store
+    with store._cop_lock:
+        stacks = [(k, v, cost) for k, (v, _ts, cost) in store._batch_cache._entries.items() if k[1][0] == "mesh.stack"]
+    ((key, batch, cost),) = stacks
+    _tag, cap, r_pad, devices, lanes = key[1]
+    assert (len(lanes), cap, r_pad, devices) == (8, 512, 8, 8) and cost == batch.nbytes()
+    assert served.cases["q1", 0]["moved"]["COP_DECODE_DEVICE_BYTES"] == batch.nbytes()     # once, not per statement
+    leaves = jax.tree_util.tree_leaves(batch)
+    assert len(leaves) == 16 * 2 + 5 + 2     # lineitem's sixteen columns, five of them strings, row_valid, n_rows
+    for leaf in leaves:
+        assert isinstance(leaf.sharding, NamedSharding) and leaf.sharding.spec == PartitionSpec("region")
+        assert leaf.sharding.mesh.devices.tolist() == jax.devices() and leaf.shape[0] == 8
+        assert [s.data.shape[0] for s in leaf.addressable_shards] == [1] * 8          # R_pad / D lanes a device
+    assert np.asarray(batch.n_rows).tolist() == [ROWS // 8] * 8
+    with store._aux_lock:
+        mesh_sides = {k: v for k, v in store._aux_batch_cache.items() if isinstance(k, tuple)}
+        merged = list(store._build_side_cache.values())
+    assert sorted(k[1] for k in mesh_sides) == [8, 8]                 # orders and customer, for a launch over eight devices
+    ((parts, orders),) = merged                                       # orders' four region chunks, concatenated once
+    assert len(parts) == 4 and orders.num_rows() == len(served.data["orders"]["orderkey"])
+    assert any(chunk is orders for chunk, _b in mesh_sides.values())
+    for _chunk, side in mesh_sides.values():
+        for leaf in jax.tree_util.tree_leaves(side):
+            assert isinstance(leaf.sharding, NamedSharding) and leaf.sharding.is_fully_replicated
+            assert leaf.devices() == set(jax.devices())
+
+
 def test_a_traced_q3_lays_the_mesh_tier_under_the_dispatch_span(served):
     got = served.run("q3", {"segment": "FURNITURE", "date": "1995-03-20"}, trace=True)
     tree = json.loads(got["rows"][0][0])
@@ -121,7 +171,11 @@ def test_a_traced_q3_lays_the_mesh_tier_under_the_dispatch_span(served):
     (probe,) = [r for r in roots if find(r, "cop.mesh_execute")]
     (stack,) = find(probe, "mesh.stack")
     assert stack["attrs"]["lanes"] == 8 and stack["attrs"]["devices"] == 8
-    assert stack["attrs"]["rows"] == ROWS and stack["attrs"]["bytes"] > 0
+    assert stack["attrs"]["rows"] == ROWS and stack["attrs"]["bytes"] > 0 and stack["attrs"]["hit"] is True
+    orders, customer = len(served.data["orders"]["orderkey"]), len(served.data["customer"]["custkey"])
+    # `orders` comes in four regions: their concatenation is the object that was uploaded
+    assert [(a["attrs"]["rows"], a["attrs"]["hit"]) for a in find(probe, "cop.aux_batch")] == [(orders, True), (customer, True)]
+    assert (got["moved"]["MESH_STACK_HITS"], got["moved"]["MESH_STACK_MISSES"], got["moved"]["COP_AUX_UPLOADS"]) == (1, 0, 0)
     (execute,) = find(probe, "cop.mesh_execute")
     assert find(execute, "mesh.stack") == [stack] and find(execute, "exec.launch") and not find(tree, "exec.compile")
     assert [n["attrs"]["program"] for n in find(execute, "exec.launch")] == ["cop_scan_sel_join_join_groupagg_m8x8"]
@@ -138,6 +192,7 @@ def test_cop_debug_raise_reaches_the_mesh_tier(served, monkeypatch):
     def broken(*_a, **_k):
         raise RuntimeError("injected mesh launch failure")
 
+    served.srv.store.evict_caches()     # the lanes are resident by now, and a hit stacks nothing
     monkeypatch.setattr(store_mod, "to_stacked_device_batch", broken)
     p = {"date": "1996-01-01", "discount": "0.03", "quantity": 24}
     got = served.run("q6", p)
@@ -159,4 +214,50 @@ def test_cop_debug_raise_reaches_the_mesh_tier(served, monkeypatch):
         assert m["MESH_COP_BATCHES"] == 0 and m["COP_FALLBACKS"] == 0
     finally:
         failpoint.disable("cop-debug-raise")
-        served.conn.query("set tidb_tpu_mesh_min_rows = default")
+        served.conn.query("set tidb_tpu_mesh_min_rows = 0")   # `= default` leaves the value where it is
+
+
+def test_an_insert_into_lineitem_makes_the_next_statement_miss_once_and_the_answer_follows_the_data(served):
+    """Last in the file: it writes to `lineitem` (and takes the row out
+    again).  The write drops the stacked batch with the other version
+    caches; the next Q3 stacks the lanes of the new data and
+    files them, the one after finds them."""
+    p = {"segment": "BUILDING", "date": "1995-03-15"}
+    want = served.dep.reference("q3", p, served.data)
+    warm = served.run("q3", p)
+    assert served.dep.mismatch("q3", want, warm["rows"]) is None
+    lead = max(want, key=lambda k: want[k][0])
+    o, l = served.data["orders"], served.data["lineitem"]
+    line = {"oidx": int(np.flatnonzero(o["orderkey"] == lead)[0]), "extendedprice": 9_999_999_00, "discount": 5,
+            "shipdate": int((np.datetime64("1998-01-01") - served.dep.EPOCH).astype(int))}
+    served.conn.query(
+        f"insert into lineitem values ({lead}, 1, 1, 8, 1.00, 9999999.00, 0.05, 0.00, 'N', 'O', "
+        "'1998-01-01', '1998-01-01', '1998-01-02', 'NONE', 'AIR', 'the mesh tier keeps what it stacked')")
+    try:
+        grown = dict(served.data, lineitem={k: np.append(v, line[k]) if k in line else v for k, v in l.items()})
+        want_new = served.dep.reference("q3", p, grown)
+        assert want_new[lead][0] > want[lead][0]
+        first, traced, third = served.run("q3", p), served.run("q3", p, trace=True), served.run("q3", dict(p, date="1995-03-16"))
+        for got in (first, third):
+            assert got["moved"]["MESH_COP_BATCHES"] == 1 and got["moved"]["MESH_COP_FALLBACKS"] == 0, got["moved"]
+        assert (first["moved"]["MESH_STACK_HITS"], first["moved"]["MESH_STACK_MISSES"]) == (0, 1)
+        assert first["moved"]["COP_AUX_UPLOADS"] == 2            # the write dropped the build scans' cop results too
+        assert served.dep.mismatch("q3", want_new, first["rows"]) is None
+        assert served.dep.mismatch("q3", want, first["rows"]) is not None
+        (stack,) = find(json.loads(traced["rows"][0][0]), "mesh.stack")
+        assert stack["attrs"]["hit"] is True and stack["attrs"]["rows"] == ROWS + 1
+        for got in (traced, third):
+            m = got["moved"]
+            assert (m["MESH_STACK_HITS"], m["MESH_STACK_MISSES"], m["COP_AUX_UPLOADS"]) == (1, 0, 0), m
+        assert served.dep.mismatch(
+            "q3", served.dep.reference("q3", dict(p, date="1995-03-16"), grown), third["rows"]) is None
+        for var in ("tidb_enable_tpu_mesh", "tidb_allow_mpp"):
+            served.conn.query(f"set {var} = OFF")
+        assert served.run("q3", p)["rows"] == first["rows"]
+        for var in ("tidb_enable_tpu_mesh", "tidb_allow_mpp"):
+            served.conn.query(f"set {var} = ON")
+    finally:
+        served.conn.query(f"delete from lineitem where l_orderkey = {lead} and l_linenumber = 8")
+    back = served.run("q3", p)
+    assert served.dep.mismatch("q3", want, back["rows"]) is None
+    assert (back["moved"]["MESH_STACK_HITS"], back["moved"]["MESH_STACK_MISSES"]) == (0, 1)

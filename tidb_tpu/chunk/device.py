@@ -120,25 +120,27 @@ def host_column_arrays(col: Column, capacity: int, str_width: int | None = None)
     return data, null, _pad(lens, capacity)
 
 
-def to_device_batch(chunk: Chunk, capacity: int | None = None, str_widths: dict[int, int] | None = None) -> DeviceBatch:
+def _put(host: "DeviceBatch", sharding) -> "DeviceBatch":
+    """A batch of host arrays onto the device: leaf by leaf onto the default
+    device, or, given a `sharding`, the whole tree in one `jax.device_put`,
+    each device receiving its own part from the host."""
+    if sharding is None:
+        return jax.tree.map(jnp.asarray, host)
+    return jax.device_put(host, sharding)
+
+
+def to_device_batch(chunk: Chunk, capacity: int | None = None, str_widths: dict[int, int] | None = None,
+                    sharding=None) -> DeviceBatch:
     n = chunk.num_rows()
     cap = capacity or max(1, n)
     cols = []
     for ci, col in enumerate(chunk.columns):
         _check_ci_ascii(col)
         w = (str_widths or {}).get(ci)
-        data, null, length = host_column_arrays(col, cap, w)
-        cols.append(
-            DeviceColumn(
-                jnp.asarray(data),
-                jnp.asarray(null),
-                jnp.asarray(length) if length is not None else None,
-                col.ft,
-            )
-        )
+        cols.append(DeviceColumn(*host_column_arrays(col, cap, w), col.ft))
     row_valid = np.zeros(cap, bool)
     row_valid[:n] = True
-    return DeviceBatch(cols, jnp.asarray(row_valid), jnp.int32(n))
+    return _put(DeviceBatch(cols, row_valid, np.int32(n)), sharding)
 
 
 def shared_str_widths(chunks: list[Chunk]) -> dict[int, int]:
@@ -171,7 +173,7 @@ def _check_ci_ascii(col: Column) -> None:
             )
 
 
-def to_stacked_device_batch(chunks: list[Chunk], capacity: int) -> DeviceBatch:
+def to_stacked_device_batch(chunks: list[Chunk], capacity: int, sharding=None) -> DeviceBatch:
     """Stack same-schema chunks into ONE region-batched DeviceBatch whose
     every leaf carries a leading region axis: data [B, cap, ...], null/
     row_valid [B, cap], n_rows [B]. This is the input shape of the vmapped
@@ -181,7 +183,9 @@ def to_stacked_device_batch(chunks: list[Chunk], capacity: int) -> DeviceBatch:
 
     All chunks must share a schema; varlen columns are padded to the
     batch-wide max width (shared_str_widths). Stacking happens host-side so
-    the whole batch ships to HBM in one transfer per column."""
+    the whole batch ships to HBM in one transfer per column, or, given a
+    `sharding` (the mesh tier's, over the region axis), in one put that
+    hands each device its own lanes."""
     assert chunks, "cannot stack an empty region batch"
     widths = shared_str_widths(chunks)
     n_cols = chunks[0].num_cols()
@@ -197,19 +201,12 @@ def to_stacked_device_batch(chunks: list[Chunk], capacity: int) -> DeviceBatch:
             lengths.append(length)
         ft = chunks[0].columns[ci].ft
         has_len = lengths[0] is not None
-        cols.append(
-            DeviceColumn(
-                jnp.asarray(np.stack(datas)),
-                jnp.asarray(np.stack(nulls)),
-                jnp.asarray(np.stack(lengths)) if has_len else None,
-                ft,
-            )
-        )
+        cols.append(DeviceColumn(np.stack(datas), np.stack(nulls), np.stack(lengths) if has_len else None, ft))
     row_valid = np.zeros((len(chunks), capacity), bool)
     for b, ch in enumerate(chunks):
         row_valid[b, : ch.num_rows()] = True
     n_rows = np.array([ch.num_rows() for ch in chunks], np.int32)
-    return DeviceBatch(cols, jnp.asarray(row_valid), jnp.asarray(n_rows))
+    return _put(DeviceBatch(cols, row_valid, n_rows), sharding)
 
 
 def pack_string_words(data: jax.Array, length: jax.Array, n_words: int = STRING_WORDS) -> jax.Array:

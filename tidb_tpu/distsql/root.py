@@ -181,6 +181,7 @@ def execute_root(
     mesh_min_rows: int = 0,
     isolation_engines: tuple = ("tpu",),
     allow_mpp: bool = False,
+    build_side: bool = False,
 ) -> Chunk:
     """Run a logical (Complete-mode) DAG over the store: split, dispatch the
     pushdown half per region, merge at root. The caller-visible result is
@@ -200,6 +201,12 @@ def execute_root(
     runs over the columnar replica's device-resident chunks at the same
     snapshot — no split, no per-region dispatch — with a typed-staleness
     fallback to the row store when the replica's frontier lags.
+
+    build_side: the result is a join's build side, a whole table. Where it
+    comes in several regions the store keeps their concatenation by the
+    parts' identity (`TPUStore.build_side`), so that the statements of a
+    data version, answered from the result cache, hand the same object to
+    the join program and find it uploaded.
 
     mesh (tidb_enable_tpu_mesh) lets the dispatch planner shard eligible
     partial-agg/TopN pushdowns over the device mesh and merge the partial
@@ -227,7 +234,7 @@ def execute_root(
                 store, dag, ranges, start_ts, aux_chunks, concurrency, cache,
                 group_capacity, paging_size, batch_cop, summary_sink, tracker,
                 low_memory, small_groups, checker, backoff_weight, replica_read,
-                mesh, mesh_min_rows, isolation_engines,
+                mesh, mesh_min_rows, isolation_engines, build_side,
             )
         if sp is not None:
             sp.set("rows", out.num_rows())
@@ -264,7 +271,7 @@ def _execute_root(
     group_capacity, paging_size, batch_cop, summary_sink, tracker,
     low_memory, small_groups, checker, backoff_weight=2,
     replica_read="leader", mesh=None, mesh_min_rows=0,
-    isolation_engines=("tpu",),
+    isolation_engines=("tpu",), build_side=False,
 ) -> Chunk:
     if "columnar" in isolation_engines:
         # engine routing (ISSUE 12): eligible analytical scans ride the
@@ -316,7 +323,7 @@ def _execute_root(
         for c in res.chunks:
             if c is not None:
                 tracker.consume(c.nbytes())
-    merged = res.merged()
+    merged = store.build_side(res.chunks) if build_side else res.merged()
     if merged is None:
         merged = Chunk.empty(plan.push_dag.output_fts())
     out = merged

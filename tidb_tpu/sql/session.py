@@ -2467,7 +2467,7 @@ class Session:
         scan = TableScan(meta.table_id, meta.scan_columns())
         dag = DAGRequest((scan,), output_offsets=tuple(range(len(meta.columns))))
         ranges = [r for pid in meta.physical_ids() for r in full_table_ranges(pid)]
-        return execute_root(self.store, dag, ranges, start_ts=ts)
+        return execute_root(self.store, dag, ranges, start_ts=ts, build_side=True)
 
     # ------------------------------------------------------------------
     def _eval_const(self, node: A.ExprNode, ft: FieldType) -> Datum:

@@ -285,7 +285,8 @@ class TPUStore:
         self._chunk_cache = _SnapshotCache(self.kv.max_committed)  # guarded_by: _cop_lock
         self._batch_cache = _SnapshotCache(self.kv.max_committed, metrics.COP_DECODE_DEVICE_BYTES)  # guarded_by: _cop_lock
         self._device_budget: int | None = None  # of _batch_cache; read off the device once
-        self._aux_batch_cache: dict = {}  # token -> (chunk, DeviceBatch); guarded_by: _aux_lock
+        self._aux_batch_cache: dict = {}  # token | (token, mesh devices) -> (chunk, DeviceBatch); guarded_by: _aux_lock
+        self._build_side_cache: dict = {}  # the parts' tokens -> (parts, their concatenation); guarded_by: _aux_lock
         self._aux_lock = threading.Lock()  # select() fans tasks over threads
         self._chunk_tokens = itertools.count(1)  # monotonic chunk identity; guarded_by: _aux_lock
         # coprocessor RESULT cache (ref: pkg/store/copr/coprocessor_cache.go):
@@ -373,6 +374,7 @@ class TPUStore:
         freed += sum(r.chunk.nbytes() for r, _flow in responses if r.chunk is not None)
         with self._aux_lock:  # select() uploads aux batches from pool threads
             self._aux_batch_cache.clear()
+            self._build_side_cache.clear()
         return freed
 
     def _drop_version_caches(self) -> tuple:  # requires: _cop_lock
@@ -627,13 +629,7 @@ class TPUStore:
         from ..util import metrics
 
         scan = dag.scan()
-        what = (
-            region.region_id,
-            region.epoch,
-            scan.table_id,
-            tuple(c.fingerprint() for c in scan.columns),
-            tuple((r.start, r.end) for r in ranges),
-        )
+        what = self._read_key(region, ranges, scan.table_id, tuple(c.fingerprint() for c in scan.columns))
         batch = None
         with self._cop_lock:
             ver = self._write_ver  # the pre-read snapshot: in the keys, and gates the filing
@@ -654,10 +650,19 @@ class TPUStore:
             self._file_decoded(ver, what, start_ts, decoded, uploaded)
         return ch, batch, False
 
+    @staticmethod
+    def _read_key(region: Region, ranges: list, table_id: int, columns: tuple) -> tuple:
+        """What a region read names its rows by in the decode caches'
+        keys, beside the store's write version; `columns` are the scan's,
+        each by its fingerprint."""
+        return (region.region_id, region.epoch, table_id, columns, tuple((r.start, r.end) for r in ranges))
+
     def _file_decoded(self, ver: int, what: tuple, start_ts: int, chunk: Chunk | None, batch: DeviceBatch | None) -> None:
         """File what a read at `start_ts` decoded and uploaded (either may
         be None) under the data version `ver` it started from, if the
-        snapshot rule allows, and count what leaves for the budgets."""
+        snapshot rule allows, and count what leaves for the budgets.
+        `what` is a region read's key or a mesh launch's (`_stacked_lanes`):
+        the device batches of both share one budget."""
         from ..util import metrics
 
         if batch is not None and self._device_budget is None:
@@ -810,9 +815,12 @@ class TPUStore:
                     chunk._device_token = tok
         return tok
 
-    def _aux_batch(self, chunk: Chunk) -> DeviceBatch:
+    def _aux_batch(self, chunk: Chunk, mesh_devices: int = 0) -> DeviceBatch:
         """Broadcast build-side chunk -> DeviceBatch, uploaded once per
-        chunk object (all region tasks of a join share the operand).
+        chunk object (all region tasks of a join share the operand). For a
+        mesh launch over `mesh_devices` the batch is an entry of its own,
+        replicated over the mesh's devices as the program's `in_specs`
+        read it, so the call replicates nothing.
 
         Bounded LRU keyed by the chunk token (never-reused identity); the
         entry pins the chunk so the device batch and its source live and
@@ -820,6 +828,14 @@ class TPUStore:
         from ..util import metrics, tracing
 
         key = self._chunk_token(chunk)
+        sharding = None
+        if mesh_devices:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            from ..parallel.mesh import region_mesh
+
+            key = (key, mesh_devices)
+            sharding = NamedSharding(region_mesh(mesh_devices), PartitionSpec())
         with tracing.span("cop.aux_batch", rows=chunk.num_rows()) as sp:
             with self._aux_lock:
                 cached = self._aux_batch_cache.get(key)
@@ -831,12 +847,36 @@ class TPUStore:
             if cached is not None:
                 return cached[1]
             metrics.COP_AUX_UPLOADS.inc()
-            batch = to_device_batch(chunk, capacity=_pow2(max(chunk.num_rows(), 1)))
+            batch = to_device_batch(chunk, capacity=_pow2(max(chunk.num_rows(), 1)), sharding=sharding)
             with self._aux_lock:
                 self._aux_batch_cache[key] = (chunk, batch)
                 while len(self._aux_batch_cache) > self._AUX_CACHE_MAX:
                     self._aux_batch_cache.pop(next(iter(self._aux_batch_cache)))
             return batch
+
+    def build_side(self, chunks: list) -> Chunk | None:
+        """A build table's region chunks as one chunk: `SelectResult.merged()`
+        with the concatenation of several kept under the parts' tokens, so
+        that a table in several regions, answered from the result cache as
+        the same chunk objects for every statement of a data version, is
+        handed to `_aux_batch` as one object a data version too, as a lone
+        chunk is. Bounded like the aux batches; the entry pins its parts
+        (a token names a live object)."""
+        if len(chunks) < 2:
+            return chunks[0] if chunks else None
+        key = tuple(self._chunk_token(c) for c in chunks)
+        with self._aux_lock:
+            kept = self._build_side_cache.pop(key, None)
+            if kept is not None:
+                self._build_side_cache[key] = kept  # refresh LRU position
+                return kept[1]
+        merged = Chunk.concat(chunks)
+        with self._aux_lock:
+            # two statements at once: the first one's object is what both hand on
+            kept = self._build_side_cache.setdefault(key, (tuple(chunks), merged))
+            while len(self._build_side_cache) > self._AUX_CACHE_MAX:
+                self._build_side_cache.pop(next(iter(self._build_side_cache)))
+        return kept[1]
 
     # -- coprocessor result cache (ref: copr/coprocessor_cache.go) ----------
     _COP_CACHE_MAX = 128
@@ -1213,6 +1253,8 @@ class TPUStore:
         if kind is None:
             return False
         t0 = time.monotonic_ns()
+        D = min(len(jax.devices()), len(entries))
+        ver = self._snapshot_write_ver()  # pre-read snapshot: in the stacked batch's key, and gates its filing
         try:
             with tracing.span("cop.mesh_decode", regions=len(entries)) as dsp:
                 chunks = [
@@ -1221,7 +1263,7 @@ class TPUStore:
                 ]
                 if dsp is not None:
                     dsp.set("bytes_to_device", sum(ch.nbytes() for ch in chunks))
-                aux_batches = [self._aux_batch(c) for c in req0.aux_chunks]
+                aux_batches = [self._aux_batch(c, mesh_devices=D) for c in req0.aux_chunks]
         except Exception:  # noqa: BLE001 — degrade, never lose the group
             if failpoint.eval("cop-debug-raise"):
                 raise
@@ -1243,21 +1285,10 @@ class TPUStore:
         if cap * len(caps) > 4 * sum(caps):
             metrics.MESH_COP_FALLBACKS.inc()
             return False
-        n_devs = len(jax.devices())
-        D = min(n_devs, len(chunks))
-        R_pad = -(-len(chunks) // D) * D  # empty lanes pad the region axis
-        fts = chunks[0].field_types()
-        lanes = list(chunks) + [Chunk.empty(fts) for _ in range(R_pad - len(chunks))]
         try:
             with tracing.span("cop.mesh_execute", regions=len(entries),
                               devices=D, kind=kind) as xsp:
-                # the lanes are stacked on the host and uploaded anew for
-                # every statement (the decoded chunks are kept, the
-                # sharded batch made of them is not)
-                with tracing.span("mesh.stack", lanes=R_pad, devices=D,
-                                  rows=sum(ch.num_rows() for ch in chunks),
-                                  bytes=sum(ch.nbytes() for ch in chunks)):
-                    stacked = to_stacked_device_batch(lanes, cap)
+                stacked = self._stacked_lanes(ver, entries, chunks, cap, D)
                 merged, lane_counts, info = drive_mesh_program_info(
                     self.programs, dag, stacked, aux_batches, group_capacity,
                     kind, D, small_groups=req0.small_groups,
@@ -1310,6 +1341,42 @@ class TPUStore:
                 mesh_merged=len(entries),
             )
         return True
+
+    def _stacked_lanes(self, ver: int, entries, chunks: list, cap: int, D: int) -> DeviceBatch:
+        """The lanes of a mesh launch as the program reads them: one batch,
+        every leaf's region axis sharded over the mesh's `D` devices. Kept
+        with the regions' own device batches (`_batch_cache`: one budget,
+        one snapshot rule, dropped with them) under the data version `ver`
+        and what the lanes read, so a later statement over the same lanes
+        finds it resident on the devices; else stacked on the host and put
+        there once, each device receiving its own lanes."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from ..parallel.mesh import REGION_AXIS, region_mesh
+        from ..util import metrics, tracing
+
+        req0 = entries[0][1]
+        scan = req0.dag.scan()
+        R_pad = -(-len(chunks) // D) * D  # empty lanes pad the region axis
+        columns = tuple(c.fingerprint() for c in scan.columns)
+        what = ("mesh.stack", cap, R_pad, D,
+                tuple(self._read_key(region, req.ranges, scan.table_id, columns) for _i, req, region in entries))
+        with tracing.span("mesh.stack", lanes=R_pad, devices=D,
+                          rows=sum(ch.num_rows() for ch in chunks),
+                          bytes=sum(ch.nbytes() for ch in chunks)) as sp:
+            with self._cop_lock:
+                stacked = self._batch_cache.get((ver, what), req0.start_ts)
+            hit = stacked is not None
+            (metrics.MESH_STACK_HITS if hit else metrics.MESH_STACK_MISSES).inc()
+            if sp is not None:
+                sp.set("hit", hit)
+            if not hit:
+                fts = chunks[0].field_types()
+                lanes = list(chunks) + [Chunk.empty(fts) for _ in range(R_pad - len(chunks))]
+                stacked = to_stacked_device_batch(
+                    lanes, cap, NamedSharding(region_mesh(D), PartitionSpec(REGION_AXIS)))
+                self._file_decoded(ver, what, req0.start_ts, None, stacked)
+        return stacked
 
     def _lane_attribution(self, region, in_chunk, out_bytes: int, counts,
                           share: int, compile_ns: int, cache_hit: bool,

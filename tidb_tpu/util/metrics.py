@@ -303,6 +303,8 @@ MESH_SELECTS = REGISTRY.counter("tidb_tpu_mesh_selects_total", "SQL plans execut
 MESH_COP_BATCHES = REGISTRY.counter("tidb_tpu_mesh_cop_batches_total", "shard_map mesh-tier launches (one merged state per launch)")
 MESH_COP_LANES = REGISTRY.counter("tidb_tpu_mesh_cop_lanes_total", "region lanes whose partial states were psum-merged on device")
 MESH_COP_FALLBACKS = REGISTRY.counter("tidb_tpu_mesh_cop_fallbacks_total", "mesh-tier groups degraded to the vmapped batch tier (overflow/trace failure)")
+MESH_STACK_HITS = REGISTRY.counter("tidb_tpu_mesh_stack_hits_total", "mesh-tier launches that found their stacked lanes resident and sharded on the mesh's devices")
+MESH_STACK_MISSES = REGISTRY.counter("tidb_tpu_mesh_stack_misses_total", "mesh-tier launches that stacked their lanes on the host and put them onto the mesh's devices")
 SPILL_PARTITIONS = REGISTRY.counter("tidb_tpu_spill_partitions_total", "out-of-capacity host-partitioned multi-pass executions (the spill analog)")
 MEM_EVICTIONS = REGISTRY.counter("tidb_tpu_mem_evictions_total", "store cache evictions by the OOM action")
 MEM_DEGRADED_QUERIES = REGISTRY.counter("tidb_tpu_mem_degraded_total", "queries degraded to the low-memory fold path")
