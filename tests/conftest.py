@@ -38,3 +38,16 @@ def pytest_configure(config):
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture(autouse=True)
+def host_states_nest_by_the_rule():
+    """A host state opened inside a host state that is neither a bottom
+    nor its `exec.*` child breaks the state clock's nesting rule
+    (util/tracing.py): whichever test drove the path fails."""
+    from tidb_tpu.util import tracing
+
+    yield
+    breaches = dict(tracing.nesting_breaches)
+    tracing.nesting_breaches.clear()
+    assert not breaches, f"host states nested against the rule: {breaches}"

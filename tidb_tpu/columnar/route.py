@@ -34,7 +34,6 @@ run that must prove this path cannot be served by the other one."""
 
 from __future__ import annotations
 
-import time
 
 from ..codec import tablecodec
 from .replica import I64_MAX, I64_MIN, ColumnarNotReady, _schema_sig
@@ -179,16 +178,14 @@ def try_columnar_select(store, dag, ranges, start_ts: int, aux_chunks: list,
 def _wait_ready(store, tables, start_ts: int, backoff_weight: int, checker):
     """`_gate` under the `columnar.gate` span (attrs `waited`: the
     data_not_ready back-off was taken; `snapshot_ts`: what it answered),
-    its time counted in COLUMNAR_GATE_WAIT_NS."""
-    from ..util import metrics, tracing
+    a host state whose wall time the clock counts in COLUMNAR_GATE_WAIT_NS."""
+    from ..util import tracing
 
-    t0 = time.perf_counter_ns()
     with tracing.span("columnar.gate", start_ts=start_ts) as sp:
         ts, waited = _gate(store, tables, start_ts, backoff_weight, checker)
         if sp is not None:
             sp.set("waited", waited)
             sp.set("snapshot_ts", ts)
-    metrics.COLUMNAR_GATE_WAIT_NS.inc(time.perf_counter_ns() - t0)
     return ts
 
 

@@ -726,7 +726,11 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
     # per-task spans on it explicitly (pkg/util/tracing's SpanFromContext
     # handover at the copIterator worker boundary). The Top SQL resource
     # tag rides the SAME seam: workers adopt the statement's tag so the
-    # store/backoff sinks attribute from pool threads.
+    # store/backoff sinks attribute from pool threads. So does the host-
+    # state clock: a pool task runs under `tracing.pool_task`, which gives
+    # the worker the bottom state `distsql.task` and hands what the task
+    # charged to the counters and to the tag; the statement's thread waits
+    # for the futures in the state `distsql.wait_tasks`.
     dispatch_span = tracing.current_span()
     stmt_tag = topsql.current_tag()
     scan_kind = _scan_kind(req)
@@ -736,6 +740,10 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
         with topsql.adopt(stmt_tag):
             return _run_one_task(store, req, task, summaries_by_task[i],
                                  dispatch_span=dispatch_span, scan_kind=scan_kind)
+
+    def pooled(fn, *args):
+        with tracing.pool_task(stmt_tag):
+            return fn(*args)
 
     # ONE execution planner picks the tier by data size and topology
     # (distsql/planner.py): single launch -> vmapped store batch -> mesh
@@ -766,8 +774,9 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
                                         summaries_by_task, dispatch_span, scan_kind,
                                         mesh=decision.tier == "mesh")
 
-        with ThreadPoolExecutor(max_workers=max(len(by_store), 1)) as pool:
-            futs = [pool.submit(run_batch, sid, entries)
+        with tracing.span("distsql.wait_tasks", tasks=len(by_store)), \
+                ThreadPoolExecutor(max_workers=max(len(by_store), 1)) as pool:
+            futs = [pool.submit(pooled, run_batch, sid, entries)
                     for sid, entries in by_store.items()]
             per_store = [f.result() for f in futs]
         batch_stats = {
@@ -778,8 +787,9 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
             "mesh_lanes": sum(s["mesh_lanes"] for s in per_store),
         }
     elif req.concurrency > 1 and len(tasks) > 1:
-        with ThreadPoolExecutor(max_workers=req.concurrency) as pool:
-            futs = [pool.submit(run_task, i, t) for i, t in enumerate(tasks)]
+        with tracing.span("distsql.wait_tasks", tasks=len(tasks)), \
+                ThreadPoolExecutor(max_workers=req.concurrency) as pool:
+            futs = [pool.submit(pooled, run_task, i, t) for i, t in enumerate(tasks)]
             for i, f in enumerate(futs):
                 results[i] = f.result()
     else:

@@ -8,9 +8,11 @@ an already compiled program: dispatch only) and `exec.wait` (from the
 call's return until the launch's outputs are on the host: device queue,
 execution, and the launch's one transfer).  `read_back` covers what the
 host then does with them, the decoding, as `exec.readback`.  Each is a
-span under the ambient one when a `TRACE` is active, a
-`jax.profiler.TraceAnnotation` always (`util/tracing.py`), and a counter
-of `util/metrics.py` always.
+span under the ambient one when a `TRACE` is active, and always a host
+state (`util/tracing.py`): a `jax.profiler.TraceAnnotation`, and wall
+time on the thread's state clock, which feeds the states' counters of
+`util/metrics.py` (`exec.wait` is PROGRAM_WAIT_NS, `exec.readback`
+PROGRAM_READBACK_NS) and Top SQL's `device_ns`.
 
 One device-to-host round trip per launch, in two steps.  The program
 itself makes one array of everything the host reads (`HostOutputs`: its
@@ -50,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..topsql import note_launch
 from ..util import metrics, tracing
 
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
@@ -266,14 +269,16 @@ def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, ga
     under `exec.wait`, which blocks until the device is through and the
     transfer has landed.  Returns (the outputs that the program's `reads`
     named, as host arrays; the `Fetch`; first-call ns): the last is the
-    wall time of call and wait when `first_call` says the program was
-    just built, else 0 — what the exec summaries and Top SQL attribute to
-    compilation.
+    wall time of the two states, call and wait, when `first_call` says the
+    program was just built, else 0: what the exec summaries and Top SQL's
+    `compile_ns` attribute to compilation.  The wait alone is what Top SQL
+    attributes to the device (`device_ns`, from the state clock; the
+    conservation ledger is fed here).
 
     `first_call` names the state on the profiler's clock, which has to be
-    named before the call begins; the span and the counters go by what
-    the listener heard during the call, so a retrace of an old program for
-    a new argument shape is an `exec.compile` too."""
+    named before the call begins; the span, the state clock and the
+    counters go by what the listener heard during the call, so a retrace
+    of an old program for a new argument shape is an `exec.compile` too."""
     fn = outputs.fn
     program = getattr(fn, "__name__", type(fn).__name__)
     heard = _calling.heard = _Heard()
@@ -286,34 +291,33 @@ def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, ga
     if n_strs:
         metrics.PROGRAM_STR_PARAMS_BOUND.inc(n_strs)
     args = (*args, *operands)
-    t0 = time.perf_counter_ns()
+    call = tracing.span("exec.compile" if first_call else "exec.launch", program=program, params=n_params)
     try:
-        with tracing.span("exec.compile" if first_call else "exec.launch", program=program, params=n_params) as sp:
+        with call as sp:
             returned = fn(*args) if gate is None else gate.call(fn, args)
-            t1 = time.perf_counter_ns()
-            if sp is not None:
+            # the state by what was heard, on the clock as in the span
+            call.rename("exec.compile" if heard.compiled else "exec.launch")
+            if sp is not None and heard.compiled:
                 _describe(sp, heard)
     finally:
         del _calling.heard
     if heard.compiled:
-        metrics.XLA_TRACE_LOWER_NS.inc(max(t1 - t0 - heard.xla_ns, 0))
-        metrics.PROGRAM_COMPILE_DURATION.observe((t1 - t0) / 1e9)
-    with tracing.span("exec.wait"):
+        metrics.XLA_TRACE_LOWER_NS.inc(max(call.wall_ns - heard.xla_ns, 0))
+        metrics.PROGRAM_COMPILE_DURATION.observe(call.wall_ns / 1e9)
+    wait = tracing.span("exec.wait")
+    with wait:
         fetch = Fetch(returned)
         out = returned.read()
-    metrics.PROGRAM_WAIT_NS.inc(time.perf_counter_ns() - t1)
-    return out, fetch, (time.perf_counter_ns() - t0 if first_call else 0)
+    # PROGRAM_WAIT_NS is the clock's; Top SQL's ledger gets the same wait
+    note_launch(wait.wall_ns)
+    return out, fetch, (call.wall_ns + wait.wall_ns if first_call else 0)
 
 
 def _describe(sp: tracing.Span, heard: _Heard) -> None:
-    """Name the call's span by what was heard, and hang each backend
-    compile under it as a span of its own, so that a reducer which knows
-    only names and durations reads `exec.compile`'s self time as tracing
-    and lowering."""
-    if not heard.compiled:
-        sp.name = "exec.launch"
-        return
-    sp.name = "exec.compile"
+    """What was heard in a call that compiled, on its span, and each
+    backend compile hung under it as a span of its own, so that a reducer
+    which knows only names and durations reads `exec.compile`'s self time
+    as tracing and lowering."""
     sp.set("trace_ns", heard.trace_ns)
     sp.set("lower_ns", heard.lower_ns)
     sp.set("xla_ns", heard.xla_ns)
@@ -329,13 +333,12 @@ class read_back:
     that reaches it was in no fetch, waits for a round trip of its own
     and is counted, as a transfer and as `late`."""
 
-    __slots__ = ("transfers", "bytes", "late", "_t0", "_span", "_sp")
+    __slots__ = ("transfers", "bytes", "late", "_span", "_sp")
 
     def __init__(self, fetch: Fetch):
         self.transfers, self.bytes, self.late = fetch.transfers, fetch.bytes, 0
 
     def __enter__(self):
-        self._t0 = time.perf_counter_ns()
         self._span = tracing.span("exec.readback")
         self._sp = self._span.__enter__()
         return self.to_host
@@ -348,7 +351,6 @@ class read_back:
         return np.asarray(x)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        metrics.PROGRAM_READBACK_NS.inc(time.perf_counter_ns() - self._t0)
         metrics.PROGRAM_READBACK_TRANSFERS.inc(self.transfers)
         metrics.PROGRAM_READBACK_BYTES.inc(self.bytes)
         metrics.PROGRAM_READBACK_LATE.inc(self.late)

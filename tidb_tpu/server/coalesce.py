@@ -39,7 +39,7 @@ from __future__ import annotations
 import threading
 import time
 
-from ..util import failpoint, metrics
+from ..util import failpoint, metrics, tracing
 
 # fall-out reasons (typed, each a `tidb_tpu_coalesce_fallbacks_total` label):
 #   window_stall  follower patience expired with the window unclaimed
@@ -257,13 +257,19 @@ class SessionCoalescer:
                         peer_store=store.cluster.leader_of(t.region_id),
                     ))
                 spans.append((lane, lo, len(reqs)))
-            t0 = time.perf_counter_ns()
+            mark = tracing.clock_mark()
             with topsql.adopt(None):
-                # untagged launch: the store's internal record_device
-                # no-ops, so device time lands ONLY through the per-lane
-                # shares below — each lane attributed once, exactly
+                # untagged launch: the store's and the launch boundary's
+                # sinks no-op, so the wait for the device lands ONLY through
+                # the per-lane shares below, each lane attributed once,
+                # exactly
                 resps = store.batch_coprocessor(reqs)
-            elapsed = time.perf_counter_ns() - t0
+            waited = tracing.clock_since(mark).get("exec.wait", 0)
+            # this thread's state clock will hand the wait to the leader's
+            # own statement at its end: taken off here, it nets to its share
+            leader = topsql.current_tag()
+            if leader is not None:
+                leader.add(device_ns=-waited)
         finally:
             store.unregister_snapshot(shared_ts)
         launch_ids = {r.batched for r in resps if r.batched}
@@ -286,14 +292,14 @@ class SessionCoalescer:
                         by_handle[int(row[0].val)] = list(row[1:])
             lane.result = by_handle
             rows_per_lane.append(len(by_handle))
-        shares = topsql.split_by_rows(elapsed, rows_per_lane)
+        shares = topsql.split_by_rows(waited, rows_per_lane)
         for (lane, _lo, _hi), share in zip(spans, shares):
             if lane.fallback:
                 continue
             park_s = max(t_flush - lane.enq, 0.0)
             metrics.COALESCE_WINDOW_WAIT.observe(park_s)
             with topsql.adopt(lane.tag):
-                topsql.record_device(share)
+                topsql.record_device_share(share)
                 topsql.record_queue_wait(park_s * 1000.0)
             lane.done.set()
 

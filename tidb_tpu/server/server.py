@@ -12,7 +12,6 @@ from __future__ import annotations
 import socket
 import socketserver
 import threading
-import time
 
 from ..sql import Session, SQLError
 from ..sql.catalog import Catalog, CatalogError
@@ -109,15 +108,20 @@ class Connection:
                 continue
             if pkt[0] == P.COM_QUIT:
                 return
-            t0 = time.perf_counter_ns()
             packets, sends = io.packets_out, io.sends
+            # the bottom state of the thread's clock (util/tracing.py): its
+            # two reads are the command's, so the states' sums add up to it;
+            # the thread only waited for the packet since its last command
+            command = tracing.host_state("server.command", cpu="ticks")
             try:
-                self.dispatch(pkt[0], pkt[1:])
-                io.flush()
+                with command:
+                    self.dispatch(pkt[0], pkt[1:])
+                    io.flush()
             finally:
                 metrics.SERVER_PACKETS_OUT.inc(io.packets_out - packets)
                 metrics.SERVER_SOCKET_SENDS.inc(io.sends - sends)
-                metrics.SERVER_HANDLE_NS.inc(time.perf_counter_ns() - t0)
+                metrics.SERVER_HANDLE_NS.inc(command.wall_ns)
+                metrics.SERVER_CPU_NS.inc(command.cpu_ns)
                 metrics.SERVER_COMMANDS.inc()  # last: a reader that sees the command sees its time and packets
 
     def dispatch(self, cmd: int, payload: bytes):
@@ -150,7 +154,6 @@ class Connection:
                 return
             last = i + 1 == len(stmts)
             io = self.io
-            t0 = time.perf_counter_ns()
             with tracing.span("server.write") as sp:
                 packets, nbytes, sends = io.packets_out, io.bytes_out, io.sends
                 self.write_result(res, more=not last)
@@ -159,7 +162,6 @@ class Connection:
                 if sp is not None:
                     sp.attrs.update(packets=io.packets_out - packets, bytes=io.bytes_out - nbytes,
                                     sends=io.sends - sends)
-            metrics.SERVER_WRITE_NS.inc(time.perf_counter_ns() - t0)
 
     SERVER_MORE_RESULTS = 0x0008
 

@@ -702,7 +702,13 @@ class Session:
         c0 = _time.thread_time()
         self._last_plan_digest = ""
         stmt_type = "invalid"
-        probe = StmtProbe.from_sql(sql)
+        # what this thread's state clock has charged so far: the statement's
+        # share, for its resource tag, is what it charges from here to
+        # `_record_stmt`
+        top_sql = self.sysvars.get_bool("tidb_enable_top_sql")
+        mark = tracing.clock_mark() if top_sql else None
+        with tracing.span("session.probe"):
+            probe = StmtProbe.from_sql(sql)
         saved = (self._stmt_probe, self._last_sql, self._record_digest)
         self._stmt_probe, self._last_sql = probe, sql
         self._record_digest = (probe.normalized, probe.digest) if probe else None
@@ -711,7 +717,7 @@ class Session:
         # below (dispatch workers, store, Backoffer, admission queue)
         # attributes into it ambiently (ISSUE 17)
         tag = None
-        if probe is not None and self.sysvars.get_bool("tidb_enable_top_sql"):
+        if probe is not None and top_sql:
             tag = topsql.ResourceTag(probe.digest, sample_sql=sql[:256])
         tag_token = topsql.activate(tag)
         gate = getattr(self.store, "admission", None)
@@ -766,7 +772,7 @@ class Session:
 
                 metrics.STATEMENTS.labels(stmt_type, "error").inc()
                 self._record_stmt(sql, (_time.perf_counter() - t0) * 1e3, 0, False, str(exc),
-                                  cpu_ms=(_time.thread_time() - c0) * 1e3)
+                                  cpu_ms=(_time.thread_time() - c0) * 1e3, clock_mark=mark)
                 if isinstance(exc, AdmissionShed):
                     # shed at the front door: MySQL 9003 "TiKV server busy"
                     # with the suggested wait riding the wire-format message,
@@ -795,23 +801,31 @@ class Session:
             metrics.STATEMENTS.labels(stmt_type, "ok").inc()
             rows = len(res.rows) if getattr(res, "rows", None) else getattr(res, "affected", 0)
             self._record_stmt(sql, (_time.perf_counter() - t0) * 1e3, rows, True,
-                              cpu_ms=(_time.thread_time() - c0) * 1e3)
+                              cpu_ms=(_time.thread_time() - c0) * 1e3, clock_mark=mark)
             return res
         finally:
             self._stmt_probe, self._last_sql, self._record_digest = saved
             topsql.deactivate(tag_token)
 
-    def _record_stmt(self, sql: str, dur_ms: float, rows: int, ok: bool, err: str = "", cpu_ms: float = 0.0):
+    def _record_stmt(self, sql: str, dur_ms: float, rows: int, ok: bool, err: str = "", cpu_ms: float = 0.0,
+                     clock_mark: dict | None = None):
         try:
 
             # flush the statement's resource tag: host CPU lands here (the
-            # exact thread_time delta — parse+plan+dispatch), the sinks
-            # already accumulated device/compile/backoff/queue; EXECUTE
-            # re-points the digest at the UNDERLYING prepared statement
-            # (same join the stmt log makes via _record_digest)
+            # exact thread_time delta — parse+plan+dispatch — to which the
+            # tag adds its pool workers'), and what this thread's state
+            # clock charged since the statement began (the wait for the
+            # device among it; the workers handed theirs over task by
+            # task); the sinks already accumulated compile/backoff/queue;
+            # EXECUTE re-points the digest at the UNDERLYING prepared
+            # statement (same join the stmt log makes via _record_digest)
             attr = None
             tag = topsql.current_tag()
             if tag is not None:
+                if clock_mark is not None:
+                    from ..util import tracing
+
+                    tag.add_host(tracing.clock_since(clock_mark))
                 rd = getattr(self, "_record_digest", None)
                 if rd is not None:
                     tag.sql_digest = rd[1]
@@ -1501,6 +1515,7 @@ class Session:
         persist_catalog(self.store, self.catalog)
 
     def _new_rewriter(self, parent_rw):
+        from ..util import tracing
         from .subquery import SubqueryRewriter
 
         rw = SubqueryRewriter(
@@ -1509,7 +1524,15 @@ class Session:
             max_recursion=self.sysvars.get_int("cte_max_recursion_depth"),
             parent=parent_rw,
         )
-        rw.exec_query = lambda q: self._exec_query(q, rw)
+
+        def nested(q):
+            # a statement inside another's host state (the root merge's
+            # row-at-a-time fallback evaluating a correlated subquery): it
+            # runs over a bottom of its own on the state clock
+            with tracing.host_state("server.command"):
+                return self._exec_query(q, rw)
+
+        rw.exec_query = nested
         return rw
 
     def _exec_query(self, stmt, parent_rw):
@@ -1575,29 +1598,34 @@ class Session:
             # entry lookup would land on keys the install path never fills
             return None
         self._text_serve_type = "select"
-        key = self._plan_cache_key(probe, probe.slot_kinds)
-        entry = self.catalog.plan_cache.lookup(
-            key, self.catalog, self.catalog.bindings_rev)
-        if entry is None:
-            entry = self._plan_cache_shared_adopt(key)
-        if entry is None:
-            return None
+        # the state covers the look-up, the privilege check and the re-bind
+        # and ends before the cached plan executes: what runs then has
+        # states of its own (util/tracing.py, the nesting rule)
+        with tracing.span("session.plan_cache") as sp:
+            key = self._plan_cache_key(probe, probe.slot_kinds)
+            entry = self.catalog.plan_cache.lookup(
+                key, self.catalog, self.catalog.bindings_rev)
+            if entry is None:
+                entry = self._plan_cache_shared_adopt(key)
+            if entry is None:
+                return None
+            if entry.tier != "pointwrite":
+                try:
+                    self._check_privileges(entry.template)
+                    run = self._plan_cache_bind(entry, list(probe.slot_values))
+                except _pc.RebindError:
+                    return None  # recipe could not re-bind: replan cold
+                if sp is not None:
+                    sp.set("status", "hit")
+                    sp.set("tier", entry.tier)
         if entry.tier == "pointwrite":
             # DML point-write tier (ISSUE 19): UPDATE/DELETE ... WHERE
             # pk = ? serves parse-free through the same digest machinery
             return self._plan_cache_serve_dml(entry, probe)
-        with tracing.span("session.plan_cache") as sp:
-            try:
-                self._check_privileges(entry.template)
-                out = self._plan_cache_execute(entry, list(probe.slot_values))
-            except _pc.RebindError:
-                return None  # recipe could not re-bind: replan cold
-            metrics.PLAN_CACHE_HITS.inc()
-            self._last_plan_cache = ("hit", "", entry.tier)
-            self._stmt_probe = None  # consumed: nested paths never re-consult
-            if sp is not None:
-                sp.set("status", "hit")
-                sp.set("tier", entry.tier)
+        out = run()
+        metrics.PLAN_CACHE_HITS.inc()
+        self._last_plan_cache = ("hit", "", entry.tier)
+        self._stmt_probe = None  # consumed: nested paths never re-consult
         names, _fts, rows = out
         if not entry.has_limit:
             ssl = self.sysvars.get_int("sql_select_limit")
@@ -1638,47 +1666,54 @@ class Session:
                 key, self.catalog, self.catalog.bindings_rev)
             if entry is None:
                 entry = self._plan_cache_shared_adopt(key)
+            run = None
             if entry is not None:
                 try:
-                    out = self._plan_cache_execute(entry, values)
+                    run = self._plan_cache_bind(entry, values)
                 except _pc.RebindError:
-                    out = None  # recipe could not re-bind: replan cold
-                if out is not None:
-                    metrics.PLAN_CACHE_HITS.inc()
-                    self._last_plan_cache = ("hit", "", entry.tier)
-                    if sp is not None:
-                        sp.set("status", "hit")
-                        sp.set("tier", entry.tier)
-                    return out, None
-            metrics.PLAN_CACHE_MISSES.inc()
-            self._last_plan_cache = ("miss", "", "")
+                    pass  # recipe could not re-bind: replan cold
+            if run is None:
+                metrics.PLAN_CACHE_MISSES.inc()
+                self._last_plan_cache = ("miss", "", "")
+                if sp is not None:
+                    sp.set("status", "miss")
+                return None, (key, _copy.deepcopy(stmt))
             if sp is not None:
-                sp.set("status", "miss")
-            return None, (key, _copy.deepcopy(stmt))
+                sp.set("status", "hit")
+                sp.set("tier", entry.tier)
+        # the hit executes outside the state, as any planned statement does
+        out = run()
+        metrics.PLAN_CACHE_HITS.inc()
+        self._last_plan_cache = ("hit", "", entry.tier)
+        return out, None
 
-    def _plan_cache_execute(self, entry, values) -> tuple:
-        """Serve a statement from a cached template. pointget re-executes
-        the key-read fast path from the bound AST; dag re-binds Consts +
-        ranges into the cached physical plan and goes straight to
-        dispatch; ast re-plans the bound template (parse skipped)."""
+    def _plan_cache_bind(self, entry, values):
+        """The re-bind of a cached template: `run()` then serves the
+        statement.  pointget re-executes the key-read fast path from the
+        bound AST; dag re-binds Consts + ranges into the cached physical
+        plan and goes straight to dispatch; ast re-plans the bound
+        template (parse skipped).  Raises RebindError; `run` does not."""
         from . import plancache as _pc
 
         if entry.tier == "dag":
             plan = _pc.rebind_plan(entry, values, self.catalog)
-            return self._execute_planned(plan)
+            return lambda: self._execute_planned(plan)
         bound = _pc.bind_template(entry.template, values)
-        if entry.tier == "pointget":
-            det = self._point_get_detect(bound, {})
-            if det is not None:
-                # plan-cache-hit point gets are the coalescable tier
-                # (ISSUE 19): the hint lets _exec_point_get park in the
-                # store's micro-batch window instead of launching alone
-                self._coalesce_hint = True
-                try:
-                    return self._exec_point_get(bound, *det)
-                finally:
-                    self._coalesce_hint = False
-        return self._run_select_inner(bound, None)
+        det = self._point_get_detect(bound, {}) if entry.tier == "pointget" else None
+        if det is None:
+            return lambda: self._run_select_inner(bound, None)
+
+        def point_get():
+            # plan-cache-hit point gets are the coalescable tier
+            # (ISSUE 19): the hint lets _exec_point_get park in the
+            # store's micro-batch window instead of launching alone
+            self._coalesce_hint = True
+            try:
+                return self._exec_point_get(bound, *det)
+            finally:
+                self._coalesce_hint = False
+
+        return point_get
 
     def _plan_cache_install(self, probe, pending) -> None:
         """Build + install the slotted template after the cold statement
@@ -2091,7 +2126,8 @@ class Session:
         finally:
             tracker.release_all()
             self._unpin_read_ts(ts)
-        rows = chunk.rows()
+        with tracing.span("session.rows", rows=chunk.num_rows()):
+            rows = chunk.rows()
         if plan.offset:
             rows = rows[plan.offset :]
         return plan.column_names, plan.dag.output_fts(), rows

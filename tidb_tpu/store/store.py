@@ -1115,7 +1115,7 @@ class TPUStore:
         elapsed = time.monotonic_ns() - t0
         from ..topsql import record_device
 
-        record_device(elapsed, compile_ns=info["compile_ns"], bytes_to_device=in_bytes)
+        record_device(compile_ns=info["compile_ns"], bytes_to_device=in_bytes)
         # per-executor produced-row counts are real (measured inside the
         # fused program); the time is the whole fused program's — XLA fuses
         # the pipeline into one kernel, so per-operator time does not exist
@@ -1311,9 +1311,10 @@ class TPUStore:
         # one launch served every lane: attribution splits by each lane's
         # decoded rows (not an equal share — a 10k-row lane did the work a
         # 10-row lane did not), and the shares sum EXACTLY to the launch
-        # total so EXPLAIN/Top SQL conservation holds
+        # total in EXPLAIN ANALYZE (Top SQL's device time is the state
+        # clock's exec.wait, not this)
         shares = split_by_rows(elapsed, [ch.num_rows() for ch in chunks])
-        record_device(elapsed, compile_ns=info["compile_ns"],
+        record_device(compile_ns=info["compile_ns"],
                       bytes_to_device=sum(ch.nbytes() for ch in chunks))
         walk = executor_walk(dag.executors)
         out_fts = merged.field_types()
@@ -1496,7 +1497,7 @@ class TPUStore:
         # launch total); overflow fall-out lanes keep their share here —
         # the launch still spent it — and bill their retry separately
         shares = split_by_rows(elapsed, [ch.num_rows() for ch in chunks])
-        record_device(elapsed, compile_ns=info["compile_ns"],
+        record_device(compile_ns=info["compile_ns"],
                       bytes_to_device=sum(ch.nbytes() for ch in chunks))
         walk = executor_walk(dag.executors)
         metrics.BATCH_COP_BATCHES.inc()

@@ -3,11 +3,12 @@
 The reference samples CPU on a timer and attributes samples to the SQL /
 plan digest stored in goroutine labels, then a reporter aggregates the
 samples into fixed windows of top-N digests. In-process we can do better
-than statistical sampling: every layer that already measures (thread CPU
-deltas at the session boundary, the fused-program clock in the store,
-the Backoffer's slept intervals, the admission gate's queue wait)
-records its EXACT measurement onto an ambient per-statement resource
-tag, and the reporter folds finished statements into windows.
+than statistical sampling: every layer that already measures (the host-
+state clock of util/tracing.py on every thread of the statement: wall and
+CPU by state, the wait for the device among them; the Backoffer's slept
+intervals, the admission gate's queue wait) records its EXACT measurement
+onto an ambient per-statement resource tag, and the reporter folds
+finished statements into windows.
 
 Three pieces:
 
@@ -40,8 +41,10 @@ from .tag import (  # noqa: F401
     adopt,
     current_tag,
     deactivate,
+    note_launch,
     record_backoff,
     record_cop_cache_hit,
     record_device,
+    record_device_share,
     record_queue_wait,
 )

@@ -72,7 +72,8 @@ class DigestStats:
 
     __slots__ = ("digest", "plan_digest", "sample_sql", "exec_count", "errors",
                  "cpu_ns", "device_ns", "compile_ns", "backoff_ms", "queue_ms",
-                 "bytes_to_device", "cop_cache_hits", "plan_cache_hits")
+                 "bytes_to_device", "cop_cache_hits", "plan_cache_hits",
+                 "host_ns")
 
     def __init__(self, digest: str):
         self.digest = digest
@@ -88,6 +89,11 @@ class DigestStats:
         self.bytes_to_device = 0
         self.cop_cache_hits = 0
         self.plan_cache_hits = 0
+        self.host_ns: dict = {}  # host state -> wall ns on every thread (util/tracing.py)
+
+    def add_host(self, host_ns: dict) -> None:
+        for state, ns in host_ns.items():
+            self.host_ns[state] = self.host_ns.get(state, 0) + ns
 
     def merge(self, other: "DigestStats") -> None:
         self.exec_count += other.exec_count
@@ -100,6 +106,7 @@ class DigestStats:
         self.bytes_to_device += other.bytes_to_device
         self.cop_cache_hits += other.cop_cache_hits
         self.plan_cache_hits += other.plan_cache_hits
+        self.add_host(other.host_ns)
 
     def as_dict(self) -> dict:
         return {
@@ -116,6 +123,7 @@ class DigestStats:
             "bytes_to_device": self.bytes_to_device,
             "cop_cache_hits": self.cop_cache_hits,
             "plan_cache_hits": self.plan_cache_hits,
+            "host_ns": dict(self.host_ns),
         }
 
 
@@ -191,9 +199,9 @@ class TopSQLCollector:
 
     # ------------------------------------------------------------- sinks
     def note_launch(self, ns: int) -> None:
-        """One fused-program launch's total device time, recorded at the
-        store while a statement tag is ambient — the right-hand side of
-        the attribution-conservation equation."""
+        """One launch's wait for the device (`exec.wait`), noted at the
+        launch boundary while a statement tag is ambient: the right-hand
+        side of the attribution-conservation equation."""
         with self._mu:
             self.launch_device_ns += ns
         metrics.TOPSQL_LAUNCH_DEVICE_NS.inc(ns)
@@ -224,6 +232,7 @@ class TopSQLCollector:
             d.bytes_to_device += snap["bytes_to_device"]
             d.cop_cache_hits += snap["cop_cache_hits"]
             d.plan_cache_hits += 1 if plan_cache_hit else 0
+            d.add_host(snap.get("host_ns", {}))
             if snap.get("plan_digest"):
                 d.plan_digest = snap["plan_digest"]
             if not d.sample_sql and snap.get("sample_sql"):

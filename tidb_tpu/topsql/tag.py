@@ -12,6 +12,13 @@ object shared by every thread of the statement, its counters guarded by
 a leaf lock no other lock is ever taken under.
 
 Sinks are free when no tag is ambient: one contextvar read, no lock.
+
+Time comes from the host-state clock (`util/tracing.py`), not from clocks
+of the tag's own: the wall time that each thread of the statement charged
+to each state lands in `host_ns` (`add_host`: the dispatch pool's workers
+at the end of each task, the session's thread at the statement's end),
+`device_ns` is the `exec.wait` among it, whatever route launched the
+program, and `cpu_ns` the session thread's CPU plus the workers'.
 """
 
 from __future__ import annotations
@@ -36,7 +43,7 @@ class ResourceTag:
     __slots__ = (
         "sql_digest", "plan_digest", "sample_sql", "_mu",
         "cpu_ns", "device_ns", "compile_ns", "backoff_ms", "queue_ms",
-        "bytes_to_device", "cop_cache_hits",
+        "bytes_to_device", "cop_cache_hits", "host_ns", "pool_cpu_ns",
     )
 
     def __init__(self, sql_digest: str, sample_sql: str = ""):
@@ -52,6 +59,20 @@ class ResourceTag:
             self.queue_ms = 0.0  # guarded_by: _mu
             self.bytes_to_device = 0  # guarded_by: _mu
             self.cop_cache_hits = 0  # guarded_by: _mu
+            self.host_ns: dict = {}  # guarded_by: _mu; host state -> wall ns, every thread
+            self.pool_cpu_ns = 0  # guarded_by: _mu; the dispatch pool's share of cpu_ns
+
+    def add_host(self, spent: dict, pool_cpu_ns: int = 0) -> None:
+        """What one thread of the statement charged to the host states,
+        {state: wall ns} as the state clock hands it over: a dispatch pool
+        task at its end, with the worker's CPU time, and the session's
+        thread at the statement's.  Its `exec.wait` is the statement's
+        `device_ns`."""
+        with self._mu:
+            for state, wall in spent.items():
+                self.host_ns[state] = self.host_ns.get(state, 0) + wall
+            self.device_ns += spent.get("exec.wait", 0)
+            self.pool_cpu_ns += pool_cpu_ns
 
     def add(self, device_ns: int = 0, compile_ns: int = 0,
             bytes_to_device: int = 0, backoff_ms: float = 0.0,
@@ -65,10 +86,11 @@ class ResourceTag:
             self.cop_cache_hits += cop_cache_hits
 
     def finish(self, cpu_ns: int) -> dict:
-        """Statement end: the session lands its exact thread-CPU delta
-        and takes the flush snapshot in one locked step."""
+        """Statement end: the session lands its thread's exact CPU delta,
+        to which the dispatch pool's is added, and takes the flush
+        snapshot."""
         with self._mu:
-            self.cpu_ns = cpu_ns
+            self.cpu_ns = cpu_ns + self.pool_cpu_ns
         return self.snapshot()
 
     def snapshot(self) -> dict:
@@ -84,6 +106,7 @@ class ResourceTag:
                 "queue_ms": self.queue_ms,
                 "bytes_to_device": self.bytes_to_device,
                 "cop_cache_hits": self.cop_cache_hits,
+                "host_ns": dict(self.host_ns),
             }
 
 
@@ -109,10 +132,9 @@ def deactivate(token) -> None:
 def adopt(tag: ResourceTag | None):
     """Cross-thread handoff: a dispatch pool worker adopts the session
     thread's tag for the duration of its task (contextvars do not cross
-    ThreadPoolExecutor, exactly like the dispatch_span handoff)."""
-    if tag is None:
-        yield
-        return
+    ThreadPoolExecutor, exactly like the dispatch_span handoff).  A None
+    tag un-tags the block: the coalescer's shared launch belongs to its
+    lanes, not to the session whose thread happens to flush it."""
     token = _tag.set(tag)
     try:
         yield
@@ -121,19 +143,33 @@ def adopt(tag: ResourceTag | None):
 
 
 # ------------------------------------------------------------------ sinks
-def record_device(launch_ns: int, compile_ns: int = 0,
-                  bytes_to_device: int = 0) -> None:
-    """One fused-program launch's device attribution: the whole launch
-    elapsed lands on the ambient statement (per-lane ExecSummary shares
-    are display attribution; the statement owns the full launch), plus
-    the launch total into the collector's conservation ledger — so
-    `sum(per-digest device_ns) == sum(launch totals)` is checkable."""
+def record_device(compile_ns: int = 0, bytes_to_device: int = 0) -> None:
+    """One cop request's launch, as the store sees it: what its first
+    call spent compiling and the bytes it handed to the device.  The time
+    the statement waited for the device is not the store's to say: it is
+    the state clock's `exec.wait`, on whatever route the program was
+    launched (`note_launch`, `ResourceTag.add_host`)."""
     t = _tag.get()
-    if t is None:
-        return
-    t.add(device_ns=launch_ns, compile_ns=compile_ns,
-          bytes_to_device=bytes_to_device)
-    COLLECTOR.note_launch(launch_ns)
+    if t is not None:
+        t.add(compile_ns=compile_ns, bytes_to_device=bytes_to_device)
+
+
+def note_launch(wait_ns: int) -> None:
+    """One launch's `exec.wait`, from `exec/launch.py`, into the
+    collector's conservation ledger while a statement's tag is ambient: the
+    same ns reach the tag's `device_ns` through the state clock, so
+    `sum(per-digest device_ns) == sum(launch waits)` is checkable."""
+    if _tag.get() is not None:
+        COLLECTOR.note_launch(wait_ns)
+
+
+def record_device_share(wait_ns: int) -> None:
+    """A coalesced lane's share of its window's one launch: the flushing
+    thread waited for the device on every lane's behalf."""
+    t = _tag.get()
+    if t is not None:
+        t.add(device_ns=wait_ns)
+        COLLECTOR.note_launch(wait_ns)
 
 
 def record_backoff(ms: float) -> None:

@@ -356,8 +356,8 @@ XLA_BACKEND_COMPILE_NS = REGISTRY.counter("tidb_tpu_xla_backend_compile_ns_total
 XLA_PERSISTENT_CACHE_HITS = REGISTRY.counter("tidb_tpu_xla_persistent_cache_hits_total", "executables loaded from JAX's persistent compile cache")
 XLA_PERSISTENT_CACHE_MISSES = REGISTRY.counter("tidb_tpu_xla_persistent_cache_misses_total", "executables compiled and written to JAX's persistent compile cache")
 PROGRAM_FETCHES = REGISTRY.counter("tidb_tpu_program_fetches_total", "device-to-host fetches started, one per program call as the call returns (equals the launches)")
-PROGRAM_WAIT_NS = REGISTRY.counter("tidb_tpu_program_wait_ns_total", "ns from a program call's return until its outputs are on the host: queue, execution, the launch's one transfer")
-PROGRAM_READBACK_NS = REGISTRY.counter("tidb_tpu_program_readback_ns_total", "ns decoding program outputs on the host")
+PROGRAM_WAIT_NS = REGISTRY.counter("tidb_tpu_program_wait_ns_total", "ns from a program call's return until its outputs are on the host: queue, execution, the launch's one transfer (the host-state clock's exec.wait)")
+PROGRAM_READBACK_NS = REGISTRY.counter("tidb_tpu_program_readback_ns_total", "ns decoding program outputs on the host (the host-state clock's exec.readback)")
 PROGRAM_READBACK_TRANSFERS = REGISTRY.counter("tidb_tpu_program_readback_transfers_total", "device arrays converted to host arrays: per launch the program's one buffer, each float leaf beside it, and the late ones")
 PROGRAM_READBACK_BYTES = REGISTRY.counter("tidb_tpu_program_readback_bytes_total", "bytes of the device arrays converted")
 PROGRAM_READBACK_LATE = REGISTRY.counter("tidb_tpu_program_readback_late_total", "device arrays converted in read-back that were in no launch's fetch: a round trip each (0 on every driver)")
@@ -365,9 +365,34 @@ PROGRAM_READBACK_LATE = REGISTRY.counter("tidb_tpu_program_readback_late_total",
 # last reply byte is handed to the socket; waiting for the client is not counted
 SERVER_COMMANDS = REGISTRY.counter("tidb_tpu_server_commands_total", "wire commands handled")
 SERVER_HANDLE_NS = REGISTRY.counter("tidb_tpu_server_handle_ns_total", "ns handling wire commands, replies included")
-SERVER_WRITE_NS = REGISTRY.counter("tidb_tpu_server_write_ns_total", "ns encoding and sending result sets")
+SERVER_WRITE_NS = REGISTRY.counter("tidb_tpu_server_write_ns_total", "ns encoding and sending result sets (the host-state clock's server.write)")
 SERVER_PACKETS_OUT = REGISTRY.counter("tidb_tpu_server_packets_out_total", "wire packets sent in reply to commands")
 SERVER_SOCKET_SENDS = REGISTRY.counter("tidb_tpu_server_socket_sends_total", "socket sends that carried those packets: one a command unless a reply outgrew the output buffer")
+# the host-state clock (util/tracing.py): every ns of a wire command or a
+# dispatch pool task is charged, wall time, to the one state that was
+# innermost on its thread.  Four states keep the wall counters they had
+# (exec.wait PROGRAM_WAIT_NS, exec.readback PROGRAM_READBACK_NS, server.write
+# SERVER_WRITE_NS, columnar.gate COLUMNAR_GATE_WAIT_NS).  Over finished
+# commands: sum of the states' wall ns - HOST_POOL_NS = SERVER_HANDLE_NS.
+# Thread CPU is read once a command and once a pool task, at their close:
+# SERVER_CPU_NS and HOST_POOL_CPU_NS.
+SERVER_CPU_NS = REGISTRY.counter("tidb_tpu_server_cpu_ns_total", "thread-CPU ns of the serving threads from one wire command's close to the next's: what SERVER_HANDLE_NS is in wall time, the packet's read included")
+HOST_SERVER_COMMAND_NS = REGISTRY.counter("tidb_tpu_host_server_command_ns_total", "wall ns inside a wire command and in no named state: the serving thread's unnamed host time (host state server.command)")
+HOST_PROBE_NS = REGISTRY.counter("tidb_tpu_host_probe_ns_total", "wall ns in the lexer's pass that builds a statement's plan-cache probe and digest (host state session.probe)")
+HOST_PARSE_NS = REGISTRY.counter("tidb_tpu_host_parse_ns_total", "wall ns parsing statements (host state session.parse)")
+HOST_PLAN_CACHE_NS = REGISTRY.counter("tidb_tpu_host_plan_cache_ns_total", "wall ns in the plan cache's look-up, privilege check and re-bind (host state session.plan_cache)")
+HOST_PLAN_NS = REGISTRY.counter("tidb_tpu_host_plan_ns_total", "wall ns planning statements (host state planner.plan)")
+HOST_ROWS_NS = REGISTRY.counter("tidb_tpu_host_rows_ns_total", "wall ns turning result chunks into rows of datums (host state session.rows)")
+HOST_WAIT_TASKS_NS = REGISTRY.counter("tidb_tpu_host_wait_tasks_ns_total", "wall ns a statement's thread blocked on its dispatch pool's tasks (host state distsql.wait_tasks)")
+HOST_TASK_NS = REGISTRY.counter("tidb_tpu_host_task_ns_total", "wall ns inside a dispatch pool task and in no named state: the pool threads' unnamed host time (host state distsql.task)")
+HOST_COP_DECODE_NS = REGISTRY.counter("tidb_tpu_host_cop_decode_ns_total", "wall ns reading and decoding region rows into device batches (host state cop.decode)")
+HOST_MESH_STACK_NS = REGISTRY.counter("tidb_tpu_host_mesh_stack_ns_total", "wall ns stacking region lanes for the cross-chip tiers (host state mesh.stack)")
+HOST_COLUMNAR_SCAN_NS = REGISTRY.counter("tidb_tpu_host_columnar_scan_ns_total", "wall ns inside a columnar scan and in no launch state: the replica's read and merge (host state columnar.scan)")
+HOST_EXEC_COMPILE_NS = REGISTRY.counter("tidb_tpu_host_exec_compile_ns_total", "wall ns in program calls that traced, lowered or compiled (host state exec.compile)")
+HOST_EXEC_LAUNCH_NS = REGISTRY.counter("tidb_tpu_host_exec_launch_ns_total", "wall ns dispatching compiled programs (host state exec.launch)")
+HOST_ROOT_MERGE_NS = REGISTRY.counter("tidb_tpu_host_root_merge_ns_total", "wall ns in the root's merge and in no launch state (host state distsql.root_merge)")
+HOST_POOL_NS = REGISTRY.counter("tidb_tpu_host_pool_ns_total", "wall ns of dispatch pool tasks, all their states together: what the states' sum holds beyond the serving threads")
+HOST_POOL_CPU_NS = REGISTRY.counter("tidb_tpu_host_pool_cpu_ns_total", "thread-CPU ns of the dispatch pool's threads from one task's close to the next's")
 STATEMENTS = REGISTRY.counter_vec(
     "tidb_tpu_statements_total", "statements executed by type and outcome",
     labelnames=("type", "status"),
@@ -464,7 +489,7 @@ COLUMNAR_RESOLVED_LAG = REGISTRY.gauge_vec(
 COLUMNAR_RESIDENT_SCANS = REGISTRY.counter(
     "tidb_tpu_columnar_resident_scans_total", "columnar scans whose program read the device-resident stable batch (no host merge, no upload)")
 COLUMNAR_GATE_WAIT_NS = REGISTRY.counter(
-    "tidb_tpu_columnar_gate_wait_ns_total", "ns columnar reads spent in the staleness gate, the data_not_ready back-off included")
+    "tidb_tpu_columnar_gate_wait_ns_total", "ns columnar reads spent in the staleness gate, the data_not_ready back-off included (the host-state clock's columnar.gate)")
 COLUMNAR_DEVICE_BYTES = REGISTRY.gauge(
     "tidb_tpu_columnar_device_bytes", "bytes of stable column batches the columnar replicas hold on the device")
 COLUMNAR_RESHAPES = REGISTRY.counter(
@@ -540,9 +565,9 @@ PD_TICK_DURATION = REGISTRY.histogram("pd_tick_seconds", "PD scheduling tick lat
 TOPSQL_RECORDS = REGISTRY.counter(
     "tidb_tpu_topsql_records_total", "finished statements folded into the Top SQL ledger")
 TOPSQL_CPU_NS = REGISTRY.counter(
-    "tidb_tpu_topsql_cpu_ns_total", "host thread-CPU ns attributed to tagged statements")
+    "tidb_tpu_topsql_cpu_ns_total", "host thread-CPU ns attributed to tagged statements: the session's thread and the dispatch pool's")
 TOPSQL_DEVICE_NS = REGISTRY.counter(
-    "tidb_tpu_topsql_device_ns_total", "fused-program device ns attributed to tagged statements")
+    "tidb_tpu_topsql_device_ns_total", "ns tagged statements waited for the device (exec.wait on every thread of the statement: queue, execution, the launch's transfer), not device-busy time")
 TOPSQL_COMPILE_NS = REGISTRY.counter(
     "tidb_tpu_topsql_compile_ns_total", "program compile ns attributed to tagged statements")
 TOPSQL_BACKOFF_MS = REGISTRY.counter(
@@ -550,7 +575,7 @@ TOPSQL_BACKOFF_MS = REGISTRY.counter(
 TOPSQL_QUEUE_MS = REGISTRY.counter(
     "tidb_tpu_topsql_queue_ms_total", "admission queue wait ms attributed to tagged statements")
 TOPSQL_LAUNCH_DEVICE_NS = REGISTRY.counter(
-    "tidb_tpu_topsql_launch_device_ns_total", "total device ns of launches that ran under a statement tag (the conservation ledger)")
+    "tidb_tpu_topsql_launch_device_ns_total", "total exec.wait ns of launches that ran under a statement tag (the conservation ledger)")
 TOPSQL_WINDOWS_SEALED = REGISTRY.counter(
     "tidb_tpu_topsql_windows_sealed_total", "Top SQL reporter windows sealed into the ring")
 TOPSQL_OTHERS_FOLDED = REGISTRY.counter(
