@@ -169,8 +169,36 @@ class TestLaunchSpans:
             assert str(sess.execute("SELECT SUM(k) FROM sb WHERE id BETWEEN 14 AND 113").values()[0][0]) == "4950"
         assert m.by["PROGRAM_READBACK_TRANSFERS"] == m.by["PROGRAM_LAUNCHES"] >= 1
         assert built == []
-        traced(sess, "SELECT SUM(k) FROM sb WHERE id BETWEEN 14 AND 113")
+        traced(sess, "SELECT SUM(k) FROM sb WHERE id BETWEEN 15 AND 114")   # the identical repeat is a cop result: no launch at all
         assert "exec.readback" in built
+
+
+    @pytest.mark.parametrize("regions", [1, 4], ids=["lone_task", "mesh_group"])
+    def test_a_fused_statement_shows_one_program_and_no_root_merge(self, sess, regions):
+        """Where the pushdown comes back as one state the store's program
+        ran the root's half too (ISSUE 37): TRACE shows the one launch with
+        `root_fused` on its cop span and no `distsql.root_merge`; EXPLAIN
+        ANALYZE keeps its per-executor rows, so it keeps the split path."""
+        if regions > 1:
+            sess.execute(f"SPLIT TABLE sb BETWEEN (0) AND (300) REGIONS {regions}")
+        sql = "SELECT c, SUM(k) FROM sb WHERE id BETWEEN {} AND 290 GROUP BY c ORDER BY SUM(k) DESC, c LIMIT 3"
+        want = sess.execute(sql.format(5)).values()
+        with Moved(LAUNCH_COUNTERS + ("ROOT_FUSED_STATEMENTS", "ROOT_FUSE_FALLBACKS")) as m:
+            tree = traced(sess, sql.format(6))
+        (cop,) = find(tree, "cop.mesh_execute" if regions > 1 else "cop.execute")
+        assert cop["attrs"]["root_fused"] is True and not find(tree, "distsql.root_merge")
+        (launched,) = find(tree, "exec.launch")
+        assert launched["attrs"]["program"] == "cop_scan_sel_groupagg_topn" + (f"_m{regions}x{regions}" if regions > 1 else "")
+        assert launched["attrs"]["params"] == 2 and len(find(tree, "exec.readback")) == 1
+        assert (m.by["PROGRAM_LAUNCHES"], m.by["PROGRAM_FETCHES"], m.by["PROGRAM_COMPILES"]) == (1, 1, 0)
+        assert (m.by["ROOT_FUSED_STATEMENTS"], m.by["ROOT_FUSE_FALLBACKS"]) == (1, 0)
+        assert tree["attrs"]["rows"] == len(want) == 3
+        with Moved(("ROOT_FUSED_STATEMENTS", "ROOT_FUSE_FALLBACKS")) as m:
+            rows = sess.execute("EXPLAIN ANALYZE " + sql.format(7)).values()
+        assert (m.by["ROOT_FUSED_STATEMENTS"], m.by["ROOT_FUSE_FALLBACKS"]) == (0, 1)
+        by_exec = {r[0]: r for r in rows}
+        assert by_exec["push[TableScan]"][2] == regions and by_exec["result"][1] == 3
+        assert by_exec["push[Aggregation]"][1] >= 3 and by_exec["push[Selection]"][1] == 290 - 7 + 1
 
 
 # ------------------------------------------------- the batched and mesh drivers
@@ -420,7 +448,7 @@ def test_program_names_come_from_the_shape_not_the_literals(sess):
     d = programs("SELECT DISTINCT c FROM sb WHERE id BETWEEN 21 AND 120 ORDER BY c")
     assert a and a[0] == "cop_scan_sel_agg"
     assert b == []   # the same shape with other literals: the program that is there is called
-    assert d[0] == "cop_scan_sel_distinct"
+    assert d[0] == "cop_scan_sel_distinct_sort"   # one region: the lone cop task's program holds the root's stages too
     assert not set(a) & set(d)
 
 

@@ -332,9 +332,16 @@ def test_wire_roundtrip_mesh_fields():
                      mesh_min_rows=1 << 33)
     back = decode_cop_request(encode_cop_request(req))
     assert back.mesh is True and back.mesh_min_rows == 1 << 33
+    assert back.whole_dag is None
     resp = CopResponse(chunk=None, region_error="x", batched=2, mesh_merged=5)
     rback = decode_cop_response(encode_cop_response(resp))
-    assert rback.batched == 2 and rback.mesh_merged == 5
+    assert rback.batched == 2 and rback.mesh_merged == 5 and rback.root_fused is False
+    # the root's half rides the request, and the marker the response (ISSUE 37)
+    whole = logical_dag((AggDesc("count", ()), AggDesc("sum", (col(1, I),))), group_by=(col(0, I),))
+    req = CopRequest(split_dag(whole).push_dag, full_table_ranges(TID), 100, 3, 1, mesh=True, whole_dag=whole)
+    back = decode_cop_request(encode_cop_request(req))
+    assert back.whole_dag.fingerprint() == whole.fingerprint() and back.dag.fingerprint() == req.dag.fingerprint()
+    assert decode_cop_response(encode_cop_response(CopResponse(chunk=None, mesh_merged=5, root_fused=True))).root_fused is True
 
 
 def test_run_sharded_partial_agg_rejects_grouped_dag():
@@ -365,6 +372,25 @@ def test_wire_mode_select_meshes():
     res = select(store, KVRequest(dag, full_table_ranges(TID), start_ts=100, use_wire=True))
     assert metrics.MESH_COP_LANES.value - l0 == 6
     assert res.batch_stats["mesh_lanes"] == 6
+
+
+@pytest.mark.parametrize("regions,stores", [(1, 1), (6, 1), (6, 2)], ids=["lone_task", "mesh_group", "two_stores"])
+def test_wire_mode_carries_the_roots_half_and_the_marker(regions, stores):
+    """Through the serialized seam the request still carries the unsplit
+    DAG and the response the marker: one store's mesh group (or the lone
+    task) answers the statement's rows, two stores' groups their merged
+    states, which the root would merge (ISSUE 37)."""
+    store = fill_store(regions=regions, stores=stores)
+    whole = logical_dag((AggDesc("count", ()), AggDesc("sum", (col(1, I),))), group_by=(col(0, I),))
+    plan = split_dag(whole)
+    res = select(store, KVRequest(plan.push_dag, full_table_ranges(TID), start_ts=100, use_wire=True, whole_dag=whole))
+    assert res.root_fused is (stores == 1)
+    got = res.merged()
+    if stores == 1:
+        want = oracle_rows(store, whole)
+        assert sorted((r[2].val, r[0].val, str(r[1].val)) for r in got.rows()) == sorted((r[2].val, r[0].val, str(r[1].val)) for r in want)
+    else:
+        assert got.num_cols() == len(plan.push_dag.output_fts()) and res.batch_stats["mesh_batches"] == 2
 
 
 # ----------------------------------------------------------- SQL + chaos

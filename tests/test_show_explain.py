@@ -79,3 +79,22 @@ def test_explain_analyze_attribution_columns(sess):
     hits2, total2 = scan2[5].split("/")
     assert hits2 == total2  # all cache hits, no recompiles
     assert scan2[4] == "0.00ms"
+
+
+def test_explain_analyze_is_the_same_beside_a_statement_whose_root_half_was_fused(sess):
+    """A lone cop task runs the whole statement in one program (ISSUE 37);
+    EXPLAIN ANALYZE declines that, so its rows are what they were: the
+    pushdown's executors with their counts, then the result."""
+    from tidb_tpu.util import metrics
+
+    q = "SELECT v, count(*) FROM t WHERE id > {} GROUP BY v ORDER BY v LIMIT 4"
+    fused = metrics.ROOT_FUSED_STATEMENTS.value
+    got = sess.execute(q.format(10)).values()
+    assert metrics.ROOT_FUSED_STATEMENTS.value == fused + 1 and len(got) == 4
+    declined = metrics.ROOT_FUSE_FALLBACKS.value
+    rows = sess.execute("EXPLAIN ANALYZE " + q.format(11)).values()
+    assert metrics.ROOT_FUSE_FALLBACKS.value == declined + 1 and metrics.ROOT_FUSED_STATEMENTS.value == fused + 1
+    assert [r[0] for r in rows][:5] == ["push[TableScan]", "push[Selection]", "push[Aggregation]",
+                                        "root[Aggregation]", "root[TopN]"]
+    by_exec = {r[0]: r for r in rows}
+    assert (by_exec["push[Selection]"][1], by_exec["push[Aggregation]"][1], by_exec["result"][1]) == (89, 7, 4)

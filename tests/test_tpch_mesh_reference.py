@@ -11,7 +11,10 @@ per-request mesh tier).  Compared exactly with the numpy reference and with
 the same statements under both sysvars OFF; one program a statement shape
 whatever the draw; no fall-back; `cop-debug-raise` reaches the tier; the
 spans a traced run is reduced by; the stacked lanes and Q3's build sides stay
-on the devices from one statement to the next (PR 35).  The benchmark's cell `tpch_q1q6q3_mesh4`
+on the devices from one statement to the next (PR 35); the one store's group
+is the whole request, so the same program goes on through the root's half
+behind its merge: one launch and one read-back a statement, no merge at the
+root, the rows those of the split path (ISSUE 37).  The benchmark's cell `tpch_q1q6q3_mesh4`
 makes the same comparison on four chips at 131,072 rows; here it is 4,096."""
 
 import json
@@ -22,6 +25,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec
 
+from test_root_exec import root_half_left_off
 from test_tpch_columnar_reference import BENCH, _json, _load, forget_root_programs
 
 from tidb_tpu.server import MiniClient, MySQLServer
@@ -39,7 +43,8 @@ DRAWS = {
 }
 NAMES = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_LAUNCHES", "MESH_COP_BATCHES", "MESH_COP_LANES", "MPP_SELECTS",
          "MESH_COP_FALLBACKS", "MPP_FALLBACKS", "COP_FALLBACKS", "MESH_STACK_HITS", "MESH_STACK_MISSES", "COP_AUX_UPLOADS",
-         "COP_DECODE_DEVICE_BYTES", "COP_DECODE_HITS", "COP_DECODE_MISSES")
+         "COP_DECODE_DEVICE_BYTES", "COP_DECODE_HITS", "COP_DECODE_MISSES", "PROGRAM_FETCHES", "ROOT_FUSED_STATEMENTS",
+         "ROOT_FUSE_FALLBACKS")
 
 
 class Served:
@@ -57,6 +62,8 @@ class Served:
         self.conn.query(f"set tidb_isolation_read_engines = '{self.mix['read_engines']}'")
         forget_root_programs()   # the first executions below are counted as a fresh server's
         self.cases = {(name, i): self.run(name, p) for name, draws in DRAWS.items() for i, p in enumerate(draws)}
+        with root_half_left_off():   # the mesh tier as it was: the merged state read back, the root's merge a second program
+            self.split = {(name, i): self.run(name, p) for name, draws in DRAWS.items() for i, p in enumerate(draws)}
         for var in ("tidb_enable_tpu_mesh", "tidb_allow_mpp"):
             self.conn.query(f"set {var} = OFF")
         self.single = {(name, i): self.run(name, p) for name, draws in DRAWS.items() for i, p in enumerate(draws)}
@@ -113,11 +120,27 @@ def test_every_statement_is_one_cross_chip_program_and_none_falls_back(served, n
 @pytest.mark.parametrize("name", list(DRAWS))
 def test_the_first_draw_builds_the_programs_and_no_later_draw_builds_one(served, name):
     first = served.cases[name, 0]["moved"]
-    assert first["PROGRAM_COMPILES"] >= 2 and first["XLA_COMPILES"] >= 1, first   # the mesh program and the root's merge
+    assert first["PROGRAM_COMPILES"] >= 1 and first["XLA_COMPILES"] >= 1, first   # the statement's one mesh program
     for i in (1, 2, 3):
         m = served.cases[name, i]["moved"]
         assert m["PROGRAM_COMPILES"] == m["XLA_COMPILES"] == 0, (name, i, m)
-        assert m["PROGRAM_LAUNCHES"] == 2, (name, i, m)   # the mesh program, the root's merge; Q3's build scans are cop results
+        assert m["PROGRAM_LAUNCHES"] == m["PROGRAM_FETCHES"] == 1, (name, i, m)   # Q3's build scans are cop results
+
+
+@pytest.mark.parametrize("name,i", CASES)
+def test_the_mesh_program_finishes_the_statement_and_answers_the_split_paths_rows(served, name, i):
+    """The root's half ran behind the on-device merge: no second program.
+    With it left off, the same mesh launch hands back one merged state and
+    the root merges it (two launches, two read-backs): the same rows in the
+    same order, Q3's ten included."""
+    fused, split = served.cases[name, i], served.split[name, i]
+    assert fused["rows"] == split["rows"]
+    m = fused["moved"]
+    assert (m["ROOT_FUSED_STATEMENTS"], m["ROOT_FUSE_FALLBACKS"], m["MESH_COP_BATCHES"]) == (1, 0, 1), m
+    m = split["moved"]
+    assert (m["ROOT_FUSED_STATEMENTS"], m["ROOT_FUSE_FALLBACKS"], m["MESH_COP_BATCHES"]) == (0, 1, 1), m
+    assert m["PROGRAM_LAUNCHES"] == m["PROGRAM_FETCHES"] == 2 and m["MESH_COP_FALLBACKS"] == 0, m
+    assert m["PROGRAM_COMPILES"] == (0 if i else 2), m       # the plain mesh program and the root's merge, once a shape
 
 
 @pytest.mark.parametrize("name", list(DRAWS))
@@ -178,8 +201,9 @@ def test_a_traced_q3_lays_the_mesh_tier_under_the_dispatch_span(served):
     assert (got["moved"]["MESH_STACK_HITS"], got["moved"]["MESH_STACK_MISSES"], got["moved"]["COP_AUX_UPLOADS"]) == (1, 0, 0)
     (execute,) = find(probe, "cop.mesh_execute")
     assert find(execute, "mesh.stack") == [stack] and find(execute, "exec.launch") and not find(tree, "exec.compile")
-    assert [n["attrs"]["program"] for n in find(execute, "exec.launch")] == ["cop_scan_sel_join_join_groupagg_m8x8"]
-    assert find(probe, "cop.mesh_decode") and find(probe, "distsql.root_merge")
+    assert [n["attrs"]["program"] for n in find(execute, "exec.launch")] == ["cop_scan_sel_join_join_groupagg_topn_m8x8"]
+    assert execute["attrs"]["root_fused"] is True and len(find(probe, "exec.launch")) == 1
+    assert find(probe, "cop.mesh_decode") and not find(tree, "distsql.root_merge")
     assert got["moved"]["MESH_COP_BATCHES"] == 1 and got["moved"]["PROGRAM_COMPILES"] == 0
 
 

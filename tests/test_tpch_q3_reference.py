@@ -10,7 +10,10 @@ CPU.  One join program serves every SEGMENT and DATE (the literals are
 its operands, the string too), the build sides come from the cop result
 cache and stay uploaded, `lineitem`'s decoded chunk and device batch stay
 resident from one statement to the next (PR 33), nothing falls back to the
-oracle.  The deployment module, the statements and the mix are loaded by path."""
+oracle.  The table is one region, so its one cop task is the statement's
+whole input and runs the unsplit DAG: one program and one read-back a
+statement, no merge at the root, the rows those of the split path (ISSUE
+37).  The deployment module, the statements and the mix are loaded by path."""
 
 import json
 import os
@@ -18,6 +21,7 @@ import os
 import numpy as np
 import pytest
 
+from test_root_exec import root_half_left_off
 from test_tpch_columnar_reference import BENCH, _json, _load, forget_root_programs
 
 from tidb_tpu.server import MiniClient, MySQLServer
@@ -30,7 +34,7 @@ SEGMENTS = ("AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD")
 DATES = ("1995-03-01", "1995-03-15", "1995-03-31")
 NAMES = ("PROGRAM_COMPILES", "XLA_COMPILES", "PROGRAM_LAUNCHES", "PROGRAM_PARAMS_BOUND", "PROGRAM_STR_PARAMS_BOUND",
          "COP_AUX_UPLOADS", "COP_CACHE_HITS", "COP_REQUESTS", "COP_FALLBACKS", "COP_DECODE_HITS", "COP_DECODE_MISSES",
-         "NATIVE_DECODES")
+         "NATIVE_DECODES", "PROGRAM_FETCHES", "ROOT_FUSED_STATEMENTS", "ROOT_FUSE_FALLBACKS")
 
 
 class Served:
@@ -52,6 +56,8 @@ class Served:
         forget_root_programs()   # the first executions below are counted as a fresh server's
         self.first = self.run({"segment": "BUILDING", "date": "1995-03-15"})   # the spec's validation parameters
         self.cases = {(s, d): self.run({"segment": s, "date": d}) for s in SEGMENTS for d in DATES}
+        with root_half_left_off():
+            self.split = {(s, d): self.run({"segment": s, "date": d}) for s in SEGMENTS for d in DATES}
 
     def run(self, params: dict, trace: bool = False) -> dict:
         before = {n: getattr(metrics, n).value for n in NAMES}
@@ -82,13 +88,28 @@ def test_served_q3_equals_the_plain_reference(served, segment, date):
 
 def test_the_first_execution_builds_one_join_program_and_no_draw_another(served):
     first = served.first["moved"]
-    # two build scans, the join, the root's merge; every launch after it calls what is there
-    assert first["PROGRAM_COMPILES"] == 4 and first["COP_AUX_UPLOADS"] == 2 and first["COP_FALLBACKS"] == 0
+    # two build scans and the statement's one program; every launch after it calls what is there
+    assert first["PROGRAM_COMPILES"] == 3 and first["COP_AUX_UPLOADS"] == 2 and first["COP_FALLBACKS"] == 0
     for key, got in served.cases.items():
         m = got["moved"]
         assert m["PROGRAM_COMPILES"] == m["XLA_COMPILES"] == 0, (key, m)
-        assert m["COP_FALLBACKS"] == 0 and m["PROGRAM_LAUNCHES"] == 2, (key, m)
+        assert m["COP_FALLBACKS"] == 0 and m["PROGRAM_LAUNCHES"] == m["PROGRAM_FETCHES"] == 1, (key, m)
+        assert (m["ROOT_FUSED_STATEMENTS"], m["ROOT_FUSE_FALLBACKS"]) == (1, 0), (key, m)
         assert m["PROGRAM_STR_PARAMS_BOUND"] == 1 and m["PROGRAM_PARAMS_BOUND"] == 4, (key, m)   # SEGMENT; two DATEs, 1 - l_discount
+
+
+@pytest.mark.parametrize("date", DATES)
+@pytest.mark.parametrize("segment", SEGMENTS)
+def test_the_one_program_answers_the_split_paths_rows(served, segment, date):
+    """The lone cop task ran the unsplit DAG; with the root's half left off
+    the same statement is the join program and the root's merge, two
+    launches and two read-backs, and answers the same ten rows in the same
+    order."""
+    fused, split = served.cases[segment, date], served.split[segment, date]
+    assert fused["rows"] == split["rows"]
+    m = split["moved"]
+    assert m["PROGRAM_LAUNCHES"] == m["PROGRAM_FETCHES"] == 2 and m["COP_FALLBACKS"] == 0, m
+    assert (m["ROOT_FUSED_STATEMENTS"], m["ROOT_FUSE_FALLBACKS"]) == (0, 1), m
 
 
 def test_build_sides_come_from_the_result_cache_and_stay_uploaded(served):
@@ -114,9 +135,12 @@ def test_lineitem_is_decoded_once_and_found_resident_by_every_later_draw(served)
 
 def test_the_groups_straddle_a_rung_of_the_root_merge(served):
     """What the sticky rung is for (`ProgramCache.input_capacity`): the
-    number of groups that reach the root moves with SEGMENT and DATE."""
+    number of groups that reach the root moves with SEGMENT and DATE, and
+    where the root still merges (the split path) every draw after the
+    first calls the one merge program."""
     groups = {k: len(served.dep.reference("q3", v["params"], served.data)) for k, v in served.cases.items()}
     assert len(set(groups.values())) > 3, groups
+    assert sum(v["moved"]["PROGRAM_COMPILES"] for v in served.split.values()) == 2   # the partial join program, the merge
 
 
 def test_trace_shows_the_build_fetch_and_the_uploaded_build_sides(served):
@@ -131,7 +155,9 @@ def test_trace_shows_the_build_fetch_and_the_uploaded_build_sides(served):
     assert build["attrs"]["tables"] == 2 and build["attrs"]["rows"] == orders + customer and build["attrs"]["bytes"] > 0
     assert [c["name"] for c in build["children"]] == ["distsql.execute_root", "distsql.execute_root"]
     assert [(a["attrs"]["rows"], a["attrs"]["hit"]) for a in find(tree, "cop.aux_batch")] == [(orders, True), (customer, True)]
-    assert [n["attrs"]["program"] for n in find(tree, "exec.launch")] == ["cop_scan_sel_join_join_groupagg", "cop_scan_groupagg_topn"]
+    assert [n["attrs"]["program"] for n in find(tree, "exec.launch")] == ["cop_scan_sel_join_join_groupagg_topn"]
+    (execute,) = [n for n in find(tree, "cop.execute") if find(n, "exec.launch")]
+    assert execute["attrs"]["root_fused"] is True and not find(tree, "distsql.root_merge")
     assert find(tree, "cop.decode") and not find(tree, "exec.compile") and not find(tree, "cop.oracle_fallback")
     assert tree["attrs"]["rows"] == served.dep.expected_rows(
         "q3", served.dep.reference("q3", got["params"], served.data))

@@ -541,6 +541,8 @@ def encode_cop_request(req, _aux_index=None) -> bytes:
     w.bool_(req.mesh)
     # i64: the tidb_tpu_mesh_min_rows sysvar range (up to 1<<40) exceeds i32
     w.i64(req.mesh_min_rows)
+    # the root's half rides the request: the unsplit DAG, empty for none
+    w.blob(b"" if req.whole_dag is None else encode_dag(req.whole_dag))
     return w.done()
 
 
@@ -569,11 +571,13 @@ def decode_cop_request(b: bytes, _aux_table: list | None = None):
     replica_read = r.bool_()
     mesh = r.bool_() if r.i < len(r.b) else False
     mesh_min_rows = r.i64() if r.i < len(r.b) else 0
+    whole = r.blob() if r.i < len(r.b) else b""
     return CopRequest(dag, ranges, start_ts, region_id, epoch, aux,
                       None if paging < 0 else paging,
                       None if smg < 0 else smg,
                       peer_store=peer_store, replica_read=replica_read,
-                      mesh=mesh, mesh_min_rows=mesh_min_rows)
+                      mesh=mesh, mesh_min_rows=mesh_min_rows,
+                      whole_dag=decode_dag(whole) if whole else None)
 
 
 def encode_cop_response(resp) -> bytes:
@@ -602,6 +606,7 @@ def encode_cop_response(resp) -> bytes:
             w.blob(rg.end)
     w.i32(int(getattr(resp, "batched", 0)))
     w.i32(int(getattr(resp, "mesh_merged", 0)))
+    w.bool_(resp.root_fused)
     return w.done()
 
 
@@ -622,8 +627,9 @@ def decode_cop_response(b: bytes):
         last_range = [KeyRange(r.blob(), r.blob()) for _ in range(r.i32())]
     batched = r.i32() if r.i < len(r.b) else 0
     mesh_merged = r.i32() if r.i < len(r.b) else 0
+    root_fused = r.bool_() if r.i < len(r.b) else False
     return CopResponse(chunk, region_error, other_error, summaries, last_range, batched,
-                       mesh_merged)
+                       mesh_merged, root_fused)
 
 
 # ----------------------------------------------------- batched cop frames

@@ -234,6 +234,12 @@ class KVRequest:
     # pins the request to the vmap/pool tiers (distsql/planner.py)
     mesh_min_rows: int = 0  # tidb_tpu_mesh_min_rows: data-size floor the
     # planner applies before attempting the mesh tier
+    whole_dag: DAGRequest | None = None  # the statement's unsplit DAG, `dag`
+    # being its pushdown half: the root's half rides the request. `select`
+    # hands it on (CopRequest.whole_dag) only where one store will hold the
+    # statement's one state, the request's lone cop task or a mesh group
+    # of all its tasks, and `SelectResult.root_fused` says whether the
+    # program that answered ran it
 
 
 @dataclass
@@ -256,6 +262,8 @@ class SelectResult:
     chunks: list
     exec_summaries: list = field(default_factory=list)
     batch_stats: dict | None = None
+    root_fused: bool = False  # every cop response carried the marker: the
+    # chunks are the statement's rows, the root's half ran where they came from
 
     def merged(self) -> Chunk:
         """The regions' chunks as one; a lone chunk as it is (chunks are
@@ -399,14 +407,17 @@ def _failover(store, region_id: int, bad_store: int, boff) -> int | None:
 
 
 def _run_one_task(store, req, task, summaries, retries=MAX_RETRY,
-                  dispatch_span=None, scan_kind="table", boff=None):
+                  dispatch_span=None, scan_kind="table", boff=None, fused=None):
     """One cop task; drives the paging loop when paging is on (ref:
     copr/coprocessor.go:1393 handleCopPagingResult — each page's lastRange
     seeds the next request until the task drains). Shared by select()'s
     pool workers and the sequential select_stream path so metrics, spans,
     failpoints, wire routing AND the typed error contract cannot drift
     apart. Returns the task's chunks (retry subtasks included); summaries
-    accumulate in place.
+    accumulate in place. `fused` (a list) says the task is the request's
+    only one: its cop requests carry the root's half (`req.whole_dag`) and
+    each response's `root_fused` is appended; a task re-split after a
+    region error is several, and carries none.
 
     Region errors are CLASSIFIED (ref: copr/coprocessor.go:1424
     handleCopResponse): each kind retries on its own Backoffer budget.
@@ -475,6 +486,7 @@ def _run_one_task(store, req, task, summaries, retries=MAX_RETRY,
                 aux_chunks=req.aux_chunks, paging_size=req.paging_size,
                 small_groups=req.small_groups, peer_store=sid,
                 replica_read=req.replica_read != "leader" and sid != leader,
+                whole_dag=req.whole_dag if fused is not None else None,
             )
             if req.use_wire:
                 from ..codec.wire import decode_cop_response, encode_cop_request
@@ -544,6 +556,8 @@ def _run_one_task(store, req, task, summaries, retries=MAX_RETRY,
                     boff.backoff(err.kind, resp.region_error)
                 except BackoffExhausted as exc:
                     raise RegionUnavailableError(str(exc)) from exc
+                if fused is not None:
+                    fused.append(False)  # what is left of the task is served split
                 for s2 in _build_tasks(store, ranges):
                     out_chunks.extend(_run_one_task(
                         store, req, s2, summaries, retries - 1,
@@ -558,6 +572,8 @@ def _run_one_task(store, req, task, summaries, retries=MAX_RETRY,
                 pd.note_store_up(sid)
             summaries.append(resp.exec_summaries)
             out_chunks.append(resp.chunk)
+            if fused is not None:
+                fused.append(resp.root_fused)
             pages += 1
             if resp.last_range is None:
                 if sp is not None:
@@ -571,7 +587,7 @@ def _run_one_task(store, req, task, summaries, retries=MAX_RETRY,
 
 
 def _run_store_batch(store, req, sid, entries, results, summaries_by_task,
-                     dispatch_span, scan_kind, mesh: bool = False) -> dict:
+                     dispatch_span, scan_kind, mesh: bool = False, fused=None) -> dict:
     """ONE batched dispatch for all of a store's region tasks (ref:
     copr/batch_coprocessor.go — a TiFlash store's regions travel in one
     request): the store stacks the regions and drives one vmapped launch —
@@ -584,7 +600,11 @@ def _run_store_batch(store, req, sid, entries, results, summaries_by_task,
     that comes back with a region_error (stale epoch after a concurrent
     split, region folded by a merge, a follower's safe_ts gate) falls out
     of the batch into the standard _run_one_task retry path — the rest of
-    the batch's results stand. Returns this batch's attribution stats."""
+    the batch's results stand. `fused` (a list) says this store's group is
+    the whole request: its cop requests carry the root's half
+    (`req.whole_dag`) and each lane's `root_fused` is appended, False for
+    a lane that fell out of the batch. Returns this batch's attribution
+    stats."""
     import time as _time
 
     from ..util import failpoint as _fp
@@ -616,6 +636,7 @@ def _run_store_batch(store, req, sid, entries, results, summaries_by_task,
             replica_read=(req.replica_read != "leader"
                           and sid != store.cluster.leader_of(t.region_id)),
             mesh=mesh, mesh_min_rows=req.mesh_min_rows,
+            whole_dag=req.whole_dag if fused is not None else None,
         ))
     t_batch = _time.monotonic()
     stats = {"batches": 0, "regions": 0, "launches_saved": 0,
@@ -635,6 +656,8 @@ def _run_store_batch(store, req, sid, entries, results, summaries_by_task,
         served_ok = 0
         for (i, t), resp in zip(entries, resps):
             sums = summaries_by_task[i]
+            if fused is not None:
+                fused.append(resp.root_fused)
             if resp.region_error is not None:
                 from ..store.errors import parse_region_error
 
@@ -735,11 +758,16 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
     stmt_tag = topsql.current_tag()
     scan_kind = _scan_kind(req)
     batch_stats: dict | None = None
+    # the root's half rides the request (`req.whole_dag`) and is handed on
+    # only where one store will hold the statement's one state: each cop
+    # response then appends here whether its program ran it
+    fused: list = []
+    lone = fused if req.whole_dag is not None and len(tasks) == 1 else None
 
     def run_task(i: int, task: CopTask):
         with topsql.adopt(stmt_tag):
             return _run_one_task(store, req, task, summaries_by_task[i],
-                                 dispatch_span=dispatch_span, scan_kind=scan_kind)
+                                 dispatch_span=dispatch_span, scan_kind=scan_kind, fused=lone)
 
     def pooled(fn, *args):
         with tracing.pool_task(stmt_tag):
@@ -768,11 +796,14 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
             by_store.setdefault(_route_task(store, req, t, ctx=ctx),
                                 []).append((i, t))
 
+        mesh = decision.tier == "mesh"
+        whole = fused if req.whole_dag is not None and mesh and len(by_store) == 1 else None
+
         def run_batch(sid, entries):
             with topsql.adopt(stmt_tag):
                 return _run_store_batch(store, req, sid, entries, results,
                                         summaries_by_task, dispatch_span, scan_kind,
-                                        mesh=decision.tier == "mesh")
+                                        mesh=mesh, fused=whole)
 
         with tracing.span("distsql.wait_tasks", tasks=len(by_store)), \
                 ThreadPoolExecutor(max_workers=max(len(by_store), 1)) as pool:
@@ -799,4 +830,4 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
     chunks = [c for sub in results for c in sub if c is not None]
     summaries = [s for per_task in summaries_by_task for s in per_task]
     return SelectResult(chunks=chunks, exec_summaries=summaries,
-                        batch_stats=batch_stats)
+                        batch_stats=batch_stats, root_fused=bool(fused) and all(fused))

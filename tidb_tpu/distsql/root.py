@@ -187,6 +187,23 @@ def execute_root(
     pushdown half per region, merge at root. The caller-visible result is
     identical to running the whole DAG over all rows at once.
 
+    The root's half rides the request (`KVRequest.whole_dag`, the unsplit
+    DAG), and where the pushdown comes back as ONE state the store runs it
+    in the pushdown's own program: a request's lone cop task runs the
+    unsplit DAG over its region's batch, and a mesh launch whose one store
+    group holds every task goes on, behind its on-device merge, through
+    the Final re-group, HAVING, TopN / Sort / Limit, the projection and the
+    output offsets. Every response then carries `root_fused`, the chunks
+    are the statement's rows, and nothing is merged, uploaded, launched or
+    read back here (`tidb_tpu_root_fused_statements_total`). Anything else
+    merges here as ever and is counted
+    (`tidb_tpu_root_fuse_fallbacks_total`): several tasks outside the mesh
+    tier or over several stores, a lane answered by the cop cache or
+    retried, a mesh launch that degraded or raised its overflow flag, a
+    lone program whose capacity retries ran out; and the requests that
+    never carry it: a root half with a host-only operator, EXPLAIN ANALYZE
+    (summary_sink), low_memory, paging and build_side.
+
     allow_mpp (tidb_allow_mpp) puts the statement tier first (ref:
     mpp_gather.go:40 useMPPExecution, asked once a statement before task
     planning): an exchange-eligible DAG is planned as a fragment graph and
@@ -210,8 +227,9 @@ def execute_root(
 
     mesh (tidb_enable_tpu_mesh) lets the dispatch planner shard eligible
     partial-agg/TopN pushdowns over the device mesh and merge the partial
-    states ON DEVICE (psum over the region axis) — the root's Final merge
-    then consumes ONE state per store instead of R per-region partials.
+    states ON DEVICE (psum over the region axis) — with one store the same
+    program finishes the statement (above); with several, the root's Final
+    merge consumes ONE state per store instead of R per-region partials.
 
     paging_size applies only when the pushdown half is row-local (the store
     rejects paged aggregation/TopN/Limit); otherwise it is ignored here.
@@ -291,16 +309,28 @@ def _execute_root(
                 # as batch_stats)
                 summary_sink.append({"columnar": {"rows": served.num_rows()}})
             return served
+    from ..util import metrics
+
     plan = split_dag(dag)
     if low_memory and plan.root_dag is not None:
         folded = _execute_root_lowmem(store, plan, ranges, start_ts, aux_chunks or [], cache, group_capacity, tracker)
         if folded is not None:
+            metrics.ROOT_FUSE_FALLBACKS.inc()
             return folded
     if paging_size is not None:
         from ..exec.dag import Aggregation as _A, Limit as _L, Sort as _S, TopN as _T, executor_walk
 
         if any(isinstance(e, (_A, _T, _L, _S)) for e in executor_walk(plan.push_dag.executors)):
             paging_size = None
+    # the root's half rides the request: where the pushdown comes back as
+    # one state (a lone cop task, a mesh group of every task) the store runs
+    # the unsplit DAG in that program and there is nothing to merge here.
+    # EXPLAIN ANALYZE keeps its per-executor rows, the low-memory degrade
+    # and paging their bounded pieces, a build side its per-region chunks,
+    # and what only the host evaluates stays with the oracle's fall-back
+    rides = (plan.root_dag is not None and summary_sink is None and not low_memory
+             and paging_size is None and not build_side
+             and not any(_has_host_only_op(ex) for ex in plan.root_dag.executors[1:]))
     res: SelectResult = select(
         store,
         KVRequest(
@@ -309,6 +339,7 @@ def _execute_root(
             batch_cop=batch_cop, small_groups=small_groups, checker=checker,
             backoff_weight=backoff_weight, replica_read=replica_read,
             mesh=mesh, mesh_min_rows=mesh_min_rows,
+            whole_dag=dag if rides else None,
         ),
     )
     if summary_sink is not None:
@@ -327,9 +358,12 @@ def _execute_root(
     if merged is None:
         merged = Chunk.empty(plan.push_dag.output_fts())
     out = merged
-    if plan.root_dag is not None:
+    if plan.root_dag is not None and res.root_fused:
+        metrics.ROOT_FUSED_STATEMENTS.inc()  # `merged` holds the statement's rows
+    elif plan.root_dag is not None:
         from ..util import tracing
 
+        metrics.ROOT_FUSE_FALLBACKS.inc()
         # run_dag_on_chunks has the oracle fallback — a root merge whose
         # group count outgrows every capacity retry degrades, not crashes
         with tracing.span("distsql.root_merge", in_rows=merged.num_rows()):

@@ -213,21 +213,27 @@ def _gather_pruned(cols: list, idx, used: set, base: int) -> list:
     return out
 
 
-def _run_pipeline(executors, batches, cursor, group_capacity, join_capacity, state: _TraceState, topn_full: bool = False, small_groups: int | None = None, unique_joins: bool = True, out_offsets=None):
+def _run_pipeline(executors, batches, cursor, group_capacity, join_capacity, state: _TraceState, topn_full: bool = False, small_groups: int | None = None, unique_joins: bool = True, out_offsets=None, rows=None):
     """Trace one executor pipeline; recursion handles Join build sides.
 
     batches are consumed in canonical scan order (dag.collect_scans);
-    `cursor` is the trace-time index of the next unconsumed batch."""
+    `cursor` is the trace-time index of the next unconsumed batch.
+    `rows` = (cols, valid, fts) stands in for the scan's batch where its
+    output is already traced: the mesh program's merged state, which the
+    root's half of the statement goes on from (`_build_mesh_fn`)."""
     scan = executors[0]
     assert isinstance(scan, (TableScan, IndexScan)), "pipeline must start with a scan"
-    batch = batches[cursor[0]]
-    cursor[0] += 1
-    fts = [c.ft for c in scan.columns]
-    cols = [normalize_device_column(c) for c in batch.cols]
-    valid = batch.row_valid
-    # per-executor produced-row counts, scan first (real numbers for the
-    # exec summaries — ref: tipb.ExecutorExecutionSummary NumProducedRows)
-    state.rows(batch.n_rows)
+    if rows is not None:
+        cols, valid, fts = rows
+    else:
+        batch = batches[cursor[0]]
+        cursor[0] += 1
+        fts = [c.ft for c in scan.columns]
+        cols = [normalize_device_column(c) for c in batch.cols]
+        valid = batch.row_valid
+        # per-executor produced-row counts, scan first (real numbers for the
+        # exec summaries — ref: tipb.ExecutorExecutionSummary NumProducedRows)
+        state.rows(batch.n_rows)
 
     ei = 1
     while ei < len(executors):
@@ -669,6 +675,7 @@ def build_program(
     mesh_devices: int | None = None,
     mesh_kind: str | None = None,
     radix_joins: bool = True,
+    mesh_root: bool = False,
 ) -> CompiledDAG:
     """Compile the whole DAG tree (probe pipeline + all join build
     pipelines) into one fused XLA program over a tuple of device batches.
@@ -696,7 +703,18 @@ def build_program(
     ONE merged result instead of R per-region partials (SURVEY §3.1/§5).
     Mesh outputs: (merged packed cols, merged valid, per-lane ex_rows
     [R, n_exec], overflow scalar); overflow is GLOBAL — the driver falls
-    back to the vmapped tier, whose per-lane ladder takes over."""
+    back to the vmapped tier, whose per-lane ladder takes over.
+
+    mesh_root: `dag` is the statement's UNSPLIT DAG and the mesh program
+    finishes the statement. `split_dag` cuts the traced shape in two: the
+    lanes run the pushdown half, and behind the on-device merge the one
+    merged state goes on, inside the same shard_map body, through the
+    root's half (the Final re-group in place of the Partial2 one, HAVING,
+    TopN / Sort / Limit, the projection, the statement's output offsets),
+    so the outputs are the statement's rows, replicated. Both halves read
+    the one operand set of the one walk: the key is the unsplit DAG's
+    `program_key()`, and the name holds the root's stages too
+    (`cop_scan_sel_groupagg_sort_m8x4`)."""
     if isinstance(capacities, int):
         capacities = (capacities,)
     capacities = tuple(capacities)
@@ -707,6 +725,13 @@ def build_program(
     # happens to build the program are arguments like any later DAG's
     dag, _key, operands = dag.parameterized()
     lanes = operand_lanes(operands)  # which operand a `Param`'s lane names
+    lane_dag, root_dag = dag, None  # what a lane runs; what follows the mesh merge
+    if mesh_root:
+        from ..distsql.root import split_dag
+
+        plan = split_dag(dag)
+        lane_dag, root_dag = plan.push_dag, plan.root_dag
+        assert mesh_lanes is not None and root_dag is not None, "mesh_root needs a mesh program and a statement with a root half"
 
     radix_info: dict = {}
 
@@ -715,8 +740,8 @@ def build_program(
         state = _TraceState(params=dict(zip(lanes, args[n_scans:])))
         state.radix_joins = radix_joins
         cursor = [0]
-        cols, valid, _ = _run_pipeline(dag.executors, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins, out_offsets=dag.output_offsets)
-        packed = _pack_cols([cols[i] for i in dag.output_offsets])
+        cols, valid, _ = _run_pipeline(lane_dag.executors, batches, cursor, group_capacity, join_capacity, state, topn_full, small_groups, unique_joins, out_offsets=lane_dag.output_offsets)
+        packed = _pack_cols([cols[i] for i in lane_dag.output_offsets])
         n_out = valid.sum()
         # a plan that recorded no count: no constant/empty-shaped stand-in
         # — both a 0-length output and a folded-constant output have
@@ -732,8 +757,9 @@ def build_program(
         return packed, valid, n_out, ovfs, ex
 
     if mesh_lanes is not None:
-        fn = _build_mesh_fn(dag, program, n_scans, lanes, mesh_lanes,
-                            mesh_devices or 1, mesh_kind, group_capacity)
+        fn = _build_mesh_fn(lane_dag, program, n_scans, lanes, mesh_lanes,
+                            mesh_devices or 1, mesh_kind, group_capacity,
+                            root_dag, small_groups)
     elif vmap_batch is not None:
         # region axis on the probe batch only; aux/build batches and the
         # operands broadcast: a group holds requests of one fingerprint,
@@ -749,12 +775,22 @@ def build_program(
 
 
 def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, param_lanes: tuple, lanes: int,
-                   n_devices: int, kind: str, group_capacity: int):
+                   n_devices: int, kind: str, group_capacity: int,
+                   root: DAGRequest | None = None, small_groups: int | None = None):
     """shard_map wrapper: vmap the per-region program over each device's
     local lanes, then merge the per-region results on device (psum of
     partial states / merge-mode re-group / re-top-k) — the mesh tier's
     program body. `lanes` must divide over `n_devices` (the store pads the
-    region axis with empty lanes)."""
+    region axis with empty lanes).
+
+    `root` (the root half of the split whose pushdown half `dag` is) makes
+    the body go on where the merged state is: the gathered rows, or the
+    psum-merged scalar states, are the root pipeline's scan output, so its
+    first executor is the Final re-group (or the re-top-k) that the merge
+    stage would have run in Partial2 mode, and the rest of the statement
+    follows through the same `_run_pipeline` a one-chip program uses.
+    TopN there is the exact full sort: the rows are few, and the tail has
+    no retry ladder (any overflow is the program's one global flag)."""
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import REGION_AXIS, merge_packed_states, region_mesh
@@ -772,20 +808,30 @@ def _build_mesh_fn(dag: DAGRequest, program, n_scans: int, param_lanes: tuple, l
         # radix escape total over the region axis (join_radix attribution
         # — the mesh tier reports it like the other tiers)
         radix_esc = jax.lax.psum(ovfs[5].sum(), REGION_AXIS)
+        m_ovf = jnp.bool_(False)
         if kind == "scalar":
             # the north-star collective: partial states psum/pmin/pmax-
             # reduced over the region axis (parallel/mesh.py merge seam)
-            merged = [tuple(t) for t in merge_packed_states(list(last.aggs), packed)]
+            cols = [CompVal(v, nl, ft) for (v, nl), ft in
+                    zip(merge_packed_states(list(last.aggs), packed), out_fts)]
             mvalid = jnp.ones(1, bool)
-            m_ovf = jnp.bool_(False)
         else:
-            cols, gvalid = _gather_mesh_outputs(packed, valid, out_fts)
-            if kind == "group":
-                out_cols, mvalid, m_ovf = _mesh_merge_group(
-                    last, out_fts, cols, gvalid, group_capacity, params)
+            cols, mvalid = _gather_mesh_outputs(packed, valid, out_fts)
+            if root is not None:
+                pass  # its first executor re-groups (Final) or re-tops the gathered rows
+            elif kind == "group":
+                cols, mvalid, m_ovf = _mesh_merge_group(
+                    last, out_fts, cols, mvalid, group_capacity, params)
             else:
-                out_cols, mvalid, m_ovf = _mesh_merge_topn(last, out_fts, cols, gvalid, params)
-            merged = _pack_cols(out_cols)
+                cols, mvalid, m_ovf = _mesh_merge_topn(last, out_fts, cols, mvalid, params)
+        if root is not None:
+            tail = _TraceState(params)
+            cols, mvalid, _ = _run_pipeline(
+                root.executors, (), [0], group_capacity, 0, tail, topn_full=True,
+                small_groups=small_groups, rows=(cols, mvalid, out_fts))
+            cols = [cols[i] for i in root.output_offsets]
+            m_ovf = tail.group_overflow | tail.join_overflow | tail.topn_overflow
+        merged = _pack_cols(cols)
         ovf = jax.lax.pmax((local_ovf | m_ovf).astype(jnp.int32), REGION_AXIS) > 0
         return merged, mvalid, ex, ovf, radix_esc
 
@@ -835,7 +881,10 @@ def _mesh_merge_group(agg, state_fts, cols, valid, group_capacity: int, params: 
     Final merge's Partial2 re-group (root.py _merge_aggregation, partial
     output) traced INTO the mesh program — the output schema is the push
     DAG's partial schema again, so one merged table per store replaces R
-    per-region tables while the root's Final pass runs unchanged."""
+    per-region tables while the root's Final pass runs unchanged. A mesh
+    program that carries the root's half (`_build_mesh_fn(root=)`) skips
+    this stage: the root pipeline's own Final re-group reads the gathered
+    rows."""
     from dataclasses import replace as _replace
 
     from ..distsql.root import _merge_aggregation
@@ -954,10 +1003,11 @@ class ProgramCache:
         mesh_devices: int | None = None,
         mesh_kind: str | None = None,
         radix_joins: bool = True,
+        mesh_root: bool = False,
     ) -> CompiledDAG:
         return self.get_info(dag, capacities, group_capacity, join_capacity,
                              topn_full, small_groups, unique_joins, vmap_batch,
-                             mesh_lanes, mesh_devices, mesh_kind, radix_joins)[0]
+                             mesh_lanes, mesh_devices, mesh_kind, radix_joins, mesh_root)[0]
 
     def get_info(
         self,
@@ -973,6 +1023,7 @@ class ProgramCache:
         mesh_devices: int | None = None,
         mesh_kind: str | None = None,
         radix_joins: bool = True,
+        mesh_root: bool = False,
     ) -> tuple:
         """(program, cache_hit, compile_ns) — the attribution triple the
         exec summaries and the TRACE span tree surface (ref: the
@@ -987,12 +1038,15 @@ class ProgramCache:
         # buffer counts at execution)
         # mesh programs are specialized to their lane count AND device
         # count (shard_map shapes both into the trace); mesh_kind is
-        # derivable from the key but cheap to carry explicitly
-        key = (dag.program_key(), capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch, pallas_mode(), mesh_lanes, mesh_devices, mesh_kind, radix_joins)
+        # derivable from the key but cheap to carry explicitly; mesh_root
+        # says the key's DAG is the unsplit statement, its root half traced
+        # behind the mesh merge
+        key = (dag.program_key(), capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch, pallas_mode(), mesh_lanes, mesh_devices, mesh_kind, radix_joins, mesh_root)
         return self.built(
             key,
             lambda: build_program(dag, capacities, group_capacity, join_capacity, topn_full, small_groups, unique_joins, vmap_batch=vmap_batch,
-                                  mesh_lanes=mesh_lanes, mesh_devices=mesh_devices, mesh_kind=mesh_kind, radix_joins=radix_joins),
+                                  mesh_lanes=mesh_lanes, mesh_devices=mesh_devices, mesh_kind=mesh_kind, radix_joins=radix_joins,
+                                  mesh_root=mesh_root),
             batch_size=vmap_batch, mesh_lanes=mesh_lanes)
 
     def built(self, key: tuple, build, **attrs) -> tuple:

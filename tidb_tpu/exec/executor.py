@@ -247,6 +247,7 @@ def drive_mesh_program_info(
     mesh_devices: int,
     join_capacity: int | None = None,
     small_groups: int | None = None,
+    root: bool = False,
 ):
     """ONE shard_map launch over a region-stacked batch — the device half
     of the MESH dispatch tier: the stacked lanes shard over the device
@@ -255,6 +256,11 @@ def drive_mesh_program_info(
     aggregate states over the region axis / merge-mode re-group / re-top-k
     per `kind`) so the caller gets ONE merged chunk instead of R
     per-region partials.
+
+    root: `dag` is the statement's unsplit DAG (`kind` still names its
+    pushdown half's merge) and the program goes on through the root's half
+    behind the merge (`build_program(mesh_root=True)`): the chunk is the
+    statement's result, and the overflow flag covers the root's half too.
 
     Returns (chunk, lane_counts, info): `chunk` is the merged result (None
     when the program's global overflow flag fired — the caller degrades to
@@ -271,7 +277,7 @@ def drive_mesh_program_info(
     jc = rung_for(join_capacity or max(caps))
     prog, hit, build_ns = cache.get_info(
         dag, caps, rung_for(group_capacity), jc, False, small_groups, True,
-        mesh_lanes=R, mesh_devices=mesh_devices, mesh_kind=kind,
+        mesh_lanes=R, mesh_devices=mesh_devices, mesh_kind=kind, mesh_root=root,
     )
     (merged, mvalid, ex_rows, overflow, radix_esc), fetch, first_ns = launch.run_program(
         prog.outputs, (stacked, *aux_batches), dag.program_operands(), first_call=not hit, gate=prog.gate)

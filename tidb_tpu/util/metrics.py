@@ -303,6 +303,8 @@ MESH_SELECTS = REGISTRY.counter("tidb_tpu_mesh_selects_total", "SQL plans execut
 MESH_COP_BATCHES = REGISTRY.counter("tidb_tpu_mesh_cop_batches_total", "shard_map mesh-tier launches (one merged state per launch)")
 MESH_COP_LANES = REGISTRY.counter("tidb_tpu_mesh_cop_lanes_total", "region lanes whose partial states were psum-merged on device")
 MESH_COP_FALLBACKS = REGISTRY.counter("tidb_tpu_mesh_cop_fallbacks_total", "mesh-tier groups degraded to the vmapped batch tier (overflow/trace failure)")
+ROOT_FUSED_STATEMENTS = REGISTRY.counter("tidb_tpu_root_fused_statements_total", "statements whose root half ran in the pushdown's program (a lone cop task's, or a mesh launch's behind its merge): no second program, upload or read-back")
+ROOT_FUSE_FALLBACKS = REGISTRY.counter("tidb_tpu_root_fuse_fallbacks_total", "statements with a root half that the root merged as a second program (several tasks or stores, a lane from the cache or retried, a mesh degrade or overflow, a host-only operator, EXPLAIN ANALYZE, low memory, paging, a build side)")
 MESH_STACK_HITS = REGISTRY.counter("tidb_tpu_mesh_stack_hits_total", "mesh-tier launches that found their stacked lanes resident and sharded on the mesh's devices")
 MESH_STACK_MISSES = REGISTRY.counter("tidb_tpu_mesh_stack_misses_total", "mesh-tier launches that stacked their lanes on the host and put them onto the mesh's devices")
 SPILL_PARTITIONS = REGISTRY.counter("tidb_tpu_spill_partitions_total", "out-of-capacity host-partitioned multi-pass executions (the spill analog)")
