@@ -257,6 +257,58 @@ class TestMppDispatch:
         assert validate(text) == []
 
 
+TAIL_SQL = {
+    # HAVING alone: traced behind the final aggregate, in the program
+    "having": ("select oid, sum(v) from items group by oid having sum(v) > 300", True, True),
+    # a projection alone, and with HAVING before it
+    "projection": ("select oid, sum(v) * 2, count(*) + 1 from items group by oid", True, True),
+    "having_projection": ("select oid + 1 from items group by oid having count(*) > 12 and sum(v) < 9000",
+                          True, True),
+    # a string key passes through the tail as the exchange's packed words
+    "string_key_having": ("select seg, count(*), sum(c_id) from cust group by seg having count(*) > 1", True, True),
+    # a host-only function: the exchange serves the groups, the root the tail
+    "having_host_only": ("select seg, count(*) from cust group by seg having replace(seg, 'A', 'Z') = 'Z'",
+                         True, False),
+    # ORDER BY / LIMIT tails are another tier decision (ROADMAP M9): refused
+    "order_by": ("select oid, sum(v) from items group by oid having sum(v) > 300 order by oid", False, False),
+    "order_by_limit": ("select oid, sum(v) from items group by oid order by sum(v) desc, oid limit 5", False, False),
+    "limit": ("select oid, count(*) from items group by oid limit 5", False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(TAIL_SQL))
+def test_the_statement_tier_takes_a_having_or_projection_tail_and_no_ordering_one(case):
+    """ISSUE 38's tail rule: TableScan [Selection]* Aggregation(GROUP BY)
+    followed by Selections and Projections rides the exchange tier, the
+    tail in the exchange program where the device can trace it and at the
+    root where it cannot; an ORDER BY / LIMIT tail keeps to the tiers of
+    `execute_root`. Every answer is the one that `tidb_allow_mpp` OFF
+    gives."""
+    from tidb_tpu.mpp.fragment import mesh_eligible, split_tail
+
+    sql, taken, in_program = TAIL_SQL[case]
+    s = _q3_session()
+    m0, t0, f0 = M.MPP_SELECTS.value, M.MPP_TAIL_STATEMENTS.value, M.MPP_FALLBACKS.value
+    rows = s.execute(sql).rows
+    assert (M.MPP_SELECTS.value - m0, M.MPP_FALLBACKS.value - f0) == (int(taken), 0)
+    assert M.MPP_TAIL_STATEMENTS.value - t0 == int(in_program)
+    s.execute("set tidb_allow_mpp = OFF")
+    off = s.execute(sql).rows
+    if case == "limit":   # any five groups: each has to be one of the whole answer's
+        whole = _canon(s.execute("select oid, count(*) from items group by oid").rows)
+        assert len(rows) == len(off) == 5 and set(_canon(rows)) <= set(whole)
+    elif "order_by" in case:
+        assert [tuple(map(str, r)) for r in rows] == [tuple(map(str, r)) for r in off]
+    else:
+        assert _canon(rows) == _canon(off) and rows
+    from tidb_tpu.parser import parse
+    from tidb_tpu.sql.planner import plan_select
+
+    stmt = parse(sql)
+    dag = plan_select(stmt[0] if isinstance(stmt, list) else stmt, s.catalog).dag
+    assert (mesh_eligible(dag) is not None) == taken and (split_tail(dag)[1] is not None) == taken
+
+
 class TestNonUniqueRadixBuild:
     """The satellite pin: the radix kernel's expansion lift must agree
     with the monolithic join on duplicate build keys, escapes included."""

@@ -133,18 +133,18 @@ def test_a_traced_join_lays_the_exchange_tier_under_the_dispatch_span(served):
     assert not [n for r in find(tree, "session.execute") for n in r["children"] if n["name"].startswith("mpp.")]
 
 
-@pytest.mark.parametrize("where", ["run_exchange_join_agg", "stack_region_batches"])
+@pytest.mark.parametrize("where", ["run_exchange_join_agg", "exchange_lanes"])
 def test_cop_debug_raise_reaches_the_exchange_tier(served, monkeypatch, where):
     """Unarmed, a failure inside the tier is a counted fall-back onto the
     tiers below (degrade, never fail); armed it fails the statement."""
     from tidb_tpu.mpp import exchange_op
-    from tidb_tpu.parallel import mesh
+    from tidb_tpu.store.store import TPUStore
 
     def broken(*_a, **_k):
         raise RuntimeError("injected exchange failure")
 
     monkeypatch.setattr(*((exchange_op, "run_exchange_join_agg") if where == "run_exchange_join_agg"
-                          else (mesh, "stack_region_batches")), broken)
+                          else (TPUStore, "exchange_lanes")), broken)
     sql = JOIN.format(s="MACH", d="1995-03-17", p="2.50")
     got = served.run(sql)
     assert got["moved"]["MPP_FALLBACKS"] == 1 and got["moved"]["MPP_SELECTS"] == 0
@@ -155,12 +155,19 @@ def test_cop_debug_raise_reaches_the_exchange_tier(served, monkeypatch, where):
         monkeypatch.undo()
         sound = served.run(sql)
         assert sound["moved"]["MPP_SELECTS"] == 1 and sound["rows"] == got["rows"]
-        # an eligibility decline stays a counted decline: a value wider than the exchange carries
+        # a value wider than the exchange carries, in a column the statement
+        # never reads, decides nothing (ISSUE 38: TPC-H's l_comment beside Q18)
         served.sess.execute(f"insert into f values (5001, 1, '{'w' * 12}', '1995-03-01', 1.00, 1)")
         served.sess.execute("alter table f modify column s varchar(64)")
         served.sess.execute(f"insert into f values (5002, 1, '{'w' * 40}', '1995-03-01', 1.00, 1)")
+        unread = served.run(sql)["moved"]
+        assert unread["MPP_FALLBACKS"] == 0 and unread["MPP_SELECTS"] == 1
+        # in one it reads, an eligibility decline stays a counted decline
+        served.sess.execute("alter table dim modify column seg varchar(64)")
+        served.sess.execute(f"insert into dim values (5003, '{'w' * 40}')")
         wide = served.run(sql)["moved"]
         assert wide["MPP_FALLBACKS"] == 1 and wide["MPP_SELECTS"] == 0
     finally:
         failpoint.disable("cop-debug-raise")
         served.sess.execute("delete from f where id > 5000")
+        served.sess.execute("delete from dim where k > 5000")

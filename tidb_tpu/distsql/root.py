@@ -109,6 +109,17 @@ def _has_host_only_op(ex) -> bool:
     return host_only_exprs(exprs)
 
 
+def tail_dag(dag: DAGRequest, head: DAGRequest) -> DAGRequest:
+    """The executors of `dag` behind its prefix `head`, over `head`'s output
+    as a virtual scan, with `dag`'s output offsets: what the root runs over
+    the rows a tier hands back for `head` (the exchange tier's tail,
+    `mpp/fragment.py` `split_tail`)."""
+    fts = head.output_fts()
+    scan = TableScan(0, tuple(ColumnInfo(-100 - i, ft) for i, ft in enumerate(fts)))
+    rest = dag.executors[len(head.executors):]
+    return DAGRequest((scan, *rest), output_offsets=dag.output_offsets, time_zone=dag.time_zone, flags=dag.flags)
+
+
 def split_dag(dag: DAGRequest) -> RootPlan:
     executors = dag.executors
     push: list = []

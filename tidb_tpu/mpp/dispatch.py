@@ -41,7 +41,7 @@ from __future__ import annotations
 
 from ..chunk import Chunk
 from ..exec.dag import DAGRequest
-from .fragment import chunks_exchange_safe, fragment_plan, mesh_eligible, split_join_dag
+from .fragment import chunks_exchange_safe, exchange_columns, fragment_plan, mesh_eligible, split_join_dag
 
 # (program key, n devices, base group capacity) -> last successful
 # (gc, scale) ladder rung; bounded FIFO, see execute_exchange_plan
@@ -56,71 +56,71 @@ def _chunks_nbytes(chunks) -> int:
     return total
 
 
-def execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
-                          group_capacity: int = 1024, programs=None) -> Chunk | None:
+def execute_exchange_plan(store, dag, chunks, lanes, aux_chunks, kind, devs,
+                          group_capacity: int = 1024) -> Chunk | None:
     """Launch the exchange program over already-scanned chunks. Region
     chunks play the task lanes; build tables are sliced across devices so
-    each slice plays a region shard. Overflow (too many groups / join
-    fan-out / hash collision) retries with 4x capacity — the capacity
-    also salts the hash, mirroring drive_program's contract — reusing the
-    scanned chunks, not rescanning. The programs live in `programs` (the
-    store's ProgramCache). Returns the projected result Chunk, or None for
-    a fallback to the per-region path (a decline; a failure raises)."""
-    from ..parallel.grouped import run_sharded_grouped_agg
-    from ..parallel.mesh import region_mesh, stack_region_batches
-    from ..util import metrics, tracing
+    each slice plays a region shard. The group tables start at the rung
+    that last held this plan shape's groups (`_LADDER_HINTS`); an overflow
+    (too many groups / join fan-out / hash collision) retries on the rung
+    that the program's need hint names, else one geometric step up
+    (exec/ladder.py `overflow_step`; the capacity also salts the hash,
+    mirroring drive_program's contract), reusing the scanned chunks, not
+    rescanning. The programs live in the store's ProgramCache.
 
-    agg = dag.executors[-1]
-    out_fts = agg.output_fts()
+    The store keeps what is stacked: the probe lanes by data version and
+    what they read (`lanes`, a `(write version, scan, start_ts, [(region,
+    ranges)] | None)`: `TPUStore.exchange_lanes`, shared with the
+    per-request mesh tier) and each build table by its chunk object
+    (`TPUStore.exchange_build`).
+
+    A tail behind the aggregate (`fragment.split_tail`: HAVING,
+    projection) runs in the program where `tail_in_program` allows, else
+    over the program's groups at the root (`run_dag_on_chunks`), inside
+    the span `mpp.tail`. Returns the projected result Chunk, or None for a
+    fallback to the per-region path (a decline; a failure raises)."""
+    from ..exec.ladder import overflow_step
+    from ..parallel.grouped import run_sharded_grouped_agg
+    from ..parallel.mesh import region_mesh
+    from ..util import metrics, tracing
+    from .fragment import split_tail, tail_in_program
+
+    head, tail = split_tail(dag)
+    in_program = not tail or tail_in_program(tail)
+    prog_dag = dag if in_program else head
     if not chunks:
         # zero rows scanned: grouped aggregation of nothing is no groups
-        return Chunk.empty([out_fts[i] for i in dag.output_offsets])
-    if not chunks_exchange_safe(chunks):
+        return Chunk.empty(dag.output_fts())
+    probe_cols, build_cols = exchange_columns(head)
+    if not chunks_exchange_safe(chunks, probe_cols):
         return None  # wide strings cannot ride the exchange byte-exactly
 
     n = len(devs)
-    n_total = ((len(chunks) + n - 1) // n) * n
     mesh = region_mesh(n)
     stacked_builds = None
-    # host-side stacking and upload of the task lanes, made anew for every
-    # statement: the probe's region chunks, then each build table sliced
-    # across the devices so that a slice plays a region shard
-    with tracing.span("mesh.stack", lanes=n_total, devices=n,
-                      rows=sum(c.num_rows() for c in chunks), bytes=_chunks_nbytes(chunks)):
-        try:
-            stacked = stack_region_batches(chunks, n_total=n_total)
-        except NotImplementedError:
-            return None  # e.g. non-ASCII CI data: the per-region path's
-            # oracle fallback owns it (chunk/device.py guard)
+    try:
+        # the probe's region chunks as lanes, then each build table sliced
+        # across the devices so that a slice plays a region shard
+        ver, scan, start_ts, read = lanes
+        stacked = store.exchange_lanes(ver, scan, start_ts, read, chunks, n)
         if kind == "join":
-            n_stages = len(split_join_dag(dag)[2])
+            n_stages = len(split_join_dag(head)[2])
             if aux_chunks is None or len(aux_chunks) < n_stages:
                 return None
-            stacked_builds = []
-            for build in aux_chunks[:n_stages]:
-                if not chunks_exchange_safe([build]):
-                    return None
-                if build.num_rows() == 0:
-                    bslices = [build]
-                else:
-                    step = (build.num_rows() + n - 1) // n
-                    bslices = [
-                        build.slice(i * step, min((i + 1) * step, build.num_rows()))
-                        for i in range(n)
-                        if i * step < build.num_rows()
-                    ]
-                try:
-                    stacked_builds.append(stack_region_batches(bslices, n_total=n))
-                except NotImplementedError:
-                    return None  # non-ASCII CI build data -> per-region path
+            if not all(chunks_exchange_safe([b], cols) for b, cols in zip(aux_chunks, build_cols)):
+                return None
+            stacked_builds = [store.exchange_build(build, n) for build in aux_chunks[:n_stages]]
+    except NotImplementedError:
+        return None  # e.g. non-ASCII CI data: the per-region path's
+        # oracle fallback owns it (chunk/device.py guard)
 
     # the ladder's start rung is remembered per plan SHAPE (the key of the
-    # program itself): a skewed key distribution that overflowed rung 1 last
-    # time will overflow it again — a repeated statement, whatever its
-    # literals, starts at the rung that last succeeded, so the steady state
-    # is ONE cached program, not a re-walk of the failed rungs
-    hint_key = (dag.program_key(), n, group_capacity)
+    # program itself): a repeated statement, whatever its literals, starts
+    # at the rung that last held its groups — the steady state is ONE
+    # cached program, not a re-walk of the failed rungs
+    hint_key = (prog_dag.program_key(), n, group_capacity)
     gc, scale = _LADDER_HINTS.get(hint_key, (group_capacity, 1))
+    stats: dict = {}
     with tracing.span("mpp.exchange", kind=kind) as sp:
         for retries in range(3):
             # a failure in here (an op the device compiler refuses that
@@ -130,11 +130,11 @@ def execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
                 from .exchange_op import run_exchange_join_agg
 
                 chunk, overflow = run_exchange_join_agg(
-                    dag, stacked, stacked_builds, mesh, group_capacity=gc, scale=scale,
-                    programs=programs)
+                    prog_dag, stacked, stacked_builds, mesh, group_capacity=gc, scale=scale,
+                    programs=store.programs, stats=stats)
             else:
-                chunk, overflow = run_sharded_grouped_agg(dag, stacked, mesh, group_capacity=gc,
-                                                          programs=programs)
+                chunk, overflow = run_sharded_grouped_agg(prog_dag, stacked, mesh, group_capacity=gc,
+                                                          programs=store.programs, stats=stats)
             if sp is not None:
                 sp.set("rung", [gc, scale])
                 sp.set("retries", retries)
@@ -143,17 +143,36 @@ def execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
                     _LADDER_HINTS.pop(next(iter(_LADDER_HINTS)))
                 _LADDER_HINTS[hint_key] = (gc, scale)
                 metrics.MESH_SELECTS.inc()
-                cols = [chunk.columns[i] for i in dag.output_offsets]
-                return Chunk(cols)
+                break
             # one overflow flag covers groups, exchange buckets, and join
-            # fan-out. Exchange/fan-out skew (scale) is far more common than
-            # group-count overflow in chain shapes, and gc inflates the group
-            # tables of EVERY device — so the middle rung grows scale alone,
-            # and only the last rung grows both
-            if scale >= 4:
-                gc *= 4
-            scale *= 4
-    return None  # caller falls back to the per-region path
+            # fan-out. A need hint past the rung is a pure group-count miss:
+            # jump to the rung that holds it. Otherwise exchange/fan-out skew
+            # (scale) is far more common than group-count overflow in chain
+            # shapes, and gc inflates the group tables of EVERY device — so
+            # the middle rung grows scale alone, and only the last grows both
+            if stats.get("need", 0) > gc:
+                gc = overflow_step(gc, 0, True, False, stats["need"], 0)[0]
+            elif kind == "join" and scale < 4:
+                scale *= 4
+            else:
+                gc, scale = overflow_step(gc, 0, True, False, 0, 0)[0], scale * 4
+        else:
+            return None  # caller falls back to the per-region path
+    if not tail:
+        return Chunk([chunk.columns[i] for i in dag.output_offsets])
+    with tracing.span("mpp.tail", executors=[type(e).__name__ for e in tail], in_program=in_program,
+                      rows_in=stats.get("groups", chunk.num_rows())) as tsp:
+        if in_program:
+            metrics.MPP_TAIL_STATEMENTS.inc()
+            chunk = Chunk([chunk.columns[i] for i in dag.output_offsets])
+        else:
+            from ..distsql.root import tail_dag
+            from ..exec.executor import run_dag_on_chunks
+
+            chunk = run_dag_on_chunks(tail_dag(dag, head), [chunk], cache=store.programs)
+        if tsp is not None:
+            tsp.set("rows_out", chunk.num_rows())
+    return chunk
 
 
 def _replica_probe_chunks(store, dag, ranges, start_ts, n_lanes,
@@ -209,6 +228,22 @@ def _replica_probe_chunks(store, dag, ranges, start_ts, n_lanes,
     ]
 
 
+def _task_lanes(store, tasks, chunks) -> list | None:
+    """[(region, ranges)] of the scan's chunks, one a task, where the scan
+    answered each task with one chunk and no region moved under it (a
+    split or a retry re-cuts the lanes): what names the stacked lanes in
+    the store's cache. None: they are stacked for the one launch."""
+    if len(chunks) != len(tasks):
+        return None
+    out = []
+    for t in tasks:
+        region = store.cluster.region_by_id(t.region_id)
+        if region is None or region.epoch != t.epoch:
+            return None
+        out.append((region, t.ranges))
+    return out
+
+
 def try_mpp_select(
     store,
     dag: DAGRequest,
@@ -256,24 +291,30 @@ def try_mpp_select(
             store, dag, ranges, start_ts, len(devs), engines,
             backoff_weight, checker)
         replica_served = chunks is not None
+        lanes = (None, None, start_ts, None)   # the replica's slices name no region read
         if chunks is None:
             # row-store scan pushdown (paging/retry, typed region errors
             # and epoch fall-out preserved — a mid-query split raises the
             # same typed shape the per-region path does)
-            from ..distsql.dispatch import KVRequest, select
+            from ..distsql.dispatch import KVRequest, _build_tasks, select
 
             scan = dag.executors[0]
             scan_dag = DAGRequest((scan,), output_offsets=tuple(range(len(scan.columns))))
+            ver = store._snapshot_write_ver()  # pre-read: in the stacked lanes' key, and gates their filing
+            tasks = _build_tasks(store, ranges)
             with tracing.span("mpp.scan", table=scan.table_id):
                 res = select(store, KVRequest(scan_dag, ranges, start_ts))
-            chunks = [c for c in res.chunks if c is not None and c.num_rows() > 0]
+            chunks = [c for c in res.chunks if c is not None]
+            lanes = (ver, scan, start_ts, _task_lanes(store, tasks, chunks))
+            if lanes[3] is None:
+                chunks = [c for c in chunks if c.num_rows() > 0]
         if failpoint.eval("mpp/exchange-stall"):
             # an exchange never delivered mid-run: abandon the MPP run
             metrics.MPP_FALLBACKS.inc()
             return None
         try:
-            out = execute_exchange_plan(dag, chunks, aux_chunks, kind, devs,
-                                        group_capacity=group_capacity, programs=store.programs)
+            out = execute_exchange_plan(store, dag, chunks, lanes, aux_chunks, kind, devs,
+                                        group_capacity=group_capacity)
         except Exception:  # noqa: BLE001 — degrade, never fail: the
             # per-region path still owns the answer (armed, the failure
             # is the answer: see the module docstring)

@@ -213,14 +213,15 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
     Split from `run_exchange_join_agg` so the jax-audit catalog can trace
     the exchange-join shape through the jaxpr checks without launching."""
     from ..exec.dag import operand_lanes
-    from ..parallel.grouped import _flatten_local, agg_exchange_phases
-    from .fragment import split_join_dag
+    from ..parallel.grouped import _flatten_local, agg_exchange_phases, tail_phase
+    from .fragment import split_join_dag, split_tail
 
     # what is traced is the plan's shape (exec/builder.py build_program):
     # the parameterisable constants of the DAG that happens to build the
     # program follow the batches as operands, like any later DAG's
     dag, _key, operands = dag.parameterized()
     lanes = operand_lanes(operands)
+    dag, tail = split_tail(dag)
     parts = split_join_dag(dag)
     assert parts is not None, "not a shuffle-join DAG shape"
     probe_scan, pre_sels, stages, agg = parts
@@ -311,20 +312,22 @@ def exchange_join_program(dag, mesh, group_capacity: int = 1024, scale: int = 1)
         # the state-exchange bucket cap is data-sized like the join
         # exchanges (distinct groups <= rows; gc-sized buckets made the agg
         # phase 8x the whole join's work at the upper ladder rungs)
-        return agg_exchange_phases(
+        outs = agg_exchange_phases(
             agg, schema, cols, valid, n_parts, group_capacity,
             max(64, 2 * scale * est // n_parts), extra_overflow=extra, params=params,
         )
+        return tail_phase(outs, agg, tail, params)
 
     from jax.sharding import PartitionSpec as P
 
+    from ..parallel.grouped import tail_fts
     from ..parallel.mesh import group_mesh_out_spec
 
     def wrap(stacked_probe, *rest):
         # the batches shard their region axis; the operands replicate
         specs = tuple(jax.tree.map(lambda _: P(REGION_AXIS), b) for b in (stacked_probe, *rest[:n_builds]))
         fn = jax.shard_map(device_fn, mesh=mesh, in_specs=specs + (P(),) * len(lanes),
-                           out_specs=group_mesh_out_spec(agg), check_vma=False)
+                           out_specs=group_mesh_out_spec(len(tail_fts(agg, tail))), check_vma=False)
         return fn(stacked_probe, *rest)
 
     return wrap
@@ -366,10 +369,13 @@ def run_exchange_join_agg(
     group_capacity: int = 1024,
     scale: int = 1,
     programs=None,
+    stats: dict | None = None,
 ):
-    """Execute scan [sel] (JOIN(scan [sel]) [sel])+ GROUP BY over the mesh
-    as ONE shard_map program; returns (chunk, overflow flag). Output layout
-    matches the single-chip executor: [agg results..., group keys...].
+    """Execute scan [sel] (JOIN(scan [sel]) [sel])+ GROUP BY [tail] over the
+    mesh as ONE shard_map program; returns (chunk, overflow flag). Output
+    layout matches the single-chip executor: [agg results..., group
+    keys...], or the tail's schema (`parallel/grouped.py`
+    `run_sharded_grouped_agg`, which fills `stats` alike).
     Multi-join chains (TPC-H Q3) re-exchange the widened probe schema at
     every stage by that stage's join key — the per-fragment dataflow
     `mpp/fragment.py` plans is exactly these phases.
@@ -379,17 +385,19 @@ def run_exchange_join_agg(
     the repartition; `scale` (grown by the caller's overflow retry)
     multiplies every data-dependent capacity: exchange buckets for skewed
     keys and the join out-capacity for fan-out > 1."""
+    from ..parallel.grouped import tail_fts
     from ..parallel.mesh import decode_group_mesh_outputs
-    from .fragment import split_join_dag
+    from .fragment import split_join_dag, split_tail
 
     if not isinstance(stacked_builds, (list, tuple)):
         stacked_builds = [stacked_builds]
-    n_stages = len(split_join_dag(dag)[2])
+    head, tail = split_tail(dag)
+    n_stages = len(split_join_dag(head)[2])
     assert len(stacked_builds) == n_stages, "one build batch per join stage"
-    agg = dag.executors[-1]
+    agg = head.executors[-1]
     outs, fetch = run_exchange_program(
         "mpp_exchange_join_agg", dag, mesh,
         lambda: exchange_join_program(dag, mesh, group_capacity=group_capacity, scale=scale),
         (group_capacity, scale), (stacked_probe, *stacked_builds), programs)
     # decode via the shared seam (parallel/mesh.py) — same layout as grouped
-    return decode_group_mesh_outputs(outs, fetch, agg)
+    return decode_group_mesh_outputs(outs, fetch, tail_fts(agg, tail), stats)

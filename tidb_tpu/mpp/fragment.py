@@ -27,9 +27,9 @@ the scanned chunks (chunks_exchange_safe).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
-from ..exec.dag import Aggregation, DAGRequest, Join, Selection, TableScan
+from ..exec.dag import Aggregation, DAGRequest, Join, Projection, Selection, TableScan
 
 # exchange partition modes (ref: mpp_exec.go:669 partition types)
 EXCHANGE_HASH = "hash"
@@ -44,15 +44,65 @@ MAX_EXCHANGE_STR = 32
 ROOT_COLLECTOR = -1
 
 
-def chunks_exchange_safe(chunks) -> bool:
-    """No string value in any scanned column exceeds the packed-word width
-    the exchange can carry byte-exactly."""
+def chunks_exchange_safe(chunks, columns=None) -> bool:
+    """No string value in any scanned column (of `columns`, the indices the
+    program reads, where given) exceeds the packed-word width the exchange
+    can carry byte-exactly."""
     for c in chunks:
-        for col in c.columns:
+        for i, col in enumerate(c.columns):
+            if columns is not None and i not in columns:
+                continue
             if col.is_varlen() and len(col):
                 if int((col.offsets[1:] - col.offsets[:-1]).max()) > MAX_EXCHANGE_STR:
                     return False
     return True
+
+
+def _refs(exprs) -> set:
+    from ..expr.ir import ColumnRef, ScalarFunc
+
+    out: set = set()
+
+    def walk(e):
+        if isinstance(e, ColumnRef):
+            out.add(e.index)
+        elif isinstance(e, ScalarFunc):
+            for a in e.args:
+                walk(a)
+
+    for e in exprs:
+        walk(e)
+    return out
+
+
+def exchange_columns(head: DAGRequest) -> tuple:
+    """(probe columns, [build columns] a join stage): the scanned columns
+    whose values an exchange plan reads — in a selection, a join key or the
+    aggregate — by index into each scan.  The rest of a scan (TPC-H's
+    `l_comment` beside Q18's `l_orderkey` and `l_quantity`) is never looked
+    at, so its width cannot decide whether the exchange carries the
+    statement byte-exactly (`chunks_exchange_safe`)."""
+    exs = head.executors
+    width = len(exs[0].columns)
+    refs: set = set()          # into the schema as the joins widen it
+    stages = []                # (offset in the widened schema, width, build's own refs)
+    for ex in exs[1:]:
+        if isinstance(ex, Selection):
+            refs |= _refs(ex.conditions)
+        elif isinstance(ex, Join):
+            refs |= _refs(ex.probe_keys)
+            own = _refs(ex.build_keys)
+            for bex in ex.build[1:]:
+                own |= _refs(bex.conditions)
+            bw = len(ex.build[0].columns)
+            stages.append((width, bw, own))
+            if ex.join_type not in ("semi", "anti"):
+                width += bw
+        elif isinstance(ex, Aggregation):
+            refs |= _refs(ex.group_by) | _refs([a for d in ex.aggs for a in d.args])
+    n_probe = len(exs[0].columns)
+    builds = [own | {i - off for i in refs if off <= i < off + bw} for off, bw, own in stages]
+    return {i for i in refs if i < n_probe}, builds
 
 
 @dataclass(frozen=True)
@@ -86,6 +136,43 @@ class FragmentPlan:
     fragments: tuple
     n_tasks: int              # tasks per fragment = mesh width
     root: int                 # idx of the Final fragment (streams to root)
+
+
+def split_tail(dag: DAGRequest) -> tuple:
+    """-> (head DAG, tail executors): the DAG cut behind its last
+    Aggregation, where everything after it is a Selection (HAVING) or a
+    Projection.  The head ends in the Aggregation and outputs its whole
+    schema; the tail runs over the final fragment's groups and the DAG's
+    output offsets follow it.  No Aggregation, or a tail that orders or
+    limits (Sort / TopN / Limit, ROADMAP M9: a tier decision of its own):
+    (dag, None)."""
+    exs = dag.executors
+    last = max((i for i, e in enumerate(exs) if isinstance(e, Aggregation)), default=None)
+    if last is None or not all(isinstance(e, (Selection, Projection)) for e in exs[last + 1:]):
+        return dag, None
+    head = replace(dag, executors=exs[:last + 1],
+                   output_offsets=tuple(range(len(exs[last].output_fts()))))
+    return head, exs[last + 1:]
+
+
+def tail_in_program(tail) -> bool:
+    """Can the tail be traced behind the final aggregate, in the exchange
+    program itself?  The groups leave the exchange with their strings as
+    packed compare words and no raw bytes, so a tail expression may pass a
+    string column through but compute nothing over strings; and the device
+    traces no host-only operator.  Otherwise the root evaluates the tail
+    over the exchange's result."""
+    from ..distsql.root import host_only_exprs
+    from ..expr.ir import ColumnRef, ScalarFunc
+
+    def strings(e) -> bool:
+        if isinstance(e, ColumnRef):
+            return False
+        return e.ft.is_string() or (isinstance(e, ScalarFunc) and any(
+            a.ft.is_string() or strings(a) for a in e.args))
+
+    exprs = [c for ex in tail for c in (ex.conditions if isinstance(ex, Selection) else ex.exprs)]
+    return not host_only_exprs(exprs) and not any(strings(e) for e in exprs)
 
 
 def split_join_dag(dag: DAGRequest):
@@ -139,10 +226,17 @@ def mesh_eligible(dag: DAGRequest) -> str | None:
       "agg"  — TableScan [Selection]* Aggregation(GROUP BY)
       "join" — TableScan [Sel]* Join(scan [Sel]*) [Sel]* Aggregation(...)
                (the hash-shuffle repartition join, split_join_dag)
-      None   — ineligible (host-only exprs, group_concat, merge mode, ...)
-    """
+      None   — ineligible (host-only exprs, group_concat, merge mode, an
+               ORDER BY / LIMIT tail, ...)
+
+    Either shape may end in a tail of Selections (HAVING) and Projections
+    (`split_tail`): it runs behind the final aggregate, in the program
+    where `tail_in_program` says so, else at the root."""
     from ..distsql.root import host_only_exprs
 
+    dag, tail = split_tail(dag)
+    if tail is None:
+        return None
     exs = dag.executors
     if len(exs) < 2 or not isinstance(exs[0], TableScan):
         return None
@@ -183,7 +277,13 @@ def fragment_plan(dag: DAGRequest, n_tasks: int) -> FragmentPlan | None:
     Agg shape: [scan+sel+Partial1] --hash(group key)--> [Final] -> root.
     The SAME Aggregation node appears in both agg-boundary fragments: its
     mode (Partial1 vs Final merge) is positional, exactly as the device
-    program splits it (grouped.agg_exchange_phases phases 1 and 3)."""
+    program splits it (grouped.agg_exchange_phases phases 1 and 3). A
+    tail (`split_tail`: HAVING, projection) is the Final fragment's, above
+    its aggregate, as the reference plans a Selection over the final
+    HashAgg inside the fragment that streams to the root."""
+    dag, tail = split_tail(dag)
+    if tail is None:
+        return None
     parts = split_join_dag(dag)
     if parts is not None:
         probe_scan, pre_sels, stages, agg = parts
@@ -221,7 +321,7 @@ def fragment_plan(dag: DAGRequest, n_tasks: int) -> FragmentPlan | None:
         root_idx = 2 * n_stages + 1
         frags.append(Fragment(
             idx=root_idx,
-            executors=(agg,),
+            executors=(agg, *tail),
             receivers=(ExchangeReceiver(join_frag_idx(n_stages - 1)),),
             sender=ExchangeSender(EXCHANGE_PASSTHROUGH, (), ROOT_COLLECTOR),
         ))
@@ -245,7 +345,7 @@ def fragment_plan(dag: DAGRequest, n_tasks: int) -> FragmentPlan | None:
         ),
         Fragment(
             idx=1,
-            executors=(agg,),
+            executors=(agg, *tail),
             receivers=(ExchangeReceiver(0),),
             sender=ExchangeSender(EXCHANGE_PASSTHROUGH, (), ROOT_COLLECTOR),
         ),

@@ -70,7 +70,10 @@ def agg_exchange_phases(agg, schema_fts, cvals, valid, n_parts: int, group_capac
     scan+sel path (run_sharded_grouped_agg) and the hash-shuffle join path
     (mpp/exchange_op.py run_exchange_join_agg); `params` are the program's
     operands by lane (`ExprCompiler`). Returns the flat output tuple
-    [group_valid, (value, null)*, overflow]."""
+    [group_valid, (value, null)*, need, overflow]: `need` is the most
+    groups any device's partial or final table had to hold (the sort
+    kernel counts them past capacity; exec/ladder.py), so an overflow
+    retry jumps to the rung that holds them."""
     comp = ExprCompiler(schema_fts, params)
     gvals = comp.run(list(agg.group_by), cvals)
     arg_exprs = [a for d in agg.aggs for a in d.args]
@@ -159,7 +162,7 @@ def agg_exchange_phases(agg, schema_fts, cvals, valid, n_parts: int, group_capac
         local_ovf = local_ovf | extra_overflow
     overflow = jax.lax.pmax(local_ovf.astype(jnp.int32), REGION_AXIS) > 0
     flat_out = [a for v, nl in out_cols for a in (v, nl)]
-    return tuple([fin.group_valid] + flat_out + [overflow])
+    return tuple([fin.group_valid] + flat_out + [_need(res, fin), overflow])
 
 
 def _distinct_exchange_phases(agg, gvals, aggs, valid, n_parts: int, group_capacity: int, bcap: int, extra_overflow=None):
@@ -206,7 +209,44 @@ def _distinct_exchange_phases(agg, gvals, aggs, valid, n_parts: int, group_capac
         local_ovf = local_ovf | extra_overflow
     overflow = jax.lax.pmax(local_ovf.astype(jnp.int32), REGION_AXIS) > 0
     flat_out = [a for v, nl in out_cols for a in (v, nl)]
-    return tuple([fin.group_valid] + flat_out + [overflow])
+    return tuple([fin.group_valid] + flat_out + [_need(fin), overflow])
+
+
+def _need(*results) -> jax.Array:
+    """The largest group count any of `results` (GroupAggResult) had to
+    hold on any device; 0 where no kernel counted past its capacity."""
+    needs = [r.need for r in results if r.need is not None]
+    if not needs:
+        return jnp.int32(0)
+    local = needs[0]
+    for n in needs[1:]:
+        local = jnp.maximum(local, n)
+    # int32: the TPU lowers a max all-reduce of 32-bit words only; a count
+    # of rows a device holds fits
+    return jax.lax.pmax(local.astype(jnp.int32), REGION_AXIS)
+
+
+def tail_phase(outs: tuple, agg, tail, params: dict) -> tuple:
+    """The statement's tail behind the final aggregate, in the exchange
+    program (mpp/fragment.py `split_tail`, `tail_in_program`): HAVING
+    selections narrow each device's owned final groups, projections
+    compute over them, as the root would over the gathered groups.
+    `outs` is `agg_exchange_phases`' tuple; returns [groups before the
+    tail, rows after it, (value, null)* of the tail's schema, need,
+    overflow]."""
+    gvalid, flat, need, overflow = outs[0], outs[1:-2], outs[-2], outs[-1]
+    fts = agg.output_fts()
+    cols = [CompVal(flat[2 * i], flat[2 * i + 1], ft) for i, ft in enumerate(fts)]
+    valid = gvalid
+    for ex in tail or ():
+        comp = ExprCompiler(fts, params)
+        if isinstance(ex, Selection):
+            valid = apply_selection(valid, comp.run(list(ex.conditions), cols))
+        else:
+            cols = comp.run(list(ex.exprs), cols)
+            fts = [e.ft for e in ex.exprs]
+    flat_out = [a for c in cols for a in (c.value, c.null | ~valid)]
+    return tuple([gvalid, valid] + flat_out + [need, overflow])
 
 
 def run_sharded_grouped_agg(
@@ -216,14 +256,23 @@ def run_sharded_grouped_agg(
     group_capacity: int = 1024,
     bucket_cap: int | None = None,
     programs=None,
+    stats: dict | None = None,
 ):
-    """Execute TableScan [Selection] Aggregation(group_by) over a
+    """Execute TableScan [Selection] Aggregation(group_by) [tail] over a
     region-sharded mesh; returns (chunk, overflow flag).
 
     The Aggregation node is taken as the LOGICAL (Complete-mode) shape; the
     partial/final split happens inside. Output chunk layout matches the
-    single-chip executor: [agg results..., group keys...]."""
-    agg = dag.executors[-1]
+    single-chip executor: [agg results..., group keys...], or the tail's
+    schema where the DAG goes on behind the aggregate with Selections and
+    Projections (`mpp/fragment.py` `split_tail`): they run over each
+    device's final groups in the same program. `stats`, where given,
+    receives `need` (the ladder's hint) and `groups` (final groups before
+    the tail)."""
+    from ..mpp.fragment import split_tail
+
+    head, tail = split_tail(dag)
+    agg = head.executors[-1]
     assert isinstance(agg, Aggregation) and agg.group_by, "grouped mesh agg needs GROUP BY"
     if any(d.name == "group_concat" for d in agg.aggs):
         raise NotImplementedError("group_concat on mesh (root-only, oracle-evaluated)")
@@ -233,29 +282,42 @@ def run_sharded_grouped_agg(
     # the traced DAG is the plan's shape; its constants are operands
     shape, _key, operands = dag.parameterized()
     lanes = operand_lanes(operands)
+    s_head, s_tail = split_tail(shape)
 
     def device_fn(local: DeviceBatch, *ops):
         params = dict(zip(lanes, ops))
         cols, valid = _flatten_local(local)
         cvals = [normalize_device_column(c) for c in cols]
-        for ex in shape.executors[1:-1]:
+        for ex in s_head.executors[1:-1]:
             if isinstance(ex, Selection):
                 conds = ExprCompiler(input_fts, params).run(list(ex.conditions), cvals)
                 valid = apply_selection(valid, conds)
             else:
                 raise TypeError(f"mesh pipeline supports scan+selection+agg, got {ex}")
-        return agg_exchange_phases(shape.executors[-1], input_fts, cvals, valid, n_parts, group_capacity, bcap,
+        outs = agg_exchange_phases(s_head.executors[-1], input_fts, cvals, valid, n_parts, group_capacity, bcap,
                                    params=params)
+        return tail_phase(outs, s_head.executors[-1], s_tail, params)
 
     spec_batch = jax.tree.map(lambda _: P(REGION_AXIS), stacked)
     from ..mpp.exchange_op import run_exchange_program
     from .mesh import decode_group_mesh_outputs, group_mesh_out_spec
 
+    out_fts = tail_fts(agg, tail)
     outs, fetch = run_exchange_program(
         "mesh_exchange_group_agg", dag, mesh,
         lambda: jax.shard_map(device_fn, mesh=mesh, in_specs=(spec_batch,) + (P(),) * len(lanes),
-                              out_specs=group_mesh_out_spec(agg), check_vma=False),
+                              out_specs=group_mesh_out_spec(len(out_fts)), check_vma=False),
         (group_capacity, bcap), (stacked,), programs)
-    # decode: [agg results..., group keys...] with Complete-mode fts —
-    # the shared seam (mesh.py) both grouped paths use
-    return decode_group_mesh_outputs(outs, fetch, agg)
+    # decode: the tail's schema (Complete-mode [aggs..., keys...] without
+    # one) — the shared seam (mesh.py) both grouped paths use
+    return decode_group_mesh_outputs(outs, fetch, out_fts, stats)
+
+
+def tail_fts(agg, tail) -> list:
+    """The schema an exchange program hands back: the aggregate's
+    Complete-mode output, then as each tail Projection makes it."""
+    fts = list(agg.output_fts())
+    for ex in tail or ():
+        if not isinstance(ex, Selection):
+            fts = [e.ft for e in ex.exprs]
+    return fts

@@ -244,33 +244,39 @@ def _merge_first_row(has_state, val_state, axis: str):
     return [(any_has.astype(jnp.int64), jnp.zeros_like(null)), (val, null)]
 
 
-def decode_group_mesh_outputs(outs, fetch, agg):
+def decode_group_mesh_outputs(outs, fetch, out_fts, stats: dict | None = None):
     """Shared host-side decode for the grouped shard_map programs
-    (grouped.py / joinmesh.py): flat output tuple [group_valid,
-    (value, null)*, overflow] with out_specs P(REGION_AXIS) having already
-    concatenated the per-device group tables along axis 0, as the launch
-    read them (host arrays) with the launch's `fetch`. Returns
-    (chunk, overflow) in the Complete-mode layout [aggs..., group keys...].
+    (grouped.py / exchange_op.py): flat output tuple [groups before the
+    tail, rows after it, (value, null)*, need, overflow]
+    (`grouped.tail_phase`) with out_specs P(REGION_AXIS) having already
+    concatenated the per-device tables along axis 0, as the launch read
+    them (host arrays) with the launch's `fetch`. Returns (chunk, overflow)
+    in `out_fts`' layout; `stats`, where given, receives `need` and
+    `groups` (final groups before the tail).
     """
     from ..exec import launch
     from ..exec.executor import decode_outputs
 
     with launch.read_back(fetch) as to_host:
-        group_valid = to_host(outs[0]).reshape(-1)
+        groups = to_host(outs[0]).reshape(-1)
+        valid = to_host(outs[1]).reshape(-1)
         overflow = bool(to_host(outs[-1]).reshape(-1)[0])
-        flat_out = outs[1:-1]
-        out_fts = [d.ft for d in agg.aggs] + [g.ft for g in agg.group_by]
+        if stats is not None:
+            stats["need"] = int(to_host(outs[-2]).reshape(-1)[0])
+            stats["groups"] = int(groups.sum())
+        flat_out = outs[2:-2]
         packed = []
         for i, _ft in enumerate(out_fts):
             v = to_host(flat_out[2 * i])
             nl = to_host(flat_out[2 * i + 1]).reshape(-1)
             packed.append((v, nl))
-        return decode_outputs(packed, group_valid, out_fts), overflow
+        return decode_outputs(packed, valid, out_fts), overflow
 
 
-def group_mesh_out_spec(agg):
-    """out_specs for the grouped shard_map programs' flat output tuple."""
+def group_mesh_out_spec(n_out_cols: int):
+    """out_specs for the grouped shard_map programs' flat output tuple
+    (`grouped.tail_phase`): two validity vectors and a (value, null) pair
+    a column, sharded; the need hint and the overflow flag, replicated."""
     from jax.sharding import PartitionSpec as P
 
-    n_out_cols = len(agg.aggs) + len(agg.group_by)
-    return tuple([P(REGION_AXIS)] * (1 + 2 * n_out_cols) + [P()])
+    return tuple([P(REGION_AXIS)] * (2 + 2 * n_out_cols) + [P(), P()])

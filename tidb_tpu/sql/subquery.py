@@ -12,8 +12,11 @@ namespace, and the outer AST is rewritten to reference it:
 
   uncorrelated scalar          -> datum literal
   uncorrelated EXISTS          -> 0/1 literal (inner runs with LIMIT 1)
-  uncorrelated IN, small       -> InList of datum literals (exact 3VL)
-  uncorrelated IN, large       -> SemiJoinCond against the materialized rows
+  uncorrelated IN conjunct     -> SemiJoinCond against the materialized rows,
+                                  whatever their number (one outer program)
+  uncorrelated NOT IN / IN in
+  a value position, small      -> InList of datum literals (exact 3VL)
+  uncorrelated NOT IN, large   -> anti SemiJoinCond against the rows
   cmp ANY/ALL (uncorrelated)   -> min/max comparison with empty/NULL guards
   correlated [NOT] IN / EXISTS -> SemiJoinCond (semi/anti join in the DAG)
   correlated scalar (agg)      -> LEFT JOIN of the inner re-grouped by its
@@ -44,8 +47,9 @@ from ..parser import ast as A
 from ..types import Datum
 from .catalog import Catalog, ColumnMeta, TableMeta
 
-# IN-lists up to this size inline as literals (one fused compare chain on
-# device); larger sets become semi joins against the materialized rows
+# NOT IN / value-position IN-lists up to this size inline as literals (one
+# fused compare chain on device); larger sets become anti/semi joins against
+# the materialized rows. An IN conjunct is a semi join at every size.
 MAX_IN_LITERALS = 64
 
 
@@ -516,9 +520,30 @@ class SubqueryRewriter:
         return TRUE_LIT() if exists ^ negated else FALSE_LIT()
 
     def _uncorrelated_in(self, node, schema, stmt, negated, conjunct=True):
-        sub = node.subquery
+        from ..util import tracing
+
         if isinstance(node.expr, A.RowExpr):
-            return self._uncorrelated_tuple_in(node, schema, stmt, negated)
+            with tracing.span("session.subquery", form="or_rows") as sp:
+                out = self._uncorrelated_tuple_in(node, schema, stmt, negated)
+                _note_subquery(sp, out, None)
+                return out
+        with tracing.span("session.subquery") as sp:
+            out, n = self._uncorrelated_value_in(node, schema, stmt, negated, conjunct)
+            _note_subquery(sp, out, n)
+            return out
+
+    def _uncorrelated_value_in(self, node, schema, stmt, negated, conjunct):
+        """x [NOT] IN (uncorrelated subquery) -> (rewritten expression,
+        rows of the set).  A WHERE conjunct `x IN (...)` is always a semi
+        join against the materialised set, empty or not: the outer
+        statement is one program whatever the inner answer's size (its
+        build side is handed over at a sticky capacity rung, TPUStore
+        `_aux_batches`), where literals would make a program per set size
+        and a FALSE literal another plan.  NULLs leave the set: in a WHERE
+        conjunct `x IN (S, NULL)` is TRUE exactly where a semi join over S
+        matches.  NOT IN and value positions keep the literal forms below,
+        whose three-valued logic the size decides."""
+        sub = node.subquery
         fields = (sub.selects[0] if isinstance(sub, A.SetOprStmt) else sub).fields
         if len(fields) != 1 or isinstance(fields[0].expr if isinstance(fields[0], A.SelectField) else fields[0], A.Star):
             raise SubqueryError("IN subquery must select exactly one column")
@@ -533,6 +558,13 @@ class SubqueryRewriter:
             if k not in seen:
                 seen.add(k)
                 uniq.append(d)
+        if conjunct and not negated:
+            nonnull = [d for d in uniq if not d.is_null()]
+            meta = self.registry.register(["v"], [fts[0]], [[d] for d in nonnull])
+            return A.SemiJoinCond(meta.name, [x], ["v"], anti=False), len(nonnull)
+        return self._in_set(x, uniq, fts, negated, conjunct), len(uniq)
+
+    def _in_set(self, x, uniq, fts, negated, conjunct):
         if len(uniq) <= MAX_IN_LITERALS:
             if not uniq:
                 # x IN () is never TRUE; x NOT IN () is always TRUE
@@ -542,18 +574,16 @@ class SubqueryRewriter:
             raise SubqueryError(
                 f"IN subquery with >{MAX_IN_LITERALS} values is only supported as a WHERE conjunct"
             )
-        has_null = any(d.is_null() for d in uniq)
-        if negated and has_null:
+        # a large set here is a NOT IN conjunct's (an IN conjunct is a semi
+        # join before it gets here)
+        if any(d.is_null() for d in uniq):
             # x NOT IN (S ∪ {NULL}) is never TRUE (three-valued logic)
             return FALSE_LIT()
-        nonnull = [d for d in uniq if not d.is_null()]
-        meta = self.registry.register(["v"], [fts[0]], [[d] for d in nonnull])
-        marker = A.SemiJoinCond(meta.name, [x], ["v"], anti=negated)
-        if negated:
-            # NULL probe against non-empty S is NULL -> row filtered; the
-            # anti join alone would keep it
-            return A.BinaryOp("and", marker, A.IsNull(copy.deepcopy(x), negated=True))
-        return marker
+        meta = self.registry.register(["v"], [fts[0]], [[d] for d in uniq])
+        marker = A.SemiJoinCond(meta.name, [x], ["v"], anti=True)
+        # NULL probe against non-empty S is NULL -> row filtered; the anti
+        # join alone would keep it
+        return A.BinaryOp("and", marker, A.IsNull(copy.deepcopy(x), negated=True))
 
     def _uncorrelated_tuple_in(self, node, schema, stmt, negated):
         """(a, b) [NOT] IN (select x, y ...): fold the materialized rows
@@ -1023,6 +1053,32 @@ class SubqueryRewriter:
             # left join's null extension must be patched back
             return A.FuncCall("ifnull", [ref, A.Literal(0, "int")])
         return ref
+
+
+def _note_subquery(sp, out, rows) -> None:
+    """The rewrite's form and the rows materialised into the outer
+    statement, on the `session.subquery` span and the counter."""
+    from ..util import metrics
+
+    if rows is not None:
+        metrics.SUBQUERY_MATERIALIZED_ROWS.inc(rows)
+    if sp is None:
+        return
+    if isinstance(out, A.SemiJoinCond):
+        form = "anti_join" if out.anti else "semi_join"
+    elif isinstance(out, A.BinaryOp) and isinstance(out.left, A.SemiJoinCond):
+        form = "anti_join"
+    elif isinstance(out, A.InList):
+        form = "in_list"
+    elif out is None:
+        form = "true"
+    elif isinstance(out, A.Literal):
+        form = "true" if out.value else "false"
+    else:
+        form = sp.attrs.get("form", "expr")
+    sp.set("form", form)
+    if rows is not None:
+        sp.set("rows", rows)
 
 
 def _has_agg_expr(n) -> bool:

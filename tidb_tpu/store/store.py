@@ -827,44 +827,75 @@ class TPUStore:
                     chunk._device_token = tok
         return tok
 
-    def _aux_batch(self, chunk: Chunk, mesh_devices: int = 0) -> DeviceBatch:
+    def _aux_batches(self, dag: DAGRequest, chunks: list, mesh_devices: int = 0) -> list:
+        """The join build sides of `dag` as its program takes them
+        (`_aux_batch`). A build side the statement materialised (a subquery's
+        rows, a table id below 0: `sql/subquery.py`) is handed over at a
+        sticky capacity rung of the plan's shape
+        (`ProgramCache.input_capacity`), so that a set whose size moves with
+        the inner statement's literals, from none to thousands of rows,
+        calls one program; a table's build side keeps its own size."""
+        from ..exec.dag import collect_scans
+
+        builds = collect_scans(dag.executors)[1:]
+        out = []
+        for i, chunk in enumerate(chunks):
+            cap = None
+            if i < len(builds) and builds[i].table_id < 0:
+                cap = self.programs.input_capacity(dag, ("aux", i), chunk.num_rows())
+            out.append(self._aux_batch(chunk, mesh_devices, capacity=cap))
+        return out
+
+    def _aux_batch(self, chunk: Chunk, mesh_devices: int = 0, capacity: int | None = None) -> DeviceBatch:
         """Broadcast build-side chunk -> DeviceBatch, uploaded once per
         chunk object (all region tasks of a join share the operand). For a
         mesh launch over `mesh_devices` the batch is an entry of its own,
         replicated over the mesh's devices as the program's `in_specs`
-        read it, so the call replicates nothing.
+        read it, so the call replicates nothing. `capacity` (rows the batch
+        holds) defaults to the chunk's rows rounded up to a power of two.
 
         Bounded LRU keyed by the chunk token (never-reused identity); the
         entry pins the chunk so the device batch and its source live and
         die together."""
-        from ..util import metrics, tracing
+        from ..util import tracing
 
         key = self._chunk_token(chunk)
+        if capacity is not None:
+            key = (key, mesh_devices, capacity)
         sharding = None
         if mesh_devices:
             from jax.sharding import NamedSharding, PartitionSpec
 
             from ..parallel.mesh import region_mesh
 
-            key = (key, mesh_devices)
+            if capacity is None:
+                key = (key, mesh_devices)
             sharding = NamedSharding(region_mesh(mesh_devices), PartitionSpec())
         with tracing.span("cop.aux_batch", rows=chunk.num_rows()) as sp:
-            with self._aux_lock:
-                cached = self._aux_batch_cache.get(key)
-                if cached is not None:
-                    self._aux_batch_cache.pop(key)  # refresh LRU position
-                    self._aux_batch_cache[key] = cached
+            batch, hit = self._kept_aux(key, chunk, lambda: to_device_batch(
+                chunk, capacity=capacity or _pow2(max(chunk.num_rows(), 1)), sharding=sharding))
             if sp is not None:
-                sp.set("hit", cached is not None)
-            if cached is not None:
-                return cached[1]
-            metrics.COP_AUX_UPLOADS.inc()
-            batch = to_device_batch(chunk, capacity=_pow2(max(chunk.num_rows(), 1)), sharding=sharding)
-            with self._aux_lock:
-                self._aux_batch_cache[key] = (chunk, batch)
-                while len(self._aux_batch_cache) > self._AUX_CACHE_MAX:
-                    self._aux_batch_cache.pop(next(iter(self._aux_batch_cache)))
+                sp.set("hit", hit)
             return batch
+
+    def _kept_aux(self, key, chunk: Chunk, make) -> tuple:
+        """(the batch kept under `key`, True), its LRU position refreshed;
+        else (`make()`, False), uploaded, counted and kept, pinning `chunk`,
+        the oldest entry past `_AUX_CACHE_MAX` dropped."""
+        from ..util import metrics
+
+        with self._aux_lock:
+            cached = self._aux_batch_cache.pop(key, None)
+            if cached is not None:
+                self._aux_batch_cache[key] = cached  # refresh LRU position
+                return cached[1], True
+        metrics.COP_AUX_UPLOADS.inc()
+        batch = make()
+        with self._aux_lock:
+            self._aux_batch_cache[key] = (chunk, batch)
+            while len(self._aux_batch_cache) > self._AUX_CACHE_MAX:
+                self._aux_batch_cache.pop(next(iter(self._aux_batch_cache)))
+        return batch, False
 
     def build_side(self, chunks: list) -> Chunk | None:
         """A build table's region chunks as one chunk: `SelectResult.merged()`
@@ -1097,7 +1128,7 @@ class TPUStore:
                     dsp.set("bytes_to_device", in_bytes)
                     dsp.set("rows", in_rows)
                     dsp.set("hit", hit)
-            batches = [batch] + [self._aux_batch(c) for c in req.aux_chunks]
+            batches = [batch] + self._aux_batches(dag, req.aux_chunks)
             with tracing.span("cop.execute", region_id=req.region_id, root_fused=fused) as xsp:
                 chunk, ex_rows, info = drive_program_info(self.programs, dag, batches, group_capacity,
                                                           small_groups=req.small_groups)
@@ -1314,7 +1345,7 @@ class TPUStore:
                 ]
                 if dsp is not None:
                     dsp.set("bytes_to_device", sum(ch.nbytes() for ch in chunks))
-                aux_batches = [self._aux_batch(c, mesh_devices=D) for c in req0.aux_chunks]
+                aux_batches = self._aux_batches(dag, req0.aux_chunks, mesh_devices=D)
         except Exception:  # noqa: BLE001 — degrade, never lose the group
             if failpoint.eval("cop-debug-raise"):
                 raise
@@ -1339,7 +1370,8 @@ class TPUStore:
         try:
             with tracing.span("cop.mesh_execute", regions=len(entries),
                               devices=D, kind=kind, root_fused=whole is not None) as xsp:
-                stacked = self._stacked_lanes(ver, entries, chunks, cap, D)
+                stacked = self._stacked_lanes(ver, dag.scan(), req0.start_ts,
+                                              [(region, req.ranges) for _i, req, region in entries], chunks, cap, D)
                 merged, lane_counts, info = drive_mesh_program_info(
                     self.programs, whole or dag, stacked, aux_batches, group_capacity,
                     kind, D, small_groups=req0.small_groups, root=whole is not None,
@@ -1394,41 +1426,81 @@ class TPUStore:
             )
         return True
 
-    def _stacked_lanes(self, ver: int, entries, chunks: list, cap: int, D: int) -> DeviceBatch:
+    def _stacked_lanes(self, ver: int, scan, start_ts: int, lanes: list | None, chunks: list,
+                       cap: int, D: int) -> DeviceBatch:
         """The lanes of a mesh launch as the program reads them: one batch,
         every leaf's region axis sharded over the mesh's `D` devices. Kept
         with the regions' own device batches (`_batch_cache`: one budget,
         one snapshot rule, dropped with them) under the data version `ver`
-        and what the lanes read, so a later statement over the same lanes
-        finds it resident on the devices; else stacked on the host and put
-        there once, each device receiving its own lanes."""
+        and what the lanes read (`lanes`: each chunk's region and ranges,
+        `scan` its columns), so a later statement over the same lanes finds
+        it resident on the devices, whichever cross-chip tier asks; else
+        stacked on the host and put there once, each device receiving its
+        own lanes. `lanes` None: the chunks name no region read, and the
+        batch is stacked and put for this launch alone."""
         from jax.sharding import NamedSharding, PartitionSpec
 
         from ..parallel.mesh import REGION_AXIS, region_mesh
         from ..util import metrics, tracing
 
-        req0 = entries[0][1]
-        scan = req0.dag.scan()
         R_pad = -(-len(chunks) // D) * D  # empty lanes pad the region axis
-        columns = tuple(c.fingerprint() for c in scan.columns)
-        what = ("mesh.stack", cap, R_pad, D,
-                tuple(self._read_key(region, req.ranges, scan.table_id, columns) for _i, req, region in entries))
+        what = None
+        if lanes is not None:
+            columns = tuple(c.fingerprint() for c in scan.columns)
+            what = ("mesh.stack", cap, R_pad, D,
+                    tuple(self._read_key(region, ranges, scan.table_id, columns) for region, ranges in lanes))
         with tracing.span("mesh.stack", lanes=R_pad, devices=D,
                           rows=sum(ch.num_rows() for ch in chunks),
                           bytes=sum(ch.nbytes() for ch in chunks)) as sp:
-            with self._cop_lock:
-                stacked = self._batch_cache.get((ver, what), req0.start_ts)
+            stacked = None
+            if what is not None:
+                with self._cop_lock:
+                    stacked = self._batch_cache.get((ver, what), start_ts)
             hit = stacked is not None
             (metrics.MESH_STACK_HITS if hit else metrics.MESH_STACK_MISSES).inc()
             if sp is not None:
                 sp.set("hit", hit)
             if not hit:
                 fts = chunks[0].field_types()
-                lanes = list(chunks) + [Chunk.empty(fts) for _ in range(R_pad - len(chunks))]
+                padded = list(chunks) + [Chunk.empty(fts) for _ in range(R_pad - len(chunks))]
                 stacked = to_stacked_device_batch(
-                    lanes, cap, NamedSharding(region_mesh(D), PartitionSpec(REGION_AXIS)))
-                self._file_decoded(ver, what, req0.start_ts, None, stacked)
+                    padded, cap, NamedSharding(region_mesh(D), PartitionSpec(REGION_AXIS)))
+                if what is not None:
+                    self._file_decoded(ver, what, start_ts, None, stacked)
         return stacked
+
+    def exchange_lanes(self, ver: int, scan, start_ts: int, lanes: list | None, chunks: list,
+                       D: int) -> DeviceBatch:
+        """The exchange tier's probe lanes (`mpp/dispatch.py`): the
+        regions' chunks of a statement's scan stacked as the per-request
+        mesh tier stacks them (a power-of-two capacity, the region axis
+        padded onto the `D` devices and sharded over them) and kept by the
+        same key, so both tiers find one resident batch where they read the
+        same columns and ranges (`_stacked_lanes`)."""
+        cap = max(_pow2(max(ch.num_rows(), 1)) for ch in chunks)
+        return self._stacked_lanes(ver, scan, start_ts, lanes, chunks, cap, D)
+
+    def exchange_build(self, chunk: Chunk, n: int) -> DeviceBatch:
+        """A shuffle join's build table for the exchange tier over `n`
+        devices: sliced into `n` lanes (a slice plays a region shard) and
+        stacked, sharded over the devices. Kept, like `_aux_batch`, by the
+        chunk object (a build side answered from the result cache is the
+        same object a data version: `build_side`), so a later statement
+        finds it on the devices; the entry pins the chunk."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from ..parallel.mesh import REGION_AXIS, region_mesh
+
+        rows = chunk.num_rows()
+        step = max(-(-rows // n), 1)
+
+        def stack() -> DeviceBatch:
+            slices = [chunk.slice(i * step, min((i + 1) * step, rows)) for i in range(n) if i * step < rows]
+            slices += [Chunk.empty(chunk.field_types()) for _ in range(n - len(slices))]
+            return to_stacked_device_batch(slices, max(1, max(c.num_rows() for c in slices)),
+                                           NamedSharding(region_mesh(n), PartitionSpec(REGION_AXIS)))
+
+        return self._kept_aux((self._chunk_token(chunk), "exchange", n), chunk, stack)[0]
 
     def _lane_attribution(self, region, in_chunk, out_bytes: int, counts,
                           share: int, compile_ns: int, cache_hit: bool,
@@ -1482,7 +1554,7 @@ class TPUStore:
                 ]
                 if dsp is not None:
                     dsp.set("bytes_to_device", sum(ch.nbytes() for ch in chunks))
-                aux_batches = [self._aux_batch(c) for c in req0.aux_chunks]
+                aux_batches = self._aux_batches(req0.dag, req0.aux_chunks)
         except Exception:  # noqa: BLE001 — degrade, never lose the batch
             for i, req, _region in entries:
                 responses[i] = self.coprocessor(req, group_capacity)

@@ -535,6 +535,12 @@ MPP_FALLBACKS = REGISTRY.counter(
     "tidb_tpu_mpp_fallbacks_total", "mpp-eligible plans that fell back (dispatch lost, exchange stall, overflow ladder exhausted, stack refusal)")
 MPP_EXCHANGED_BYTES = REGISTRY.counter(
     "tidb_tpu_mpp_exchanged_bytes_total", "bytes entering the all_to_all exchange (probe + build sides, pre-partition)")
+MPP_TAIL_STATEMENTS = REGISTRY.counter(
+    "tidb_tpu_mpp_tail_statements_total",
+    "exchange-tier statements whose tail (HAVING, projection) ran behind the final aggregate in the exchange program")
+SUBQUERY_MATERIALIZED_ROWS = REGISTRY.counter(
+    "tidb_tpu_subquery_materialized_rows_total",
+    "rows of uncorrelated subqueries materialised into the outer statement (literals or a semi join's build side)")
 
 # placement driver (tidb_tpu/pd) — its own pd_ namespace, like the
 # reference PD process exposing pd_scheduler_*/pd_hotspot_* families
