@@ -150,9 +150,17 @@ def test_the_cpu_clock_is_read_at_a_commands_close_and_no_oftener_than_it_ticks(
     assert reads["cpu"] == 1 and m.cpu > 0   # this command's CPU and the one's before it
 
 
+def over_stores(store, n: int = 3) -> None:
+    """The table's regions spread over `n` stores: a statement's store
+    groups fan out to the dispatch executor."""
+    store.cluster.set_stores(n)
+    store.cluster.scatter()
+
+
 def test_a_statement_whose_tasks_ran_on_the_pool_conserves_with_the_pools_share(served):
     _srv, client = served
     ask(client, "SPLIT TABLE hc BETWEEN (0) AND (300) REGIONS 4")
+    over_stores(_srv.store)
     with Charged() as m:
         ask(client, "SELECT k, COUNT(*) FROM hc WHERE id > 5 GROUP BY k ORDER BY k LIMIT 3")
     assert m.pool > 0 and 0 <= m.pool_cpu <= m.pool + 10_000_000 and m.cpu > 0
@@ -162,8 +170,26 @@ def test_a_statement_whose_tasks_ran_on_the_pool_conserves_with_the_pools_share(
     assert m.wall["distsql.task"] < m.pool   # the pool's total holds the workers' named states too
 
 
+def test_a_statement_whose_one_store_batch_ran_on_its_own_thread_conserves_with_no_pool(served):
+    """Four regions on one store: the batch runs on the serving thread, its
+    states nest under the command's bottom, and nothing waits for a pool."""
+    _srv, client = served
+    ask(client, "SPLIT TABLE hc BETWEEN (0) AND (300) REGIONS 4")
+    ask(client, "SELECT k, COUNT(*) FROM hc WHERE id > 4 GROUP BY k ORDER BY k LIMIT 3")   # the program is built
+    inline, started = metrics.DISTSQL_INLINE_DISPATCHES.value, metrics.DISTSQL_POOL_THREADS_STARTED.value
+    with Charged() as m:
+        ask(client, "SELECT k, COUNT(*) FROM hc WHERE id > 5 GROUP BY k ORDER BY k LIMIT 3")
+    assert metrics.DISTSQL_INLINE_DISPATCHES.value == inline + 1
+    assert metrics.DISTSQL_POOL_THREADS_STARTED.value == started
+    assert m.pool == 0 and m.pool_cpu == 0 and sum(m.wall.values()) == m.handle > 0
+    assert not {"distsql.wait_tasks", "distsql.task"} & set(m.wall)
+    assert m.wall["exec.wait"] > 0 and m.wall["server.command"] > 0
+    assert not tracing.nesting_breaches
+
+
 def test_pool_workers_hand_their_sums_to_the_statements_tag(sess):
     sess.execute("SPLIT TABLE hc BETWEEN (0) AND (300) REGIONS 4")
+    over_stores(sess.store)
     COLLECTOR.reset()
     with Charged() as m:
         sess.execute("SELECT k, COUNT(*) FROM hc WHERE id > 7 GROUP BY k ORDER BY k LIMIT 3")
@@ -174,6 +200,20 @@ def test_pool_workers_hand_their_sums_to_the_statements_tag(sess):
     assert row["device_ns"] == row["host_ns"]["exec.wait"] == m.wall["exec.wait"] > 0
     # the session's thread and the workers', together
     assert row["cpu_ns"] >= m.pool_cpu >= 0 and row["cpu_ns"] > 0
+    assert COLLECTOR.totals["device_ns"] == COLLECTOR.launch_device_ns
+
+
+def test_a_one_store_batch_hands_the_statements_thread_sums_to_its_tag(sess):
+    sess.execute("SPLIT TABLE hc BETWEEN (0) AND (300) REGIONS 4")
+    sess.execute("SELECT k, COUNT(*) FROM hc WHERE id > 6 GROUP BY k ORDER BY k LIMIT 3")   # the program is built
+    COLLECTOR.reset()
+    with Charged() as m:
+        sess.execute("SELECT k, COUNT(*) FROM hc WHERE id > 7 GROUP BY k ORDER BY k LIMIT 3")
+    COLLECTOR.rotate(force=True)
+    (row,) = [d for w in COLLECTOR.windows_view() for d in w["digests"] if "hc" in d["sample_sql"]]
+    # everything ran on the session's thread: the tag holds every state the clock charged
+    assert m.pool == 0 and row["host_ns"] == m.wall and "distsql.task" not in m.wall
+    assert row["device_ns"] == m.wall["exec.wait"] > 0
     assert COLLECTOR.totals["device_ns"] == COLLECTOR.launch_device_ns
 
 

@@ -14,6 +14,7 @@ counters), the PD tick's topsql.report span, scrape_check on the new
 metric families, and a lockwatch storm over rotation vs sessions vs
 the PD tick."""
 
+import contextlib
 import json
 import os
 import sys
@@ -39,7 +40,7 @@ from tidb_tpu.topsql import (
     split_by_rows,
 )
 from tidb_tpu.types import Datum, new_longlong
-from tidb_tpu.util import metrics
+from tidb_tpu.util import metrics, tracing
 from tidb_tpu.util.stmtlog import normalize_sql
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
@@ -166,18 +167,30 @@ class TestDigestUnification:
 # ------------------------------------------------ attribution conservation
 
 
+@contextlib.contextmanager
+def statement(tag):
+    """What a `Session` does around a statement: the tag ambient, and at
+    its end what the statement's own thread charged handed to the tag (the
+    dispatch executor's tasks hand theirs over themselves)."""
+    mark = tracing.clock_mark()
+    with topsql.adopt(tag):
+        yield
+    tag.add_host(tracing.clock_since(mark))
+
+
 class TestConservation:
     def test_tiers_conserve_device_time(self):
         """sum(per-digest device_ns) == sum(launch waits), exactly,
         across the per-region, vmapped-batch and mesh tiers: both sides
         are the state clock's `exec.wait`, the tag's handed over by the
-        pool's workers task by task, the ledger's noted launch by launch.
-        Per-lane ExecSummary shares sum to each cop request's elapsed,
-        which holds the wait."""
+        executor's workers task by task and by the statement's own thread
+        at its end, the ledger's noted launch by launch.  Per-lane
+        ExecSummary shares sum to each cop request's elapsed, which holds
+        the wait."""
         COLLECTOR.reset()
         store = fill_store(n=200, regions=8)
         tag = ResourceTag("tier-test")
-        with topsql.adopt(tag):
+        with statement(tag):
             select(store, kvreq(scan_dag(), 100, concurrency=2, mesh=False))
             store.evict_caches()
             res_b = select(store, kvreq(scan_dag(), 101, batch_cop=True, mesh=False))
@@ -194,13 +207,30 @@ class TestConservation:
         store.evict_caches()
         ledger = COLLECTOR.launch_device_ns
         tag2 = ResourceTag("lane-sum")
-        with topsql.adopt(tag2):
+        with statement(tag2):
             res2 = select(store, kvreq(scan_dag(), 103, batch_cop=True, mesh=False))
         lane_total = sum(task[0].time_processed_ns for task in res2.exec_summaries)
         assert lane_total > tag2.device_ns == COLLECTOR.launch_device_ns - ledger > 0
+        # one store: the batch ran on the calling thread, no worker's state and no wait for one
+        assert {"exec.launch", "exec.wait", "exec.readback"} <= set(tag2.host_ns)
+        assert not {"distsql.task", "distsql.wait_tasks"} & set(tag2.host_ns) and tag2.pool_cpu_ns == 0
+
+    def test_a_several_store_batch_hands_the_workers_states_to_the_tag(self):
+        """Store groups fanned out to the dispatch executor: each worker's
+        task hands its states to the statement's tag, the calling thread's
+        wait is not among them, and the ledger still balances."""
+        COLLECTOR.reset()
+        store = fill_store(n=200, regions=8)
+        store.cluster.set_stores(2)
+        store.cluster.scatter()
+        tag = ResourceTag("two-stores")
+        with topsql.adopt(tag):
+            res = select(store, kvreq(scan_dag(), 104, batch_cop=True, mesh=False))
+        assert res.batch_stats["regions"] == 8 and res.batch_stats["batches"] == 2
+        assert tag.device_ns == COLLECTOR.launch_device_ns > 0
         # the workers' states, and nothing of the calling thread's
-        assert {"distsql.task", "exec.launch", "exec.wait", "exec.readback"} <= set(tag2.host_ns)
-        assert "distsql.wait_tasks" not in tag2.host_ns and tag2.pool_cpu_ns >= 0
+        assert {"distsql.task", "exec.launch", "exec.wait", "exec.readback"} <= set(tag.host_ns)
+        assert "distsql.wait_tasks" not in tag.host_ns and tag.pool_cpu_ns >= 0
 
     def test_cop_cache_hits_lose_nothing(self):
         """A fully cached re-read does zero device work: the tag shows

@@ -3,16 +3,21 @@
 task split copr/coprocessor.go:331 buildCopTasks; retry-on-region-error
 coprocessor.go:1424).
 
-Concurrency mirrors `tidb_distsql_scan_concurrency` (sysvar.go:1956) with a
-thread pool; device execution itself serializes on the single JAX stream,
-but scan-decode and host encode overlap.
+Concurrency mirrors `tidb_distsql_scan_concurrency` (sysvar.go:1956): a
+request runs at most `concurrency` of its tasks at once on the dispatch
+layer's one long-lived executor (`POOL_THREADS` daemon threads shared by
+every statement); device execution itself serializes on the single JAX
+stream, but scan-decode and host encode overlap.  A request with one unit
+of work (one store group) runs it on the statement's own thread.
 """
 
 from __future__ import annotations
 
+import contextvars
+import queue
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, wait
 from dataclasses import dataclass, field
 
 from .. import topsql
@@ -25,6 +30,7 @@ from ..store import CopRequest, KeyRange, TPUStore
 I64_MIN = -(1 << 63)
 I64_MAX = (1 << 63) - 1
 MAX_RETRY = 8
+POOL_THREADS = 16  # the dispatch executor's threads, shared by every statement's fan-out
 
 
 class RegionUnavailableError(RuntimeError):
@@ -726,16 +732,94 @@ def _admission_guard(store):
     return gate.before_dispatch() if gate is not None else nullcontext()
 
 
-def select(store: TPUStore, req: KVRequest) -> SelectResult:
-    from ..util import tracing
-    from .planner import choose_tier
+# ------------------------------------------------------------ the executor
 
+_on_executor = threading.local()  # .yes on the executor's own threads
+
+
+class _Executor:
+    """The dispatch layer's one executor: `size` daemon threads, started
+    together at its first fan-out and kept for the life of the process,
+    serving every statement's tasks from one queue.  Each task runs in a
+    context of its own, as on a fresh thread: nothing ambient leaks from
+    one statement's task into the next."""
+
+    def __init__(self, size: int):
+        from ..util import metrics
+
+        self._work: queue.SimpleQueue = queue.SimpleQueue()
+        for i in range(size):
+            threading.Thread(target=self._serve, name=f"distsql-dispatch-{i}", daemon=True).start()
+            metrics.DISTSQL_POOL_THREADS_STARTED.inc()
+
+    def submit(self, fn, *args) -> Future:
+        fut: Future = Future()
+        self._work.put((fut, fn, args))
+        return fut
+
+    def _serve(self) -> None:
+        _on_executor.yes = True
+        while True:
+            fut, fn, args = self._work.get()
+            if fut.set_running_or_notify_cancel():
+                try:
+                    fut.set_result(contextvars.Context().run(fn, *args))
+                except BaseException as exc:  # noqa: BLE001 - handed to the waiting statement
+                    fut.set_exception(exc)
+            del fut, fn, args
+
+
+_executor: _Executor | None = None  # guarded_by: _executor_lock
+_executor_lock = threading.Lock()
+
+
+def _dispatch_executor() -> _Executor:
+    global _executor
+    with _executor_lock:
+        if _executor is None:
+            _executor = _Executor(POOL_THREADS)
+        return _executor
+
+
+def _fan_out(calls: list, limit: int, stmt_tag) -> list:
+    """Each `(fn, args)` of `calls` as one task of the dispatch executor, at
+    most `limit` of them at once; their results in order.  A task runs
+    under `tracing.pool_task` (the worker's bottom state, its sums to the
+    counters and to the statement's Top SQL tag) with the statement's tag
+    adopted; the statement's thread waits in `distsql.wait_tasks`.  A
+    failure is raised, the first by index, once every task has ended.
+    Called from one of the executor's own threads the calls run there, in
+    order: a task queued behind its own caller could wait forever."""
+    from ..util import tracing
+
+    if getattr(_on_executor, "yes", False):
+        return [fn(*args) for fn, args in calls]
+    pool = _dispatch_executor()
+    slots = threading.Semaphore(limit)
+
+    def task(fn, args):
+        try:
+            with tracing.pool_task(stmt_tag), topsql.adopt(stmt_tag):
+                return fn(*args)
+        finally:
+            slots.release()
+
+    with tracing.span("distsql.wait_tasks", tasks=len(calls)):
+        futs = []
+        for fn, args in calls:
+            slots.acquire()
+            futs.append(pool.submit(task, fn, args))
+        wait(futs)
+    return [f.result() for f in futs]
+
+
+def select(store: TPUStore, req: KVRequest) -> SelectResult:
     with _admission_guard(store):
         return _select_admitted(store, req)
 
 
 def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
-    from ..util import tracing
+    from ..util import metrics, tracing
     from .planner import choose_tier
 
     tasks = _build_tasks(store, req.ranges)
@@ -744,16 +828,14 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
     # finish in arbitrary order, and a shared append list would make
     # EXPLAIN ANALYZE region attribution nondeterministic across runs
     summaries_by_task: list = [[] for _ in tasks]
-    # cross-thread span handoff: pool workers don't inherit contextvars,
-    # so capture the dispatching thread's span here and parent the
-    # per-task spans on it explicitly (pkg/util/tracing's SpanFromContext
-    # handover at the copIterator worker boundary). The Top SQL resource
-    # tag rides the SAME seam: workers adopt the statement's tag so the
-    # store/backoff sinks attribute from pool threads. So does the host-
-    # state clock: a pool task runs under `tracing.pool_task`, which gives
-    # the worker the bottom state `distsql.task` and hands what the task
-    # charged to the counters and to the tag; the statement's thread waits
-    # for the futures in the state `distsql.wait_tasks`.
+    # cross-thread span handoff: the executor's tasks don't inherit
+    # contextvars, so capture the dispatching thread's span here and parent
+    # the per-task spans on it explicitly (pkg/util/tracing's
+    # SpanFromContext handover at the copIterator worker boundary). The Top
+    # SQL resource tag rides the SAME seam (`_fan_out`: workers adopt the
+    # statement's tag so the store/backoff sinks attribute from their
+    # threads), and so does the host-state clock (`tracing.pool_task`).
+    # Work that runs on the statement's own thread needs neither.
     dispatch_span = tracing.current_span()
     stmt_tag = topsql.current_tag()
     scan_kind = _scan_kind(req)
@@ -765,13 +847,8 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
     lone = fused if req.whole_dag is not None and len(tasks) == 1 else None
 
     def run_task(i: int, task: CopTask):
-        with topsql.adopt(stmt_tag):
-            return _run_one_task(store, req, task, summaries_by_task[i],
-                                 dispatch_span=dispatch_span, scan_kind=scan_kind, fused=lone)
-
-    def pooled(fn, *args):
-        with tracing.pool_task(stmt_tag):
-            return fn(*args)
+        return _run_one_task(store, req, task, summaries_by_task[i],
+                             dispatch_span=dispatch_span, scan_kind=scan_kind, fused=lone)
 
     # ONE execution planner picks the tier by data size and topology
     # (distsql/planner.py): single launch -> vmapped store batch -> mesh
@@ -800,16 +877,19 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
         whole = fused if req.whole_dag is not None and mesh and len(by_store) == 1 else None
 
         def run_batch(sid, entries):
-            with topsql.adopt(stmt_tag):
-                return _run_store_batch(store, req, sid, entries, results,
-                                        summaries_by_task, dispatch_span, scan_kind,
-                                        mesh=mesh, fused=whole)
+            return _run_store_batch(store, req, sid, entries, results,
+                                    summaries_by_task, dispatch_span, scan_kind,
+                                    mesh=mesh, fused=whole)
 
-        with tracing.span("distsql.wait_tasks", tasks=len(by_store)), \
-                ThreadPoolExecutor(max_workers=max(len(by_store), 1)) as pool:
-            futs = [pool.submit(pooled, run_batch, sid, entries)
-                    for sid, entries in by_store.items()]
-            per_store = [f.result() for f in futs]
+        if len(by_store) > 1:
+            per_store = _fan_out([(run_batch, group) for group in by_store.items()],
+                                 len(by_store), stmt_tag)
+        else:
+            # one unit of work: the statement's own thread runs it, under
+            # its own tag and span; its states nest under the thread's own
+            # bottom, as a lone cop task's do
+            per_store = [run_batch(sid, entries) for sid, entries in by_store.items()]
+            metrics.DISTSQL_INLINE_DISPATCHES.inc(len(per_store))
         batch_stats = {
             "batches": sum(s["batches"] for s in per_store),
             "regions": sum(s["regions"] for s in per_store),
@@ -818,11 +898,7 @@ def _select_admitted(store: TPUStore, req: KVRequest) -> SelectResult:
             "mesh_lanes": sum(s["mesh_lanes"] for s in per_store),
         }
     elif req.concurrency > 1 and len(tasks) > 1:
-        with tracing.span("distsql.wait_tasks", tasks=len(tasks)), \
-                ThreadPoolExecutor(max_workers=req.concurrency) as pool:
-            futs = [pool.submit(pooled, run_task, i, t) for i, t in enumerate(tasks)]
-            for i, f in enumerate(futs):
-                results[i] = f.result()
+        results[:] = _fan_out([(run_task, it) for it in enumerate(tasks)], req.concurrency, stmt_tag)
     else:
         for i, t in enumerate(tasks):
             results[i] = run_task(i, t)

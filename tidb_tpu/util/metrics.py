@@ -290,6 +290,8 @@ COP_EXECUTOR_ROWS = REGISTRY.counter_vec(
     labelnames=("executor",),
 )
 DISTSQL_TASKS = REGISTRY.counter("tidb_tpu_distsql_tasks_total", "per-region cop tasks dispatched")
+DISTSQL_INLINE_DISPATCHES = REGISTRY.counter("tidb_tpu_distsql_inline_dispatches_total", "batch and mesh tier requests whose one store group ran on the statement's own thread")
+DISTSQL_POOL_THREADS_STARTED = REGISTRY.counter("tidb_tpu_distsql_pool_threads_started_total", "threads the dispatch executor has started: flat after its first fan-out")
 DISTSQL_STORE_TASKS = REGISTRY.counter_vec(
     "tidb_tpu_distsql_store_tasks_total", "cop tasks dispatched per placement store",
     labelnames=("store",),

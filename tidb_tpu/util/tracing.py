@@ -282,7 +282,7 @@ class pool_task(host_state):
     __slots__ = ("_tag",)
 
     def __init__(self, tag=None):
-        super().__init__("distsql.task", cpu="close")  # a pool's thread may end with its task
+        super().__init__("distsql.task", cpu="close")  # the executor's thread only waits between tasks
         self._tag = tag
 
     def __exit__(self, exc_type, exc, tb) -> bool:
