@@ -3,6 +3,13 @@ seed by `oltp_common.lua`'s value rules, the load by multi-row INSERT as
 `sysbench prepare` does, table after table, and the plain reference for
 `oltp_read_only.lua`'s five statement shapes: slices of the generated arrays.
 
+For `oltp_read_write.lua` (`../sysbench_32x16k_rw/`) the same module keeps
+the optional functions of a configuration that writes, at the end of the
+file: which statements write, the state at the end of the load (`State`:
+the generated arrays beneath, every row a write has set above them), a
+write's effect on a state with its affected rows, the reads over a state,
+and the read-back statements.
+
 Nothing here imports the program: the harness hands `load` a connected
 wire client.
 """
@@ -108,3 +115,121 @@ def control(name: str, params: dict, data: dict) -> list:
     ids 1..n-lost."""
     lost = data["n"] % data["insert_batch_rows"] or data["insert_batch_rows"]
     return _answer(name, params, data, data["n"] - lost)
+
+
+# --------------------------------------------------------------------------
+# a configuration that writes (oltp_read_write.lua): the optional functions
+# the history judge calls (harness/judge.py `History`)
+# --------------------------------------------------------------------------
+
+WRITES = frozenset({"index_update", "non_index_update", "delete", "insert"})
+RANGES = frozenset({"simple_range", "sum_range", "order_range", "distinct_range"})
+
+
+def writes(name: str) -> bool:
+    return name in WRITES
+
+
+class State:
+    """The tables at one point of a history: the load's arrays beneath, and
+    above them every row that a write has set, `(k, c, pad)` or None for a
+    deleted row, by table and id.  A key is `(table, id)`."""
+
+    def __init__(self, data: dict):
+        self.data = data
+        self.rows: dict = {}
+
+    def get(self, key):
+        t, i = key
+        ids = self.rows.get(t)
+        if ids is not None and i in ids:
+            return ids[i]
+        data, n = self.data, self.data["n"]
+        if not (1 <= t <= data["tables"] and 1 <= i <= n):
+            return None
+        return (int(data["k"][t - 1][i - 1]), data["c"][t - 1][i - 1].decode(), data["pad"][(t - 1) * n + i - 1])
+
+    def put(self, key, row) -> None:
+        self.rows.setdefault(key[0], {})[key[1]] = row
+
+
+def load_state(data: dict) -> State:
+    """The state at the end of the load: every generated row, as loaded."""
+    return State(data)
+
+
+def keys(name: str, params: dict) -> list:
+    """The rows a statement reads or writes; `(table, None)` is every row of
+    the table (the read-back's look-up by `k`)."""
+    t = int(params["t"])
+    if name in RANGES:
+        return [(t, i) for i in range(int(params["a"]), int(params["b"]) + 1)]
+    if name == "k_read_back":
+        return [(t, None)]
+    return [(t, int(params["id"]))]
+
+
+def apply(name: str, params: dict, state: State):
+    """A write's effect on `state`, with the rows it affects; None where
+    the statement has to fail (an INSERT of an id that is there).  An
+    UPDATE affects the row it finds: the drawn strings never repeat, so a
+    row found is a row changed.  `k = k + 1` adds to the value in `state`,
+    which the judge folds at commit, as pessimistic DML reads at
+    `for_update_ts`."""
+    key = (int(params["t"]), int(params["id"]))
+    row = state.get(key)
+    if name == "insert":
+        if row is not None:
+            return None
+        state.put(key, (int(params["k"]), params["c"], params["pad"]))
+        return 1
+    if row is None:
+        return 0
+    k, c, pad = row
+    if name == "index_update":
+        state.put(key, (k + 1, c, pad))
+    elif name == "non_index_update":
+        state.put(key, (k, params["c"], pad))
+    elif name == "delete":
+        state.put(key, None)
+    else:
+        raise KeyError(name)
+    return 1
+
+
+def reference_at(name: str, params: dict, state: State) -> list:
+    """The statement's rows as wire text over `state`."""
+    t = int(params["t"])
+    if name == "pk_read_back":
+        row = state.get((t, int(params["id"])))
+        return [] if row is None else [[str(params["id"]), str(row[0]), row[1], row[2]]]
+    if name == "k_read_back":
+        k, data = int(params["k"]), state.data
+        ids = set(np.flatnonzero(data["k"][t - 1] == k) + 1) if 1 <= t <= data["tables"] else set()
+        for i, row in state.rows.get(t, {}).items():
+            (ids.add if row is not None and row[0] == k else ids.discard)(i)
+        return [[str(i)] for i in sorted(ids)]
+    if name == "point_select":
+        row = state.get((t, int(params["id"])))
+        return [] if row is None else [[row[1]]]
+    a, b = int(params["a"]), int(params["b"])
+    rows = [row for row in (state.get((t, i)) for i in range(a, b + 1)) if row is not None]
+    if name == "simple_range":
+        return [[c] for _, c, _ in rows]
+    if name == "sum_range":
+        return [[str(sum(k for k, _, _ in rows))]] if rows else [[None]]
+    if name == "order_range":
+        return [[c] for c in sorted(c for _, c, _ in rows)]
+    if name == "distinct_range":
+        return [[c] for c in sorted({c for _, c, _ in rows})]
+    raise KeyError(name)
+
+
+def read_back(values: dict) -> list:
+    """The read-back after the run, `[(statement, params)]`: every row
+    written (`values`: key -> the rows it may hold or has held, None for
+    absent) by primary key, then through `k_1` every `k` it may hold or
+    has held, so that an index entry left behind is read too."""
+    steps = [("pk_read_back", {"t": t, "id": i}) for t, i in sorted(values)]
+    ks = sorted({(t, row[0]) for (t, _), rows in values.items() for row in rows if row is not None})
+    return steps + [("k_read_back", {"t": t, "k": k}) for t, k in ks]

@@ -7,11 +7,21 @@ A step is `{"sql": text}` (sent as it is, not compared: BEGIN, COMMIT) or
 `{"statement": name, "repeat": n, "once": {param: rule}, "params": {param:
 rule}}`, the name being a key of the configuration's `statements.json`;
 `once` is drawn one time for the step, `params` anew for each repetition.
-A rule is a JSON
+`statement` may be a list of names: each repetition sends them one after
+the other with one draw of `once` and `params` between them (sysbench's
+delete and insert of one table and id).  A rule is a JSON
 scalar (a constant), `{"uniform_int": [low, high]}` with both ends
-included, `{"choice": [...]}`, or `{"plus": [other_param, n]}`.  An end of
-`uniform_int` may be a number or a size of the configuration's file, with
-an offset: `"table_size-99"`.
+included, `{"choice": [...]}`, `{"plus": [other_param, n]}`, or
+`{"sb_string": n}`: n groups of 11 random digits joined by '-', sysbench's
+`###########-` template (`oltp_common.lua`: `c` 10 groups, `pad` 5).  An
+end of `uniform_int` may be a number or a size of the configuration's
+file, with an offset: `"table_size-99"`.  A rule draws from the client's
+stream only where a mix names it.
+
+A mix may list `restart_on: [errno, ...]`: an attempt answered with one
+of these MySQL error codes is rolled back and the operation starts again
+with fresh draws from the same stream, as sysbench restarts an event on
+the errors of its `--mysql-ignore-errors` (`run.py`).
 """
 
 from __future__ import annotations
@@ -35,18 +45,19 @@ class Mix:
     def __init__(self, spec: dict, statements: dict, sizes: dict):
         self.spec, self.statements, self.sizes = spec, statements, sizes
         self.clients = int(spec["clients"])
+        self.restart_on = frozenset(int(e) for e in spec.get("restart_on", ()))
         if spec.get("loop") != "closed":
             raise ValueError(f"loop {spec.get('loop')!r}: this generator drives closed loops")
         for step in spec["operation"]:
-            if "statement" in step and step["statement"] not in statements:
-                raise KeyError(f"traffic names statement {step['statement']!r}; "
-                               f"the configuration has {sorted(statements)}")
+            for name in _names(step):
+                if name not in statements:
+                    raise KeyError(f"traffic names statement {name!r}; "
+                                   f"the configuration has {sorted(statements)}")
 
     def statement_names(self) -> list:
         seen = []
         for step in self.spec["operation"]:
-            if "statement" in step and step["statement"] not in seen:
-                seen.append(step["statement"])
+            seen += [name for name in _names(step) if name not in seen]
         return seen
 
     def _bound(self, v) -> int:
@@ -69,6 +80,9 @@ class Mix:
                 out[key] = rule["choice"][int(rng.integers(0, len(rule["choice"])))]
             elif "plus" in rule:
                 out[key] = out[rule["plus"][0]] + int(rule["plus"][1])
+            elif "sb_string" in rule:
+                digits = rng.integers(0, 10**11, size=int(rule["sb_string"]))
+                out[key] = "-".join(f"{v:011d}" for v in digits.tolist())
             else:
                 raise ValueError(f"parameter {key!r}: unknown rule {rule!r}")
         return out
@@ -83,9 +97,14 @@ class Mix:
             once = self._draw(step.get("once", {}), rng)
             for _ in range(int(step.get("repeat", 1))):
                 params = {**once, **self._draw(step.get("params", {}), rng)}
-                steps.append(Step(self.statements[step["statement"]].format(**params),
-                                  step["statement"], params))
+                steps += [Step(self.statements[name].format(**params), name, params) for name in _names(step)]
         return steps
+
+
+def _names(step: dict) -> list:
+    """The statement names of a step: none for raw SQL, one, or a list."""
+    names = step.get("statement", [])
+    return [names] if isinstance(names, str) else list(names)
 
 
 def client_rng(seed: int, client: int, phase: int):

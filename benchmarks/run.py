@@ -13,6 +13,11 @@ reference.  The last line of stdout is the result; every earlier line is
 one JSON object too (see README.md).  `--control` puts the
 configuration's control in the program's place for the comparison.
 
+A mix that writes runs each operation as attempts (`WritingOperation`),
+keeps every statement sent after the load in the judge's history, and
+reads back every row written once the clients are through and before the
+server closes (`read_back`); a mix that only reads takes none of that.
+
 There is one path through this file.  `tests/` drives it on the CPU by
 putting a stand-in in `engine.device`'s place and a manifest of small
 configurations in `catalog.MANIFEST`'s.
@@ -43,12 +48,13 @@ os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 from harness import engine, judge, spans, xplane  # noqa: E402
-from harness.traffic import Mix, client_rng  # noqa: E402
+from harness.traffic import Mix, Step, client_rng  # noqa: E402
 
 WIRE_TIMEOUT = 1100.0      # a first execution compiles; the wire must wait
 WARMUP_SEED = 0            # warm-up draws the same literals whatever --seed: its programs stay cached
 PROFILE_SECONDS = 5.0      # the profiler's window inside a traced run
 LATE_ANSWER_SECONDS = 60.0  # how long past the close an answer is waited for
+MAX_ATTEMPTS = 100         # a restarted operation gives up after this many attempts
 
 
 def emit(**line) -> None:
@@ -85,11 +91,88 @@ class Operation:
         self.t1 = time.perf_counter()
 
 
+class TracedError(Exception):
+    """A traced statement whose span tree carries an error: TRACE answers
+    the statement's failure as a row, with no error code."""
+
+
+class WritingOperation(Operation):
+    """One operation of a mix that writes: a BEGIN ... COMMIT attempt, and
+    another with fresh draws from the client's stream each time an attempt
+    is answered with an error code of the mix's `restart_on`.  An attempt
+    that ends in an error is rolled back.  `steps`, `answers` and `spans`
+    hold every statement of every attempt (the ROLLBACKs too), `attempts`
+    what the judge's history keeps of each (`judge.Attempt`).  `broken`: a
+    statement got no reply, so the connection carries nothing more."""
+
+    __slots__ = ("attempts", "broken")
+
+    def __init__(self, client: int, traced: bool, steps: list):
+        super().__init__(client, traced, steps)
+        self.attempts: list = []
+        self.broken = False
+
+    def run(self, conn, annotate, mix: Mix, rng) -> None:
+        self.t0 = time.perf_counter()
+        steps, self.steps = self.steps, []
+        for n in range(MAX_ATTEMPTS):
+            attempt, code = self._attempt(conn, annotate, steps)
+            self.attempts.append(attempt)
+            if attempt.error is None or self.broken or code not in mix.restart_on or n + 1 == MAX_ATTEMPTS:
+                break
+            attempt.restarted = True
+            steps = mix.operation(rng)
+        self.error = attempt.error
+        self.t1 = time.perf_counter()
+
+    def _attempt(self, conn, annotate, steps: list):
+        answers, times, error, code, outcome = [], [], None, None, "committed"
+        for step in steps:
+            t = time.perf_counter()
+            try:
+                if step.name is None:
+                    conn.query(step.sql)
+                    answer = None
+                else:
+                    with annotate(xplane.CLIENT_MARK + step.name):
+                        got = conn.query(("trace format='json' " if self.traced else "") + step.sql)
+                    if self.traced:
+                        answer = spans.parse(got[1][0][0])
+                        if "error" in answer.get("attrs", {}):
+                            raise TracedError(answer["attrs"]["error"])
+                    else:
+                        answer = got[1] if isinstance(got, tuple) else got   # rows, or rows affected
+            except Exception as e:  # noqa: BLE001 - a failed attempt is kept, not fatal
+                times.append((t, time.perf_counter()))
+                error, code = f"{type(e).__name__}: {e}", getattr(e, "code", None)
+                replied = isinstance(e, TracedError) or isinstance(code, int)
+                self.broken = not replied
+                outcome = "unknown" if not replied and judge.verb(step) == "commit" else "aborted"
+                break
+            answers.append(answer)
+            times.append((t, time.perf_counter()))
+        attempt = judge.Attempt(self.client, "", self.traced, steps[:len(times)], answers, times, error, outcome)
+        self.steps += attempt.steps
+        self.answers += answers + [None] * (len(times) - len(answers))
+        self.spans += times
+        if error is not None and not self.broken:
+            rollback = Step("rollback")
+            t = time.perf_counter()
+            try:
+                conn.query(rollback.sql)
+            except Exception as e:  # noqa: BLE001
+                self.broken = not isinstance(getattr(e, "code", None), int)
+            self.steps.append(rollback)
+            self.answers.append(None)
+            self.spans.append((t, time.perf_counter()))
+        return attempt, code
+
+
 class Clients:
     """The mix's client threads; each owns one connection."""
 
-    def __init__(self, srv, mix: Mix, seed: int, trace: bool):
-        self.mix, self.seed, self.trace = mix, seed, trace
+    def __init__(self, srv, mix: Mix, seed: int, trace: bool, writing: bool = False):
+        self.mix, self.seed, self.trace, self.writing = mix, seed, trace, writing
         self.conns = [engine.connect(srv, WIRE_TIMEOUT) for _ in range(mix.clients)]
         engines = mix.spec["read_engines"]
         for c in self.conns:
@@ -108,10 +191,16 @@ class Clients:
             n = 0
             while (count is None or n < count) and (until is None or time.perf_counter() < until):
                 # in a traced run every second operation is sent as TRACE
-                op = Operation(i, self.trace and n % 2 == 1, self.mix.operation(rng))
-                op.run(self.conns[i], self.annotate)
+                if self.writing:
+                    op = WritingOperation(i, self.trace and n % 2 == 1, self.mix.operation(rng))
+                    op.run(self.conns[i], self.annotate, self.mix, rng)
+                else:
+                    op = Operation(i, self.trace and n % 2 == 1, self.mix.operation(rng))
+                    op.run(self.conns[i], self.annotate)
                 done[i].append(op)
                 n += 1
+                if getattr(op, "broken", False):
+                    break
 
         threads = [threading.Thread(target=loop, args=(i,), daemon=True) for i in range(len(self.conns))]
         for t in threads:
@@ -143,15 +232,21 @@ def prove_paths(mix: Mix, conn, checker) -> None:
     first execution builds and compiles its program here."""
     proofs = mix.spec.get("proofs", {})
     pallas = engine.pallas_mode()
-    for step in mix.operation(client_rng(WARMUP_SEED, mix.clients, 0)):
+    writing = isinstance(checker, judge.History)
+    steps, answers, times = mix.operation(client_rng(WARMUP_SEED, mix.clients, 0)), [], []
+    for step in steps:
         before = engine.counters()
         t = time.perf_counter()
         got = conn.query(step.sql)
         wall = time.perf_counter() - t
+        if writing:   # the history judges the proof with the rest, once the run is over
+            answers.append(None if step.name is None else got[1] if isinstance(got, tuple) else got)
+            times.append((t, t + wall))
         moved = engine.moved(before)
         if step.name is None:
             continue
-        checker.statement(step, got[1], where="warm-up")
+        if not writing:
+            checker.statement(step, got[1], where="warm-up")
         if moved["oracle_fallbacks"]:
             raise SystemExit(f"{step.name}: {moved['oracle_fallbacks']} oracle fallback(s) in warm-up")
         if step.name in proofs.get("columnar", ()):
@@ -163,6 +258,29 @@ def prove_paths(mix: Mix, conn, checker) -> None:
             elif not moved["kernels"]:
                 raise SystemExit(f"{step.name}: no Pallas kernel was traced into its program: {moved}")
         emit(warmup=step.name, wall_s=round(wall, 4), **moved)
+    if writing:
+        checker.record(judge.Attempt(mix.clients, "warm-up", False, steps, answers, times))
+
+
+def read_back(checker, conn, ops: list, mix: Mix) -> None:
+    """Every row written during the run read back over the admin
+    connection, untimed, after the clients are through: the deployment's
+    read-back statements, kept in the history as one more attempt."""
+    t0 = time.perf_counter()
+    steps = [Step(mix.statements[name].format(**params), name, params)
+             for name, params in checker.read_back_steps(ops)]
+    answers, times, error = [], [], None
+    for step in steps:
+        t = time.perf_counter()
+        try:
+            answers.append(conn.query(step.sql)[1])
+        except Exception as e:  # noqa: BLE001 - an unanswered read-back statement is a mismatch
+            error = f"{type(e).__name__}: {e}"
+            break
+        times.append((t, time.perf_counter()))
+    checker.record(judge.Attempt(-1, "read-back", False, steps, answers, times, error))
+    emit(phase="read_back", wall_s=round(time.perf_counter() - t0, 3), statements=len(steps),
+         answered=len(answers), error=error)
 
 
 def profile_window(seconds: float, t_open: float):
@@ -211,8 +329,10 @@ def set_up(cell: Cell, config: dict, mix: Mix, seed: int, trace: bool):
         engine.fill_replica(srv, admin, config, dep.replica_tables(config), emit)
     emit(phase="loaded", wall_s=round(time.perf_counter() - T0, 3), **engine.moved(before))
 
-    checker = judge.Checker(dep, data)
-    clients = Clients(srv, mix, seed, trace)
+    writing = judge.writes(dep, mix)
+    checker = (judge.History(dep, data, mix, config.get("control", "").split(":")[0]) if writing
+               else judge.Checker(dep, data))
+    clients = Clients(srv, mix, seed, trace, writing)
     before = engine.counters()
     prove_paths(mix, clients.conns[0], checker)
     clients.drive(phase=0, count=int(mix.spec.get("warmup_operations", 1)))
@@ -274,6 +394,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, control: bool = F
     ops, unanswered = clients.join(LATE_ANSWER_SECONDS)
     window = engine.moved(before)
     device["memory_peak_bytes"] = engine.memory_peak_bytes()
+    if isinstance(checker, judge.History):
+        read_back(checker, admin, ops, mix)
     clients.close()
     admin.close()
     srv.close()
@@ -318,7 +440,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, control: bool = F
     verdict = checker.window(ops, unanswered)
     if control:
         emit(program=verdict)
-        verdict = judge.Checker(checker.dep, checker.data, control=True).window(ops, unanswered)
+        verdict = checker.under_control().window(ops, unanswered)
     emit(phase="reference", wall_s=round(time.perf_counter() - t, 3), examples=verdict.pop("examples"))
     result = {
         "correct": verdict.pop("correct"), "attempted": len(ops) + unanswered,

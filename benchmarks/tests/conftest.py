@@ -6,9 +6,9 @@
 three stand-ins put underneath it from here: `engine.device` answers for
 the CPU, `catalog.MANIFEST` names a manifest whose configurations are the
 real ones at small sizes (and which holds, beside BENCHMARK.json's cells,
-the TPC-H cells of `data/tpch_cells.json` that wait under PERF.md's Open
-questions), and the trace reduction takes the XLA CPU client's threads
-for a device plane.  Their numbers are no measurements.
+the entries kept ready in `data/tpch_cells.json` and `data/write_cells.json`),
+and the trace reduction takes the XLA CPU client's threads for a device
+plane.  Their numbers are no measurements.
 """
 
 import json
@@ -45,24 +45,26 @@ def host_cpu_events(profile) -> dict:
     return {"/host:CPU xla client": events} if events else {}
 
 
-def small_manifest(tmp_dir: str) -> str:
-    """BENCHMARK.json plus the waiting TPC-H cells, every configuration's
-    file rewritten at SMALL sizes, pointing at the real deployment module
-    and statements."""
+def small_manifest(tmp_dir: str, sizes: dict | None = None) -> str:
+    """BENCHMARK.json plus the entries kept ready, every configuration's
+    file rewritten at SMALL sizes (`sizes` over them, by configuration),
+    pointing at the real deployment module and statements."""
     with open(os.path.join(CHECKOUT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    with open(os.path.join(HERE, "data", "tpch_cells.json")) as f:
-        waiting = json.load(f)
-    for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        have = {e["name"] for e in manifest[key]}
-        manifest[key] += [e for e in waiting[key] if e["name"] not in have]
+    for kept in ("tpch_cells.json", "write_cells.json"):
+        with open(os.path.join(HERE, "data", kept)) as f:
+            waiting = json.load(f)
+        for key in ("configs", "workloads", "end_to_end", "per_layer"):
+            have = {e["name"] for e in manifest[key]}
+            manifest[key] += [e for e in waiting[key] if e["name"] not in have]
     for entry in manifest["configs"]:
         real = os.path.join(CHECKOUT, entry["file"])
         with open(real) as f:
             config = json.load(f)
         config.update({k: v for k, v in SMALL.items() if k in config})
-        config["deployment"] = os.path.join(os.path.dirname(real), "deployment.py")
-        config["statements"] = os.path.join(os.path.dirname(real), "statements.json")
+        config.update((sizes or {}).get(entry["name"], {}))
+        for key, default in (("deployment", "deployment.py"), ("statements", "statements.json")):
+            config[key] = os.path.normpath(os.path.join(os.path.dirname(real), config.get(key, default)))
         entry["file"] = os.path.join(tmp_dir, entry["name"] + ".json")
         with open(entry["file"], "w") as f:
             json.dump(config, f)
@@ -76,10 +78,19 @@ def small_manifest(tmp_dir: str) -> str:
 def small_run(tmp_path, monkeypatch, capsys):
     """`small_run(workload, seed, seconds, trace=False, control=False)` ->
     the result line of a whole run of the harness on the CPU."""
+    return small_run_at(tmp_path, monkeypatch, capsys)
+
+
+def small_run_at(tmp_path, monkeypatch, capsys, sizes: dict | None = None, clients: int | None = None):
+    """`small_run`, with `sizes` over SMALL by configuration, and the mix's
+    clients taken down to `clients` where the CPU cannot hold its count."""
     import run as bench
     from harness import catalog, engine, xplane
 
-    monkeypatch.setattr(catalog, "MANIFEST", small_manifest(str(tmp_path)))
+    monkeypatch.setattr(catalog, "MANIFEST", small_manifest(str(tmp_path), sizes))
+    if clients:
+        real_mix = bench.Mix
+        monkeypatch.setattr(bench, "Mix", lambda spec, *a: real_mix({**spec, "clients": clients}, *a))
     monkeypatch.setattr(engine, "device", lambda chips: {"platform": "cpu", "kind": "cpu", "count": chips})
     monkeypatch.setattr(bench, "peaks", lambda kind: None)
     real = xplane.device_events
@@ -90,3 +101,4 @@ def small_run(tmp_path, monkeypatch, capsys):
         return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
     return go
+

@@ -101,9 +101,11 @@ def test_manifest_has_the_cell_and_its_metrics():
         assert (entries[metric]["source"], entries[metric]["moves"]) == (source, moves)
         assert entries[metric]["workloads"] == [CELL]
         assert entries[metric]["layer"] == ("kernels" if metric == "device_roofline" else ROUTE)
-    # the cell reports every metric that lists no cells, and none of sysbench's own
+    # the cell reports every metric that lists no cells (these and any appended
+    # later), and none of sysbench's own
     reported = {m["name"] for m in catalog.Cell(CELL).metrics("per_layer")}
-    assert reported == {"frontend_ms_per_op", "cop_host_ms_per_op", "launches_per_op", "programs_built_per_op",
+    assert not {"trace_lower_ms_per_op", "xla_compile_ms_per_op", "cop_decode_ms_per_op"} & reported
+    assert reported >= {"frontend_ms_per_op", "cop_host_ms_per_op", "launches_per_op", "programs_built_per_op",
                         "cop_cache_hits_per_op", "device_idle_pct", "device_roofline", "columnar_scan_ms_per_op",
                         "columnar_gate_ms_per_op", "columnar_fallbacks_per_op"}
     assert {m["name"] for m in catalog.Cell(CELL).metrics("end_to_end")} == {"ops_per_s", "op_p50_ms", "setup_s"}
@@ -130,7 +132,8 @@ def test_small_manifest_resolves_with_the_cell_in_both_files(tmp_path, monkeypat
     for key in ("configs", "workloads", "end_to_end", "per_layer"):
         names = [e["name"] for e in manifest[key]]
         assert len(names) == len(set(names)), (key, names)
-    assert [w["name"] for w in manifest["workloads"]] == ["sysbench_ro_uniform", CELL, "tpch_q3_params"]
+    names = [w["name"] for w in manifest["workloads"]]
+    assert names[:3] == ["sysbench_ro_uniform", CELL, "tpch_q3_params"] and "tpch_q1q6q3_mesh4" in names
     (mine,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert "131,072" in mine["why"]     # BENCHMARK.json's entry, not the waiting one
     monkeypatch.setattr(catalog, "MANIFEST", path)

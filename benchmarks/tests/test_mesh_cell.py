@@ -61,7 +61,7 @@ def test_the_cell_is_in_the_manifest_with_its_configuration_and_metrics():
             "cop_cache_hits_per_op", "device_idle_pct"} <= set(per_layer)
     assert "device_roofline" not in per_layer and "mpp_exchange_ms_per_op" not in per_layer
     four = [w["name"] for w in cell.manifest["workloads"] if w["chips"] == 4]
-    assert four == [CELL] and len(four) <= len(cell.manifest["workloads"]) // 2
+    assert CELL in four and len(four) <= len(cell.manifest["workloads"]) // 2
 
 
 def test_program_names_mesh_names_only_counters_the_program_had_before():
@@ -116,7 +116,8 @@ def test_traced_run_reports_the_cells_metrics(small_run):
     assert wanted <= set(m)
     assert m["mesh_statements_per_op"] == 3.0 and m["mesh_fallbacks_per_op"] == 0.0
     assert m["programs_built_per_op"] == 0.0 and m["mesh_stack_ms_per_op"] > 0
-    assert m["launches_per_op"] >= 6.0 and m["cop_host_ms_per_op"] > m["mesh_stack_ms_per_op"]
+    # one launch a statement since the root's half rides the mesh program
+    assert m["launches_per_op"] == 3.0 and m["cop_host_ms_per_op"] > m["mesh_stack_ms_per_op"]
     assert line["breakdown"]["device_ops"]
 
 

@@ -87,7 +87,7 @@ def test_cell_resolves_from_the_manifest():
     assert cell.traffic["read_engines"] == "tpu" and cell.traffic["proofs"] == {"pallas": ["q3"]}
     assert cell.deployment.scan_bytes("q3", cell.config) is None   # no roofline in this cell
     per_layer = {m["name"]: m for m in cell.metrics("per_layer")}
-    assert set(per_layer) == {"frontend_ms_per_op", "cop_host_ms_per_op", "launches_per_op", "programs_built_per_op",
+    assert set(per_layer) >= {"frontend_ms_per_op", "cop_host_ms_per_op", "launches_per_op", "programs_built_per_op",
                               "cop_cache_hits_per_op", "device_idle_pct", "cop_decode_ms_per_op"}
     assert per_layer["cop_decode_ms_per_op"]["layer"] == COP and per_layer["cop_decode_ms_per_op"]["moves"] == "op_p50_ms"
     assert {m["name"] for m in cell.metrics("end_to_end")} == {"ops_per_s", "op_p50_ms", "setup_s"}

@@ -12,7 +12,9 @@ from harness.catalog import BENCH_DIR
 from harness.traffic import Mix, client_rng
 
 MIXES = sorted(os.path.basename(p)[:-5] for p in glob.glob(os.path.join(BENCH_DIR, "traffic", "*.json")))
-CONFIG_OF = {"ro_uniform": "sysbench_32x16k", "q1q6_params": "tpch_sf0p02", "q3_params": "tpch_sf0p02"}
+CONFIG_OF = {"ro_uniform": "sysbench_32x16k", "q1q6_params": "tpch_sf0p02", "q3_params": "tpch_sf0p02",
+             "q1q6q3_params": "tpch_sf0p02_mesh4", "q18_params": "tpch_sf0p02_q18_mesh4",
+             "rw_uniform": "sysbench_32x16k_rw"}
 
 
 def _mix(name: str) -> Mix:
@@ -72,3 +74,40 @@ def test_tpch_parameters_stay_inside_the_specs_ranges():
     assert seen["date"] == {f"{y}-01-01" for y in range(1993, 1998)}
     assert seen["discount"] == {f"0.0{d}" for d in range(2, 10)} and seen["quantity"] == {24, 25}
     assert len(seen["segment"]) == 5 and seen["q3date"] == {f"1995-03-{d:02d}" for d in range(1, 32)}
+
+
+def test_sysbench_read_write_draws_as_oltp_common_does():
+    mix = _mix("rw_uniform")
+    assert mix.restart_on == {1205, 1213, 9007}
+    rng = client_rng(2**31 + 3, 5, 1)
+    tables, ks = set(), set()
+    for _ in range(300):
+        steps = mix.operation(rng)
+        names = [s.name for s in steps]
+        assert names == ([None] + ["point_select"] * 10 + ["simple_range", "sum_range", "order_range",
+                                                            "distinct_range", "index_update", "non_index_update",
+                                                            "delete", "insert"] + [None])
+        assert [s.sql for s in steps if s.name is None] == ["begin", "commit"]   # 20 statements: 14 reads, 4 writes, 2 other
+        update, non_index, delete, insert = steps[15:19]
+        assert len(non_index.params["c"]) == 119 and non_index.params["c"].count("-") == 9
+        assert (delete.params["t"], delete.params["id"]) == (insert.params["t"], insert.params["id"])  # one draw
+        assert len(insert.params["c"]) == 119 and len(insert.params["pad"]) == 59 and insert.params["pad"].count("-") == 4
+        assert insert.sql == (f"insert into sbtest{insert.params['t']} (id, k, c, pad) values "
+                              f"({insert.params['id']}, {insert.params['k']}, '{insert.params['c']}', '{insert.params['pad']}')")
+        for s in (update, non_index, delete):
+            tables.add(s.params["t"])
+            assert 1 <= s.params["id"] <= 16384
+        ks.add(insert.params["k"])
+    assert tables == set(range(1, 33)) and min(ks) >= 1 and max(ks) <= 16384 and max(ks) > 16000
+
+
+def test_sb_string_is_the_deployments_rule():
+    """The mix's `sb_string` and the load's `_groups` make the same strings
+    from the same stream."""
+    from harness.catalog import load_module
+
+    dep = load_module(os.path.join(BENCH_DIR, "configs", "sysbench_32x16k", "deployment.py"), "sysbench_dep_t")
+    mix = Mix({"loop": "closed", "clients": 1, "operation": []}, {}, {})
+    a, b = client_rng(9, 0, 1), client_rng(9, 0, 1)
+    drawn = [mix._draw({"c": {"sb_string": 10}}, a)["c"] for _ in range(3)]
+    assert drawn == [dep._groups(b, 1, 10)[0] for _ in range(3)]
