@@ -107,8 +107,11 @@ def test_pessimistic_lock_conflict(pair):
     s1, s2 = pair
     s1.execute("BEGIN")
     s1.execute("UPDATE t SET v = 1 WHERE id = 2")
-    with pytest.raises(SQLError, match="locked"):
+    # the lock is waited for, here by the holder's own thread: the wait runs out
+    s2.execute("SET innodb_lock_wait_timeout = 1")
+    with pytest.raises(SQLError, match="Lock wait timeout exceeded") as ei:
         s2.execute("UPDATE t SET v = 2 WHERE id = 2")
+    assert ei.value.code == 1205
     s1.execute("COMMIT")
     s2.execute("UPDATE t SET v = 2 WHERE id = 2")
     assert s2.execute("SELECT v FROM t WHERE id = 2").values() == [[2]]
@@ -129,8 +132,10 @@ def test_select_for_update_locks(pair):
     s1, s2 = pair
     s1.execute("BEGIN")
     s1.execute("SELECT * FROM t WHERE id = 2 FOR UPDATE")
-    with pytest.raises(SQLError):
+    s2.execute("SET innodb_lock_wait_timeout = 1")
+    with pytest.raises(SQLError) as ei:
         s2.execute("DELETE FROM t WHERE id = 2")
+    assert ei.value.code == 1205
     s1.execute("ROLLBACK")
     s2.execute("DELETE FROM t WHERE id = 2")
     assert s2.execute("SELECT count(*) FROM t").values() == [[1]]

@@ -146,7 +146,8 @@ class Connection:
                 res = self.session.execute(stmt_sql)
             except (SQLError, PlanError, CatalogError, ParseError) as exc:
                 # typed statement errors carry their MySQL errno (9005
-                # region-unavailable, 3024/1317 killed); the rest are 1105
+                # region-unavailable, 3024/1317 killed, 9007 write conflict,
+                # 1205 lock wait timeout, 1213 deadlock); the rest are 1105
                 self.io.write(P.err_packet(getattr(exc, "code", 1105), str(exc)))
                 return
             except Exception as exc:  # noqa: BLE001 — wire must answer
@@ -265,6 +266,7 @@ class MySQLServer:
         except (ConnectionError, OSError):
             pass
         finally:
+            conn.session.close()  # a transaction left open lets go of its locks
             try:
                 sock.close()
             except OSError:

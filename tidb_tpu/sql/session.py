@@ -52,6 +52,7 @@ class TxnState:
     index_muts: dict = field(default_factory=dict)  # index-key subset of mutations
     named_savepoints: dict = field(default_factory=dict)  # SAVEPOINT name -> snapshot
     schema_ver: int = -1  # catalog version at txn start (DDL fencing)
+    for_update_ts: int = 0  # the newest ts a pessimistic statement read its rows at, under their locks
 
     def savepoint(self):
         """Statement-level snapshot: a failed statement inside an explicit
@@ -200,6 +201,9 @@ def _sql_str_escape(s: str) -> str:
     out of the literal (ADVICE r5 low — the CREATE/DROP USER mirror SQL).
     Backslashes must double FIRST, then quotes."""
     return s.replace("\\", "\\\\").replace("'", "''")
+
+
+_PESSIMISTIC_RETRIES = 256  # re-runs of one pessimistic statement (TiDB's pessimistic-txn.max-retry-count)
 
 
 class SQLError(ValueError):
@@ -552,10 +556,15 @@ class Session:
             # TSO monotonicity then guarantees no reader can hold a
             # read_ts >= commit_ts before the apply completes
             if self._coalesce_commit(txn) is None:
-                self.store.txn.commit_txn(txn.mutations, txn.start_ts, self.store.next_ts)
+                # a pessimistic txn's index entries are bounded by its newest
+                # current read (`TxnEngine.prewrite`); for_update_ts stays 0
+                # in the other modes
+                self.store.txn.commit_txn(
+                    txn.mutations, txn.start_ts, self.store.next_ts,
+                    conflict_ts=max(txn.start_ts, txn.for_update_ts), wait_s=self._lock_wait_s())
         except TxnError as exc:
             self.store.txn.release_all(txn.start_ts)
-            raise SQLError(str(exc)) from exc
+            raise self._txn_error(exc, txn.start_ts) from exc
         except QuorumLostError:
             # a quorum-lost region refused the commit before anything
             # applied: drop the locks and let execute() map it to 9005
@@ -595,6 +604,12 @@ class Session:
             max_lanes=self.sysvars.get_int("tidb_tpu_coalesce_max_lanes"),
         )
 
+    def close(self) -> None:
+        """The session's connection closed: an open transaction rolls back
+        and lets go of its locks, so that no other transaction waits on
+        them."""
+        self._rollback()
+
     def _rollback(self):
         txn, self.txn = self.txn, None
         if txn is not None:
@@ -617,7 +632,8 @@ class Session:
             try:
                 return fn()
             except Exception:
-                self.txn.restore(sp)
+                if self.txn is not None:  # a deadlock rolled the whole transaction back
+                    self.txn.restore(sp)
                 raise
         self._begin(explicit=False)
         try:
@@ -637,32 +653,105 @@ class Session:
         if self.txn is not None:
             self._commit()
 
-    def _lock_rows(self, meta: TableMeta, handles):
-        """Pessimistic intention locks at DML/SELECT-FOR-UPDATE time
-        (explicit pessimistic txns only; autocommit statements commit
-        immediately so prewrite conflict checks suffice). Partitioned
+    def _pessimistic(self) -> bool:
+        """DML locks its rows and reads them at a for_update_ts (explicit
+        pessimistic txns only; autocommit statements commit immediately so
+        prewrite conflict checks suffice)."""
+        return self.txn is not None and self.txn.explicit and self.txn.mode == "pessimistic"
+
+    def _lock_wait_s(self) -> float:
+        return float(self.sysvars.get_int("innodb_lock_wait_timeout"))
+
+    def _txn_error(self, exc, start_ts: int) -> SQLError:
+        """The error a transaction engine failure answers, with TiDB's
+        errno: 9007 a write conflict, 1205 a lock wait that timed out, 1213
+        a deadlock (the whole transaction rolls back, so the other side of
+        the cycle proceeds); anything else 1105."""
+        from ..store.txn import Deadlock, KeyIsLocked, WriteConflict
+        from ..util import metrics
+
+        if isinstance(exc, WriteConflict):
+            metrics.TXN_WRITE_CONFLICTS.inc()
+            return SQLError(f"Write conflict, txnStartTS={start_ts}, conflictCommitTS={exc.conflict_ts} "
+                            "[try again later]", code=9007)
+        if isinstance(exc, Deadlock):
+            self._rollback()
+            return SQLError("Deadlock found when trying to get lock; try restarting transaction", code=1213)
+        if isinstance(exc, KeyIsLocked):
+            return SQLError("Lock wait timeout exceeded; try restarting transaction", code=1205)
+        return SQLError(str(exc))
+
+    def _acquire_rows(self, meta: TableMeta, handles, for_update_ts: int) -> int:
+        """Pessimistic intention locks on `handles`' row keys, waiting up
+        to innodb_lock_wait_timeout for another transaction's.  Partitioned
         tables lock the handle's key in EVERY partition — over-locking is
-        sound, and the row's partition is value-dependent."""
+        sound, and the row's partition is value-dependent.  Returns the
+        newest commit above `for_update_ts` among them (0: none); the locks
+        are held either way."""
         from ..store.txn import TxnError
 
-        if self.txn is None or not self.txn.explicit or self.txn.mode != "pessimistic":
-            return
+        if not self._pessimistic():
+            return 0
         keys = [
             tablecodec.encode_row_key(pid, h)
             for h in handles
             for pid in meta.physical_ids()
         ]
         if not keys:
-            return
-        # conflict bound = the txn's snapshot ts: a commit that landed after
-        # our snapshot means this statement computed against stale rows —
-        # fail with a retryable conflict instead of losing the update.
-        # (TiDB instead re-reads at for_update_ts; stricter is still sound.)
+            return 0
+        start_ts = self.txn.start_ts
         try:
-            self.store.txn.acquire_pessimistic(keys, keys[0], self.txn.start_ts, self.txn.start_ts)
+            newer = self.store.txn.acquire_pessimistic(keys, keys[0], start_ts, for_update_ts,
+                                                       wait_s=self._lock_wait_s())
         except TxnError as exc:
-            raise SQLError(str(exc)) from exc
+            raise self._txn_error(exc, start_ts) from exc
         self.txn.locked |= set(keys)
+        return newer
+
+    def _lock_rows(self, meta: TableMeta, handles, for_update_ts: int | None = None):
+        """Locks for rows read at `for_update_ts` (default the snapshot):
+        a commit that landed on one of them after it means the statement
+        computed against stale rows, answered with a write conflict."""
+        from ..store.txn import WriteConflict
+
+        if not self._pessimistic():
+            return
+        ts = self.txn.start_ts if for_update_ts is None else for_update_ts
+        newer = self._acquire_rows(meta, handles, ts)
+        if newer:
+            raise self._txn_error(WriteConflict(b"", newer, ts), self.txn.start_ts)
+
+    def _current_read(self, meta: TableMeta, scan) -> tuple:
+        """-> (rows, ts): the rows a DML statement changes, `scan(ts)` ->
+        [(handle, row)].  In a pessimistic transaction a current read (ref:
+        TiDB's pessimistic DML): the rows are read at a for_update_ts drawn
+        now and locked; where one was committed after that ts (its lock's
+        holder committed while this statement waited), the statement's
+        scan runs again at a newer ts under the locks it holds, at most
+        `_PESSIMISTIC_RETRIES` times, each a `txn.retry` span and counted in
+        TXN_PESSIMISTIC_RETRIES.  SELECTs keep the snapshot.  Outside one:
+        the snapshot, and no lock (prewrite checks conflicts)."""
+        from ..store.txn import WriteConflict
+        from ..util import metrics, tracing
+
+        txn = self.txn
+        if not self._pessimistic():
+            return scan(txn.start_ts), txn.start_ts
+        ts = self.store.next_ts()
+        rows = scan(ts)
+        newer = self._acquire_rows(meta, [h for h, _ in rows], ts)
+        retries = 0
+        while newer:
+            if retries == _PESSIMISTIC_RETRIES:
+                raise self._txn_error(WriteConflict(b"", newer, ts), txn.start_ts)
+            retries += 1
+            metrics.TXN_PESSIMISTIC_RETRIES.inc()
+            ts = self.store.next_ts()
+            with tracing.span("txn.retry", for_update_ts=ts):
+                rows = scan(ts)
+                newer = self._acquire_rows(meta, [h for h, _ in rows], ts)
+        txn.for_update_ts = max(txn.for_update_ts, ts)
+        return rows, ts
 
     # ------------------------------------------------- buffered write path
     # row_ops stays keyed by the LOGICAL table id (handles are unique
@@ -2741,6 +2830,10 @@ class Session:
                 if meta.handle_col is not None:
                     i = [c.name for c in meta.columns].index(meta.handle_col)
                     datums[i] = Datum.i64(handle)
+            if self._pessimistic():
+                # a pessimistic INSERT locks its row first and checks for a
+                # duplicate at a for_update_ts drawn after the lock
+                _, ts = self._current_read(meta, lambda _ts, h=handle: [(h, None)])
             exists = self._read_row(meta, handle, ts) is not None
             if exists:
                 # duplicate primary key (ref: ER_DUP_ENTRY / REPLACE / IGNORE)
@@ -2758,7 +2851,7 @@ class Session:
                 raise SQLError(f"duplicate entry for unique key {conflict[1].name!r}")
             while conflict is not None:
                 c_handle, _c_idx = conflict
-                self._lock_rows(meta, [c_handle])
+                self._lock_rows(meta, [c_handle], ts)
                 old_row = self._read_row(meta, c_handle, ts)
                 if old_row is not None:
                     self._write_indexes(meta, old_row, c_handle, delete=True)
@@ -2766,7 +2859,6 @@ class Session:
                     self.txn.row_delta[meta.table_id] = self.txn.row_delta.get(meta.table_id, 0) - 1
                     n += 1  # MySQL counts each replaced row
                 conflict = self._find_unique_conflict(meta, datums, handle, ts)
-            self._lock_rows(meta, [handle])
             if exists and stmt.replace and meta.indices:
                 # REPLACE drops the old row's index entries; the old row is
                 # fetched by its known key (no table scan)
@@ -2869,7 +2961,7 @@ class Session:
                     f"cannot delete or update a parent row: a foreign key "
                     f"constraint fails ({child.name}.{fk.name})"
                 )
-            self._lock_rows(child, [h for h, _ in matched])
+            self._lock_rows(child, [h for h, _ in matched], ts)
             if fk.on_delete == "cascade":
                 n += self._fk_on_parent_delete(child, [r for _, r in matched], ts, depth + 1)
                 for handle, row in matched:
@@ -2916,7 +3008,7 @@ class Session:
                     f"cannot delete or update a parent row: a foreign key "
                     f"constraint fails ({child.name}.{fk.name})"
                 )
-            self._lock_rows(child, [h for h, _ in matched])
+            self._lock_rows(child, [h for h, _ in matched], ts)
             for handle, row in matched:
                 nrow = list(row)
                 for ci, pc in zip(fk.cols, fk.ref_cols):
@@ -3069,9 +3161,8 @@ class Session:
         if not isinstance(stmt.table, A.TableName):
             raise SQLError("multi-table UPDATE not supported")
         meta = self.catalog.table(stmt.table.name)
-        ts = self.txn.start_ts
-        matched = self._scan_rows_with_handles(meta, stmt.where, ts, stmt.order_by, stmt.limit)
-        self._lock_rows(meta, [h for h, _ in matched])
+        matched, ts = self._current_read(
+            meta, lambda ts: self._scan_rows_with_handles(meta, stmt.where, ts, stmt.order_by, stmt.limit))
         scope = _Scope([_TableRef(meta, meta.name.rsplit(".", 1)[-1], 0)])
         lw = _Lowerer(scope)
         col_pos = {c.name: i for i, c in enumerate(meta.columns)}
@@ -3110,7 +3201,7 @@ class Session:
                 # PK change moves the row to a new key (ref: updateRecord's
                 # remove+add when the handle changes)
                 self._buf_delete_row(meta, handle, row)
-                self._lock_rows(meta, [new_handle])
+                self._lock_rows(meta, [new_handle], ts)
             elif meta.partition is not None and meta.pid_for_row(row) != meta.pid_for_row(new_row):
                 # partition-column change moves the row across partitions
                 # (MySQL row movement): drop the old physical key
@@ -3124,9 +3215,8 @@ class Session:
         if stmt.multi_table:
             raise SQLError("multi-table DELETE is not supported yet")
         meta = self.catalog.table(stmt.table.name)
-        ts = self.txn.start_ts
-        matched = self._scan_rows_with_handles(meta, stmt.where, ts, stmt.order_by, stmt.limit)
-        self._lock_rows(meta, [h for h, _ in matched])
+        matched, ts = self._current_read(
+            meta, lambda ts: self._scan_rows_with_handles(meta, stmt.where, ts, stmt.order_by, stmt.limit))
         self._fk_on_parent_delete(meta, [r for _, r in matched], ts)
         for handle, row in matched:
             self._buf_delete_row(meta, handle, row)

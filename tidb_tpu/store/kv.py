@@ -13,14 +13,20 @@ import bisect
 import threading
 from dataclasses import dataclass, field
 
+# New keys up to this many are inserted one by one into the sorted key list
+# (a bisection and a move of the list's tail each); more are merged in one
+# pass over the list, which costs about as much as this many insertions.
+_INSORT_KEYS = 256
+
 
 class MemKV:
-    __slots__ = ("_data", "_keys", "_dirty", "lock", "max_version")
+    __slots__ = ("_data", "_keys", "_new", "_dirty", "lock", "max_version")
 
     def __init__(self):
         self._data: dict[bytes, list[tuple[int, bytes | None]]] = {}  # guarded_by: lock
         self._keys: list[bytes] = []  # guarded_by: lock
-        self._dirty = False  # guarded_by: lock
+        self._new: list[bytes] = []  # keys put since `_keys` was sorted; guarded_by: lock
+        self._dirty = False  # a key left `_data` since `_keys` was sorted; guarded_by: lock
         # largest commit_ts ever written: a snapshot at start_ts >=
         # max_version sees EVERY committed version, which is what makes a
         # coprocessor response reusable across snapshots (store cop cache)
@@ -40,7 +46,7 @@ class MemKV:
             prev_live = bool(versions) and versions[-1][1] is not None
             if versions is None:
                 self._data[key] = [(ts, value)]
-                self._dirty = True
+                self._new.append(key)
             else:
                 versions.append((ts, value))
                 if len(versions) > 1 and versions[-2][0] > ts:
@@ -52,7 +58,21 @@ class MemKV:
     def _ensure_sorted(self):  # requires: lock
         if self._dirty:
             self._keys = sorted(self._data.keys())
+            self._new.clear()
             self._dirty = False
+        elif self._new:
+            # a commit's few new keys are put in place, not every key sorted
+            # again: at a million keys that sort held this lock, and the
+            # interpreter, for tenths of a second after every commit that
+            # added a key, and every read of the store waited on it
+            if len(self._new) <= _INSORT_KEYS:
+                for k in self._new:
+                    bisect.insort(self._keys, k)
+            else:  # two sorted runs: the list's sort is one merge
+                self._new.sort()
+                self._keys += self._new
+                self._keys.sort()
+            self._new.clear()
 
     def get(self, key: bytes, ts: int) -> bytes | None:
         with self.lock:

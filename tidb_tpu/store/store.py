@@ -33,6 +33,22 @@ from .kv import MemKV
 from .region import Cluster, Region
 
 
+# The least capacity a region's batch is uploaded at: a batch is sized at
+# its rows' power of two, and a program is compiled per capacity, so without
+# a floor a range that runs off its table's end (fewer rows than it asked
+# for) met rung 64, 32, 16, ... and compiled it where it was served.
+# sysbench's ranges of 100 rows are on rung 128 already, and every table of
+# the TPC-H cells has more rows than this.  A join's build side keeps its own
+# power of two: the join kernels choose their strategy by the two sides'
+# capacities.
+MIN_BATCH_ROWS = 128
+
+
+def batch_rung(rows: int) -> int:
+    """The capacity a region batch of `rows` rows is uploaded at."""
+    return _pow2(max(rows, MIN_BATCH_ROWS))
+
+
 @dataclass(frozen=True)
 class KeyRange:
     """(ref: coprocessor.KeyRange)."""
@@ -452,13 +468,18 @@ class TPUStore:
         # every key of the three caches embeds the old write version, so
         # entries can never serve stale data — the clear just frees dead
         # weight: host bytes, HBM, and places in the LRU windows
-        from ..util import failpoint
+        from ..util import failpoint, tracing
 
         failpoint.eval("store/before-bump-write-ver")  # the commit's rows are in the kv, the version is the old one
-        with self._cop_lock:
-            self._write_ver += 1
-            dead = self._drop_version_caches()
-        del dead  # outside the lock
+        with tracing.span("store.cache_drop") as sp:
+            with self._cop_lock:
+                self._write_ver += 1
+                entries = len(self._chunk_cache) + len(self._cop_cache) + len(self._batch_cache)
+                device_bytes = self._batch_cache.used
+                dead = self._drop_version_caches()
+            del dead  # outside the lock: the batches' HBM is returned here
+            if sp is not None:
+                sp.attrs.update(entries=entries, device_bytes=device_bytes)
 
     def _snapshot_write_ver(self) -> int:
         """Locked read of the store write version — the pre-read snapshot
@@ -657,7 +678,7 @@ class TPUStore:
             if ch is None:
                 ch = decoded = self._decode_region(region, ranges, scan, start_ts)
             if device:
-                batch = uploaded = to_device_batch(ch, capacity=_pow2(max(ch.num_rows(), 1)))
+                batch = uploaded = to_device_batch(ch, capacity=batch_rung(ch.num_rows()))
         finally:  # a chunk whose upload raised is the oracle fall-back's input: filed too
             self._file_decoded(ver, what, start_ts, decoded, uploaded)
         return ch, batch, False
@@ -1116,7 +1137,7 @@ class TPUStore:
                         region, req.ranges, dag, req.start_ts, req.paging_size
                     )
                     in_bytes, in_rows = page.nbytes(), page.num_rows()
-                    batch, hit = to_device_batch(page, capacity=_pow2(max(page.num_rows(), 1))), False
+                    batch, hit = to_device_batch(page, capacity=batch_rung(page.num_rows())), False
                 else:
                     rc, batch, hit = self._region_read(region, req.ranges, dag, req.start_ts, device=True)
                     in_bytes, in_rows = rc.nbytes(), rc.num_rows()

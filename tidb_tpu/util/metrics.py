@@ -450,6 +450,20 @@ COALESCE_WINDOW_WAIT = REGISTRY.histogram(
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.05),
 )
 OPEN_TXNS = REGISTRY.gauge("tidb_tpu_open_txns", "transactions currently open")
+# the pessimistic write path (store/txn.py, sql/session.py): lock waits,
+# their ends, and the statements that re-read at a newer for_update_ts
+TXN_LOCK_WAITS = REGISTRY.counter(
+    "tidb_tpu_txn_lock_waits_total", "lock acquires and prewrites that waited for another transaction's lock")
+TXN_LOCK_WAIT_NS = REGISTRY.counter(
+    "tidb_tpu_txn_lock_wait_ns_total", "wall ns spent waiting for other transactions' locks")
+TXN_LOCK_WAIT_TIMEOUTS = REGISTRY.counter(
+    "tidb_tpu_txn_lock_wait_timeouts_total", "lock waits that ran past innodb_lock_wait_timeout (errno 1205)")
+TXN_DEADLOCKS = REGISTRY.counter(
+    "tidb_tpu_txn_deadlocks_total", "lock waits refused because they would close a wait-for cycle (errno 1213)")
+TXN_WRITE_CONFLICTS = REGISTRY.counter(
+    "tidb_tpu_txn_write_conflicts_total", "statements answered with a write conflict (errno 9007)")
+TXN_PESSIMISTIC_RETRIES = REGISTRY.counter(
+    "tidb_tpu_txn_pessimistic_retries_total", "pessimistic DML statements re-run at a newer for_update_ts")
 NATIVE_DECODES = REGISTRY.counter("tidb_tpu_native_decode_batches_total", "region batches decoded by the C++ rowcodec")
 NATIVE_DECODE_FALLBACKS = REGISTRY.counter("tidb_tpu_native_decode_fallbacks_total", "native decode errors served by the python decoder")
 
