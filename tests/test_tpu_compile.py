@@ -1,5 +1,6 @@
 """Deviceless TPU compiles: the four Pallas kernels of the served path at
-one real shape each, plus the fused Q6 program, compiled for a described
+one real shape each, plus the fused Q6 program and an upload's unpack of a
+65,536-row batch, compiled for a described
 (not attached) v5e chip.  Interpret mode cannot see what the Mosaic
 lowering refuses (block shapes, SMEM/VMEM budgets, 64-bit index
 arithmetic); this does, at no chip time.  Nothing runs, so nothing here
@@ -112,9 +113,39 @@ def _q6(shape):
     return build_program(dag, ROWS, group_capacity=16).fn, (batch, *operands)
 
 
+def _upload_unpack(shape):
+    """The unpack of an upload's buffer (exec/launch.py) for a 65,536-row
+    batch of lineitem's sixteen columns: every int64 column, mask and
+    length under 1 MiB rides in the buffer.  Without a barrier on each
+    leaf's slice this compile took 522 s for the described v5e."""
+    import math
+
+    import numpy as np
+
+    from tidb_tpu.exec.launch import _unpack_program
+
+    n = 1 << 16
+    leaves = []
+    for c in range(16):
+        if c in (8, 9):   # char(1)
+            leaves += [((n, 1), np.uint8), ((n,), np.bool_), ((n,), np.int32)]
+        elif c == 14:     # char(10), seven bytes wide at most
+            leaves += [((n, 7), np.uint8), ((n,), np.bool_), ((n,), np.int32)]
+        elif c in (13, 15):  # the wide strings ride beside the buffer
+            leaves += [((n,), np.bool_), ((n,), np.int32)]
+        else:
+            leaves += [((n,), np.int64), ((n,), np.bool_)]
+    leaves += [((n,), np.bool_), ((), np.int32)]
+    layout, offset = [], 0
+    for shp, dt in leaves:
+        layout.append((shp, np.dtype(dt), offset))
+        offset += -(-math.prod(shp) * np.dtype(dt).itemsize // 8) * 8
+    return _unpack_program(tuple(layout))[0], (shape((offset,), np.uint8),)
+
+
 @pytest.mark.parametrize("case,pallas_calls", [
-    (_dense, 1), (_postsort, 1), (_membership, 1), (_probe, 1), (_q6, 0),
-], ids=["dense_pallas", "postsort_segscan", "membership_segscan", "probe_tables_pallas", "q6_program"])
+    (_dense, 1), (_postsort, 1), (_membership, 1), (_probe, 1), (_q6, 0), (_upload_unpack, 0),
+], ids=["dense_pallas", "postsort_segscan", "membership_segscan", "probe_tables_pallas", "q6_program", "upload_unpack"])
 def test_compiles_for_v5e(case, pallas_calls, one_chip, monkeypatch):
     # the env value, not the backend, routes to the Mosaic lowering here:
     # jax.default_backend() is "cpu" during a deviceless compile

@@ -19,7 +19,7 @@ Type mapping onto device dtypes:
 
 Reference seam: these batches are what the unistore coprocessor decodes rows
 into (ref: cophandler/mpp_exec.go:110-244 tableScanExec -> chunk.Chunk); we
-decode straight to numpy then ship whole columns to HBM in one transfer.
+decode straight to numpy then ship the whole batch to HBM in one transfer.
 """
 
 from __future__ import annotations
@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..types import FieldType, TypeCode
+from ..util import metrics
 from .chunk import Chunk
 from .column import Column, numpy_dtype_for
 
@@ -121,11 +122,18 @@ def host_column_arrays(col: Column, capacity: int, str_width: int | None = None)
 
 
 def _put(host: "DeviceBatch", sharding) -> "DeviceBatch":
-    """A batch of host arrays onto the device: leaf by leaf onto the default
-    device, or, given a `sharding`, the whole tree in one `jax.device_put`,
-    each device receiving its own part from the host."""
+    """A batch of host arrays onto the device in one host-to-device call:
+    onto the default device as one byte buffer and the leaves that ride
+    beside it, cut back into leaves there (`exec/launch.py` `upload`), or,
+    given a `sharding`, the whole tree in one `jax.device_put`, each device
+    receiving its own part from the host."""
+    from ..exec import launch  # the exec package imports this module
+
+    metrics.COP_UPLOADS.inc()
     if sharding is None:
-        return jax.tree.map(jnp.asarray, host)
+        leaves, treedef = jax.tree.flatten(host)
+        return treedef.unflatten(launch.upload(leaves))
+    launch.count_transfer()
     return jax.device_put(host, sharding)
 
 
@@ -140,7 +148,7 @@ def to_device_batch(chunk: Chunk, capacity: int | None = None, str_widths: dict[
         cols.append(DeviceColumn(*host_column_arrays(col, cap, w), col.ft))
     row_valid = np.zeros(cap, bool)
     row_valid[:n] = True
-    return _put(DeviceBatch(cols, row_valid, np.int32(n)), sharding)
+    return _put(DeviceBatch(cols, row_valid, np.array(n, np.int32)), sharding)
 
 
 def shared_str_widths(chunks: list[Chunk]) -> dict[int, int]:

@@ -1049,13 +1049,16 @@ class ProgramCache:
                                   mesh_root=mesh_root),
             batch_size=vmap_batch, mesh_lanes=mesh_lanes)
 
-    def built(self, key: tuple, build, **attrs) -> tuple:
+    def built(self, key: tuple, build, traced: bool = True, **attrs) -> tuple:
         """(program, cache_hit, compile_ns) of `key`, `build()` being called
         by the one thread that finds it missing: the counted, single-flight
         half of `get_info`, which every compiled program of the engine goes
-        through — the cop programs above and the exchange programs of
-        `mpp/exchange_op.py`, whose key is the plan's shape too.  `attrs`
-        that are not None go onto the `exec.program` span of a miss."""
+        through — the cop programs above, the exchange programs of
+        `mpp/exchange_op.py`, whose key is the plan's shape too, and the
+        uploads' unpack functions (`exec/launch.py`), which are no
+        statement's program and open no `exec.program` span (`traced`
+        False).  `attrs` that are not None go onto the span of a miss."""
+        import contextlib
         import threading
         import time as _t
 
@@ -1067,19 +1070,22 @@ class ProgramCache:
                 with self._stats_mu:
                     self.hits += 1
                 metrics.PROGRAM_CACHE_HITS.inc()
-                with tracing.span("exec.program", cache_hit=True):
-                    pass
+                if traced:
+                    with tracing.span("exec.program", cache_hit=True):
+                        pass
                 return prog, True, 0
             with self._stats_mu:
                 ev = self._inflight.get(key)
-                if ev is None:
+                # a key filed between the look-up above and this lock is a hit
+                if ev is None and key not in self._cache:
                     self._inflight[key] = threading.Event()
                     break  # this thread owns the compile
             # another thread is compiling this key: wait, then re-read the
             # cache (if its compile raised, the next waiter claims the key)
-            ev.wait()
+            if ev is not None:
+                ev.wait()
         try:
-            with tracing.span("exec.program", cache_hit=False) as sp:
+            with tracing.span("exec.program", cache_hit=False) if traced else contextlib.nullcontext() as sp:
                 with self._stats_mu:
                     self.compiles += 1
                 metrics.PROGRAM_COMPILES.inc()

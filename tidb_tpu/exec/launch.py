@@ -40,6 +40,17 @@ the thread that compiles, so a thread-local slot that `run_program` fills
 for the length of the call tells the listener whether a compile belongs
 to a program or to an eager `jnp` operation outside any program
 (`exec.eager_compile`, under whatever span is ambient where it ran).
+
+The way in mirrors the way out: `upload` puts a batch's host leaves onto
+the default device in one host-to-device call.  Every leaf that would be
+laid into a launch's buffer (`rides_beside` is the one rule for both
+directions) is copied into one 8-byte aligned `uint8` buffer on the host;
+the buffer and the leaves beside it go in one `jax.device_put` of one
+list, and one jitted function per layout (`upload_unpack`, kept in
+`UNPACKS`, a `ProgramCache`, so a new layout counts as a program built)
+slices and bit-casts the buffer back into the leaves.  The unpack is no
+launch: it reads nothing back and does not go through `run_program`, and
+only a call in which it compiles is a span (`exec.compile`).
 """
 
 from __future__ import annotations
@@ -157,6 +168,14 @@ class FirstCallGate:
 _BESIDE_BYTES = 1 << 20
 
 
+def rides_beside(shape, dtype) -> bool:
+    """Whether a leaf stays an array of its own beside the byte buffer, in
+    a launch's read-back and in an upload alike: floats, because the TPU
+    keeps no IEEE float64 to bit-cast, and leaves of `_BESIDE_BYTES` and
+    more."""
+    return jnp.issubdtype(dtype, jnp.floating) or math.prod(shape) * np.dtype(dtype).itemsize >= _BESIDE_BYTES
+
+
 class HostOutputs:
     """What the host reads of a program, made one array by the program.
 
@@ -183,11 +202,11 @@ class HostOutputs:
             leaves, treedef = jax.tree.flatten(out if reads is None else reads(out))
             parts, own, layout, offset = [], [], [], 0
             for a in leaves:
-                nbytes = math.prod(a.shape) * a.dtype.itemsize
-                if jnp.issubdtype(a.dtype, jnp.floating) or nbytes >= _BESIDE_BYTES:
+                if rides_beside(a.shape, a.dtype):
                     layout.append((a.shape, np.dtype(a.dtype), None, len(own)))
                     own.append(a)
                     continue
+                nbytes = math.prod(a.shape) * a.dtype.itemsize
                 layout.append((a.shape, np.dtype(a.dtype), offset, offset + nbytes))
                 parts.append(_as_bytes(a))
                 pad = -nbytes % 8
@@ -281,7 +300,6 @@ def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, ga
     of an old program for a new argument shape is an `exec.compile` too."""
     fn = outputs.fn
     program = getattr(fn, "__name__", type(fn).__name__)
-    heard = _calling.heard = _Heard()
     metrics.PROGRAM_LAUNCHES.inc()
     # one constant a slot; a string takes a row of the bytes and a length
     n_strs = sum(len(o) for o in operands if o.ndim == 2)
@@ -290,11 +308,27 @@ def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, ga
         metrics.PROGRAM_PARAMS_BOUND.inc(n_params)
     if n_strs:
         metrics.PROGRAM_STR_PARAMS_BOUND.inc(n_strs)
-    args = (*args, *operands)
-    call = tracing.span("exec.compile" if first_call else "exec.launch", program=program, params=n_params)
+    returned, call = _call(fn, (*args, *operands), gate, first_call, program=program, params=n_params)
+    wait = tracing.span("exec.wait")
+    with wait:
+        fetch = Fetch(returned)
+        out = returned.read()
+    # PROGRAM_WAIT_NS is the clock's; Top SQL's ledger gets the same wait
+    note_launch(wait.wall_ns)
+    return out, fetch, (call.wall_ns + wait.wall_ns if first_call else 0)
+
+
+def _call(fn, args, gate: FirstCallGate | None, first_call: bool, **attrs) -> tuple:
+    """(what `fn(*args)` returned, its span): the call as the state
+    `exec.compile` where JAX traced, lowered or compiled during it, else
+    `exec.launch`.  `first_call` names the state on the profiler's clock
+    before the call; the span, the clock and the counters go by what was
+    heard."""
+    heard = _calling.heard = _Heard()
+    call = tracing.span("exec.compile" if first_call else "exec.launch", **attrs)
     try:
         with call as sp:
-            returned = fn(*args) if gate is None else gate.call(fn, args)
+            out = fn(*args) if gate is None else gate.call(fn, args)
             # the state by what was heard, on the clock as in the span
             call.rename("exec.compile" if heard.compiled else "exec.launch")
             if sp is not None and heard.compiled:
@@ -304,13 +338,7 @@ def run_program(outputs: HostOutputs, args, operands=(), *, first_call: bool, ga
     if heard.compiled:
         metrics.XLA_TRACE_LOWER_NS.inc(max(call.wall_ns - heard.xla_ns, 0))
         metrics.PROGRAM_COMPILE_DURATION.observe(call.wall_ns / 1e9)
-    wait = tracing.span("exec.wait")
-    with wait:
-        fetch = Fetch(returned)
-        out = returned.read()
-    # PROGRAM_WAIT_NS is the clock's; Top SQL's ledger gets the same wait
-    note_launch(wait.wall_ns)
-    return out, fetch, (call.wall_ns + wait.wall_ns if first_call else 0)
+    return out, call
 
 
 def _describe(sp: tracing.Span, heard: _Heard) -> None:
@@ -359,3 +387,84 @@ class read_back:
             self._sp.set("bytes", self.bytes)
             self._sp.set("late", self.late)
         return self._span.__exit__(exc_type, exc, tb)
+
+
+_sent = threading.local()  # .transfers: host-to-device calls of this thread's uploads
+
+
+def count_transfer() -> None:
+    """One host-to-device call of an upload: `COP_UPLOAD_TRANSFERS`, and
+    this thread's count (`upload_transfers`)."""
+    metrics.COP_UPLOAD_TRANSFERS.inc()
+    _sent.transfers = getattr(_sent, "transfers", 0) + 1
+
+
+def upload_transfers() -> int:
+    """Host-to-device calls that uploads on this thread have made: read
+    before and after a piece of work, the difference is its own."""
+    return getattr(_sent, "transfers", 0)
+
+
+UNPACKS = None  # the ProgramCache of the uploads' unpack functions, by layout: made at the first upload (the cache's module imports this one)
+_UNPACKS_MU = threading.Lock()  # makes UNPACKS once
+
+
+def upload(leaves: list) -> list:
+    """Host arrays onto the default device in one host-to-device call,
+    as device arrays of the same shapes and dtypes, uncommitted as
+    `jnp.asarray` makes them.  The leaves that `rides_beside` keeps apart
+    go as they are; the others are laid into one `uint8` buffer at 8-byte
+    aligned offsets, in order, and the buffer comes apart on the device in
+    the layout's `upload_unpack`."""
+    beside = [rides_beside(a.shape, a.dtype) for a in leaves]
+    packed = [a for a, b in zip(leaves, beside) if not b]
+    layout, offset = [], 0
+    for a in packed:
+        layout.append((a.shape, a.dtype, offset))
+        offset += -(-a.nbytes // 8) * 8
+    buf = np.zeros(offset, np.uint8)
+    for (_shape, _dtype, start), a in zip(layout, packed):
+        buf[start: start + a.nbytes] = a.reshape(-1).view(np.uint8)
+    count_transfer()
+    buf, *own = jax.device_put([buf, *(a for a, b in zip(leaves, beside) if b)])
+    layout = tuple(layout)
+    (fn, gate), _hit, _ns = _unpacks().built(("upload_unpack", layout), lambda: _unpack_program(layout), traced=False)
+    out = fn(buf) if gate.done else _call(fn, (buf,), gate, True, program=fn.__name__)[0]
+    own, out = iter(own), iter(out)
+    return [next(own) if b else next(out) for b in beside]
+
+
+def _unpacks():
+    global UNPACKS
+    if UNPACKS is None:
+        with _UNPACKS_MU:
+            if UNPACKS is None:
+                from .builder import ProgramCache
+
+                UNPACKS = ProgramCache()
+    return UNPACKS
+
+
+def _unpack_program(layout: tuple) -> tuple:
+    """(the jitted function that cuts an upload's buffer back into its
+    leaves, per `layout`'s (shape, dtype, start); its `FirstCallGate`)."""
+    metrics.COP_UPLOAD_PROGRAMS.inc()
+
+    def upload_unpack(buf):
+        # each leaf's slice behind a barrier: fused, the slices of a large
+        # buffer take the TPU compiler minutes (a 65,536-row batch of sixteen
+        # columns compiled for a v5e in 522 s, and in 1.2 s with barriers)
+        return tuple(_from_bytes(jax.lax.optimization_barrier(buf[start: start + math.prod(shape) * dtype.itemsize]), shape, dtype)
+                     for shape, dtype, start in layout)
+
+    return jax.jit(upload_unpack), FirstCallGate()
+
+
+def _from_bytes(b, shape, dtype):
+    """Flat `uint8` in the host's byte order as an array of `shape` and
+    `dtype`: `_as_bytes` undone."""
+    if dtype == np.bool_:
+        return b.astype(jnp.bool_).reshape(shape)
+    if dtype.itemsize == 1:
+        return jax.lax.bitcast_convert_type(b, dtype).reshape(shape)
+    return jax.lax.bitcast_convert_type(b.reshape(-1, dtype.itemsize), dtype).reshape(shape)

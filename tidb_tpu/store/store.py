@@ -28,6 +28,7 @@ from ..codec.rowcodec import RowEncoder, decode_row_to_datum_map, fill_origin_de
 from ..exec.builder import DEFAULT_GROUP_CAPACITY, ProgramCache
 from ..exec.dag import DAGRequest
 from ..exec.executor import OverflowRetryError, drive_batched_program_info, drive_program_info, run_dag_reference, _pow2
+from ..exec.launch import upload_transfers
 from ..types import Datum
 from .kv import MemKV
 from .region import Cluster, Region
@@ -1124,6 +1125,7 @@ class TPUStore:
         info = {"cache_hit": False, "compile_ns": 0}
         try:
             with tracing.span("cop.decode", region_id=req.region_id) as dsp:
+                sent = upload_transfers()
                 if req.paging_size is not None:
                     from ..exec.dag import Aggregation as _Agg, Limit as _Limit, Sort as _Sort, TopN as _TopN
 
@@ -1149,6 +1151,7 @@ class TPUStore:
                     dsp.set("bytes_to_device", in_bytes)
                     dsp.set("rows", in_rows)
                     dsp.set("hit", hit)
+                    dsp.set("transfers", upload_transfers() - sent)
             batches = [batch] + self._aux_batches(dag, req.aux_chunks)
             with tracing.span("cop.execute", region_id=req.region_id, root_fused=fused) as xsp:
                 chunk, ex_rows, info = drive_program_info(self.programs, dag, batches, group_capacity,

@@ -280,6 +280,9 @@ COP_CACHE_HITS = REGISTRY.counter("tidb_tpu_cop_cache_hits_total", "cop requests
 COP_DECODE_HITS = REGISTRY.counter("tidb_tpu_cop_decode_hits_total", "region reads that found everything they needed resident: the decoded chunk and, where one was asked for, its device batch")
 COP_DECODE_MISSES = REGISTRY.counter("tidb_tpu_cop_decode_misses_total", "region reads that scanned and decoded the region, or uploaded its batch again")
 COP_DECODE_EVICTIONS = REGISTRY.counter("tidb_tpu_cop_decode_evictions_total", "decoded chunks and device batches that left the store's decode caches for their byte budgets")
+COP_UPLOADS = REGISTRY.counter("tidb_tpu_cop_uploads_total", "batches of host arrays put onto the device (chunk/device.py _put)")
+COP_UPLOAD_TRANSFERS = REGISTRY.counter("tidb_tpu_cop_upload_transfers_total", "host-to-device calls those uploads made: one a batch")
+COP_UPLOAD_PROGRAMS = REGISTRY.counter("tidb_tpu_cop_upload_programs_total", "uploads' unpack functions built, one a batch layout first seen (in PROGRAM_COMPILES too)")
 COP_DECODE_DEVICE_BYTES = REGISTRY.gauge("tidb_tpu_cop_decode_device_bytes", "bytes of region batches the stores' decode caches hold on the device")
 BATCH_COP_BATCHES = REGISTRY.counter("tidb_tpu_batch_cop_batches_total", "vmapped multi-region coprocessor launches")
 BATCH_COP_REGIONS = REGISTRY.counter("tidb_tpu_batch_cop_regions_total", "regions served by batched coprocessor launches")
