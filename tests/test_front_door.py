@@ -175,11 +175,17 @@ class TestPlanCacheMatrix:
             d0 = declines(reason)
             s.execute(sql)
             assert declines(reason) == d0 + 1, reason
-        # session-state reasons: open txn + stale read
+        # session-state reasons: a transaction's buffered writes + stale
+        # read; a clean transaction is served like autocommit
+        s.execute("select v from t where id = 1")  # install
         s.execute("begin")
-        d0 = declines("in_txn")
-        s.execute("select v from t where id = 1")
-        assert declines("in_txn") == d0 + 1
+        d0, h0 = declines("in_txn"), hits()
+        s.execute("select v from t where id = 2")
+        assert declines("in_txn") == d0 and hits() == h0 + 1
+        s.execute("update t set k = 'z' where id = 7")
+        d0 = declines("txn_dirty")
+        s.execute("select v from t where id = 3")
+        assert declines("txn_dirty") == d0 + 1
         s.execute("commit")
         ts = s.store.kv.max_committed()
         s.execute(f"set tidb_snapshot = '{ts}'")

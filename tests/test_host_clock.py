@@ -256,9 +256,11 @@ def test_sixteen_threads_charge_what_their_bottoms_measured():
 
 # ------------------------------------------------- plain and traced alike
 def test_a_plain_select_and_the_same_under_trace_charge_the_same_states(sess):
-    sess.execute("SELECT SUM(k) FROM hc WHERE id BETWEEN 1 AND 100")   # the shape's program is built
-    sess.execute("BEGIN")   # as sysbench does: no parse-free serve inside a transaction
-    with Charged() as plain:
+    sess.execute("SET tidb_enable_plan_cache = OFF")
+    sess.execute("SELECT SUM(k) FROM hc WHERE id BETWEEN 1 AND 100")   # the shape's program is built, no plan cached
+    sess.execute("SET tidb_enable_plan_cache = ON")
+    sess.execute("BEGIN")
+    with Charged() as plain:   # a miss inside a clean transaction: parsed, planned and installed
         sess.execute("SELECT SUM(k) FROM hc WHERE id BETWEEN 2 AND 101")
     with Charged() as under_trace:
         sess.execute("TRACE FORMAT='json' SELECT SUM(k) FROM hc WHERE id BETWEEN 3 AND 102")
@@ -267,8 +269,8 @@ def test_a_plain_select_and_the_same_under_trace_charge_the_same_states(sess):
     assert set(plain.wall) == set(under_trace.wall) == {
         "session.probe", "session.parse", "session.plan_cache", "planner.plan", "cop.decode",
         "exec.launch", "exec.wait", "exec.readback", "session.rows"}
-    # outside a transaction the plain statement is served parse-free; TRACE always parses
-    sess.execute("SELECT SUM(k) FROM hc WHERE id BETWEEN 4 AND 103")   # installs the plan
+    # the plain statement is served parse-free, here from the entry the transaction installed; TRACE always parses
+    sess.execute("SELECT SUM(k) FROM hc WHERE id BETWEEN 4 AND 103")
     with Charged() as plain:
         sess.execute("SELECT SUM(k) FROM hc WHERE id BETWEEN 5 AND 104")
     with Charged() as under_trace:

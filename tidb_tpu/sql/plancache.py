@@ -318,7 +318,7 @@ DECLINE_REASONS = (
     "not_select", "ddl", "set_opr", "multi_statement", "user_var",
     "in_txn", "stale_read", "for_update", "cte", "subquery",
     "derived_table", "view", "memtable", "no_table", "literal_shape",
-    "positional_ref", "uncacheable", "disabled", "dml_shape",
+    "positional_ref", "uncacheable", "disabled", "dml_shape", "txn_dirty",
 )
 
 _DDL_KINDS = (
@@ -345,14 +345,16 @@ def stmt_kind_reason(stmt) -> str | None:
 
 def shape_decline(stmt, session, probe: StmtProbe) -> str | None:
     """Typed reason this SELECT cannot be cached, or None. Session-state
-    reasons (txn, stale read) are re-checked per statement; structural
-    reasons transfer to every digest-equal statement."""
+    reasons (stale read, a transaction's buffered writes) are re-checked
+    per statement; structural reasons transfer to every digest-equal
+    statement. A transaction with no buffered writes to the statement's
+    tables installs and hits as autocommit does: the plan does not
+    depend on the transaction, and its reads take the snapshot at
+    execution."""
     if probe.multi_stmt:
         return "multi_statement"
     if probe.has_var:
         return "user_var"
-    if session.txn is not None:
-        return "in_txn"
     if session.sysvars.get("tidb_snapshot"):
         return "stale_read"
     if stmt.for_update:
@@ -403,6 +405,7 @@ def shape_decline(stmt, session, probe: StmtProbe) -> str | None:
             tables(n.right)
 
     tables(stmt.from_clause)
+    table_ids = []
     for t in names:
         eff_db = (t.db or session.db or "").lower()
         if eff_db in ("information_schema", "performance_schema"):
@@ -410,10 +413,20 @@ def shape_decline(stmt, session, probe: StmtProbe) -> str | None:
         if session.catalog.view_of(t.name) is not None:
             return "view"
         try:
-            session.catalog.table(t.name)
+            table_ids.append(session.catalog.table(t.name).table_id)
         except Exception:  # noqa: BLE001 — unknown table: let the planner error
             return "uncacheable"
+    if txn_dirty(session.txn, table_ids):
+        return "txn_dirty"
     return None
+
+
+def txn_dirty(txn, table_ids) -> bool:
+    """Has the open transaction buffered row writes to any of these
+    tables? Its reads of such a table overlay the buffered rows
+    (the parse path's UnionScan analog, session._shadow_dirty_tables),
+    which no cached plan holds."""
+    return txn is not None and any(txn.row_ops.get(t) for t in table_ids)
 
 
 # --------------------------------------------------------------- table fps

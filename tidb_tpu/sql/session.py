@@ -1671,16 +1671,15 @@ class Session:
         values into the cached template — lexer-only, no parse, no plan.
         Returns None on any miss or ineligibility; the parse path then
         runs and counts its own miss/decline. Session-state declines
-        (txn, stale read) re-check here because they vary per statement;
-        structural shape was proven at install time and transfers to
-        every digest-equal statement."""
-        from ..util import metrics, tracing
+        (stale read, a transaction's buffered writes) re-check here
+        because they vary per statement; structural shape was proven at
+        install time and transfers to every digest-equal statement."""
+        from ..util import tracing
         from . import plancache as _pc
 
         if (probe is None or probe.has_param or probe.has_var
                 or probe.multi_stmt or probe.n_masked == 0
                 or not self.sysvars.get_bool("tidb_enable_plan_cache")
-                or self.txn is not None
                 or self.sysvars.get("tidb_snapshot")):
             # n_masked == 0 shapes stay on the parse path: binding cannot
             # distinguish them from DDL/EXPLAIN/SET text anyway, and the
@@ -1698,6 +1697,14 @@ class Session:
                 entry = self._plan_cache_shared_adopt(key)
             if entry is None:
                 return None
+            if self.txn is not None and (
+                    entry.tier == "pointwrite"
+                    or _pc.txn_dirty(self.txn, (fp[0] for fp in entry.table_fps.values()))):
+                # inside a transaction a point write takes its locks and
+                # its current read on the parse path, and a read of a
+                # table the transaction has written needs its buffered
+                # rows; the parse path counts the decline
+                return None
             if entry.tier != "pointwrite":
                 try:
                     self._check_privileges(entry.template)
@@ -1712,8 +1719,7 @@ class Session:
             # pk = ? serves parse-free through the same digest machinery
             return self._plan_cache_serve_dml(entry, probe)
         out = run()
-        metrics.PLAN_CACHE_HITS.inc()
-        self._last_plan_cache = ("hit", "", entry.tier)
+        self._count_plan_cache_hit(entry)
         self._stmt_probe = None  # consumed: nested paths never re-consult
         names, _fts, rows = out
         if not entry.has_limit:
@@ -1772,9 +1778,16 @@ class Session:
                 sp.set("tier", entry.tier)
         # the hit executes outside the state, as any planned statement does
         out = run()
-        metrics.PLAN_CACHE_HITS.inc()
-        self._last_plan_cache = ("hit", "", entry.tier)
+        self._count_plan_cache_hit(entry)
         return out, None
+
+    def _count_plan_cache_hit(self, entry) -> None:
+        from ..util import metrics
+
+        metrics.PLAN_CACHE_HITS.inc()
+        if self.txn is not None:  # a SELECT sees only an explicit one
+            metrics.PLAN_CACHE_TXN_HITS.inc()
+        self._last_plan_cache = ("hit", "", entry.tier)
 
     def _plan_cache_bind(self, entry, values):
         """The re-bind of a cached template: `run()` then serves the
@@ -1953,7 +1966,8 @@ class Session:
 
     def _dml_shape_decline(self, stmt) -> str | None:
         """Typed decline for non-point DML shapes (None = cacheable
-        point write). Mirrors shape_decline's session checks, then
+        point write). Any open transaction declines: pessimistic DML
+        takes its locks and its current read on the parse path. Then
         requires the WHERE clause to be a pure pk-equality the handle
         extractor accepts."""
         if self.txn is not None:

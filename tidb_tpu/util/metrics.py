@@ -407,6 +407,8 @@ STATEMENTS = REGISTRY.counter_vec(
 # production front door (ISSUE 15) — digest-keyed plan cache + admission
 PLAN_CACHE_HITS = REGISTRY.counter(
     "tidb_tpu_plan_cache_hits_total", "statements served from the digest-keyed plan cache (parse+plan skipped)")
+PLAN_CACHE_TXN_HITS = REGISTRY.counter(
+    "tidb_tpu_plan_cache_txn_hits_total", "plan-cache hits served inside an explicit transaction")
 PLAN_CACHE_MISSES = REGISTRY.counter(
     "tidb_tpu_plan_cache_misses_total", "cacheable statements that planned cold and installed an entry")
 PLAN_CACHE_EVICTIONS = REGISTRY.counter(
